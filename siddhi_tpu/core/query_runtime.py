@@ -136,15 +136,12 @@ class CompiledSingleChain:
 class _AuxWarnPool:
     """Deferred aux-flag checks with NO background thread.
 
-    The hot dispatch path never blocks on device scalars (and does no device
-    work at all — even eager coalesce ops cost seconds through a degraded
-    relay): submitted flags accumulate in a bounded host-side backlog, and
-    the one blocking device->host read happens only (a) in `flush()` and
-    (b) at most once per `drain_every_s` from a main-thread submit.
-    Transfers are pinned to the main thread on purpose: on some tunneled PJRT backends a
-    device->host read issued from a helper thread permanently degrades every
-    subsequent dispatch in the process (measured ~2.5 ms/call), so a daemon
-    drain thread would un-do the engine's own fast path.
+    The hot dispatch path never blocks on device scalars and does no device
+    work at all: submitted flags accumulate in a bounded host-side backlog,
+    and the one blocking device->host read happens only (a) in `flush()` and
+    (b) at most once per `drain_every_s`, from whichever thread submits
+    then. What that read costs a running pipeline on a directly attached
+    chip is unmeasured.
 
     Backlog entries hold weakrefs to the query runtime, so a shut-down app is
     collectable even if nobody flushes."""
@@ -162,25 +159,14 @@ class _AuxWarnPool:
         self._pending: dict = {}
         self._last_drain = _time.monotonic()
         # periodic-drain cadence; 0 or negative disables automatic drains
-        # (flush()/shutdown still drain) — benches that must keep the relay
-        # in its fast mode set SIDDHI_TPU_AUX_DRAIN_S=0
+        # (flush()/shutdown still drain) — benches that want no read inside
+        # a timed region set SIDDHI_TPU_AUX_DRAIN_S=0
         try:
             self.drain_every_s = float(
                 os.environ.get("SIDDHI_TPU_AUX_DRAIN_S", "5.0")
             )
         except ValueError:
             self.drain_every_s = 5.0
-
-    def _may_autodrain(self) -> bool:
-        if self.drain_every_s <= 0:
-            return False
-        if threading.current_thread() is threading.main_thread():
-            return True
-        # helper threads may drain only on backends where a non-main-thread
-        # transfer does not degrade dispatch (see class docstring)
-        from siddhi_tpu.utils.backend import transfer_degrades_dispatch
-
-        return not transfer_degrades_dispatch()
 
     def submit(self, qr, flags: dict) -> None:
         with self._lock:
@@ -196,24 +182,19 @@ class _AuxWarnPool:
             for k, v in flags.items():
                 vs = acc.setdefault(k, [])
                 vs.append(v)
-                # bound the backlog with NO device work (eager coalesce ops
-                # through a degraded relay cost seconds): keep the first
+                # bound the backlog with NO device work: keep the first
                 # COALESCE_AT flags (overflows usually start early) plus a
                 # ring of the most recent ones
                 if len(vs) > 2 * self.COALESCE_AT:
                     del vs[self.COALESCE_AT]
         import time as _time
 
-        if (
-            _time.monotonic() - self._last_drain > self.drain_every_s
-            and self._may_autodrain()
-        ):
+        if 0 < self.drain_every_s < _time.monotonic() - self._last_drain:
             self.flush()
 
     def flush(self) -> None:
         """Drain everything with ONE blocking device read for the whole
-        backlog (all runtimes, all flag kinds stacked into one vector).
-        Call from the main thread on transfer-sensitive backends."""
+        backlog (all runtimes, all flag kinds stacked into one vector)."""
         import time as _time
 
         import numpy as np
@@ -453,12 +434,9 @@ class BaseQueryRuntime:
 
     def _warn_aux(self, aux: dict) -> None:
         """Surface overflow flags WITHOUT stalling the dispatch pipeline:
-        flags accumulate (and periodically coalesce on-device) in the
-        process-wide `_AuxWarnPool`; the one blocking device read happens in
-        its periodic main-thread drain or in `flush_aux_warnings`. No helper
-        thread is involved — on some tunneled PJRT backends any device->host
-        read from a non-main thread permanently degrades every subsequent
-        dispatch in the process."""
+        flags accumulate in the process-wide `_AuxWarnPool`; the one
+        blocking device read happens in its periodic drain or in
+        `flush_aux_warnings`."""
         flags = {
             k: v
             for k, v in aux.items()

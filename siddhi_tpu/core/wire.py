@@ -544,6 +544,7 @@ def build_codec(schema, capacity: int, keep, narrow: dict):
         _bitcast_split,
     )
     from siddhi_tpu.core.types import null_value
+    from siddhi_tpu.ops.prefix import cumsum as prefix_cumsum
 
     narrow = {k: _normalize(v) for k, v in (narrow or {}).items()}
     cap = int(capacity)
@@ -680,7 +681,11 @@ def build_codec(schema, capacity: int, keep, narrow: dict):
             elif kind == "delta":
                 d_base = _bitcast_split(buf, o, 1, np.dtype(np.int64))[0]
                 d = _bitcast_split(buf, o + 8, cap, dt)
-                vals = d_base + jnp.cumsum(d.astype(jnp.int64))
+                # ops.prefix.cumsum, not jnp.cumsum: the native int64
+                # lowering ran out of scoped vmem on the v5e at B=32768
+                # (RESOURCE_EXHAUSTED in reduce-window, PR 21); integer
+                # adds are exact under the blocked scan's reassociation
+                vals = d_base + prefix_cumsum(d.astype(jnp.int64))
                 cols_out[name] = vals.astype(jnp.dtype(wide))
             elif kind == "bitpack":
                 nb = -(-cap // 8)

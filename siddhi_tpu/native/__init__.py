@@ -10,6 +10,7 @@ fall back to the pure-Python queue path transparently.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -35,13 +36,20 @@ def load_ring_library() -> Optional[ctypes.CDLL]:
         if _LIB is not None or _LIB_FAILED:
             return _LIB
         src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "ring.cpp")
-        out = os.path.join(_build_dir(), "libsiddhi_ring.so")
         try:
-            if not os.path.exists(out) or os.path.getmtime(out) < os.path.getmtime(src):
+            # the binary is named by the source it was built from: _build/
+            # is git-ignored, so a copied tree can carry a binary of some
+            # other ring.cpp, and an mtime says nothing about that
+            with open(src, "rb") as f:
+                digest = hashlib.sha256(f.read()).hexdigest()[:16]
+            out = os.path.join(_build_dir(), f"libsiddhi_ring_{digest}.so")
+            if not os.path.exists(out):
+                tmp = f"{out}.{os.getpid()}.tmp"
                 subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", out, src],
+                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", tmp, src],
                     check=True, capture_output=True,
                 )
+                os.replace(tmp, out)  # never expose a half-written binary
             lib = ctypes.CDLL(out)
         except Exception:
             _LIB_FAILED = True

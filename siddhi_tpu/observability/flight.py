@@ -23,10 +23,8 @@ Cost when ENABLED: the fused send_columns path records from the host-side
 wire columns (free), but the per-batch publish path must read the device
 batch back (`np.asarray` per lane) — one d2h sync per publish. That is the
 price of the black box: negligible on CPU, a real per-batch readback on
-accelerators, and on transfer-degraded relay backends
-(utils/backend.transfer_degrades_dispatch) the first such read permanently
-slows dispatch — there, prefer arming only ingress streams fed by
-columnar sends, or accept the relay's synchronous mode while debugging.
+accelerators (its cost on a directly attached chip is unmeasured) — there,
+prefer arming only ingress streams fed by columnar sends.
 """
 
 from __future__ import annotations

@@ -13,34 +13,11 @@ The hooks are PULL-only: nothing is collected, sampled, or scheduled until
 a caller asks, so the hot dispatch path cost of the whole subsystem is
 zero. Reads that touch device state (window fills, table occupancy, NFA
 token pulls) do one host transfer per component — an on-demand operator
-action, not a steady cost. EXCEPT on transfer-degraded relay backends
-(utils/backend.transfer_degrades_dispatch), where the FIRST device->host
-read from any thread permanently degrades every later dispatch: there the
-device-touching fields degrade to None (`device_reads_ok()`), and an
-operator who accepts the cost opts back in with
-SIDDHI_TPU_STATUS_DEVICE=1.
+action, not a steady cost. A field degrades to None only when a concurrent
+donated-state dispatch deleted the buffers under the read.
 """
 
 from __future__ import annotations
-
-import os
-
-
-def device_reads_ok() -> bool:
-    """May an introspection pull read device state back to the host?
-
-    False only on transfer-degraded relay backends (where one d2h read
-    permanently poisons dispatch latency) without the explicit
-    SIDDHI_TPU_STATUS_DEVICE=1 opt-in. The component describe_state()
-    implementations consult this and report None for device-derived fields
-    (window fill, table rows, NFA instance counts, aggregation buckets)
-    instead of paying the read.
-    """
-    if os.environ.get("SIDDHI_TPU_STATUS_DEVICE", "").strip() == "1":
-        return True
-    from siddhi_tpu.utils.backend import transfer_degrades_dispatch
-
-    return not transfer_degrades_dispatch()
 
 
 def _fmt(v) -> str:

@@ -52,9 +52,9 @@ Opt-in with `@app:lineage(capacity='N', mode='full|sample')`. Three layers:
 
 Costs: zero when off — one `is None` / attribute check per hot-path site,
 the same contract as statistics/tracing/flight. When ON, each observed
-step pays one device→host read of its small `__lin.*` lanes (documented:
-on transfer-degraded relay backends this is the flight-recorder caveat
-again), and host memory is bounded by `capacity` per arena / recorder ring
+step pays one device→host read of its small `__lin.*` lanes (the
+flight-recorder caveat again: a blocking read per observed step), and host
+memory is bounded by `capacity` per arena / recorder ring
 with oldest-first eviction.
 
 Known degradations (recorded as `approx` on the affected records instead

@@ -144,10 +144,9 @@ class SelfMonitor:
                 out.append((
                     f"stream.{sid}", "pipeline_occupancy", ps.occupancy(), 0.0
                 ))
-        # window fill is a device->host read; describe_state() itself skips
-        # it (fill=None) on transfer-degraded relays, where a scheduler-
-        # thread d2h would permanently degrade dispatch — see
-        # observability/introspect.device_reads_ok
+        # window fill is a device->host read from the scheduler thread;
+        # describe_state() reports fill=None when a concurrent donated-state
+        # dispatch deleted the buffers under it
         for wid, nw in list(rt.named_windows.items()):
             d = nw.describe_state()
             if d.get("fill") is not None:

@@ -197,7 +197,6 @@ class KeyShardedGroupExec:
         from siddhi_tpu.core.event import EventBatch, KIND_CURRENT
         from siddhi_tpu.core.flow import Flow
         from siddhi_tpu.observability.lineage import LIN
-        from siddhi_tpu.parallel.mesh import shard_map_unchecked
 
         qr = self.qr
         D = self.n
@@ -294,11 +293,12 @@ class KeyShardedGroupExec:
                 aux_out,
             )
 
-        fn = shard_map_unchecked(
+        fn = jax.shard_map(
             local,
-            self.mesh,
-            (P(KEY_AXIS), P(), P()),
-            (P(KEY_AXIS), P(), P()),
+            mesh=self.mesh,
+            in_specs=(P(KEY_AXIS), P(), P()),
+            out_specs=(P(KEY_AXIS), P(), P()),
+            check_vma=False,
         )
         st2, out, aux = fn(state, batch, now)
         return st2, tstates, out, aux
@@ -308,9 +308,7 @@ class KeyShardedGroupExec:
     def describe_state(self) -> dict:
         """Per-device key occupancy and skew for /status.json, Prometheus
         (siddhi_keyshard_* families) and explain(). Device-derived fields
-        are omitted on transfer-degraded backends (introspect contract)."""
-        from siddhi_tpu.observability.introspect import device_reads_ok
-
+        are omitted until the query has state."""
         qr = self.qr
         g = qr.selector.group.capacity
         d: dict = {
@@ -319,7 +317,7 @@ class KeyShardedGroupExec:
             "axis": KEY_AXIS,
             "group_capacity": g,
         }
-        if qr.state is None or not device_reads_ok():
+        if qr.state is None:
             return d
         import jax
 

@@ -28,34 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def import_shard_map():
-    """Version-tolerant `shard_map` import: newer jax exports it at the top
-    level (`jax.shard_map`), older releases keep it under
-    `jax.experimental.shard_map`. The seed carried an ImportError here for
-    releases without the top-level export."""
-    try:
-        from jax import shard_map as sm  # jax >= 0.6-ish
-    except ImportError:  # pragma: no cover - depends on installed jax
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
-def shard_map_unchecked(fn, mesh, in_specs, out_specs):
-    """`shard_map` with replication checking disabled, tolerant of the
-    `check_rep` (old) -> `check_vma` (new) kwarg rename."""
-    sm = import_shard_map()
-    try:
-        return sm(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:
-        return sm(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-
-
 @dataclasses.dataclass
 class ShardedPartitionedQuery:
     """A partitioned query whose [P] state axis lives across a device mesh."""
@@ -255,11 +227,14 @@ def _make_routed_step(qr, mesh, axis: str, n_dev: int):
                 )
             return states2, outs, aux_red
 
-        local_sharded = shard_map_unchecked(
+        local_sharded = jax.shard_map(
             local,
-            mesh,
-            (P(axis), P(axis), P(axis), P(axis), P(axis), P(axis), P()),
-            (P(axis), P(axis), P()),
+            mesh=mesh,
+            in_specs=(
+                P(axis), P(axis), P(axis), P(axis), P(axis), P(axis), P()
+            ),
+            out_specs=(P(axis), P(axis), P()),
+            check_vma=False,
         )
         states2, outs, aux = local_sharded(
             states, r_ts, r_kind, r_valid, r_cols, r_slot, now

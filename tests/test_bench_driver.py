@@ -45,13 +45,14 @@ def _last_json_line(text: str) -> dict:
 class TestBenchDriverExitPaths:
     def test_deadline_skips_all_legs_and_emits_final_json(self):
         """--deadline smaller than the 60 s per-leg floor: every leg is
-        skipped, the driver exits 0, and the final line is valid JSON with
-        the skip reasons recorded."""
+        skipped, the final line is valid JSON with the skip reasons
+        recorded, and the driver exits non-zero (a skipped leg is not a
+        completed run)."""
         proc = subprocess.run(
             [sys.executable, BENCH, "--deadline", "5"],
             capture_output=True, text=True, timeout=120, env=_env(),
         )
-        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert proc.returncode == 1, proc.stderr[-2000:]
         got = _last_json_line(proc.stdout)
         assert got["metric"] == "engine_throughput_geomean"
         failed = got["detail"].get("failed_legs", [])
@@ -70,7 +71,7 @@ class TestBenchDriverExitPaths:
             [sys.executable, BENCH],
             capture_output=True, text=True, timeout=120, env=env,
         )
-        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert proc.returncode == 1, proc.stderr[-2000:]
         got = _last_json_line(proc.stdout)
         assert got["metric"] == "engine_throughput_geomean"
         failed = got["detail"].get("failed_legs", [])
@@ -100,7 +101,7 @@ class TestBenchDriverExitPaths:
             [sys.executable, BENCH, "--deadline", "5"],
             capture_output=True, text=True, timeout=120, env=_env(),
         )
-        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert proc.returncode == 1, proc.stderr[-2000:]
         lines = [
             ln for ln in proc.stdout.strip().splitlines() if ln.strip()
         ]
@@ -131,6 +132,7 @@ class TestBenchDriverExitPaths:
                 proc.kill()
                 proc.communicate()
         got = _last_json_line(out)
+        assert proc.returncode != 0  # an interrupted run is not a pass
         assert got["metric"] == "engine_throughput_geomean"
         # the interrupted leg is recorded, not silently dropped
         failed = got["detail"].get("failed_legs", [])

@@ -62,6 +62,26 @@ class TestCodec:
             assert np.array_equal(np.asarray(b.cols[k]), v), k
         assert bool(np.asarray(b.valid).all())
 
+    def test_delta_decode_has_no_64bit_cumsum(self):
+        """XLA:TPU lowers a 64-bit cumsum to a reduce-window that ran out of
+        scoped vmem on the v5e at B=32768 (PR 21's chip smoke): the delta
+        lane must rebuild its values with the blocked scan, and still
+        round-trip exactly at a size that takes the blocked path."""
+        import jax
+
+        cap = 2048
+        ts, cols = _sample(cap)
+        # sym/vol of _sample outgrow their 16-row encodings at this size
+        enc = {k: ENC[k] for k in ("seq", "flag", "__tsd__")}
+        encode, decode, _total = SCHEMA.wire_codec(cap, None, enc)
+        buf, base = encode(ts, cols, cap)
+        text = str(jax.make_jaxpr(decode)(buf, np.int32(cap), base))
+        bad = [ln for ln in text.splitlines()
+               if "cumsum" in ln and "i64[" in ln]
+        assert not bad, bad
+        b = decode(buf, np.int32(cap), base)
+        assert np.array_equal(np.asarray(b.cols["seq"]), cols["seq"])
+
     def test_partial_batch(self):
         cap = 16
         ts, cols = _sample(cap)

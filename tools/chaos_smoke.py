@@ -172,10 +172,17 @@ def _read_jsonl(path):
     return out
 
 
+def _children_platform() -> str:
+    """This is a crash/restore parity check, not a device check: children
+    run on the CPU unless the caller's JAX_PLATFORMS chooses otherwise. The
+    result names the platform so a reader cannot take it for a chip run."""
+    return os.environ.get("JAX_PLATFORMS") or "cpu"
+
+
 def _spawn(d, events, resume=False, env_extra=None, churn=False):
     env = dict(os.environ)
     env.pop("SIDDHI_TPU_FAULTS", None)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = _children_platform()
     if env_extra:
         env.update(env_extra)
     cmd = [
@@ -330,6 +337,7 @@ def run_chaos(
 
     result = {
         "events": events,
+        "children_jax_platforms": _children_platform(),
         "killed_at": "splicing 2" if churn else kill_at,
         "checkpoints_after_kill": len(snaps),
         "stored_entries_before_resume": stored_before,

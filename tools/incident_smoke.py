@@ -18,6 +18,11 @@ sets (tier1.yml repeats the step across legs); the replay subprocess
 inherits the same env, so the parity holds per-leg AND the checksum is
 stable across legs. Exit 0 = pass.
 
+This is a parity check, not a device check, and it needs two processes:
+the live side and the replay child are BOTH pinned to JAX_PLATFORMS=cpu
+here, explicitly — a chip belongs to one process at a time, so a live
+side that took it would leave the replay child failing or hanging.
+
 With SMOKE_OUT_DIR=<dir> the live + replayed emission JSONs (and the
 bundle itself) land there for the `incident-replay` workflow artifact.
 """
@@ -37,6 +42,11 @@ sys.path.insert(
 
 
 def main() -> int:
+    # before anything imports jax; the replay child inherits it
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    print("incident smoke: live side and replay child pinned to "
+          "JAX_PLATFORMS=cpu (parity check, not a device run)")
+
     from siddhi_tpu import SiddhiManager
     from siddhi_tpu.observability.blackbox import (
         attach_emission_collector, emissions_checksum,

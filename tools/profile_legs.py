@@ -56,7 +56,7 @@ def profile_leg(name: str, batch=32768, reps=4):
 
     ev_per_chunk = K * bsz
 
-    # warm up + flip relay to truth mode
+    # warm up: compile, and complete one real readback
     def run_once(w):
         states = []
         for ep in fi.endpoints:
