@@ -45,6 +45,13 @@ from inside a device program: the probe must find them and they must work.
 Stage F — the declared wire encodings (dict gather, delta cumsum, bit
 unpack; texts from bench.py plus a BOOL lane) decode inside the chunk
 program, and what is delivered equals a NumPy filter of what was sent.
+Stage G — device->host reads off the main thread while the chip is busy:
+the periodic aux-flag drain must fire from an `@async` worker and from a
+user thread inside `send_columns` (a deliberately overflowing group table
+makes it observable: the engine's one ERROR must come from that thread,
+with nobody flushing), an explicit flush must run from a helper thread
+while the main thread is mid-send, and the rows must equal a NumPy
+reference throughout.
 """
 
 from __future__ import annotations
@@ -54,6 +61,7 @@ import json
 import logging
 import os
 import sys
+import threading
 import time
 
 import numpy as np
@@ -98,19 +106,25 @@ class _EngineLog(logging.Handler):
 
     def __init__(self):
         super().__init__(level=logging.DEBUG)
-        self.bad: list[str] = []
+        self.bad: list[tuple[str, int]] = []  # (text, emitting thread)
         self.hash_log: list[str] = []  # what `#log` stages printed
 
     def emit(self, record: logging.LogRecord) -> None:
         if record.levelno >= logging.WARNING or record.exc_info:
-            self.bad.append(f"{record.levelname} {record.name}: "
-                            f"{record.getMessage()}")
+            self.bad.append((f"{record.levelname} {record.name}: "
+                             f"{record.getMessage()}", record.thread))
         elif record.name.startswith("siddhi_tpu.log."):
             self.hash_log.append(record.getMessage())
 
+    def take(self, needle: str) -> list[int]:
+        """Remove the records whose text holds `needle`; their threads."""
+        hit = [t for text, t in self.bad if needle in text]
+        self.bad = [(text, t) for text, t in self.bad if needle not in text]
+        return hit
+
     def require_clean(self, stage: str) -> None:
         bad, self.bad = self.bad, []
-        check(not bad, f"stage {stage}: engine logged {bad}")
+        check(not bad, f"stage {stage}: engine logged {[b[0] for b in bad]}")
 
 
 # --------------------------------------------------------------------------
@@ -491,6 +505,9 @@ def stage_c(SiddhiManager, sizes: dict, seed: int, n_dev: int,
             "rows_delivered": len(rows_of[True]),
             "stage_wall_s": round(time.perf_counter() - t_stage, 1),
         }
+        if B != sizes["batch"]:
+            out[axis]["reduced"] = {
+                "batch": f"{B} of {sizes['batch']}, ROADMAP S7"}
         print(f"stage C/{axis} ok: {json.dumps(out[axis])}", flush=True)
     return out
 
@@ -659,6 +676,206 @@ def stage_f(SiddhiManager, sizes: dict, seed: int, log: _EngineLog) -> dict:
 
 
 # --------------------------------------------------------------------------
+# stage G
+# --------------------------------------------------------------------------
+
+OVERFLOW_ERROR = "group-by slot table overflowed"
+HOT_KEYS = 16
+
+
+def overflow_app(batch: int, async_ann: str) -> str:
+    return f"""
+    @app:statistics(reporter='none')
+    @app:batch(size='{batch}')
+    @app:groupCapacity(size='{HOT_KEYS}')
+    {async_ann}
+    define stream K (k long, v long);
+    @info(name='q') from K select k, count() as n, sum(v) as s
+    group by k insert into KOut;
+    """
+
+
+def make_overflow_data(seed: int, n: int) -> dict:
+    """The first HOT_KEYS rows claim every slot of the group table; after
+    them odd rows revisit those keys and each even row is a key seen once.
+    An overflowed key loses only its carry ACROSS micro-batches, so with one
+    row per cold key the exact per-key running count and sum are the right
+    answer wherever the micro-batch boundaries fall."""
+    i = np.arange(n, dtype=np.int64)
+    hot = (i < HOT_KEYS) | (i % 2 == 1)
+    return {
+        "ts": T0_MS + i,
+        "k": np.where(hot, i % HOT_KEYS, 10**6 + i),
+        "v": np.random.default_rng(seed).integers(0, 1000, n).astype(np.int64),
+    }
+
+
+def running_by_key(k: np.ndarray, v: np.ndarray) -> tuple:
+    """Per-key running (count, sum) in arrival order, plain NumPy."""
+    order = np.argsort(k, kind="stable")
+    ks, vs = k[order], v[order]
+    start = np.concatenate([[True], ks[1:] != ks[:-1]])
+    first = np.flatnonzero(start)[np.cumsum(start) - 1]  # own segment's head
+    c = np.cumsum(vs)
+    n = np.empty(len(k), np.int64)
+    s = np.empty(len(k), np.int64)
+    n[order] = np.arange(len(k)) - first + 1
+    s[order] = c - (c - vs)[first]
+    return n, s
+
+
+def _drain_period_s() -> float:
+    """The aux-flag pool's periodic-drain cadence, read as the engine reads
+    it when `siddhi_tpu` is imported (core/query_runtime.py); stage G has to
+    outwait it. Call it before `import bench`, which presets the variable
+    to 0 for its own leg processes."""
+    period = float(os.environ.get("SIDDHI_TPU_AUX_DRAIN_S", "5.0"))
+    check(period > 0, "SIDDHI_TPU_AUX_DRAIN_S <= 0 turns the periodic drain "
+          "off; stage G cannot run")
+    return period
+
+
+def _in_thread(fn) -> None:
+    """Run fn to its end on a fresh thread; its failure is the caller's."""
+    err = []
+
+    def body():
+        try:
+            fn()
+        except BaseException as e:  # re-raised on the caller's thread below
+            err.append(e)
+
+    t = threading.Thread(target=body, name="smoke-sender")
+    t.start()
+    t.join()
+    if err:
+        raise err[0]
+
+
+def _require_helper_thread_drain(leg: str, log: _EngineLog) -> None:
+    threads = log.take(OVERFLOW_ERROR)
+    check(len(threads) == 1,
+          f"G/{leg}: the overflow ERROR surfaced {len(threads)} times with "
+          "nobody flushing; the periodic drain owed exactly one")
+    check(threads[0] != threading.main_thread().ident,
+          f"G/{leg}: the periodic drain ran on the main thread")
+    log.require_clean(f"G/{leg}")
+
+
+def stage_g(SiddhiManager, sizes: dict, seed: int, period: float,
+            log: _EngineLog) -> dict:
+    out = {}
+
+    # ---- leg 1: per-batch path; the @async worker submits the flags, so
+    # every periodic drain of this leg is a blocking read on that worker
+    t_stage = time.perf_counter()
+    n = sizes["async_rows"]
+    data = make_overflow_data(seed, n)
+    want_n, want_s = running_by_key(data["k"], data["v"])
+    mgr = SiddhiManager()
+    rt = mgr.create_siddhi_app_runtime(
+        overflow_app(256, "@async(buffer.size='1024', workers='1')"))
+    rows = []
+    rt.add_callback("q", lambda ts, ins, rem: rows.extend(e.data for e in ins))
+    rt.start()
+    h = rt.get_input_handler("K")
+
+    def feed(lo, hi):
+        for i in range(lo, hi):
+            h.send((int(data["k"][i]), int(data["v"][i])))
+        deadline = time.monotonic() + 120
+        while len(rows) < hi and time.monotonic() < deadline:
+            time.sleep(0.02)
+        check(len(rows) == hi, f"G/async: {len(rows)} of {hi} rows delivered")
+
+    feed(0, n // 2)
+    time.sleep(period + 0.5)  # the next submit finds the drain overdue
+    feed(n // 2, n)
+    _require_helper_thread_drain("async", log)
+    check(rt.snapshot_status()["streams"]["K"]["async"]["native_ring"] is True,
+          "G/async: the stream fell back to the Python queue")
+    got = np.array(rows, dtype=np.int64).reshape(n, 3)
+    check(np.array_equal(got[:, 0], data["k"])
+          and np.array_equal(got[:, 1], want_n)
+          and np.array_equal(got[:, 2], want_s),
+          "G/async: delivered rows differ from the NumPy reference")
+    rt.shutdown()
+    mgr.shutdown()
+    out["async"] = {"rows": n, "drain_thread_is_main": False,
+                    "stage_wall_s": round(time.perf_counter() - t_stage, 1)}
+    print(f"stage G/async ok: {json.dumps(out['async'])}", flush=True)
+
+    # ---- leg 2: fused path. Sends 1 and 2 come from a user thread (the
+    # periodic drain fires inside its send_columns, beside the pipeline's
+    # own drain worker); during send 3, from the main thread, a helper
+    # thread flushes in a loop
+    t_stage = time.perf_counter()
+    B = sizes["batch"]
+    n_send = B * SEND_BATCHES
+    data = make_overflow_data(seed + 1, 3 * n_send)
+    want_n, want_s = running_by_key(data["k"], data["v"])
+    mgr = SiddhiManager()
+    rt = mgr.create_siddhi_app_runtime(overflow_app(B, ""))
+    got = []
+    rt.add_callback("q", lambda ts, ins, rem: got.append(
+        np.array([e.data for e in ins], dtype=np.int64).reshape(len(ins), 3)))
+    rt.start()
+    h = rt.get_input_handler("K")
+    qr = rt.queries["q"]
+    ledgers = []
+
+    def send(part):
+        lo, hi = part * n_send, (part + 1) * n_send
+        h.send_columns(data["ts"][lo:hi],
+                       {"k": data["k"][lo:hi], "v": data["v"][lo:hi]})
+        ledgers.append(_compile_ledger(rt))
+
+    _in_thread(lambda: send(0))
+    time.sleep(period + 0.5)
+    _in_thread(lambda: send(1))
+    _require_helper_thread_drain("fused", log)
+
+    sending = threading.Event()
+    sending.set()
+    flushes = [0]
+
+    def flusher():
+        while sending.is_set():
+            qr.flush_aux_warnings()
+            flushes[0] += 1
+            time.sleep(0.01)
+
+    t = threading.Thread(target=flusher, name="smoke-flusher")
+    t.start()
+    try:
+        send(2)
+    finally:
+        sending.clear()
+        t.join()
+    check(flushes[0] >= 1, "G/fused: no flush completed during the send")
+    log.require_clean("G/fused")
+    _require_fused(rt.snapshot_status(), "K", 3 * SEND_BATCHES // 32,
+                   rt.profile_report())
+    _require_warm_only("G/fused", ledgers)
+    got = np.concatenate(got)
+    check(got.shape == (3 * n_send, 3)
+          and np.array_equal(got[:, 0], data["k"])
+          and np.array_equal(got[:, 1], want_n)
+          and np.array_equal(got[:, 2], want_s),
+          "G/fused: delivered rows differ from the NumPy reference")
+    out["fused"] = {
+        "batch": B, "rows": 3 * n_send, "drain_thread_is_main": False,
+        "flushes_during_send": flushes[0],
+        "setup_compile_s": _compile_seconds(rt),
+        "stage_wall_s": round(time.perf_counter() - t_stage, 1),
+    }
+    rt.shutdown()
+    mgr.shutdown()
+    print(f"stage G/fused ok: {json.dumps(out['fused'])}", flush=True)
+    return out
+
+
+# --------------------------------------------------------------------------
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -690,6 +907,7 @@ def main(argv=None) -> int:
               f"--shard {args.shard} needs {args.shard} devices, "
               f"{device['count']} visible")
 
+    drain_period = _drain_period_s()
     from siddhi_tpu import SiddhiManager
     from siddhi_tpu.observability.profiler import jit_cache_size
     from siddhi_tpu.utils.backend import configure_compile_cache
@@ -721,7 +939,9 @@ def main(argv=None) -> int:
                   "B": stage_b(SiddhiManager, sizes, args.seed, log),
                   "D": stage_d(SiddhiManager, sizes, log),
                   "E": stage_e(SiddhiManager, log),
-                  "F": stage_f(SiddhiManager, sizes, args.seed, log)}
+                  "F": stage_f(SiddhiManager, sizes, args.seed, log),
+                  "G": stage_g(SiddhiManager, sizes, args.seed, drain_period,
+                               log)}
 
     print(json.dumps({
         "ok": True,

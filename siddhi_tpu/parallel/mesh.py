@@ -59,8 +59,12 @@ class ShardedPartitionedQuery:
         def count(valid):
             return lax.psum(valid.sum()[None], self.axis)
 
-        counted = shard_map_unchecked(
-            count, self.mesh, P(self.axis), P(None)
+        counted = jax.shard_map(
+            count,
+            mesh=self.mesh,
+            in_specs=P(self.axis),
+            out_specs=P(None),
+            check_vma=False,
         )
         return int(counted(outs.valid)[0])
 

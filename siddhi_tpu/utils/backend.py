@@ -26,7 +26,10 @@ def configure_compile_cache() -> str:
     Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and this
     sets no directory in code. Otherwise the cache is `<checkout>/.jax_cache`.
     The size and compile-time thresholds are dropped either way, so every
-    program is kept: a fresh machine pays each compile at most once.
+    program is kept: a fresh machine pays each compile at most once, and a
+    small program that many runtimes rebuild is compiled once even within a
+    cold run (tier-1 from an empty cache: 672 s and 677 s, against 855 s and
+    776 s with the former 0.3 s compile-time threshold; CPU, PR 21).
     Call before the first compile; calling again gives the same path.
     """
     import jax

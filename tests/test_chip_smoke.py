@@ -74,6 +74,10 @@ def test_dry_run_passes_every_stage_on_cpu():
         "pattern_2state", "count_sequence",
     }
     assert got["stages"]["D"]["native_ring"] is True
+    g = got["stages"]["G"]  # the periodic aux-flag drain ran off the main thread
+    assert g["async"]["drain_thread_is_main"] is False
+    assert g["fused"]["drain_thread_is_main"] is False
+    assert g["fused"]["flushes_during_send"] >= 1
 
 
 class TestCompileCacheLocation:

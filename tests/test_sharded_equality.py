@@ -70,6 +70,7 @@ def _run(qr, mgr, sharded: bool, feed):
         mesh = Mesh(np.asarray(jax.devices()[:8]), ("part",))
         sq = shard_partitioned_query(qr, mesh)
         step = sq.step
+        psums = []
     else:
         import jax.numpy as jnp
 
@@ -90,6 +91,8 @@ def _run(qr, mgr, sharded: bool, feed):
         batch = schema.to_batch_cols(ts, cols, mgr.interner, capacity=64)
         outs, _aux = step(batch, int(ts[-1]))
         v = np.asarray(outs.valid)
+        if sharded:
+            psums.append((sq.total_emitted(outs), int(v.sum())))
         ts_a = np.asarray(outs.ts)
         cols_a = {c: np.asarray(a) for c, a in outs.cols.items()}
         step_rows = sorted(
@@ -97,10 +100,11 @@ def _run(qr, mgr, sharded: bool, feed):
             for i in map(tuple, np.argwhere(v))
         )
         rows.append(step_rows)
-    return rows
+    return (rows, psums) if sharded else rows
 
 
-def test_sharded_matches_unsharded_over_key_churn():
+@pytest.fixture(scope="module")
+def runs():
     feed = _batches()
     mgr1, rt1, qr1 = _build()
     unsharded = _run(qr1, mgr1, sharded=False, feed=feed)
@@ -108,12 +112,26 @@ def test_sharded_matches_unsharded_over_key_churn():
     mgr1.shutdown()
 
     mgr2, rt2, qr2 = _build()
-    sharded = _run(qr2, mgr2, sharded=True, feed=feed)
+    sharded, psums = _run(qr2, mgr2, sharded=True, feed=feed)
     rt2.shutdown()
     mgr2.shutdown()
+    return feed, unsharded, sharded, psums
 
+
+def test_sharded_matches_unsharded_over_key_churn(runs):
+    feed, unsharded, sharded, _psums = runs
     assert len(unsharded) == len(sharded) == len(feed)
     n_rows = sum(len(r) for r in unsharded)
     assert n_rows > 1000, f"feed produced too few outputs ({n_rows}) to be meaningful"
     for i, (a, b) in enumerate(zip(unsharded, sharded)):
         assert a == b, f"step {i}: sharded output diverged"
+
+
+def test_total_emitted_psum_counts_every_valid_row(runs):
+    """`ShardedPartitionedQuery.total_emitted` (the explicit psum that
+    `__graft_entry__.dryrun_multichip` calls each step) agrees with a host
+    count of the valid lane on every step."""
+    _feed, _unsharded, _sharded, psums = runs
+    assert len(psums) == 60
+    assert sum(got for got, _ in psums) > 1000
+    assert all(got == want for got, want in psums), psums
