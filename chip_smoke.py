@@ -7,9 +7,12 @@
 One process, public entry points only (`SiddhiManager` -> `send_columns` ->
 query callback). Exits non-zero unless every stage passed; without
 `--dry-run` it also exits non-zero, before building anything, unless
-`jax.devices()[0].platform == "tpu"`. The last line of stdout is one JSON
-object. No number printed here is a speed claim: wall and compile seconds
-are set-up facts of this run.
+`jax.devices()[0].platform == "tpu"`. The last two lines of stdout are JSON
+objects: the run's summary (stages, cache, set-up seconds, `"claim": null`),
+then the result, `{"ok": true, "device": {"platform", "kind", "count"}}` with
+exactly those keys and the device as JAX reports it. A run that fails prints
+no result line. No number printed here is a speed claim: wall and compile
+seconds are set-up facts of this run.
 
 Stage A — the deployment. Smart-plug load aggregation, the shape the north
 star names. Provenance: `BASELINE.json` calls it "DEBS-2013 smart-grid"; as
@@ -944,8 +947,6 @@ def main(argv=None) -> int:
                                log)}
 
     print(json.dumps({
-        "ok": True,
-        "device": device,
         "dry_run": args.dry_run,
         "jax": jax.__version__,
         "seed": args.seed,
@@ -955,6 +956,8 @@ def main(argv=None) -> int:
         "stages": stages,
         "claim": None,
     }), flush=True)
+    # the result line: these keys and no others, last on stdout
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

@@ -29,14 +29,20 @@ def _run(args, cwd=ROOT, script=SMOKE, timeout=120):
     )
 
 
-def _result_line(stdout: str):
-    """The last stdout line as a result object, or None if it is not one."""
+def _json_line(stdout: str, which: int):
+    """stdout line `which` (-1 = last) as an object, or None if it is not one."""
     lines = [ln for ln in stdout.strip().splitlines() if ln.strip()]
     try:
-        got = json.loads(lines[-1]) if lines else None
+        got = json.loads(lines[which]) if len(lines) >= -which else None
     except ValueError:
         return None
-    return got if isinstance(got, dict) and "ok" in got else None
+    return got if isinstance(got, dict) else None
+
+
+def _result_line(stdout: str):
+    """The last stdout line as a result object, or None if it is not one."""
+    got = _json_line(stdout, -1)
+    return got if got is not None and "ok" in got else None
 
 
 def test_default_run_without_a_tpu_fails_before_building_anything():
@@ -62,9 +68,17 @@ def test_dry_run_passes_every_stage_on_cpu():
     proc = _run(["--dry-run"], timeout=900)
     assert proc.returncode == 0, proc.stderr[-4000:]
     assert "DRY RUN" in proc.stdout
-    got = _result_line(proc.stdout)
-    assert got is not None and got["ok"] is True and got["dry_run"] is True
-    assert got["device"]["platform"] == "cpu"
+    # the result line holds exactly these keys; what else the run has to
+    # say is in the summary line before it
+    result = _result_line(proc.stdout)
+    assert result is not None and set(result) == {"ok", "device"}
+    assert result["ok"] is True
+    assert set(result["device"]) == {"platform", "kind", "count"}
+    assert result["device"]["platform"] == "cpu"
+    assert isinstance(result["device"]["kind"], str)
+    assert type(result["device"]["count"]) is int
+    got = _json_line(proc.stdout, -2)
+    assert got is not None and got["dry_run"] is True
     assert list(got)[-1] == "claim" and got["claim"] is None
     a = got["stages"]["A"]
     assert a["rows_delivered"] > 0  # the NumPy comparison ran over these
