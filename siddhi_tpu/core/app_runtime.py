@@ -29,6 +29,7 @@ from siddhi_tpu.core.stream_junction import (
     StreamJunction,
     system_clock_ms,
 )
+from siddhi_tpu.observability.profiler import COMPILE_EVENTS, GC_SPANS
 from siddhi_tpu.query_api.annotation import find_annotation
 from siddhi_tpu.query_api.execution import (
     InsertIntoStream,
@@ -51,6 +52,7 @@ class SiddhiAppRuntime:
         self.name = app.name
         self.clock = system_clock_ms
         self._running = False
+        self._gc_spans = False  # holds observability.profiler.GC_SPANS
         self._lock = threading.RLock()
         self._debugger = None
 
@@ -1343,8 +1345,12 @@ class SiddhiAppRuntime:
 
                 qr.timer_targets[side] = fire
 
-    def _decode(self, schema: StreamSchema, batch: EventBatch):
-        return schema.from_batch(batch, self.interner)
+    def _decode(
+        self, schema: StreamSchema, batch: EventBatch, *stall_trackers, wf=None
+    ):
+        return schema.from_batch(
+            batch, self.interner, *stall_trackers, wf=wf
+        )
 
     def _maybe_schedule(self, qr: QueryRuntime, aux: dict) -> None:
         hnt = getattr(qr, "host_next_timer", None)
@@ -1736,6 +1742,8 @@ class SiddhiAppRuntime:
         churn = self.manager.churn_stats(self.name, create=False)
         if churn is not None:
             status["churn"] = churn.describe_state()
+        # process-wide, and served with or without @app:statistics
+        status["compile_events"] = COMPILE_EVENTS.snapshot()
         return status
 
     # ---- flight recorder (observability/flight.py) ------------------------
@@ -2027,6 +2035,10 @@ class SiddhiAppRuntime:
 
     def start(self) -> None:
         self._running = True
+        if not self._gc_spans:
+            # `siddhi:gc` spans while any app runs (one hook per process)
+            GC_SPANS.acquire()
+            self._gc_spans = True
         # @app:fuse(disable='true') / SIDDHI_TPU_FUSE=0 skips the fused
         # ingest engines entirely (see _build_fused_ingest)
         if self._fuse_enabled:
@@ -2153,6 +2165,9 @@ class SiddhiAppRuntime:
 
     def shutdown(self) -> None:
         self._running = False
+        if self._gc_spans:
+            GC_SPANS.release()
+            self._gc_spans = False
         if self._watermark is not None:
             # tail delivery FIRST: release every buffered row through the
             # still-live junctions and fire the timers the final watermark

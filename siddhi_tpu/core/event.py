@@ -24,6 +24,7 @@ from siddhi_tpu.core.types import (
     InternTable,
     null_value,
 )
+from siddhi_tpu.observability.profiler import stage
 
 # ComplexEvent.Type equivalents (reference: core/event/ComplexEvent.java:48-53).
 KIND_CURRENT = 0
@@ -439,27 +440,32 @@ class StreamSchema:
         return codec
 
     def from_batch(
-        self, batch: EventBatch, interner: InternTable
+        self, batch: EventBatch, interner: InternTable, *stall_trackers,
+        wf=None,
     ) -> list[tuple[int, int, tuple]]:
-        """Unpack valid rows to host `(timestamp, kind, data_tuple)` triples."""
+        """Unpack valid rows to host `(timestamp, kind, data_tuple)` triples:
+        the per-batch path's `readback` stage (the blocking read, recorded
+        into `stall_trackers` and the waterfall `wf`) and its `decode`."""
         # ONE device->host transfer for all lanes: a pytree device_get moves
         # one array per lane, each its own blocking round trip (see
         # d2h_codec). Host decode rides the vectorized
         # column_lists path (one compaction + bulk .tolist() per column).
         pack, unpack, _total = self.d2h_codec(batch.capacity)
-        buf = np.asarray(pack(batch))
-        ts, kind, valid, host_cols = unpack(buf)
-        idx = np.nonzero(valid)[0]
-        if idx.size == 0:
-            return []
-        return rows_from_arrays(
-            self,
-            ts[idx],
-            kind[idx],
-            {n: c[idx] for n, c in host_cols.items()},
-            idx.size,
-            interner,
-        )
+        with stage("readback", *stall_trackers, wf=wf):
+            buf = np.asarray(pack(batch))
+        with stage("decode"):
+            ts, kind, valid, host_cols = unpack(buf)
+            idx = np.nonzero(valid)[0]
+            if idx.size == 0:
+                return []
+            return rows_from_arrays(
+                self,
+                ts[idx],
+                kind[idx],
+                {n: c[idx] for n, c in host_cols.items()},
+                idx.size,
+                interner,
+            )
 
 
 def column_lists(schema, cols: dict, n: int, interner) -> list[list]:

@@ -33,6 +33,7 @@ delivery order are identical either way.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Callable, Optional
@@ -43,6 +44,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from siddhi_tpu.core.event import WireNarrowMisfit
+from siddhi_tpu.observability.profiler import (
+    CAUSE_DELIVER_SET,
+    CAUSE_FULL_WIDTH,
+    CAUSE_TAIL_K,
+    stage,
+)
 from siddhi_tpu.testing import faults as _faults
 
 
@@ -152,6 +159,9 @@ class FusedJunctionIngest:
         self._aliased = False
         # achieved-dispatch accounting (vs the plan's n*K -> 1 prediction)
         self.chunks_dispatched = 0
+        # the `chunk` id of the stage spans (observability/profiler.py):
+        # what ties a chunk's drain-worker spans to its sender's
+        self._chunk_ids = itertools.count(1)
         self.batches_fused = 0
         self.events_fused = 0
         self._fused = None
@@ -380,6 +390,9 @@ class FusedJunctionIngest:
         self._logical_row_bytes = logical_row_bytes(schema.attrs)
         impls = [ep.impl_factory() for ep in self.endpoints]
         impls_want = [ep.qr.output_events for ep in self.endpoints]
+        qids = [
+            getattr(ep.qr, "query_id", i) for i, ep in enumerate(self.endpoints)
+        ]
         # deliver lanes ship only the out-schema columns: a lineage-armed
         # group-by step carries a __group_key__ col beside its outputs,
         # which the host deliver layout must never see
@@ -403,7 +416,8 @@ class FusedJunctionIngest:
 
             def body(carry, xs):
                 (sts, shr), tst = carry
-                batch = decode(xs[0], xs[1], xs[2])
+                with jax.named_scope("wire_decode"):
+                    batch = decode(xs[0], xs[1], xs[2])
                 new_states = []
                 new_shr = list(shr)
                 auxes = []
@@ -417,7 +431,8 @@ class FusedJunctionIngest:
                         # hold, by the share-set identity invariant
                         st = dict(st)
                         st["chain"] = shr[g]
-                    st2, tst, out, aux = impl(st, tst, batch, now)
+                    with jax.named_scope(f"q.{qids[ei]}"):
+                        st2, tst, out, aux = impl(st, tst, batch, now)
                     if g is not None:
                         st2 = dict(st2)
                         ch = st2.pop("chain")
@@ -454,14 +469,15 @@ class FusedJunctionIngest:
                         )
 
                         want = impls_want[ei]
-                        if want is _OEF.CURRENT:
-                            dv = out.valid & (out.kind == _KC)
-                        elif want is _OEF.EXPIRED:
-                            dv = out.valid & (out.kind == _KE)
-                        else:
-                            dv = out.valid & (
-                                (out.kind == _KC) | (out.kind == _KE)
-                            )
+                        with jax.named_scope("deliver_mask"):
+                            if want is _OEF.CURRENT:
+                                dv = out.valid & (out.kind == _KC)
+                            elif want is _OEF.EXPIRED:
+                                dv = out.valid & (out.kind == _KE)
+                            else:
+                                dv = out.valid & (
+                                    (out.kind == _KC) | (out.kind == _KE)
+                                )
                         lanes = {"ts": out.ts}
                         if want is _OEF.ALL:
                             lanes["kind"] = out.kind
@@ -497,39 +513,40 @@ class FusedJunctionIngest:
             from siddhi_tpu.ops.scatter import set_at
 
             packs = []
-            for stacked, dv in out_stack:
-                K = dv.shape[0]  # shape-driven: one traced fn serves any K
-                cap = dv.shape[1]
-                R = K * cap
-                flat = dv.reshape(R)  # [K, cap] row-major = arrival order
-                rank = jnp.cumsum(flat.astype(jnp.int32)) - flat.astype(
-                    jnp.int32
-                )
-                dst = jnp.where(flat, rank, R)
-                segs = []
-                for name in sorted(stacked):
-                    arr = stacked[name].reshape(R)
-                    if arr.dtype == jnp.bool_:
-                        arr = arr.astype(jnp.uint8)
-                    packed = set_at(jnp.zeros((R,), arr.dtype), dst, arr)
-                    u8 = jax.lax.bitcast_convert_type(packed, jnp.uint8)
-                    if u8.ndim == 1:  # already byte-wide lanes
-                        u8 = u8[:, None]
-                    segs.append(u8)
-                data_buf = jnp.concatenate(segs, axis=1)
-                W = data_buf.shape[1]
-                # header rows carry the per-iteration counts INSIDE the
-                # buffer: the steady-state drain is then ONE d2h transfer
-                # (each is a blocking host round trip of its own)
-                cnt_u8 = jax.lax.bitcast_convert_type(
-                    dv.sum(axis=1, dtype=jnp.int32), jnp.uint8
-                ).reshape(-1)  # [4K]
-                hdr_rows = -(-cnt_u8.shape[0] // W)
-                hdr = jnp.zeros((hdr_rows * W,), jnp.uint8)
-                hdr = hdr.at[: cnt_u8.shape[0]].set(cnt_u8).reshape(hdr_rows, W)
-                packs.append(
-                    {"buf": jnp.concatenate([hdr, data_buf], axis=0)}
-                )
+            with jax.named_scope("deliver_pack"):
+                for stacked, dv in out_stack:
+                    K = dv.shape[0]  # shape-driven: one traced fn serves any K
+                    cap = dv.shape[1]
+                    R = K * cap
+                    flat = dv.reshape(R)  # [K, cap] row-major = arrival order
+                    rank = jnp.cumsum(flat.astype(jnp.int32)) - flat.astype(
+                        jnp.int32
+                    )
+                    dst = jnp.where(flat, rank, R)
+                    segs = []
+                    for name in sorted(stacked):
+                        arr = stacked[name].reshape(R)
+                        if arr.dtype == jnp.bool_:
+                            arr = arr.astype(jnp.uint8)
+                        packed = set_at(jnp.zeros((R,), arr.dtype), dst, arr)
+                        u8 = jax.lax.bitcast_convert_type(packed, jnp.uint8)
+                        if u8.ndim == 1:  # already byte-wide lanes
+                            u8 = u8[:, None]
+                        segs.append(u8)
+                    data_buf = jnp.concatenate(segs, axis=1)
+                    W = data_buf.shape[1]
+                    # header rows carry the per-iteration counts INSIDE the
+                    # buffer: the steady-state drain is then ONE d2h transfer
+                    # (each is a blocking host round trip of its own)
+                    cnt_u8 = jax.lax.bitcast_convert_type(
+                        dv.sum(axis=1, dtype=jnp.int32), jnp.uint8
+                    ).reshape(-1)  # [4K]
+                    hdr_rows = -(-cnt_u8.shape[0] // W)
+                    hdr = jnp.zeros((hdr_rows * W,), jnp.uint8)
+                    hdr = hdr.at[: cnt_u8.shape[0]].set(cnt_u8).reshape(hdr_rows, W)
+                    packs.append(
+                        {"buf": jnp.concatenate([hdr, data_buf], axis=0)}
+                    )
             return states_out, tstates, aux_red, lin_stack, tuple(packs)
 
         # donate the per-endpoint states (exclusively owned); tstates may
@@ -584,9 +601,10 @@ class FusedJunctionIngest:
             k *= 2
         return min(k, self.K)
 
-    def try_send(self, timestamps, cols, now: int) -> bool:
+    def try_send(self, timestamps, cols, now: int, send_id=None) -> bool:
         """Attempt fused ingest of the whole call. Returns False to make the
-        caller fall back to the per-batch path."""
+        caller fall back to the per-batch path. `send_id` is the junction's
+        number for this call (the `send` id of its stage spans)."""
         n = len(timestamps)
         B = self.junction.batch_size
         # engage for any call of at least two micro-batches: shorter tails
@@ -601,10 +619,6 @@ class FusedJunctionIngest:
         with self._lock:
             if deliver and getattr(self, "_deliver_set", None) != dset:
                 if self._fused_deliver is not None:
-                    from siddhi_tpu.observability.profiler import (
-                        CAUSE_DELIVER_SET,
-                    )
-
                     self._cause_hints[True] = CAUSE_DELIVER_SET
                 self._fused_deliver = None  # callback set changed: rebuild
             if (self._fused_deliver if deliver else self._fused) is None:
@@ -645,6 +659,21 @@ class FusedJunctionIngest:
 
         if not self._prewarmed:
             self._prewarm_tail(prog, now)
+        if send_id is None:
+            send_id = next(self.junction.send_ids)
+        with stage(
+            "send", send=send_id, stream=self.junction.schema.stream_id,
+            rows=n, path="fused",
+        ):
+            return self._send_engaged(
+                prog, encode, deliver, dset, ts_arr, cols, n, B, now
+            )
+
+    def _send_engaged(
+        self, prog, encode, deliver, dset, ts_arr, cols, n, B, now
+    ) -> bool:
+        """The engaged send: program and codec are chosen, pick the chunk
+        loop (sharded, pipelined or serial) and commit the side records."""
 
         # flight recorder: the fused path never materializes an EventBatch
         # host-side, so record straight from the (host, physical) columns —
@@ -761,8 +790,6 @@ class FusedJunctionIngest:
             encode, _decode, _nb = self.junction.schema.wire_codec(
                 self.junction.batch_size, self._keep, {}
             )
-            from siddhi_tpu.observability.profiler import CAUSE_FULL_WIDTH
-
             # both programs were discarded: each mode's next compile is
             # rebuild-caused
             self._cause_hints[False] = CAUSE_FULL_WIDTH
@@ -771,7 +798,7 @@ class FusedJunctionIngest:
 
     def _dispatch_chunk(
         self, prog, wire, counts, bases, now, ds, tracked, tr, stream_span,
-        ps=None, wf=None, deliver=False, lin_ks=None,
+        ps=None, wf=None, deliver=False, lin_ks=None, chunk=None,
     ):
         """One donated-state dispatch under the app lock: collect states,
         run the program, write back, publish stats, surface aux flags.
@@ -781,7 +808,10 @@ class FusedJunctionIngest:
         IngestPipeline.retire. On a dispatch failure owned by the
         junction's exception handler returns (None, None) and the caller
         skips to the next chunk, like per-batch send_columns would."""
-        with self.app._process_lock:
+        lock = self.app._process_lock
+        with stage("lock_wait", chunk=chunk):
+            lock.acquire()
+        try:
             states = []
             for ep in self.endpoints:
                 if ep.qr.state is None:
@@ -799,14 +829,11 @@ class FusedJunctionIngest:
                 if tr is not None
                 else None
             )
-            ct = self.junction.compile_telemetry
-            t0 = (
-                time.perf_counter_ns()
-                if (
-                    ds is not None or tracked or ps is not None
-                    or ct is not None or wf is not None
-                )
-                else 0
+            # the chunk is the unit of processing here, so the endpoints'
+            # latency trackers record the chunk's dispatch wall time
+            clock = stage(
+                "dispatch", ds and ds.step, ps and ps.dispatch, *tracked,
+                wf=wf, chunk=chunk,
             )
             try:
                 # fault-injection site `device_dispatch` (testing/faults.py):
@@ -815,49 +842,36 @@ class FusedJunctionIngest:
                 # chunk-program explosion takes
                 if _faults.ACTIVE is not None:
                     _faults.ACTIVE.check("device_dispatch", self.component)
-                new_all, tstates, aux_red, lin_stack, packs = prog(
-                    arg0, tstates, wire,
-                    counts, bases, np.int64(now),
-                )
-                if t0:
-                    dt = time.perf_counter_ns() - t0
-                    for lt in tracked:
-                        lt.record_ns(dt)
-                    if ds is not None:
-                        ds.step.record_ns(dt)
-                        ds.h2d_bytes.add(int(wire.nbytes))
-                        ds.h2d_chunks.add(1)
-                        # live roofline numerator/denominator pair: the
-                        # always-on wire bytes/event gauge rides these
-                        n_ev = int(counts.sum())
-                        ds.h2d_events.add(n_ev)
-                        # logical-vs-encoded split (core/wire.py): what the
-                        # full-width wire would have shipped for the same
-                        # events, so the encoded gauge has a denominator
-                        ds.h2d_logical.add(
-                            n_ev * self._logical_row_bytes
-                        )
-                    if ps is not None:
-                        ps.dispatch.record_ns(dt)
-                    if wf is not None:
-                        wf.stage("dispatch", dt)
-                    if ct is not None:
-                        # fused compile telemetry: the chunk program retraces
-                        # per (K, wire width); rebuild paths leave a cause
-                        # hint, short tails are tail-variant compiles
-                        K = int(counts.shape[0])
-                        hint = self._cause_hints.pop(deliver, None)
-                        if hint is None and K < self.K:
-                            from siddhi_tpu.observability.profiler import (
-                                CAUSE_TAIL_K,
-                            )
-
-                            hint = CAUSE_TAIL_K
-                        ct.observe(
-                            self.component + ("_deliver" if deliver else ""),
-                            prog, (K, int(wire.shape[1])), dt,
-                            cause_hint=hint,
-                        )
+                with clock:
+                    new_all, tstates, aux_red, lin_stack, packs = prog(
+                        arg0, tstates, wire,
+                        counts, bases, np.int64(now),
+                    )
+                if ds is not None:
+                    ds.h2d_bytes.add(int(wire.nbytes))
+                    ds.h2d_chunks.add(1)
+                    # live roofline numerator/denominator pair: the
+                    # always-on wire bytes/event gauge rides these
+                    n_ev = int(counts.sum())
+                    ds.h2d_events.add(n_ev)
+                    # logical-vs-encoded split (core/wire.py): what the
+                    # full-width wire would have shipped for the same
+                    # events, so the encoded gauge has a denominator
+                    ds.h2d_logical.add(n_ev * self._logical_row_bytes)
+                ct = self.junction.compile_telemetry
+                if ct is not None and clock.ns:
+                    # fused compile telemetry: the chunk program retraces
+                    # per (K, wire width); rebuild paths leave a cause
+                    # hint, short tails are tail-variant compiles
+                    K = int(counts.shape[0])
+                    hint = self._cause_hints.pop(deliver, None)
+                    if hint is None and K < self.K:
+                        hint = CAUSE_TAIL_K
+                    ct.observe(
+                        self.component + ("_deliver" if deliver else ""),
+                        prog, (K, int(wire.shape[1])), clock.ns,
+                        cause_hint=hint,
+                    )
             except Exception as e:
                 # the call donated the state buffers: they are gone either
                 # way, so reset to fresh state (lazily re-initialized on
@@ -881,6 +895,8 @@ class FusedJunctionIngest:
                 ep.qr._writeback_table_states(
                     {tid: tstates[tid] for tid in tids}
                 )
+        finally:
+            lock.release()
         self.chunks_dispatched += 1
         self.batches_fused += int(counts.shape[0])
         self.events_fused += int(counts.sum())
@@ -1067,10 +1083,11 @@ class FusedJunctionIngest:
                 if prof is not None
                 else None
             )
-            t_enc = time.perf_counter_ns() if wf is not None else 0
+            chunk = next(self._chunk_ids)
             try:
                 wire, counts, bases = self._encode_chunk(
-                    encode, ts_arr, cols, c_off, c_end, B, K
+                    encode, ts_arr, cols, c_off, c_end, B, K,
+                    wf=wf, chunk=chunk,
                 )
             except WireNarrowMisfit:
                 try:
@@ -1097,14 +1114,13 @@ class FusedJunctionIngest:
                     handler(e)
                     return True
                 wire, counts, bases = self._encode_chunk(
-                    encode, ts_arr, cols, c_off, c_end, B, K
+                    encode, ts_arr, cols, c_off, c_end, B, K,
+                    wf=wf, chunk=chunk,
                 )
-            if wf is not None:
-                wf.stage("encode", time.perf_counter_ns() - t_enc)
 
             packs, _completion = self._dispatch_chunk(
                 prog, wire, counts, bases, now, ds, tracked, tr, stream_span,
-                wf=wf, deliver=deliver,
+                wf=wf, deliver=deliver, chunk=chunk,
             )
             if packs is not None and deliver:
                 # drain the PREVIOUS chunk now that this chunk's device work
@@ -1112,9 +1128,9 @@ class FusedJunctionIngest:
                 # callbacks still fire in order before send_columns returns
                 if pending_drain is not None:
                     self._drain_guarded(*pending_drain)
-                if wf is not None:
-                    wf.t_mark = time.perf_counter_ns()
-                pending_drain = (packs, K, wf)
+                pending_drain = (
+                    packs, K, wf, {"chunk": chunk}, time.perf_counter_ns()
+                )
             else:
                 if prof is not None:
                     prof.end(wf)
@@ -1123,13 +1139,13 @@ class FusedJunctionIngest:
             self._drain_guarded(*pending_drain)
         return True
 
-    def _drain_guarded(self, packs, K: int, wf=None) -> None:
+    def _drain_guarded(self, packs, K: int, *drain_args) -> None:
         """Drain with the junction's failure machinery owning callback
         errors (same contract on every ingest path — per-batch dispatch,
         @async workers, pipelined drain): guarded junctions route the
         failure, unguarded ones re-raise to the sender."""
         try:
-            self._drain(packs, K, wf)
+            self._drain(packs, K, *drain_args)
         except Exception as e:
             j = self.junction
             if j.exception_handler is None and j.fault_policy is None:
@@ -1156,11 +1172,11 @@ class FusedJunctionIngest:
                 c_off, n, B, ps,
             )
             while staged is not None:
-                dev_wire, counts, bases, K, slot, wf = staged
+                dev_wire, counts, bases, K, slot, wf, chunk = staged
                 staged = None
                 packs, completion = self._dispatch_chunk(
                     prog, dev_wire, counts, bases, now, ds, tracked, tr,
-                    stream_span, ps, wf=wf, deliver=deliver,
+                    stream_span, ps, wf=wf, deliver=deliver, chunk=chunk,
                 )
                 pl.retire(slot, completion)
                 dispatched = True
@@ -1168,9 +1184,7 @@ class FusedJunctionIngest:
                     # hand the packs to the drain worker BEFORE staging the
                     # next chunk: nothing downstream can lose them, and the
                     # worker's readback+decode overlaps the encode below
-                    if wf is not None:
-                        wf.t_mark = time.perf_counter_ns()
-                    pl.submit(packs, K, wf)
+                    pl.submit(packs, K, wf, chunk)
                 elif wf is not None:
                     prof = self.junction.profiler
                     if prof is not None:
@@ -1216,8 +1230,8 @@ class FusedJunctionIngest:
         self, pl, prog, encode, deliver, dset, ts_arr, cols, c_off, n, B, ps
     ):
         """Encode the next chunk into a pooled wire buffer and start its
-        async h2d transfer. Returns ((dev_wire, counts, bases, K, slot, wf),
-        next_off, prog, encode) — prog/encode may have been swapped by a
+        async h2d transfer. Returns ((dev_wire, counts, bases, K, slot, wf,
+        chunk), next_off, prog, encode) — prog/encode may have been swapped by a
         full-width rebuild on a narrow-wire misfit; the caller must
         pl.retire(slot, ...) once the chunk's dispatch is submitted."""
         K = self._chunk_K(-(-(n - c_off) // B))
@@ -1228,11 +1242,13 @@ class FusedJunctionIngest:
             if prof is not None
             else None
         )
-        t0 = time.perf_counter_ns() if (ps is not None or wf is not None) else 0
+        chunk = next(self._chunk_ids)
+        enc = ps and ps.encode
+        slot = pl.acquire(K, self._wire_bytes, chunk)
         try:
-            slot = pl.acquire(K, self._wire_bytes)
             wire, counts, bases = self._encode_chunk(
-                encode, ts_arr, cols, c_off, c_end, B, K, out=slot.buf
+                encode, ts_arr, cols, c_off, c_end, B, K, slot.buf,
+                enc, wf, chunk,
             )
         except WireNarrowMisfit:
             # drain everything first: the pending packs were produced by the
@@ -1250,25 +1266,16 @@ class FusedJunctionIngest:
                 )
                 self._disabled = True
                 raise _RebuildFailed(e) from e
-            slot = pl.acquire(K, self._wire_bytes)
+            slot = pl.acquire(K, self._wire_bytes, chunk)
             wire, counts, bases = self._encode_chunk(
-                encode, ts_arr, cols, c_off, c_end, B, K, out=slot.buf
+                encode, ts_arr, cols, c_off, c_end, B, K, slot.buf,
+                enc, wf, chunk,
             )
-        if t0:
-            dt = time.perf_counter_ns() - t0
-            if ps is not None:
-                ps.encode.record_ns(dt)
-            if wf is not None:
-                wf.stage("encode", dt)
-            t0 = time.perf_counter_ns()
-        dev_wire = pl.ship(slot)
-        if t0:
-            dt = time.perf_counter_ns() - t0
-            if ps is not None:
-                ps.h2d.record_ns(dt)
-            if wf is not None:
-                wf.stage("h2d", dt)
-        return (dev_wire, counts, bases, K, slot, wf), c_end, prog, encode
+        with stage("h2d", ps and ps.h2d, wf=wf, chunk=chunk):
+            dev_wire = pl.ship(slot)
+        return (
+            (dev_wire, counts, bases, K, slot, wf, chunk), c_end, prog, encode
+        )
 
     def _prewarm_tail(self, prog, now: int) -> None:
         """Opt-in (SIDDHI_TPU_PREWARM_TAIL=1): compile the smallest tail
@@ -1306,38 +1313,45 @@ class FusedJunctionIngest:
                 self.junction.schema.stream_id, exc_info=True,
             )
 
-    def _encode_chunk(self, encode, ts_arr, cols, c_off, c_end, B, K, out=None):
+    def _encode_chunk(
+        self, encode, ts_arr, cols, c_off, c_end, B, K, out=None,
+        tracker=None, wf=None, chunk=None,
+    ):
         """Encode one K-batch chunk into the [K, bytes] wire stack; with
         `out` (a pooled pipeline buffer) the rows are written in place
-        instead of allocating a fresh stack."""
-        bufs = [] if out is None else None
-        counts = np.zeros((K,), dtype=np.int32)
-        bases = np.zeros((K,), dtype=np.int64)
-        for k in range(K):
-            lo = c_off + k * B
-            hi = min(lo + B, c_end)
-            m = max(hi - lo, 0)
-            counts[k] = m
-            if m > 0:
-                buf, base = encode(
-                    ts_arr[lo:hi],
-                    {kk: v[lo:hi] for kk, v in cols.items()},
-                    m,
-                )
-                bases[k] = base
-                if out is None:
-                    bufs.append(buf)
+        instead of allocating a fresh stack. The `encode` stage of both
+        chunk loops."""
+        with stage("encode", tracker, wf=wf, chunk=chunk):
+            bufs = [] if out is None else None
+            counts = np.zeros((K,), dtype=np.int32)
+            bases = np.zeros((K,), dtype=np.int64)
+            for k in range(K):
+                lo = c_off + k * B
+                hi = min(lo + B, c_end)
+                m = max(hi - lo, 0)
+                counts[k] = m
+                if m > 0:
+                    buf, base = encode(
+                        ts_arr[lo:hi],
+                        {kk: v[lo:hi] for kk, v in cols.items()},
+                        m,
+                    )
+                    bases[k] = base
+                    if out is None:
+                        bufs.append(buf)
+                    else:
+                        out[k, :] = buf
+                elif out is None:
+                    bufs.append(np.zeros_like(bufs[0]))
                 else:
-                    out[k, :] = buf
-            elif out is None:
-                bufs.append(np.zeros_like(bufs[0]))
-            else:
-                out[k, :] = 0
-        if out is not None:
-            return out, counts, bases  # [K, bytes]
-        return np.stack(bufs), counts, bases  # [K, bytes]
+                    out[k, :] = 0
+            if out is not None:
+                return out, counts, bases  # [K, bytes]
+            return np.stack(bufs), counts, bases  # [K, bytes]
 
-    def _drain(self, packs, K: int, wf=None) -> None:
+    def _drain(
+        self, packs, K: int, wf=None, ids=None, t_submit=0, tracker=None
+    ) -> None:
         """Deliver one chunk's packed outputs to query callbacks: one counts
         readback + one sliced transfer per endpoint-with-callbacks, then a
         vectorized host decode, preserving per-micro-batch callback grouping
@@ -1345,103 +1359,77 @@ class FusedJunctionIngest:
         query/output/callback/QueryCallback.java:52-105). `K` is the chunk's
         batch count (variable: short tails ride smaller-K programs).
 
-        With a waterfall `wf` (observability/profiler.py), the drain
-        attributes its spans: `queue` (dispatch-submit to drain-start),
-        `device` (the FIRST blocking readback, dominated by waiting for the
-        program), `readback` (top-up transfers), `deliver` (decode +
-        callback wall), then closes the chunk's record."""
+        The `drain` stage, on the drain worker or, serial, on the sender;
+        `ids` are the sender's `send` and `chunk`, and `t_submit`
+        (perf_counter_ns at the hand-off) gives the time the chunk waited,
+        which no span can cross threads to show.
+        Inside it: `readback_wait` (the FIRST blocking readback, dominated
+        by waiting for the program; the waterfall's `device`), `readback`
+        (top-up transfers), then `decode` and `callback` in
+        deliver_endpoint (the waterfall's `deliver`); closes the chunk's
+        waterfall record."""
         import jax
 
         if not hasattr(self, "_drain_guess"):
             self._drain_guess = {}
-        ds = self.junction.device_stats
-        wf_get_ns = 0  # device+readback spans, excluded from 'deliver'
+        queued = time.perf_counter_ns() - t_submit if t_submit else 0
+        if wf is not None and queued:
+            wf.stage("queue", queued)
+        sync = self.junction.device_stats
+        sync = sync and sync.sync_stall
         first_get = True
-        t_drain0 = 0
-        if wf is not None:
-            t_drain0 = time.perf_counter_ns()
-            if wf.t_mark:
-                wf.stage("queue", t_drain0 - wf.t_mark)
-                wf.t_mark = 0
-        # packs align with the endpoints the program was built to deliver
-        for i, pack in zip(self._deliver_idx, packs):
-            qr = self.endpoints[i].qr
-            if not getattr(qr, "query_callbacks", None):
-                continue
-            layout, row_bytes = self._deliver_layout[i]
-            hdr_rows = -(-4 * K // row_bytes)
-            R = pack["buf"].shape[0] - hdr_rows
+        with stage("drain", tracker, queued_us=queued // 1000, **(ids or {})):
+            # packs align with the endpoints the program was built to deliver
+            for i, pack in zip(self._deliver_idx, packs):
+                qr = self.endpoints[i].qr
+                if not getattr(qr, "query_callbacks", None):
+                    continue
+                layout, row_bytes = self._deliver_layout[i]
+                hdr_rows = -(-4 * K // row_bytes)
+                R = pack["buf"].shape[0] - hdr_rows
 
-            def bucket(x: int) -> int:
-                return min(R, 1 << max(0, int(x - 1).bit_length()))
+                def bucket(x: int) -> int:
+                    return min(R, 1 << max(0, int(x - 1).bit_length()))
 
-            # ONE round trip in the steady state: the buffer's header rows
-            # carry the per-iteration counts, and the prefix is sized from
-            # the previous chunk's total; top up only when the guess
-            # undershoots (workload rates are stable)
-            guess = bucket(self._drain_guess.get(i, R))
-            # ascontiguousarray: this backend's device_get can hand back a
-            # strided view of the device-layout buffer for some slice sizes,
-            # and the .view(dtype) reinterprets below require dense bytes
-            t0 = (
-                time.perf_counter_ns()
-                if (ds is not None or wf is not None)
-                else 0
-            )
-            head = np.ascontiguousarray(
-                jax.device_get(pack["buf"][: hdr_rows + guess])
-            )
-            if t0:
-                dt = time.perf_counter_ns() - t0
-                if ds is not None:
-                    ds.sync_stall.record_ns(dt)
-                if wf is not None:
-                    # the first blocking readback waits for the program:
-                    # that's the chunk's device span; later ones are pure
-                    # readback
-                    wf.stage("device" if first_get else "readback", dt)
-                    first_get = False
-                    wf_get_ns += dt
-            cnts = head[:hdr_rows].reshape(-1)[: 4 * K].view(np.int32)
-            total = int(cnts.sum())
-            self._drain_guess[i] = max(total, 1)
-            if total == 0:
-                continue
-            L = bucket(total)
-            if L <= guess:
-                host = head[hdr_rows:]
-            else:
-                t0 = (
-                    time.perf_counter_ns()
-                    if (ds is not None or wf is not None)
-                    else 0
-                )
-                tail = np.ascontiguousarray(
-                    jax.device_get(
-                        pack["buf"][hdr_rows + guess : hdr_rows + L]
+                # ONE round trip in the steady state: the buffer's header
+                # rows carry the per-iteration counts, and the prefix is
+                # sized from the previous chunk's total; top up only when
+                # the guess undershoots (workload rates are stable)
+                guess = bucket(self._drain_guess.get(i, R))
+                # ascontiguousarray: this backend's device_get can hand back
+                # a strided view of the device-layout buffer for some slice
+                # sizes, and the .view(dtype) reinterprets below require
+                # dense bytes
+                with stage(
+                    "readback_wait" if first_get else "readback", sync,
+                    wf=wf, wf_name="device" if first_get else "readback",
+                ):
+                    head = np.ascontiguousarray(
+                        jax.device_get(pack["buf"][: hdr_rows + guess])
                     )
-                )
-                if t0:
-                    dt = time.perf_counter_ns() - t0
-                    if ds is not None:
-                        ds.sync_stall.record_ns(dt)
-                    if wf is not None:
-                        wf.stage("readback", dt)
-                        first_get = False
-                        wf_get_ns += dt
-                host = np.concatenate([head[hdr_rows:], tail])
-            self.deliver_endpoint(i, host, cnts, total)
-        if wf is not None:
-            # deliver = the drain wall minus the blocking readbacks
-            wf.stage(
-                "deliver",
-                time.perf_counter_ns() - t_drain0 - wf_get_ns,
-            )
-            prof = self.junction.profiler
-            if prof is not None:
-                prof.end(wf)
+                first_get = False
+                cnts = head[:hdr_rows].reshape(-1)[: 4 * K].view(np.int32)
+                total = int(cnts.sum())
+                self._drain_guess[i] = max(total, 1)
+                if total == 0:
+                    continue
+                L = bucket(total)
+                if L <= guess:
+                    host = head[hdr_rows:]
+                else:
+                    with stage("readback", sync, wf=wf):
+                        tail = np.ascontiguousarray(
+                            jax.device_get(
+                                pack["buf"][hdr_rows + guess : hdr_rows + L]
+                            )
+                        )
+                    host = np.concatenate([head[hdr_rows:], tail])
+                self.deliver_endpoint(i, host, cnts, total, wf)
+        prof = self.junction.profiler
+        if wf is not None and prof is not None:
+            prof.end(wf)
 
-    def deliver_endpoint(self, i: int, host, cnts, total: int) -> None:
+    def deliver_endpoint(self, i: int, host, cnts, total: int, wf=None) -> None:
         """Decode endpoint `i`'s packed output rows and fire its callbacks
         per micro-batch segment. `host` is the header-stripped byte buffer
         (rows at the front, `row_bytes` wide per `_deliver_layout[i]`),
@@ -1449,7 +1437,11 @@ class FusedJunctionIngest:
         `total` their sum. Shared by `_drain` (one chunk's buffer) and the
         batch shard router's merged drain (segments interleaved back into
         global batch order, parallel/shard.py) — one delivery code path, so
-        callback grouping/ordering semantics cannot drift between them."""
+        callback grouping/ordering semantics cannot drift between them.
+        The `decode` stage (lane views + host decode), one `callback` stage
+        per micro-batch and the `release` stage, in which the chunk's rows
+        are dropped (freeing a million `Event`s takes its time); together
+        the waterfall's `deliver`."""
         from siddhi_tpu.core.event import (
             KIND_CURRENT,
             KIND_EXPIRED,
@@ -1469,24 +1461,41 @@ class FusedJunctionIngest:
                 f"stream.{qr.out_schema.stream_id}"
             ).add(total)
         layout, _row_bytes = self._deliver_layout[i]
-        lanes = {}
-        for name, dt, off in layout:
-            lanes[name] = np.ascontiguousarray(
-                host[:total, off : off + dt.itemsize]
-            ).view(dt)[:, 0]
         want = qr.output_events
-        cols = {n: lanes[f"c.{n}"] for n in qr.out_schema.attr_names}
         raw = getattr(qr, "raw_query_callbacks", None)
-        if want is not OutputEventsFor.ALL and raw is not None and len(
+        # single-kind fast path: decode straight to Event lists and invoke
+        # the USER callbacks (skips the triple intermediate)
+        fast = want is not OutputEventsFor.ALL and raw is not None and len(
             raw
-        ) == len(qr.query_callbacks):
-            # single-kind fast path: decode straight to Event lists and
-            # invoke the USER callbacks (skips the triple intermediate)
-            from siddhi_tpu.core.event import events_from_arrays
+        ) == len(qr.query_callbacks)
+        with stage("decode", wf=wf, wf_name="deliver"):
+            lanes = {}
+            for name, dt, off in layout:
+                lanes[name] = np.ascontiguousarray(
+                    host[:total, off : off + dt.itemsize]
+                ).view(dt)[:, 0]
+            cols = {n: lanes[f"c.{n}"] for n in qr.out_schema.attr_names}
+            if fast:
+                from siddhi_tpu.core.event import events_from_arrays
 
-            events = events_from_arrays(
-                qr.out_schema, lanes["ts"], cols, total, qr._interner
-            )
+                events = events_from_arrays(
+                    qr.out_schema, lanes["ts"], cols, total, qr._interner
+                )
+            else:
+                kind = (
+                    lanes["kind"]
+                    if want is OutputEventsFor.ALL
+                    else int(
+                        KIND_CURRENT
+                        if want is not OutputEventsFor.EXPIRED
+                        else KIND_EXPIRED
+                    )
+                )
+                rows = rows_from_arrays(
+                    qr.out_schema, lanes["ts"], kind, cols, total,
+                    qr._interner,
+                )
+        if fast:
             expired = want is OutputEventsFor.EXPIRED
             off = 0
             for k in range(len(cnts)):
@@ -1496,24 +1505,18 @@ class FusedJunctionIngest:
                 seg = events[off : off + c]
                 off += c
                 ts = seg[-1][0]
-                for cb in raw:
-                    if expired:
-                        cb(ts, None, seg)
-                    else:
-                        cb(ts, seg, None)
+                with stage(
+                    "callback", wf=wf, wf_name="deliver", batch=k, rows=c
+                ):
+                    for cb in raw:
+                        if expired:
+                            cb(ts, None, seg)
+                        else:
+                            cb(ts, seg, None)
+            with stage("release", wf=wf, wf_name="deliver"):
+                # what no callback kept of the chunk's rows is freed here
+                events = seg = lanes = cols = None
             return
-        kind = (
-            lanes["kind"]
-            if want is OutputEventsFor.ALL
-            else int(
-                KIND_CURRENT
-                if want is not OutputEventsFor.EXPIRED
-                else KIND_EXPIRED
-            )
-        )
-        rows = rows_from_arrays(
-            qr.out_schema, lanes["ts"], kind, cols, total, qr._interner
-        )
         split = want is OutputEventsFor.ALL
         off = 0
         for k in range(len(cnts)):
@@ -1531,8 +1534,13 @@ class FusedJunctionIngest:
                 ins, removed = seg, []
             if ins or removed:
                 ts = seg[-1][0]
-                for cb in qr.query_callbacks:
-                    cb(ts, ins or None, removed or None)
+                with stage(
+                    "callback", wf=wf, wf_name="deliver", batch=k, rows=c
+                ):
+                    for cb in qr.query_callbacks:
+                        cb(ts, ins or None, removed or None)
+        with stage("release", wf=wf, wf_name="deliver"):
+            rows = seg = ins = removed = lanes = cols = None
 
     def _probe_aux_keys(self, i: int) -> list:
         """Sorted non-timer aux keys for endpoint i, discovered by tracing

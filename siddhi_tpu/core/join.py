@@ -580,21 +580,16 @@ class JoinQueryRuntime(BaseQueryRuntime):
             if self.state is None:
                 self.state = self._fresh(self.init_state())
             tstates = self._collect_table_states()
-            timed = self._need_step_clock()
-            if timed:
-                import time as _time
-
-                t0 = _time.perf_counter_ns()
-            self.state, tstates, out, aux = self._steps[side](
-                self.state, tstates, batch, jnp.asarray(now, dtype=jnp.int64)
-            )
-            if timed:
-                # one jitted program per join side: the telemetry component
-                # embeds the side (see BaseQueryRuntime._observe_step)
-                self._observe_step(
-                    self._steps[side], (side, int(batch.ts.shape[0])),
-                    _time.perf_counter_ns() - t0,
+            with self._step_stage() as clock:
+                self.state, tstates, out, aux = self._steps[side](
+                    self.state, tstates, batch,
+                    jnp.asarray(now, dtype=jnp.int64),
                 )
+            # one jitted program per join side: the telemetry component
+            # embeds the side (see BaseQueryRuntime._observe_compile)
+            self._observe_compile(
+                self._steps[side], (side, int(batch.ts.shape[0])), clock.ns
+            )
             self._writeback_table_states(tstates)
             lin = self.lineage
             if lin is not None:

@@ -27,10 +27,6 @@ from siddhi_tpu.core.types import InternTable
 from siddhi_tpu.query_api.execution import Query, StateInputStream
 
 
-# tuning hook (tools/exp_count.py): overrides the count-kernel chunk size
-COUNT_CHUNK_OVERRIDE: Optional[int] = None
-
-
 class PatternQueryRuntime(BaseQueryRuntime):
     def __init__(
         self,
@@ -161,11 +157,7 @@ class PatternQueryRuntime(BaseQueryRuntime):
                 # is known to be low (overflow still detected + warned).
                 m0 = max(1, prog.slots[0].min_count)
                 kernel = prog.apply_batch_count
-                chunk = (
-                    COUNT_CHUNK_OVERRIDE
-                    or self._pattern_chunk
-                    or max(1, prog.T * m0)
-                )
+                chunk = self._pattern_chunk or max(1, prog.T * m0)
 
         if kernel is not None:
             ker, C0 = kernel, chunk
@@ -337,21 +329,16 @@ class PatternQueryRuntime(BaseQueryRuntime):
                 self.state = self._fresh(self.init_state(now))
             step = self._steps[stream_id]
             tstates = self._collect_table_states()
-            timed = self._need_step_clock()
-            if timed:
-                import time as _time
-
-                t0 = _time.perf_counter_ns()
-            self.state, tstates, out, aux = step(
-                self.state, tstates, batch, jnp.asarray(now, dtype=jnp.int64)
-            )
-            if timed:
-                # one jitted program per pattern stream: the telemetry
-                # component embeds the stream id (see _observe_step)
-                self._observe_step(
-                    step, (stream_id, int(batch.ts.shape[0])),
-                    _time.perf_counter_ns() - t0,
+            with self._step_stage() as clock:
+                self.state, tstates, out, aux = step(
+                    self.state, tstates, batch,
+                    jnp.asarray(now, dtype=jnp.int64),
                 )
+            # one jitted program per pattern stream: the telemetry
+            # component embeds the stream id (see _observe_compile)
+            self._observe_compile(
+                step, (stream_id, int(batch.ts.shape[0])), clock.ns
+            )
             self._writeback_table_states(tstates)
             lin = self.lineage
             if lin is not None:

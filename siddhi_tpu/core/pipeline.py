@@ -43,9 +43,13 @@ from __future__ import annotations
 import os
 import queue
 import threading
+import time
 from typing import Optional
 
 import numpy as np
+
+from siddhi_tpu.observability.profiler import inherited_ids, stage
+from siddhi_tpu.testing import faults as _faults
 
 DEFAULT_DEPTH = 2
 _MAX_DEPTH = 8
@@ -160,7 +164,8 @@ class IngestPipeline:
     def __init__(self, junction, depth: int = DEFAULT_DEPTH, drain_fn=None):
         self.junction = junction
         self.depth = max(1, int(depth))
-        self.drain_fn = drain_fn  # fn(packs, K): the ingest's _drain
+        # fn(packs, K, wf, ids, t_submit, tracker): the ingest's _drain
+        self.drain_fn = drain_fn
         self.stats = None  # PipelineStats | None, set by the owner
         self._pool: dict[tuple, dict] = {}  # (K, nb) -> {slots, next}
         self._cv = threading.Condition()
@@ -172,9 +177,10 @@ class IngestPipeline:
 
     # ---- wire buffer pool ------------------------------------------------
 
-    def acquire(self, K: int, wire_bytes: int) -> _WireSlot:
+    def acquire(self, K: int, wire_bytes: int, chunk=None) -> _WireSlot:
         """A host buffer for one [K, wire_bytes] chunk, safe to overwrite:
-        pooled, blocking on the slot's reuse gate (see _WireSlot)."""
+        pooled, blocking on the slot's reuse gate (see _WireSlot): the
+        `slot_wait` stage of chunk `chunk`."""
         key = (int(K), int(wire_bytes))
         ent = self._pool.get(key)
         if ent is None:
@@ -188,14 +194,15 @@ class IngestPipeline:
         slot = slots[ent["next"]]
         ent["next"] = (ent["next"] + 1) % len(slots)
         if slot.ref is not None:
-            try:
-                slot.ref.block_until_ready()
-            except Exception:
-                # failed execution: the gating work is no longer running,
-                # so the buffer is free (gate arrays are never donated —
-                # see _dispatch_chunk's completion contract — so deletion
-                # cannot race this wait)
-                pass
+            with stage("slot_wait", chunk=chunk):
+                try:
+                    slot.ref.block_until_ready()
+                except Exception:
+                    # failed execution: the gating work is no longer
+                    # running, so the buffer is free (gate arrays are never
+                    # donated — see _dispatch_chunk's completion contract —
+                    # so deletion cannot race this wait)
+                    pass
             slot.ref = None
         if slot.dev is not None:
             # staging ring: free the previous cycle's device wire once the
@@ -287,17 +294,21 @@ class IngestPipeline:
             and threading.current_thread() is self._thread
         )
 
-    def submit(self, packs, K: int, wf=None) -> None:
+    def submit(self, packs, K: int, wf=None, chunk=None) -> None:
         """Queue one chunk's packed outputs for ordered delivery (`wf`:
-        the chunk's stage waterfall, closed by the drain). Blocks while
-        `depth` chunks are already in flight (backpressure)."""
+        the chunk's stage waterfall, closed by the drain; `chunk`: its id
+        in the stage spans). Blocks while `depth` chunks are already in
+        flight (backpressure): the `submit_wait` stage."""
         if self._thread is None:
             self._start_thread()
         with self._cv:
-            while self._inflight >= self.depth and not self._closed:
-                self._cv.wait()
+            if self._inflight >= self.depth and not self._closed:
+                with stage("submit_wait", chunk=chunk):
+                    while self._inflight >= self.depth and not self._closed:
+                        self._cv.wait()
             self._inflight += 1
-        self._q.put((packs, K, wf))
+        ids = {**inherited_ids(), "chunk": chunk}
+        self._q.put((packs, K, wf, ids, time.perf_counter_ns()))
 
     def pending_error(self) -> bool:
         """True once an unguarded drain failure is stashed for barrier():
@@ -312,8 +323,10 @@ class IngestPipeline:
         drain failure here when the junction has no handler/policy to own it
         (the pipelined analog of the serial path's in-line drain raising)."""
         with self._cv:
-            while self._inflight > 0:
-                self._cv.wait()
+            if self._inflight > 0:
+                with stage("barrier"):
+                    while self._inflight > 0:
+                        self._cv.wait()
         err, self._error = self._error, None
         if err is not None:
             raise err
@@ -332,9 +345,8 @@ class IngestPipeline:
             item = self._q.get()
             if item is None:
                 return
-            packs, K, wf = item
             try:
-                self._drain_one(packs, K, wf)
+                self._drain_one(*item)
             except Exception as exc:  # must not kill the worker
                 self._on_drain_error(exc)
             finally:
@@ -342,11 +354,7 @@ class IngestPipeline:
                     self._inflight -= 1
                     self._cv.notify_all()
 
-    def _drain_one(self, packs, K: int, wf=None) -> None:
-        import time
-
-        from siddhi_tpu.testing import faults as _faults
-
+    def _drain_one(self, packs, K: int, wf, ids, t_submit) -> None:
         # fault-injection site `drain_worker` (testing/faults.py): the
         # pipelined analog of the @async drain-worker site — an injected
         # fault rides the same guarded/unguarded routing a poisoned
@@ -356,12 +364,7 @@ class IngestPipeline:
                 "drain_worker", self.junction.schema.stream_id
             )
         ps = self.stats
-        t0 = time.perf_counter_ns() if ps is not None else 0
-        try:
-            self.drain_fn(packs, K, wf)
-        finally:
-            if t0:
-                ps.drain.record_ns(time.perf_counter_ns() - t0)
+        self.drain_fn(packs, K, wf, ids, t_submit, ps and ps.drain)
 
     def _on_drain_error(self, exc: Exception) -> None:
         """A guarded junction's failure machinery owns the error — the same

@@ -27,6 +27,7 @@ from siddhi_tpu.core.executor import Scope, compile_expression
 from siddhi_tpu.core.flow import Flow
 from siddhi_tpu.core.selector import CompiledSelector
 from siddhi_tpu.core.types import AttrType, InternTable
+from siddhi_tpu.observability.profiler import stage
 from siddhi_tpu.query_api.annotation import find_annotation
 from siddhi_tpu.query_api.execution import (
     Filter,
@@ -64,6 +65,9 @@ class CompiledSingleChain:
         # call) when @app:lineage is off
         self.lineage_probe = None
         self.stages: list[tuple[str, object]] = []
+        # each stage's device scope (jax.named_scope): `filter`,
+        # `fn.<name>`, `window.<type>`
+        self.scopes: list[str] = []
         attrs = dict(schema.attr_types)
         for h in stream.handlers:
             if isinstance(h, Filter):
@@ -71,6 +75,7 @@ class CompiledSingleChain:
                 if cond.type is not AttrType.BOOL:
                     raise SiddhiAppCreationError("filter must be a boolean expression")
                 self.stages.append(("filter", cond))
+                self.scopes.append("filter")
             elif isinstance(h, WindowHandler):
                 if self.window is not None:
                     raise SiddhiAppCreationError("only one window per stream")
@@ -81,11 +86,12 @@ class CompiledSingleChain:
                 win_schema = StreamSchema(schema.stream_id, list(attrs.items()))
                 self.window = window_factory(h.window, win_schema, self.ref)
                 self.stages.append(("window", self.window))
+                self.scopes.append(f"window.{h.window.name.lower()}")
             elif isinstance(h, StreamFunctionHandler):
-                stage = make_stream_function(
+                fn = make_stream_function(
                     h, attrs, self.ref, scope, schema.stream_id
                 )
-                for name, t in stage.new_attrs:
+                for name, t in fn.new_attrs:
                     if name in attrs:
                         raise SiddhiAppCreationError(
                             f"stream function '#{h.name}' output '{name}' "
@@ -94,7 +100,8 @@ class CompiledSingleChain:
                     attrs[name] = t
                     # later filters/selectors resolve the appended attrs
                     scope.add_stream(self.ref, attrs)
-                self.stages.append(("fn", stage))
+                self.stages.append(("fn", fn))
+                self.scopes.append(f"fn.{h.name}")
         self.out_attrs: list[tuple[str, AttrType]] = list(attrs.items())
 
     def init_state(self):
@@ -102,16 +109,17 @@ class CompiledSingleChain:
 
     def apply(self, state, flow: Flow):
         probe = self.lineage_probe
-        for kind, stage in self.stages:
-            if kind == "filter":
-                flow = self._filter(flow, [stage])
-            elif kind == "fn":
-                flow = stage.apply(flow)
-            else:  # window
-                if probe is not None:
-                    probe(flow)  # admit mask = post-filter, pre-window
-                    probe = None
-                state, flow = stage.apply(state, flow)
+        for (kind, op), scope in zip(self.stages, self.scopes):
+            if kind == "window" and probe is not None:
+                probe(flow)  # admit mask = post-filter, pre-window
+                probe = None
+            with jax.named_scope(scope):
+                if kind == "filter":
+                    flow = self._filter(flow, [op])
+                elif kind == "fn":
+                    flow = op.apply(flow)
+                else:  # window
+                    state, flow = op.apply(state, flow)
         if probe is not None:
             probe(flow)  # windowless chain: probe the final flow
         return state, flow
@@ -197,11 +205,21 @@ class _AuxWarnPool:
         backlog (all runtimes, all flag kinds stacked into one vector)."""
         import time as _time
 
-        import numpy as np
-
         with self._lock:
             pending, self._pending = self._pending, {}
             self._last_drain = _time.monotonic()
+        if not pending:
+            return
+        flags = sum(
+            len(vs) for _ref, acc in pending.values() for vs in acc.values()
+        )
+        with stage("aux_drain", flags=flags):
+            self._drain(pending)
+
+    @staticmethod
+    def _drain(pending: dict) -> None:
+        import numpy as np
+
         plan = []  # (qr, [keys]) aligned with scalars
         scalars = []
         for _qid, (qr_ref, acc) in pending.items():
@@ -560,32 +578,30 @@ class BaseQueryRuntime:
                 self.query_id,
             )
 
-    def _need_step_clock(self) -> bool:
-        """One check deciding whether a receive path should time its jitted
-        step (device-budget tracker or compile telemetry wired)."""
-        return (
-            self.device_step_tracker is not None
-            or self.compile_telemetry is not None
+    def _tls_wf(self):
+        """send_columns' active per-batch chunk on this thread, if any."""
+        prof = self.profiler
+        return prof.tls_wf() if prof is not None else None
+
+    def _step_stage(self) -> stage:
+        """The `step` stage every receive path (single/pattern/join) runs
+        its jitted step in: device-time histogram and the waterfall's
+        `device` sub-stage."""
+        return stage(
+            "step", self.device_step_tracker, wf=self._tls_wf(),
+            wf_name="device", query=self.query_id,
         )
 
-    def _observe_step(self, prog, signature, wall_ns: int) -> None:
-        """Shared step-call accounting for every receive path (single/
-        pattern/join): device-time histogram, waterfall 'device' sub-stage
-        (thread-local, set by send_columns' per-batch chunk), and compile
-        telemetry for `prog` under `query.<id>[signature]`-scoped ledgers.
+    def _observe_compile(self, prog, signature, wall_ns: int) -> None:
+        """Compile telemetry for `prog`, called `wall_ns` ago, under
+        `query.<id>[signature]`-scoped ledgers.
 
         `signature` must identify the PROGRAM as well as the call shape
         when the runtime jits several (pattern per-stream steps, join
         sides): telemetry tracks one jit cache per component, so the
         component key embeds everything up to the batch capacity."""
-        dt = self.device_step_tracker
-        if dt is not None:
-            dt.record_ns(wall_ns)
-            prof = self.profiler
-            if prof is not None:
-                prof.tls_stage("device", wall_ns)
         ct = self.compile_telemetry
-        if ct is not None:
+        if ct is not None and wall_ns:
             prog_key, shape = signature
             comp = f"query.{self.query_id}"
             if prog_key:
@@ -593,25 +609,12 @@ class BaseQueryRuntime:
             ct.observe(comp, prog, shape, wall_ns)
 
     def _timed_decode(self, decode, schema, out):
-        """Host decode with the d2h truth-sync stall recorded: decoding a
-        device batch is the blocking read that forces real completion of the
-        dependent chain (the live version of bench.py's truth sync)."""
-        st = self.sync_stall_tracker
-        if st is None:
-            return decode(schema, out)
-        import time as _time
-
-        t0 = _time.perf_counter_ns()
-        try:
-            return decode(schema, out)
-        finally:
-            dns = _time.perf_counter_ns() - t0
-            st.record_ns(dns)
-            prof = self.profiler
-            if prof is not None:
-                # waterfall: the blocking decode is the 'readback' sub-stage
-                # of send_columns' active per-batch chunk (if any)
-                prof.tls_stage("readback", dns)
+        """Host decode, its blocking read recorded as the d2h truth-sync
+        stall: reading a device batch forces real completion of the
+        dependent chain (the waterfall's `readback` sub-stage)."""
+        return decode(
+            schema, out, self.sync_stall_tracker, wf=self._tls_wf()
+        )
 
     def route_output(self, out: EventBatch, now: int, decode) -> None:
         """Dispatch a step's output to query callbacks / downstream junction.
@@ -658,8 +661,9 @@ class BaseQueryRuntime:
                     ins = []
                 if ins or removed:
                     ts = events[-1][0]
-                    for cb in self.query_callbacks:
-                        cb(ts, ins or None, removed or None)
+                    with stage("callback", rows=len(events)):
+                        for cb in self.query_callbacks:
+                            cb(ts, ins or None, removed or None)
         if self.publish_fn is not None:
             self.publish_fn(out, now)
 
@@ -858,9 +862,11 @@ class QueryRuntime(BaseQueryRuntime):
     def _step_impl(self, state, tstates, batch: EventBatch, now):
         flow = Flow(batch=batch, ref=self.ref, now=now, tables=tstates)
         chain_state, flow = self.chain.apply(state["chain"], flow)
-        sel_state, out = self.selector.apply(state["sel"], flow)
+        with jax.named_scope("selector"):
+            sel_state, out = self.selector.apply(state["sel"], flow)
         if self.table_op is not None:
-            tstates = self.table_op(tstates, out, now, flow.aux)
+            with jax.named_scope("table_op"):
+                tstates = self.table_op(tstates, out, now, flow.aux)
         if self.lineage is not None:
             # provenance lanes (observability/lineage.py): extra program
             # OUTPUTS only — the emission lanes above are untouched
@@ -895,23 +901,18 @@ class QueryRuntime(BaseQueryRuntime):
                     ks.init_state() if ks is not None else self.init_state()
                 )
             tstates = self._collect_table_states()
-            timed = self._need_step_clock()
-            if timed:
-                import time as _time
-
-                t0 = _time.perf_counter_ns()
-            self.state, tstates, out, aux = self._step(
-                self.state, tstates, batch, jnp.asarray(now, dtype=jnp.int64)
-            )
-            if timed:
-                # compile telemetry: the jit retraces per batch capacity
-                # (timer batches, downstream cap-64 re-publishes); a
-                # recompile at a seen capacity means the carried state
-                # pytree drifted (donation_mismatch)
-                self._observe_step(
-                    self._step, ("", int(batch.ts.shape[0])),
-                    _time.perf_counter_ns() - t0,
+            with self._step_stage() as clock:
+                self.state, tstates, out, aux = self._step(
+                    self.state, tstates, batch,
+                    jnp.asarray(now, dtype=jnp.int64),
                 )
+            # compile telemetry: the jit retraces per batch capacity (timer
+            # batches, downstream cap-64 re-publishes); a recompile at a
+            # seen capacity means the carried state pytree drifted
+            # (donation_mismatch)
+            self._observe_compile(
+                self._step, ("", int(batch.ts.shape[0])), clock.ns
+            )
             self._writeback_table_states(tstates)
             lin = self.lineage
             if lin is not None:

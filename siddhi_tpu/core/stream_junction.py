@@ -10,6 +10,7 @@ send_batch.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Any, Callable, Sequence
@@ -18,6 +19,7 @@ import numpy as np
 
 from siddhi_tpu.core.event import EventBatch, StreamSchema
 from siddhi_tpu.core.types import InternTable
+from siddhi_tpu.observability.profiler import stage
 from siddhi_tpu.testing import faults as _faults
 
 # subscriber: fn(batch: EventBatch, now_ms: int) -> None
@@ -70,6 +72,9 @@ class StreamJunction:
         # None (one attribute check) when statistics are off
         self.profiler = None
         self.compile_telemetry = None
+        # numbers the send_columns calls: the `send` id of their stage
+        # spans (observability/profiler.py)
+        self.send_ids = itertools.count(1)
         # flight recorder (observability.flight.FlightRecorder): bounded
         # ring of the last N events through this junction, opt-in via
         # @flightRecorder(size='N') / SIDDHI_TPU_FLIGHT=N; None = one
@@ -836,38 +841,15 @@ class InputHandler:
             return
         numeric = all(np.asarray(v).dtype.kind not in "OUS" for v in cols.values())
         fi = j.fused_ingest
-        if numeric and fi is not None and fi.try_send(timestamps, cols, now):
+        send_id = next(j.send_ids)
+        if numeric and fi is not None and fi.try_send(
+            timestamps, cols, now, send_id
+        ):
             return
         if numeric:
-            encode, decode = j.schema.packed_codec(j.batch_size)
-            prof = j.profiler
-            for ofs in range(0, n, j.batch_size):
-                end = min(ofs + j.batch_size, n)
-                m = end - ofs
-                # per-batch waterfall (observability/profiler.py): encode +
-                # dispatch walls here; the query step adds device/readback
-                # sub-stages through the profiler's thread-local context.
-                # wf is None when statistics are off/disabled (one check).
-                wf = prof.begin(j.schema.stream_id, m) if prof is not None else None
-                t0 = time.perf_counter_ns() if wf is not None else 0
-                buf = encode(
-                    timestamps[ofs:end],
-                    {k: v[ofs:end] for k, v in cols.items()},
-                    m,
-                )
-                batch = decode(buf, np.int32(m))
-                if wf is None:
-                    j.publish_batch(batch, now)
-                    continue
-                wf.stage("encode", time.perf_counter_ns() - t0)
-                prof.tls_begin(wf)
-                t0 = time.perf_counter_ns()
-                try:
-                    j.publish_batch(batch, now)
-                finally:
-                    wf.stage("dispatch", time.perf_counter_ns() - t0)
-                    prof.tls_end()
-                    prof.end(wf)
+            sid = j.schema.stream_id
+            with stage("send", send=send_id, stream=sid, rows=n, path="batch"):
+                self._send_packed(timestamps, cols, n, now)
             return
         for ofs in range(0, n, j.batch_size):
             ts_chunk = timestamps[ofs : ofs + j.batch_size]
@@ -876,6 +858,40 @@ class InputHandler:
                 ts_chunk, chunk, j.interner, capacity=j.batch_size
             )
             j.publish_batch(batch, now)
+
+    def _send_packed(self, timestamps, cols, n: int, now: int) -> None:
+        """The per-batch path of all-numeric columns: one packed transfer
+        and one publish per micro-batch. Each is a chunk of the waterfall
+        (observability/profiler.py): `encode` and `publish` here (the
+        waterfall's `dispatch`), and the query step adds its device and
+        readback stages through the profiler's thread-local chunk."""
+        j = self.junction
+        encode, decode = j.schema.packed_codec(j.batch_size)
+        prof = j.profiler
+        for ofs in range(0, n, j.batch_size):
+            end = min(ofs + j.batch_size, n)
+            m = end - ofs
+            # None when statistics are off or disabled (one check)
+            wf = (
+                prof.begin(j.schema.stream_id, m, "batch")
+                if prof is not None else None
+            )
+            with stage("encode", wf=wf):
+                buf = encode(
+                    timestamps[ofs:end],
+                    {k: v[ofs:end] for k, v in cols.items()},
+                    m,
+                )
+                batch = decode(buf, np.int32(m))
+            if wf is not None:
+                prof.tls_begin(wf)
+            try:
+                with stage("publish", wf=wf, wf_name="dispatch"):
+                    j.publish_batch(batch, now)
+            finally:
+                if wf is not None:
+                    prof.tls_end()
+                    prof.end(wf)
 
 
 def system_clock_ms() -> int:

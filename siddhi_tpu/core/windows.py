@@ -352,72 +352,74 @@ class SlidingWindow(WindowStage):
         """Sort-free length-window step (see apply). Positions:
         insertion i (rank order) emits EXPIRED at i + E_i - 1 when it evicts
         (E = inclusive eviction count) and its CURRENT at i + E_i."""
-        ranks = jnp.arange(bsz, dtype=jnp.int32)
-        in_rank = ranks < c
-        # insertion i evicts iff the window is full at that point
-        e = in_rank & (total + ranks >= w)
-        E = jnp.cumsum(e.astype(jnp.int32))
-        cur_pos_rank = ranks + E
-        exp_pos_rank = jnp.where(e, cur_pos_rank - 1, BIG)
+        with jax.named_scope("ring_emit"):
+            ranks = jnp.arange(bsz, dtype=jnp.int32)
+            in_rank = ranks < c
+            # insertion i evicts iff the window is full at that point
+            e = in_rank & (total + ranks >= w)
+            E = jnp.cumsum(e.astype(jnp.int32))
+            cur_pos_rank = ranks + E
+            exp_pos_rank = jnp.where(e, cur_pos_rank - 1, BIG)
 
-        # evicted element (seq = total + i - w): a ring slot if it predates
-        # this batch, else the batch row of rank i - w
-        seq_ev = total + ranks.astype(jnp.int64) - w
-        from_ring = seq_ev < total
-        ring_slot = jnp.where(seq_ev >= 0, seq_ev % w, 0).astype(jnp.int32)
-        batch_rank = jnp.clip(ranks - w, 0, bsz - 1)
-        elem_idx = jnp.where(
-            from_ring, ring_slot, w + perm[batch_rank]
-        ).astype(jnp.int32)
+            # evicted element (seq = total + i - w): a ring slot if it predates
+            # this batch, else the batch row of rank i - w
+            seq_ev = total + ranks.astype(jnp.int64) - w
+            from_ring = seq_ev < total
+            ring_slot = jnp.where(seq_ev >= 0, seq_ev % w, 0).astype(jnp.int32)
+            batch_rank = jnp.clip(ranks - w, 0, bsz - 1)
+            elem_idx = jnp.where(
+                from_ring, ring_slot, w + perm[batch_rank]
+            ).astype(jnp.int32)
 
-        n_out = 2 * bsz
-        trig_ts = b.ts[perm[jnp.clip(ranks, 0, bsz - 1)]]  # trigger row ts
-        out_ts = jnp.zeros((n_out,), jnp.int64)
-        out_kind = jnp.zeros((n_out,), jnp.int8)
-        out_valid = jnp.zeros((n_out,), jnp.bool_)
-        out_cols = {n: jnp.zeros((n_out,), a.dtype) for n, a in b.cols.items()}
+            n_out = 2 * bsz
+            trig_ts = b.ts[perm[jnp.clip(ranks, 0, bsz - 1)]]  # trigger row ts
+            out_ts = jnp.zeros((n_out,), jnp.int64)
+            out_kind = jnp.zeros((n_out,), jnp.int8)
+            out_valid = jnp.zeros((n_out,), jnp.bool_)
+            out_cols = {n: jnp.zeros((n_out,), a.dtype) for n, a in b.cols.items()}
 
-        # scatter EXPIREDs (rank space); set_at keeps int64 lanes fast
-        exp_dst = jnp.where(e, exp_pos_rank, n_out)
-        out_ts = _set_at(out_ts, exp_dst, trig_ts)
-        out_kind = out_kind.at[exp_dst].set(np.int8(KIND_EXPIRED), mode="drop")
-        out_valid = out_valid.at[exp_dst].set(True, mode="drop")
-        for n in out_cols:
-            out_cols[n] = _set_at(out_cols[n], exp_dst, elem_cols[n][elem_idx])
-        # scatter CURRENTs (row space: row r has rank[r], position via gather)
-        cur_pos_row = cur_pos_rank[jnp.clip(rank, 0, bsz - 1)]
-        cur_dst = jnp.where(valid_cur, cur_pos_row, n_out)
-        out_ts = _set_at(out_ts, cur_dst, b.ts)
-        out_valid = out_valid.at[cur_dst].set(True, mode="drop")
-        for n in out_cols:
-            out_cols[n] = _set_at(out_cols[n], cur_dst, b.cols[n])
-        out = EventBatch(ts=out_ts, kind=out_kind, valid=out_valid, cols=out_cols)
+            # scatter EXPIREDs (rank space); set_at keeps int64 lanes fast
+            exp_dst = jnp.where(e, exp_pos_rank, n_out)
+            out_ts = _set_at(out_ts, exp_dst, trig_ts)
+            out_kind = out_kind.at[exp_dst].set(np.int8(KIND_EXPIRED), mode="drop")
+            out_valid = out_valid.at[exp_dst].set(True, mode="drop")
+            for n in out_cols:
+                out_cols[n] = _set_at(out_cols[n], exp_dst, elem_cols[n][elem_idx])
+            # scatter CURRENTs (row space: row r has rank[r], position via gather)
+            cur_pos_row = cur_pos_rank[jnp.clip(rank, 0, bsz - 1)]
+            cur_dst = jnp.where(valid_cur, cur_pos_row, n_out)
+            out_ts = _set_at(out_ts, cur_dst, b.ts)
+            out_valid = out_valid.at[cur_dst].set(True, mode="drop")
+            for n in out_cols:
+                out_cols[n] = _set_at(out_cols[n], cur_dst, b.cols[n])
+            out = EventBatch(ts=out_ts, kind=out_kind, valid=out_valid, cols=out_cols)
 
-        # --- membership matrix (same contract as the sorted path) ---
-        own_row_rank = rank  # row -> rank
-        birth_pos = jnp.concatenate(
-            [
-                jnp.full((w,), -1, jnp.int32),
-                jnp.where(valid_cur, cur_pos_row, np.int32(-1)),
-            ]
-        )
-        E_at = E[jnp.clip(trig_rank, 0, bsz - 1)]
-        death_pos = jnp.where(
-            len_trig_valid, trig_rank + E_at - 1, BIG
-        )
-        pos_row = jnp.arange(n_out)
-        member = (
-            present[None, :]
-            & (birth_pos[None, :] <= pos_row[:, None])
-            & (pos_row[:, None] < death_pos[None, :])
-        )
-        member_cols = {(self.ref, None, n): elem_cols[n] for n in elem_cols}
-        member_cols[(self.ref, None, TS_ATTR)] = elem_ts
-        member_env = Env(member_cols, now=flow.now)
+            # --- membership matrix (same contract as the sorted path) ---
+            own_row_rank = rank  # row -> rank
+            birth_pos = jnp.concatenate(
+                [
+                    jnp.full((w,), -1, jnp.int32),
+                    jnp.where(valid_cur, cur_pos_row, np.int32(-1)),
+                ]
+            )
+            E_at = E[jnp.clip(trig_rank, 0, bsz - 1)]
+            death_pos = jnp.where(
+                len_trig_valid, trig_rank + E_at - 1, BIG
+            )
+            pos_row = jnp.arange(n_out)
+            member = (
+                present[None, :]
+                & (birth_pos[None, :] <= pos_row[:, None])
+                & (pos_row[:, None] < death_pos[None, :])
+            )
+            member_cols = {(self.ref, None, n): elem_cols[n] for n in elem_cols}
+            member_cols[(self.ref, None, TS_ATTR)] = elem_ts
+            member_env = Env(member_cols, now=flow.now)
 
-        new_state = self._ring_state(
-            state, len_trig_valid, valid_cur, rank, c, total, b, bwts, seq_batch
-        )
+        with jax.named_scope("ring_update"):
+            new_state = self._ring_state(
+                state, len_trig_valid, valid_cur, rank, c, total, b, bwts, seq_batch
+            )
         return new_state, Flow(
             batch=out,
             ref=flow.ref,

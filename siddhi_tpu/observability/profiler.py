@@ -22,6 +22,18 @@ at one attribute check, the same contract as every tracker):
   their full breakdowns, so "what did the p99.99 chunk spend its time on"
   is answerable after the fact without logging every chunk.
 
+* `stage()` — THE instrumentation site of a host stage: one context manager
+  that, while a `jax.profiler` session is open, writes a `siddhi:<name>`
+  span into the profiler's own trace (same file and clock as the device
+  plane), and, when the statistics gate is on, records the same interval
+  into the trackers and the chunk waterfall it was handed. Span names are
+  listed in PERF.md §3 and the README.
+
+* `CompileEvents` — process-wide count of XLA backend compiles (builds and
+  persistent-cache loads), fed by `jax.monitoring`; needs no
+  `@app:statistics` and sees eager programs. `CompileTelemetry` keeps the
+  causes.
+
 Recompile-cause taxonomy (stable strings, documented in the README):
 
     first_compile       the program's first call (expected, once)
@@ -46,9 +58,17 @@ folded into `runtime.explain()` node annotations (observability/explain.py).
 
 from __future__ import annotations
 
+import collections
+import gc
 import threading
 import time
 from typing import Optional
+
+import jax
+import jax.monitoring
+from jax.profiler import TraceAnnotation
+
+from siddhi_tpu.observability.metrics import LogHistogram
 
 CAUSE_FIRST = "first_compile"
 CAUSE_SHAPE = "shape_change"
@@ -211,17 +231,19 @@ class StageWaterfall:
     NOT the stage sum)."""
 
     __slots__ = (
-        "stream", "seq", "events", "t0_ns", "total_ns", "stages", "t_mark",
+        "stream", "seq", "events", "path", "t0_ns", "total_ns", "stages",
     )
 
-    def __init__(self, stream: str, seq: int, events: int) -> None:
+    def __init__(
+        self, stream: str, seq: int, events: int, path: str = "fused"
+    ) -> None:
         self.stream = stream
         self.seq = seq
         self.events = int(events)
+        self.path = path  # "fused" chunk or per-"batch" send_columns slice
         self.t0_ns = time.perf_counter_ns()
         self.total_ns = 0
         self.stages: dict[str, int] = {}
-        self.t_mark = 0  # scratch timestamp (dispatch->drain queue span)
 
     def stage(self, name: str, ns: int) -> None:
         self.stages[name] = self.stages.get(name, 0) + int(ns)
@@ -240,7 +262,9 @@ class StageWaterfall:
 
 class Profiler:
     """Bounded top-K ring of the slowest chunks, with full stage
-    breakdowns, plus chunk/event counters.
+    breakdowns, plus chunk/event counters and, per path and stage, a
+    histogram over EVERY chunk (`stages_ms`: the per-stage mean an operator
+    reads without a profiler session).
 
     `begin()` returns None when the gate is off — every downstream
     `wf.stage(...)` site is already behind an `if wf is not None` (or the
@@ -256,17 +280,20 @@ class Profiler:
         self.chunks = 0
         self.events = 0
         self._top: list[StageWaterfall] = []  # sorted slowest-first
+        self._stage_hist: dict[tuple, LogHistogram] = {}  # (path, stage)
         self._tls = threading.local()
 
     # ---- chunk lifecycle --------------------------------------------------
 
-    def begin(self, stream: str, events: int) -> Optional[StageWaterfall]:
+    def begin(
+        self, stream: str, events: int, path: str = "fused"
+    ) -> Optional[StageWaterfall]:
         if not self._gate.enabled:
             return None
         with self._lock:
             self._seq += 1
             seq = self._seq
-        return StageWaterfall(stream, seq, events)
+        return StageWaterfall(stream, seq, events, path)
 
     def end(self, wf: Optional[StageWaterfall]) -> None:
         if wf is None or not self._gate.enabled:
@@ -275,6 +302,11 @@ class Profiler:
         with self._lock:
             self.chunks += 1
             self.events += wf.events
+            for name, ns in (*wf.stages.items(), ("total", wf.total_ns)):
+                h = self._stage_hist.get((wf.path, name))
+                if h is None:
+                    h = self._stage_hist[(wf.path, name)] = LogHistogram()
+                h.record(ns)
             top = self._top
             if len(top) < self.top_k:
                 top.append(wf)
@@ -294,17 +326,217 @@ class Profiler:
     def tls_end(self) -> None:
         self._tls.wf = None
 
-    def tls_stage(self, name: str, ns: int) -> None:
-        wf = getattr(self._tls, "wf", None)
-        if wf is not None:
-            wf.stage(name, ns)
+    def tls_wf(self) -> Optional[StageWaterfall]:
+        """The calling thread's active chunk, for `stage(..., wf=...)`."""
+        return getattr(self._tls, "wf", None)
 
     # ---- reporting --------------------------------------------------------
 
     def report(self) -> dict:
         with self._lock:
+            stages: dict = {}
+            for (path, name), h in self._stage_hist.items():
+                p50, p99 = h.quantiles([0.5, 0.99])
+                stages.setdefault(path, {})[name] = {
+                    "count": h.count,
+                    "mean": round(h.mean / 1e6, 4),
+                    "p50": round(p50 / 1e6, 4),
+                    "p99": round(p99 / 1e6, 4),
+                }
             return {
                 "chunks": self.chunks,
                 "events": self.events,
                 "slowest": [w.to_dict() for w in self._top],
+                "stages_ms": stages,
             }
+
+
+# ---------------------------------------------------------------------------
+# stage spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+SPAN_PREFIX = "siddhi:"
+# ids a span hands down to the spans opened inside it on the same thread:
+# the request (`send`, a per-junction counter) and, on the fused path, the
+# chunk, which is also what ties the drain worker's spans to the sender's
+_INHERITED = ("send", "chunk")
+_ctx = threading.local()
+
+
+def inherited_ids() -> dict:
+    """The calling thread's open `send` / `chunk`, for a hand-off to
+    another thread (empty with no profiler session open)."""
+    ctx = _ctx.__dict__
+    return {k: ctx[k] for k in _INHERITED if ctx.get(k) is not None}
+
+
+class stage:
+    """`with stage("encode", ps and ps.encode, wf=wf, chunk=7): ...`
+
+    While a `jax.profiler` session is open the block is a `siddhi:<name>`
+    span in the profiler's trace, with `ids` (and the enclosing spans'
+    `send` / `chunk`) as its stats. Independently, when any tracker (an
+    object with `record_ns`; None entries are skipped) or a waterfall `wf`
+    is given, the block's wall time is recorded into them — under
+    `wf_name` in the waterfall, whose stage names predate the spans — and
+    left in `.ns` for the caller (compile telemetry wants it). With neither
+    a session nor a collector the site costs the `is_enabled` check."""
+
+    __slots__ = (
+        "name", "ns", "_trackers", "_wf", "_wf_name", "_ids", "_ann",
+        "_saved", "_t0",
+    )
+
+    def __init__(self, name: str, *trackers, wf=None, wf_name=None, **ids):
+        self.name = name
+        self.ns = 0
+        self._trackers = trackers
+        self._wf = wf
+        self._wf_name = wf_name or name
+        self._ids = ids
+        self._ann = None
+        self._saved = None
+        self._t0 = 0
+
+    def __enter__(self):
+        if TraceAnnotation.is_enabled():
+            ids, ctx = self._ids, _ctx.__dict__
+            self._saved = {k: ctx.get(k) for k in _INHERITED}
+            for k in _INHERITED:
+                if k in ids:
+                    ctx[k] = ids[k]
+                elif ctx.get(k) is not None:
+                    ids[k] = ctx[k]
+            self._ann = TraceAnnotation(SPAN_PREFIX + self.name, **ids)
+            self._ann.__enter__()
+        if self._wf is not None:
+            self._t0 = time.perf_counter_ns()
+        else:
+            for t in self._trackers:
+                if t is not None:
+                    self._t0 = time.perf_counter_ns()
+                    break
+        return self
+
+    def set(self, **ids) -> None:
+        """Stats known only once the block ran (`collected` of a gc)."""
+        if self._ann is not None:
+            self._ann.set_metadata(**ids)
+
+    def __exit__(self, *exc) -> None:
+        if self._t0:
+            self.ns = dt = time.perf_counter_ns() - self._t0
+            for t in self._trackers:
+                if t is not None:
+                    t.record_ns(dt)
+            if self._wf is not None:
+                self._wf.stage(self._wf_name, dt)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            _ctx.__dict__.update(self._saved)
+
+
+class _GcSpans:
+    """`siddhi:gc` spans round the collections of Python's collector, from a
+    `gc.callbacks` hook held while any app runtime runs. A collection
+    starts and stops on one thread and no second one starts meanwhile, so
+    one open span is all there is to keep."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._users = 0
+        self._open: Optional[stage] = None
+
+    def acquire(self) -> None:
+        with self._lock:
+            self._users += 1
+            if self._users == 1:
+                gc.callbacks.append(self._hook)
+
+    def release(self) -> None:
+        with self._lock:
+            self._users -= 1
+            if self._users == 0:
+                gc.callbacks.remove(self._hook)
+
+    def _hook(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if TraceAnnotation.is_enabled():
+                self._open = stage("gc", generation=info["generation"])
+                self._open.__enter__()
+        elif self._open is not None:
+            span, self._open = self._open, None
+            span.set(collected=info["collected"])
+            span.__exit__(None, None, None)
+
+
+GC_SPANS = _GcSpans()
+
+
+# ---------------------------------------------------------------------------
+# compile events, process-wide
+# ---------------------------------------------------------------------------
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+class CompileEvents:
+    """Every XLA backend compile of the process, as `jax.monitoring`
+    reports it: `compiles` counts programs built OR loaded from the
+    persistent cache (either stalls the caller), `cache_loads` the loads
+    among them, `compile_s` their summed wall time; `recent` is a ring of
+    the last 64 with the `time.perf_counter()` reading at which each
+    ended, so a reader can ask which fell inside an interval of its own."""
+
+    RING = 64
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.compiles = 0
+        self.cache_loads = 0
+        self.compile_s = 0.0
+        self._recent: collections.deque = collections.deque(maxlen=self.RING)
+
+    def install(self) -> None:
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, seconds: float, **kw) -> None:
+        if event != _BACKEND_COMPILE:
+            return
+        with self._lock:
+            self.compiles += 1
+            self.compile_s += seconds
+            self._recent.append({
+                "t": time.perf_counter(),
+                "name": kw.get("fun_name"),
+                "seconds": round(seconds, 6),
+            })
+
+    def _event(self, event: str, **kw) -> None:
+        if event == _CACHE_HIT:
+            with self._lock:
+                self.cache_loads += 1
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {
+                "compiles": self.compiles,
+                "cache_loads": self.cache_loads,
+                "compile_s": round(self.compile_s, 6),
+                "recent": list(self._recent),
+            }
+
+
+COMPILE_EVENTS = CompileEvents()
+COMPILE_EVENTS.install()
+
+# The device scopes are HLO metadata, which JAX leaves out of its
+# persistent-cache key by default: an executable that a build without them
+# (or with other names) cached would then be loaded in place of this build's
+# and show that build's names in every profile (seen on the chip, PR 24: the
+# parent commit's cached chunk program served this tree, and its trace had
+# no scope). With the metadata in the key, two builds share an entry only
+# where their programs carry the same names and source lines.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)

@@ -223,39 +223,41 @@ class KeyShardedGroupExec:
             # psum reconstructs each lane's unsharded value exactly: the
             # owner computed it from the identical replicated inputs plus
             # the only aggregator lanes that row's group ever touches.
-            merged_valid = lax.psum(out.valid.astype(jnp.int32), KEY_AXIS) > 0
+            # (the device scope `keyshard.exchange`: the collectives)
+            with jax.named_scope("keyshard.exchange"):
+                merged_valid = lax.psum(out.valid.astype(jnp.int32), KEY_AXIS) > 0
 
-            def merge_col(c):
-                if jnp.issubdtype(c.dtype, jnp.floating):
-                    # bitcast BEFORE masking: summing float identities
-                    # would flip -0.0 to +0.0 and canonicalize NaNs
-                    bits_dt = {2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}[
-                        c.dtype.itemsize
-                    ]
-                    bits = lax.bitcast_convert_type(c, bits_dt)
-                    summed = lax.psum(
-                        jnp.where(mine, bits, jnp.zeros((), bits_dt)),
-                        KEY_AXIS,
-                    )
-                    return lax.bitcast_convert_type(summed, c.dtype)
-                if c.dtype == jnp.bool_:
-                    return (
-                        lax.psum(
-                            jnp.where(mine, c, False).astype(jnp.int32),
+                def merge_col(c):
+                    if jnp.issubdtype(c.dtype, jnp.floating):
+                        # bitcast BEFORE masking: summing float identities
+                        # would flip -0.0 to +0.0 and canonicalize NaNs
+                        bits_dt = {2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}[
+                            c.dtype.itemsize
+                        ]
+                        bits = lax.bitcast_convert_type(c, bits_dt)
+                        summed = lax.psum(
+                            jnp.where(mine, bits, jnp.zeros((), bits_dt)),
                             KEY_AXIS,
                         )
-                        > 0
+                        return lax.bitcast_convert_type(summed, c.dtype)
+                    if c.dtype == jnp.bool_:
+                        return (
+                            lax.psum(
+                                jnp.where(mine, c, False).astype(jnp.int32),
+                                KEY_AXIS,
+                            )
+                            > 0
+                        )
+                    return lax.psum(
+                        jnp.where(mine, c, jnp.zeros((), c.dtype)), KEY_AXIS
                     )
-                return lax.psum(
-                    jnp.where(mine, c, jnp.zeros((), c.dtype)), KEY_AXIS
-                )
 
-            out2 = EventBatch(
-                out.ts,
-                out.kind,
-                merged_valid,
-                {nm: merge_col(c) for nm, c in out.cols.items()},
-            )
+                out2 = EventBatch(
+                    out.ts,
+                    out.kind,
+                    merged_valid,
+                    {nm: merge_col(c) for nm, c in out.cols.items()},
+                )
 
             if qr.lineage is not None:
                 # same lanes as QueryRuntime._step_impl, from the same

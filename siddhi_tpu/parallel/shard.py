@@ -55,6 +55,8 @@ from typing import Optional
 
 import numpy as np
 
+from siddhi_tpu.observability.profiler import stage
+
 log = logging.getLogger(__name__)
 
 SHARD_ENV = "SIDDHI_TPU_SHARD"
@@ -354,7 +356,8 @@ class BatchShardRouter:
             # (_drain_guarded): a guarded junction's machinery owns callback
             # errors, an unguarded one re-raises to the sender
             try:
-                self._merged_drain(fi, results, M, D)
+                with stage("shard.merge", devices=D):
+                    self._merged_drain(fi, results, M, D)
             except Exception as e:
                 j = self.junction
                 if j.exception_handler is None and j.fault_policy is None:

@@ -113,14 +113,35 @@ class TestProfilerUnit:
         assert len(tops) == 2 and 2 not in tops  # the fast one evicted
         assert rep["slowest"][0]["total_ms"] >= rep["slowest"][1]["total_ms"]
 
+    def test_stages_ms_is_over_every_chunk_not_the_top_k(self):
+        prof = Profiler(gate=_Gate(), top_k=2)
+        for i in range(12):
+            wf = prof.begin("S", 10, "fused" if i % 3 else "batch")
+            wf.stage("encode", (i + 1) * 1_000_000)
+            prof.end(wf)
+        rep = prof.report()
+        assert len(rep["slowest"]) == 2
+        fused = rep["stages_ms"]["fused"]["encode"]
+        batch = rep["stages_ms"]["batch"]["encode"]
+        assert fused["count"] == 8 and batch["count"] == 4
+        # exact sums over all chunks, not over the two kept waterfalls
+        assert fused["mean"] == pytest.approx(
+            sum(i + 1 for i in range(12) if i % 3) / 8
+        )
+        assert batch["mean"] == pytest.approx((1 + 4 + 7 + 10) / 4)
+        assert batch["p50"] <= batch["p99"] <= 10.5
+        assert rep["stages_ms"]["fused"]["total"]["count"] == 8
+
     def test_gate_off_returns_none_and_records_nothing(self):
         g = _Gate()
         g.enabled = False
         prof = Profiler(gate=g)
         assert prof.begin("S", 1) is None
         prof.end(None)  # must not raise
-        prof.tls_stage("device", 123)  # no active wf: no-op
-        assert prof.report() == {"chunks": 0, "events": 0, "slowest": []}
+        assert prof.tls_wf() is None  # no active chunk on this thread
+        assert prof.report() == {
+            "chunks": 0, "events": 0, "slowest": [], "stages_ms": {},
+        }
 
 
 class TestEngineProfile:
