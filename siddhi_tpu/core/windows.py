@@ -36,7 +36,7 @@ from siddhi_tpu.core.event import (
     StreamSchema,
 )
 from siddhi_tpu.core.executor import Env, Scope, TS_ATTR, compile_expression
-from siddhi_tpu.ops.prefix import cummax as _cummax
+from siddhi_tpu.ops.prefix import compact_front as _compact_front, cummax as _cummax
 from siddhi_tpu.ops.group import permute_by as _permute_by
 from siddhi_tpu.ops.scatter import compact_set_at as _compact_set_at, set_at as _set_at
 from siddhi_tpu.core.flow import Flow
@@ -136,6 +136,20 @@ class WindowStage:
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass
+class _ElementView:
+    """SlidingWindow._element_view: [W + B] lanes, ring slots then batch rows."""
+
+    ts: jnp.ndarray
+    wts: jnp.ndarray
+    seq: jnp.ndarray
+    cols: dict
+    present: jnp.ndarray
+    trig_rank: jnp.ndarray
+    len_trig_valid: jnp.ndarray
+    perm: jnp.ndarray  # [B]: rank -> batch row
+
+
 class SlidingWindow(WindowStage):
     """Generic ring: capacity W (always length-evicts at W) plus optional time
     predicate over a per-event 'window time' (event ts, or an attribute for
@@ -164,6 +178,15 @@ class SlidingWindow(WindowStage):
         self.t = duration_ms
         self.time_attr = time_attr
         self.needs_scheduler = use_scheduler
+        # which length step the last trace took: "slice" (O(batch)) or
+        # "scatter"; None for time-bounded windows and before the first trace
+        self.ring_step: Optional[str] = None
+
+    def describe_state(self, state) -> dict:
+        d = super().describe_state(state)
+        if self.ring_step is not None:
+            d["ring_step"] = self.ring_step
+        return d
 
     def share_signature(self):
         if self.needs_scheduler:
@@ -187,7 +210,6 @@ class SlidingWindow(WindowStage):
         bsz = b.capacity
         w = self.w
         k = w + bsz
-        total = state["total"]
 
         valid_cur = b.valid & (b.kind == KIND_CURRENT)
         is_timer = b.valid & (b.kind == KIND_TIMER)
@@ -198,46 +220,38 @@ class SlidingWindow(WindowStage):
             bwts = b.ts
         rank = jnp.cumsum(valid_cur.astype(jnp.int32)) - valid_cur.astype(jnp.int32)
         c = valid_cur.sum(dtype=jnp.int32)
-        seq_batch = jnp.where(valid_cur, total + rank, np.int64(-1))
-
-        # element view: ring slots then batch rows
-        elem_ts = jnp.concatenate([state["ts"], b.ts])
-        elem_wts = jnp.concatenate([state["wts"], bwts])
-        elem_seq = jnp.concatenate([state["seq"], seq_batch])
-        elem_cols = {
-            n: jnp.concatenate([state["cols"][n], b.cols[n]]) for n in b.cols
-        }
-        present = elem_seq >= 0
-        own_row = jnp.concatenate(
-            [jnp.full((w,), -1, jnp.int32), jnp.arange(bsz, dtype=jnp.int32)]
-        )
-
-        # --- eviction triggers ---
-        # capacity/length: evicted by the insertion of seq_e + W
-        trig_rank = (elem_seq + w - total).astype(jnp.int32)
-        len_trig_valid = present & (trig_rank >= 0) & (trig_rank < c)
-        perm = jnp.argsort(~valid_cur, stable=True).astype(jnp.int32)  # rank -> row
-        trig_row_len = jnp.where(
-            len_trig_valid, perm[jnp.clip(trig_rank, 0, bsz - 1)], BIG
-        )
 
         if self.t is None:
             # Pure length window: deaths pair 1:1 with insertions (the
             # insertion of seq_e + W evicts seq_e), so the EXPIRED/CURRENT
             # interleaving is pure rank arithmetic — no candidate lexsort
             # (reference behavior: LengthWindowProcessor.java emits the
-            # displaced event then the arriving one, per event).
-            return self._apply_length(
-                state, flow, b, bsz, w, total, valid_cur, bwts, rank, c,
-                seq_batch, elem_ts, elem_cols, present,
-                trig_rank, len_trig_valid, perm,
+            # displaced event then the arriving one, per event). The step
+            # adapts on a shape: with capacity >= batch no row can expire
+            # inside the batch that brought it, and the step touches only
+            # the ring rows it replaces.
+            self.ring_step = self._pick_ring_step(bsz)
+            step = (
+                self._apply_length_slice
+                if self.ring_step == "slice"
+                else self._apply_length
             )
+            return step(state, flow, valid_cur, bwts, rank, c)
+
+        ev = self._element_view(state, b, bwts, valid_cur, rank, c)
+        elem_ts, elem_seq, elem_cols, present = ev.ts, ev.seq, ev.cols, ev.present
+        own_row = jnp.concatenate(
+            [jnp.full((w,), -1, jnp.int32), jnp.arange(bsz, dtype=jnp.int32)]
+        )
+        trig_row_len = jnp.where(
+            ev.len_trig_valid, ev.perm[jnp.clip(ev.trig_rank, 0, bsz - 1)], BIG
+        )
 
         trigger_ok = valid_cur | is_timer
         due = (
             trigger_ok[None, :]
             & present[:, None]
-            & (bwts[None, :] - elem_wts[:, None] >= self.t)
+            & (bwts[None, :] - ev.wts[:, None] >= self.t)
             & (jnp.arange(bsz, dtype=jnp.int32)[None, :] >= own_row[:, None])
         )
         has_time_trig = due.any(axis=1)
@@ -248,7 +262,6 @@ class SlidingWindow(WindowStage):
 
         # --- candidate assembly: K expired + B current candidates ---
         death_key = jnp.where(evict, trig_row * 2, BIG)
-        birth_key = jnp.where(own_row >= 0, own_row * 2 + 1, -1)
 
         cand_key = jnp.concatenate(
             [death_key, jnp.where(valid_cur, jnp.arange(bsz, dtype=jnp.int32) * 2 + 1, BIG)]
@@ -263,7 +276,6 @@ class SlidingWindow(WindowStage):
         cand_seq = elem_seq[cand_elem]
 
         order = jnp.lexsort((cand_seq, jnp.where(cand_valid, cand_key, BIG)))
-        out_n = k + bsz
         o_elem = cand_elem[order]
         o_exp = cand_is_exp[order]
         o_valid = cand_valid[order]
@@ -287,22 +299,9 @@ class SlidingWindow(WindowStage):
             own_row >= 0, inv[k + jnp.clip(own_row, 0, bsz - 1)], np.int32(-1)
         )
         death_pos = jnp.where(evict, inv[jnp.arange(k)], BIG)
-        alive_src = present
-        pos_row = jnp.arange(k + bsz)
-        member = (
-            alive_src[None, :]
-            & (birth_pos[None, :] <= pos_row[:, None])
-            & (pos_row[:, None] < death_pos[None, :])
-        )
-        member_cols = {
-            (self.ref, None, n): elem_cols[n] for n in elem_cols
-        }
-        member_cols[(self.ref, None, TS_ATTR)] = elem_ts
-        member_env = Env(member_cols, now=flow.now)
+        member, member_env = self._member(flow, ev, birth_pos, death_pos, k + bsz)
 
-        new_state = self._ring_state(
-            state, evict, valid_cur, rank, c, total, b, bwts, seq_batch
-        )
+        new_state = self._ring_state(state, evict, valid_cur, rank, c, b, bwts, ev)
 
         aux = dict(flow.aux)
         if self.needs_scheduler and self.t is not None:
@@ -320,14 +319,49 @@ class SlidingWindow(WindowStage):
             tables=flow.tables,
         )
 
+    def _element_view(self, state, b, bwts, valid_cur, rank, c):
+        """The [W + B] element view, ring slots then batch rows, with each
+        element's length-eviction trigger: an element is evicted by the
+        insertion of seq + W, which has rank `trig_rank` in this batch."""
+        w = self.w
+        total = state["total"]
+        seq = jnp.concatenate(
+            [state["seq"], jnp.where(valid_cur, total + rank, np.int64(-1))]
+        )
+        present = seq >= 0
+        trig_rank = (seq + w - total).astype(jnp.int32)
+        return _ElementView(
+            ts=jnp.concatenate([state["ts"], b.ts]),
+            wts=jnp.concatenate([state["wts"], bwts]),
+            seq=seq,
+            cols={n: jnp.concatenate([state["cols"][n], b.cols[n]]) for n in b.cols},
+            present=present,
+            trig_rank=trig_rank,
+            len_trig_valid=present & (trig_rank >= 0) & (trig_rank < c),
+            perm=jnp.argsort(~valid_cur, stable=True).astype(jnp.int32),
+        )
 
-    def _ring_state(
-        self, state, evict, valid_cur, rank, c, total, b, bwts, seq_batch
-    ):
-        """Post-step ring buffers, shared by the sorted and length-only paths.
+    def _member(self, flow, ev, birth_pos, death_pos, n_out):
+        """[n_out, W + B] membership matrix and the Env over the element
+        view's columns: an element is a member from output row `birth_pos`
+        until output row `death_pos`."""
+        pos_row = jnp.arange(n_out)
+        member = (
+            ev.present[None, :]
+            & (birth_pos[None, :] <= pos_row[:, None])
+            & (pos_row[:, None] < death_pos[None, :])
+        )
+        member_cols = {(self.ref, None, n): col for n, col in ev.cols.items()}
+        member_cols[(self.ref, None, TS_ATTR)] = ev.ts
+        return member, Env(member_cols, now=flow.now)
+
+    def _ring_state(self, state, evict, valid_cur, rank, c, b, bwts, ev):
+        """Post-step ring buffers, shared by the sorted and the scatter
+        length paths.
         Rows already evicted within this batch (expired before the batch
         ended) must NOT be re-inserted, or they would expire a second time."""
         w = self.w
+        total = state["total"]
         ring_evicted = evict[:w]
         batch_evicted = evict[w:]
         insert = valid_cur & ~batch_evicted & (rank >= c - w)
@@ -340,24 +374,48 @@ class SlidingWindow(WindowStage):
             },
             "ts": _place_ring(state["ts"], ring_evicted, slots, b.ts),
             "wts": _place_ring(state["wts"], ring_evicted, slots, bwts),
-            "seq": _set_at(new_seq, slots, seq_batch),
+            "seq": _set_at(new_seq, slots, ev.seq[w:]),
             "total": total + c,
         }
 
-    def _apply_length(
-        self, state, flow, b, bsz, w, total, valid_cur, bwts, rank, c,
-        seq_batch, elem_ts, elem_cols, present,
-        trig_rank, len_trig_valid, perm,
-    ):
-        """Sort-free length-window step (see apply). Positions:
-        insertion i (rank order) emits EXPIRED at i + E_i - 1 when it evicts
-        (E = inclusive eviction count) and its CURRENT at i + E_i."""
+    def _length_positions(self, total, c, bsz):
+        """Output positions of the length step, by rank: insertion i evicts
+        iff the window is full at that point; with E the inclusive count of
+        evictions it emits its EXPIRED at i + E_i - 1 and its CURRENT at
+        i + E_i."""
+        ranks = jnp.arange(bsz, dtype=jnp.int32)
+        e = (ranks < c) & (total + ranks >= self.w)
+        E = jnp.cumsum(e.astype(jnp.int32))
+        return ranks, e, E
+
+    def _length_member(self, flow, ev, valid_cur, rank, c, E):
+        """Membership of the length step (same contract as the sorted
+        path). Only min / max / distinctCount read it; when none does,
+        nothing here reaches the output or the state, and XLA drops it
+        together with the element view's concatenations."""
+        bsz = valid_cur.shape[0]
+        cur_pos_row = (rank + E[jnp.clip(rank, 0, bsz - 1)]).astype(jnp.int32)
+        birth_pos = jnp.concatenate(
+            [
+                jnp.full((self.w,), -1, jnp.int32),
+                jnp.where(valid_cur, cur_pos_row, np.int32(-1)),
+            ]
+        )
+        E_at = E[jnp.clip(ev.trig_rank, 0, bsz - 1)]
+        death_pos = jnp.where(ev.len_trig_valid, ev.trig_rank + E_at - 1, BIG)
+        return self._member(flow, ev, birth_pos, death_pos, 2 * bsz)
+
+    def _apply_length(self, state, flow, valid_cur, bwts, rank, c):
+        """Sort-free length-window step for any capacity (see apply): the
+        ring is read by gathers and written by scatters."""
+        b = flow.batch
+        bsz = b.capacity
+        w = self.w
+        total = state["total"]
+        ev = self._element_view(state, b, bwts, valid_cur, rank, c)
+        perm = ev.perm
         with jax.named_scope("ring_emit"):
-            ranks = jnp.arange(bsz, dtype=jnp.int32)
-            in_rank = ranks < c
-            # insertion i evicts iff the window is full at that point
-            e = in_rank & (total + ranks >= w)
-            E = jnp.cumsum(e.astype(jnp.int32))
+            ranks, e, E = self._length_positions(total, c, bsz)
             cur_pos_rank = ranks + E
             exp_pos_rank = jnp.where(e, cur_pos_rank - 1, BIG)
 
@@ -384,7 +442,7 @@ class SlidingWindow(WindowStage):
             out_kind = out_kind.at[exp_dst].set(np.int8(KIND_EXPIRED), mode="drop")
             out_valid = out_valid.at[exp_dst].set(True, mode="drop")
             for n in out_cols:
-                out_cols[n] = _set_at(out_cols[n], exp_dst, elem_cols[n][elem_idx])
+                out_cols[n] = _set_at(out_cols[n], exp_dst, ev.cols[n][elem_idx])
             # scatter CURRENTs (row space: row r has rank[r], position via gather)
             cur_pos_row = cur_pos_rank[jnp.clip(rank, 0, bsz - 1)]
             cur_dst = jnp.where(valid_cur, cur_pos_row, n_out)
@@ -393,33 +451,118 @@ class SlidingWindow(WindowStage):
             for n in out_cols:
                 out_cols[n] = _set_at(out_cols[n], cur_dst, b.cols[n])
             out = EventBatch(ts=out_ts, kind=out_kind, valid=out_valid, cols=out_cols)
-
-            # --- membership matrix (same contract as the sorted path) ---
-            own_row_rank = rank  # row -> rank
-            birth_pos = jnp.concatenate(
-                [
-                    jnp.full((w,), -1, jnp.int32),
-                    jnp.where(valid_cur, cur_pos_row, np.int32(-1)),
-                ]
-            )
-            E_at = E[jnp.clip(trig_rank, 0, bsz - 1)]
-            death_pos = jnp.where(
-                len_trig_valid, trig_rank + E_at - 1, BIG
-            )
-            pos_row = jnp.arange(n_out)
-            member = (
-                present[None, :]
-                & (birth_pos[None, :] <= pos_row[:, None])
-                & (pos_row[:, None] < death_pos[None, :])
-            )
-            member_cols = {(self.ref, None, n): elem_cols[n] for n in elem_cols}
-            member_cols[(self.ref, None, TS_ATTR)] = elem_ts
-            member_env = Env(member_cols, now=flow.now)
+            member, member_env = self._length_member(flow, ev, valid_cur, rank, c, E)
 
         with jax.named_scope("ring_update"):
             new_state = self._ring_state(
-                state, len_trig_valid, valid_cur, rank, c, total, b, bwts, seq_batch
+                state, ev.len_trig_valid, valid_cur, rank, c, b, bwts, ev
             )
+        return new_state, Flow(
+            batch=out,
+            ref=flow.ref,
+            now=flow.now,
+            extra_cols={},
+            member=member,
+            member_env=member_env,
+            aux=dict(flow.aux),
+            tables=flow.tables,
+        )
+
+    def _pick_ring_step(self, bsz: int) -> str:
+        return "slice" if self.w >= bsz else "scatter"
+
+    def _apply_length_slice(self, state, flow, valid_cur, bwts, rank, c):
+        """Length-window step in O(batch), for capacity >= batch. Insertion
+        of rank r goes to slot (total + r) % W, and the row it evicts, seq
+        total + r - W, holds that very slot. So the slots written are one
+        run (mod W) from total % W, the expired rows are the run's old
+        contents in order, and every evicted slot is overwritten in the
+        same step: each ring lane is read and written through slices of
+        the run alone, 64-bit lanes included. The output is an interleave
+        by arithmetic: the first n0 = clip(W - total, 0, c) insertions
+        evict nothing and emit their CURRENT at position p = rank; after
+        them rank n0 + j emits EXPIRED at n0 + 2j and CURRENT at n0 + 2j + 1.
+        Output and state are identical to _apply_length's."""
+        b = flow.batch
+        bsz = b.capacity
+        w = self.w
+        total = state["total"]
+        ring = {k: state[k] for k in ("cols", "ts", "wts", "seq")}
+        with jax.named_scope("ring_emit"):
+            new = _compact_front(
+                valid_cur, {"cols": dict(b.cols), "ts": b.ts, "wts": bwts}
+            )
+            new["seq"] = total + jnp.arange(bsz, dtype=jnp.int64)
+
+            # the run [start, start + B) mod W as two slices: `tail` from
+            # s1 (start, held inside the lane) and the lane's first B rows;
+            # the run begins `off` rows into their concatenation
+            start = (total % w).astype(jnp.int32)
+            s1 = jnp.minimum(start, np.int32(w - bsz))
+            off = start - s1
+            tails = jax.tree_util.tree_map(
+                lambda lane: jax.lax.dynamic_slice(lane, (s1,), (bsz,)), ring
+            )
+
+            def run_of(lane, tail):
+                return jax.lax.dynamic_slice(
+                    jnp.concatenate([tail, lane[:bsz]]), (off,), (bsz,)
+                )
+
+            n0 = jnp.clip(w - total, 0, c).astype(jnp.int32)
+            pos = jnp.arange(2 * bsz, dtype=jnp.int32)
+            fill = pos < n0
+            out_valid = jnp.where(fill, pos, n0 + (pos - n0) // 2) < c
+            is_exp = out_valid & ~fill & ((pos - n0) % 2 == 0)
+
+            def emit(expired, current):
+                pairs = jnp.stack([expired, current], axis=1).reshape(-1)
+                pairs = jax.lax.dynamic_slice(
+                    jnp.pad(pairs, (0, bsz)), (n0,), (2 * bsz,)
+                )
+                lane = jnp.where(fill, jnp.pad(current, (0, bsz)), pairs)
+                return jnp.where(out_valid, lane, jnp.zeros((), lane.dtype))
+
+            out = EventBatch(
+                # an EXPIRED row carries its trigger row's ts: the CURRENT
+                # of the same rank
+                ts=emit(new["ts"], new["ts"]),
+                kind=jnp.where(
+                    is_exp, np.int8(KIND_EXPIRED), np.int8(KIND_CURRENT)
+                ),
+                valid=out_valid,
+                cols={
+                    n: emit(run_of(ring["cols"][n], tails["cols"][n]), col)
+                    for n, col in new["cols"].items()
+                },
+            )
+            _, _, E = self._length_positions(total, c, bsz)
+            member, member_env = self._length_member(
+                flow,
+                self._element_view(state, b, bwts, valid_cur, rank, c),
+                valid_cur, rank, c, E,
+            )
+
+        with jax.named_scope("ring_update"):
+            written = (pos >= off) & (pos - off < c)
+
+            def place(lane, tail, vals):
+                vals = jax.lax.dynamic_slice(
+                    jnp.pad(vals, (bsz, bsz)), (bsz - off,), (2 * bsz,)
+                )
+                lane = jax.lax.dynamic_update_slice(
+                    lane, jnp.where(written[:bsz], vals[:bsz], tail), (s1,)
+                )
+                # the head is read again: it overlaps the tail when the
+                # run starts inside the lane's first B rows
+                return jax.lax.dynamic_update_slice(
+                    lane,
+                    jnp.where(written[bsz:], vals[bsz:], lane[:bsz]),
+                    (0,),
+                )
+
+            new_state = jax.tree_util.tree_map(place, ring, tails, new)
+            new_state["total"] = total + c
         return new_state, Flow(
             batch=out,
             ref=flow.ref,

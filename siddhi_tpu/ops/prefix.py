@@ -11,6 +11,7 @@ reduction, which the MXU/VPU eat for B <= ~1024, and it keeps everything static.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -234,12 +235,31 @@ def first_indices(mask: jnp.ndarray, size: int, fill: int = -1) -> jnp.ndarray:
     )
 
 
-def compact(valid: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Stable-compaction permutation: indices that move valid rows to the front.
+def compact_front(valid: jnp.ndarray, lanes):
+    """Every [B] lane of the pytree with its `valid` rows moved to the front
+    in row order (stable); what lies behind them is unspecified.
 
-    returns (perm [B] int32, count scalar int32). Gather with `perm` then mask
-    rows >= count.
-    """
-    perm = jnp.argsort(~valid, stable=True).astype(jnp.int32)
-    count = valid.sum(dtype=jnp.int32)
-    return perm, count
+    A row's way to the front, the count of invalid rows before it, never
+    shrinks from one valid row to the next, so the rows can take it one
+    binary digit at a time, lowest first, and no two ever meet: log2(B)
+    passes of a shifted read and a select, all on the VPU. On the chip this
+    is the cheapest of three forms at B = 32768 with fourteen 32-bit lanes:
+    a gather by `argsort(~valid)` costs 0.23-0.32 ms per lane, one payload
+    sort of all lanes compiles for four minutes (PERF.md, PR 25)."""
+    n = valid.shape[0]
+    live = valid.astype(jnp.int32)
+    way = jnp.arange(n, dtype=jnp.int32) - (jnp.cumsum(live) - live)
+
+    def ahead(x, step):
+        return jnp.concatenate([x[step:], jnp.zeros((step,), x.dtype)])
+
+    step = 1
+    while step < n:
+        goes = valid & ((way & step) != 0)
+        comes = ahead(goes, step)
+        lanes, way = jax.tree_util.tree_map(
+            lambda x: jnp.where(comes, ahead(x, step), x), (lanes, way)
+        )
+        valid = comes | (valid & ~goes)
+        step *= 2
+    return lanes

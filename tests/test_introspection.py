@@ -361,6 +361,27 @@ class TestSnapshotStatus:
         assert st["tables"]["T"]["rows"] == 3
         mgr.shutdown()
 
+    @pytest.mark.parametrize(
+        "window,ring_step",
+        [("length(8)", "slice"), ("length(3)", "scatter"), ("time(1 min)", None)],
+    )
+    def test_window_reports_its_ring_step(self, window, ring_step):
+        # the length step adapts on a shape, capacity >= batch; the status
+        # says which step the deployed program took
+        mgr = SiddhiManager()
+        rt = mgr.create_siddhi_app_runtime(f"""
+        @app:batch(size='8')
+        define stream S (v long);
+        @info(name='q') from S#window.{window} select v insert into Out;
+        """)
+        rt.start()
+        rt.get_input_handler("S").send((1,))
+        w = rt.snapshot_status()["queries"]["q"]["window"]
+        assert w["fill"] == 1
+        assert w.get("ring_step") == ring_step
+        assert ("ring_step" in w) == (ring_step is not None)
+        mgr.shutdown()
+
     def test_manager_snapshot_includes_error_store(self):
         mgr = SiddhiManager()
         mgr.set_error_store(InMemoryErrorStore(capacity=10))

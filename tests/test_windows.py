@@ -7,9 +7,21 @@ TimeWindowTestCase.java): CURRENT/EXPIRED accounting through QueryCallback and
 running aggregates over window contents.
 """
 
+import collections
 import time
 
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax._src import core as jax_core
+from jax._src.interpreters import partial_eval as pe
+
 from siddhi_tpu import SiddhiManager
+from siddhi_tpu.core.event import EventBatch, StreamSchema
+from siddhi_tpu.core.flow import Flow
+from siddhi_tpu.core.types import AttrType
+from siddhi_tpu.core.windows import SlidingWindow
 
 
 def run_app(ql):
@@ -254,3 +266,224 @@ def test_post_window_filter_keeps_timer_scheduling():
         time.sleep(0.02)
     assert got["removed"], "timer-driven expiry never fired through post-window filter"
     mgr.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the length step against a plain deque, stage by stage (both ring steps)
+# ---------------------------------------------------------------------------
+
+_LANES = StreamSchema(
+    "S",
+    [
+        ("b", AttrType.BOOL), ("i", AttrType.INT), ("l", AttrType.LONG),
+        ("f", AttrType.FLOAT), ("d", AttrType.DOUBLE), ("s", AttrType.STRING),
+    ],
+)
+
+
+def _lane_batch(rng, valid, base):
+    n = len(valid)
+    return EventBatch(
+        ts=jnp.asarray(base + np.arange(n), jnp.int64),
+        kind=jnp.zeros((n,), jnp.int8),
+        valid=jnp.asarray(valid),
+        cols={
+            "b": jnp.asarray(rng.integers(0, 2, n).astype(bool)),
+            "i": jnp.asarray(rng.integers(-100, 100, n), jnp.int32),
+            "l": jnp.asarray(rng.integers(-(2**40), 2**40, n), jnp.int64),
+            "f": jnp.asarray(rng.random(n), jnp.float32),
+            "d": jnp.asarray(rng.random(n), jnp.float32),
+            "s": jnp.asarray(rng.integers(1, 9, n), jnp.int32),
+        },
+    )
+
+
+def _length_step(win):
+    def step(state, batch):
+        state, flow = win.apply(
+            state, Flow(batch=batch, ref="S", now=np.int64(0))
+        )
+        return state, flow.batch
+
+    return step
+
+
+def _leaves_equal(a, b):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    return len(la) == len(lb) and all(
+        x.dtype == y.dtype and np.array_equal(np.asarray(x), np.asarray(y))
+        for x, y in zip(la, lb)
+    )
+
+
+@pytest.mark.parametrize(
+    "w,bsz",
+    [(40, 16), (16, 16), (17, 16), (31, 16), (64, 8), (5, 16), (3, 8), (1, 4)],
+)
+def test_length_step_matches_deque_and_scatter_state(w, bsz):
+    """Output rows in order against a deque; ring state slot by slot against
+    the deque's contents and, where the slice step runs (W >= B), byte for
+    byte against the scatter step's. The validity patterns cycle through
+    half valid, full, empty, one row and nine tenths, so every (W, B) sees
+    the fill crossing (total < W <= total + c) and several ring wraps."""
+    rng = np.random.default_rng(w * 131 + bsz)
+    win = SlidingWindow(_LANES, "S", w)
+    step = jax.jit(_length_step(win))
+    state = win.init_state()
+    ref_win = SlidingWindow(_LANES, "S", w)
+    ref_win._pick_ring_step = lambda _bsz: "scatter"
+    ref_step = jax.jit(_length_step(ref_win))
+    ref_state = ref_win.init_state()
+
+    held: collections.deque = collections.deque()  # (seq, row)
+    total = 0
+    patterns = [
+        lambda: rng.random(bsz) < 0.5,
+        lambda: np.ones(bsz, bool),
+        lambda: np.zeros(bsz, bool),
+        lambda: np.arange(bsz) == bsz // 2,
+        lambda: rng.random(bsz) < 0.9,
+    ]
+    for k in range(3 * len(patterns)):
+        valid = patterns[k % len(patterns)]()
+        batch = _lane_batch(rng, valid, 1000 * k)
+        host = jax.tree_util.tree_map(np.asarray, batch)
+        want = []
+        for r in np.flatnonzero(valid):
+            row = tuple(host.cols[n][r] for n in host.cols)
+            if len(held) == w:
+                want.append((1, host.ts[r], held.popleft()[1]))
+            held.append((total, (host.ts[r], row)))
+            total += 1
+            want.append((0, host.ts[r], (host.ts[r], row)))
+
+        state, out = step(state, batch)
+        o = jax.tree_util.tree_map(np.asarray, out)
+        rows = np.flatnonzero(o.valid)
+        assert list(rows) == list(range(len(want))), "valid rows are a prefix"
+        got = [
+            (int(o.kind[p]), o.ts[p], tuple(o.cols[n][p] for n in o.cols))
+            for p in rows
+        ]
+        assert got == [(kind, ts, row[1]) for kind, ts, row in want]
+
+        s = jax.tree_util.tree_map(np.asarray, state)
+        assert int(s["total"]) == total
+        live = {seq % w: (seq, row) for seq, row in held}
+        for slot in range(w):
+            if slot in live:
+                seq, (ts, row) = live[slot]
+                assert s["seq"][slot] == seq and s["ts"][slot] == ts
+                assert s["wts"][slot] == ts
+                assert tuple(s["cols"][n][slot] for n in s["cols"]) == row
+            else:
+                assert s["seq"][slot] == -1
+
+        ref_state, ref_out = ref_step(ref_state, batch)
+        assert _leaves_equal(out, ref_out)
+        assert _leaves_equal(state, ref_state)
+    assert win.ring_step == ("slice" if w >= bsz else "scatter")
+    assert ref_win.ring_step == "scatter"
+
+
+def _ring_sized_eqns(jaxpr, least):
+    """(primitive, shapes) of every equation with an operand or result of
+    at least `least` elements, sub-jaxprs included (their call sites are
+    not counted: the body's own equations are)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        subs = list(jax_core.jaxprs_in_params(eqn.params))
+        for sub in subs:
+            found += _ring_sized_eqns(sub, least)
+        if subs:
+            continue
+        shapes = [
+            v.aval.shape
+            for v in (*eqn.invars, *eqn.outvars)
+            if hasattr(v.aval, "shape")
+        ]
+        if any(int(np.prod(s)) >= least for s in shapes):
+            found.append((eqn.primitive.name, shapes))
+    return found
+
+
+def test_length_step_touches_the_ring_through_slices_only():
+    """The O(batch) property, held on the CPU: with W >= B and nothing
+    reading the membership matrix, no equation that survives dead-code
+    elimination has a [W]- or [W + B]-shaped operand except the slices
+    that read the run and the dynamic_update_slices that write it."""
+    w, bsz = 4096, 64
+
+    def live_ring_eqns(win):
+        batch = _lane_batch(np.random.default_rng(0), np.ones(bsz, bool), 0)
+        closed = jax.make_jaxpr(_length_step(win))(win.init_state(), batch)
+        jaxpr, _ = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))
+        return _ring_sized_eqns(jaxpr, w)
+
+    win = SlidingWindow(_LANES, "S", w)
+    touched = live_ring_eqns(win)
+    assert win.ring_step == "slice"
+    assert {p for p, _ in touched} == {
+        "slice", "dynamic_slice", "dynamic_update_slice"
+    }, touched
+    # the guard sees what it is there to see: the scatter step's passes
+    scatter = SlidingWindow(_LANES, "S", w)
+    scatter._pick_ring_step = lambda _bsz: "scatter"
+    assert {"concatenate", "select_n", "scatter"} <= {
+        p for p, _ in live_ring_eqns(scatter)
+    }
+
+
+_HEAD = "@app:batch(size='8')\ndefine stream S (k string, v long, p float);\n"
+_RING_APPS = {
+    # core/partition.py vmaps the stage: the run's start is batched
+    "partition": _HEAD + """partition with (k of S) begin
+        @info(name='q') from S#window.length(12)
+        select k, sum(v) as s, max(p) as m insert all events into O; end;""",
+    # join sides step their rings through the stage and read them by view()
+    "join": _HEAD + """define stream T (k string, v long, p float);
+        @info(name='q') from S#window.length(9) as a
+        join T#window.length(20) as b on a.k == b.k
+        select a.k as k, a.v as av, b.v as bv insert all events into O;""",
+    # the aggregators that read the membership matrix
+    "member": _HEAD + """@info(name='q') from S#window.length(10)
+        select k, min(v) as mn, max(p) as mx, distinctCount(k) as dc
+        insert all events into O;""",
+    # one ring shared between two queries (core/fusion_exec.py)
+    "shared": _HEAD + """@info(name='q') from S#window.length(16)
+        select k, sum(v) as s insert all events into O;
+        @info(name='q2') from S#window.length(16)
+        select k, avg(p) as a group by k insert all events into O2;""",
+}
+
+
+@pytest.mark.parametrize("app", sorted(_RING_APPS))
+def test_slice_and_scatter_steps_give_the_same_app_output(app, monkeypatch):
+    def run():
+        mgr, rt = run_app(_RING_APPS[app])
+        got = []
+        for q in ("q", "q2"):
+            if q in _RING_APPS[app]:
+                rt.add_callback(q, lambda ts, ins, rem, q=q: got.append(
+                    (q, [(e.timestamp, tuple(e.data))
+                         for e in (ins or []) + (rem or [])])))
+        rng = np.random.default_rng(5)
+        t = 0
+        for _ in range(10):
+            for s in ("S", "T") if app == "join" else ("S",):
+                n = int(rng.integers(1, 30))
+                rows = [
+                    (str(rng.integers(0, 3)), int(rng.integers(0, 100)),
+                     float(rng.integers(0, 50)))
+                    for _ in range(n)
+                ]
+                rt.get_input_handler(s).send_many(
+                    rows, timestamps=list(range(t, t + n)))
+                t += n
+        mgr.shutdown()
+        return got
+
+    sliced = run()
+    monkeypatch.setattr(
+        SlidingWindow, "_pick_ring_step", lambda self, bsz: "scatter")
+    assert sliced and sliced == run()
