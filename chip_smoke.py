@@ -447,7 +447,11 @@ def stage_c(SiddhiManager, sizes: dict, seed: int, n_dev: int,
     out = {}
     for axis, (B, qid, ql) in _shard_apps(sizes).items():
         t_stage = time.perf_counter()
-        n_rows = B * 16  # the sharded steps dispatch per micro-batch
+        # one send of 16 micro-batches: under axis='keys' the sharded step
+        # runs inside the fused chunk program (one chunk of 16 on the mesh,
+        # both runs on the fused path); the partitioned twin's steps
+        # dispatch per micro-batch
+        n_rows = B * 16
         data = make_plug_data(seed + 1, n_rows)
         ql = f"@app:batch(size='{B}')\n" + ql
         rows_of, placed_on = {}, None
@@ -480,8 +484,11 @@ def stage_c(SiddhiManager, sizes: dict, seed: int, n_dev: int,
                 check(sh is not None and sh["devices"] == n_dev,
                       f"{axis}: ShardRuntime.n != {n_dev}: {sh}")
                 if axis == "keys":
-                    check(sh.get("keyshard", {}).get(qid, {}).get("sharded"),
+                    placed = sh.get("keyshard", {}).get(qid, {})
+                    check(placed.get("sharded"),
                           f"keys: query not key-sharded: {sh}")
+                    check(placed.get("path") == "fused",
+                          f"keys: the send left the fused path: {placed}")
                     state = rt.queries[qid].state
                 else:
                     placed = sh.get("partitioned", {}).get(qid, {})

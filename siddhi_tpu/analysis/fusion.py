@@ -75,7 +75,6 @@ H_RATE = "rate-limit"
 H_SCHEDULER = "scheduler"
 H_MULTI = "multi-stream"
 H_ORDERING = "ordering"
-H_KEYSHARD = "keyshard-state"
 
 _HAZARD_WHY = {
     H_ASYNC: "@async ingress runs its own worker; the fused chunk path "
@@ -90,9 +89,6 @@ _HAZARD_WHY = {
     H_ORDERING: "its insert target has downstream consumers: the fused "
                 "chunk cannot re-publish per batch without reordering "
                 "delivery",
-    H_KEYSHARD: "@app:shard axis='keys' key-shards this query's group-by "
-                "state across the mesh; its [D] state steps under its own "
-                "shard_map program and cannot join a fused chunk body",
 }
 
 
@@ -197,30 +193,8 @@ def _collect_consumers(app: SiddhiApp, defined_streams: set) -> list:
     return out
 
 
-def _keyshard_candidate(q: Query) -> bool:
-    """AST-level mirror of `parallel/keyshard.keyed_shardable`: a plain
-    windowless grouped single-stream query with no host-side ordering
-    state. Deliberately a SUPERSET of the runtime predicate (table probes
-    are invisible here) — a vetoed-but-ultimately-unsharded query simply
-    rides the residual per-batch path, which is always correct."""
-    sel = q.selector
-    if not getattr(sel, "group_by", None):
-        return False
-    stream = q.input_stream
-    if not isinstance(stream, SingleInputStream) or stream.is_inner:
-        return False
-    if any(isinstance(h, WindowHandler) for h in stream.handlers):
-        return False
-    if q.output_rate is not None:
-        return False
-    if sel.order_by or sel.limit is not None or sel.offset is not None:
-        return False
-    return True
-
-
 def _query_hazard(
     c: _Consumer, model: AppCostModel, observed_targets: set,
-    keyshard: bool = False,
 ) -> Optional[str]:
     """First fusion hazard excluding query `c` from its stream's group,
     or None when it can fuse. Order matters: report the most structural
@@ -235,8 +209,6 @@ def _query_hazard(
     qc = model.queries.get(c.qid)
     if qc is not None and qc.scheduler_armed:
         return H_SCHEDULER
-    if keyshard and _keyshard_candidate(c.query):
-        return H_KEYSHARD
     target = getattr(c.query.output_stream, "target", None)
     if target is not None and target in observed_targets:
         return H_ORDERING
@@ -266,19 +238,21 @@ def build_fusion_plan(
     plan = FusionPlan(
         app.name, model.batch_size, model.chunk_batches, costs=model
     )
-    # @app:shard axis='keys' (or the env overrides) key-shards eligible
-    # grouped queries out of fused groups — same resolution the runtime
+    # @app:shard axis='keys' (or the env overrides): eligible grouped
+    # queries keep their place in the group and the group's chunk program
+    # runs on the keys mesh (core/ingest.py) — same resolution the runtime
     # uses, so the plan and ShardRuntime placement can never disagree
-    keyshard_on = False
+    mesh = None
     try:
         from siddhi_tpu.parallel.shard import resolve_shard_annotation
 
         devs, axis = resolve_shard_annotation(
             find_annotation(app.annotations, "app:shard")
         )
-        keyshard_on = devs >= 2 and axis == "keys"
+        if devs >= 2 and axis == "keys":
+            mesh = {"devices": devs, "axis": axis}
     except Exception:  # pragma: no cover — plan must survive bad apps
-        keyshard_on = False
+        pass
     consumers = _collect_consumers(app, set(sym.streams))
 
     # streams whose defined consumers number >= 2 are fusion-planning
@@ -308,7 +282,7 @@ def build_fusion_plan(
         fusable: list[_Consumer] = []
         for c in cs:
             hazard = H_ASYNC if async_ann is not None else _query_hazard(
-                c, model, observed_targets, keyshard=keyshard_on
+                c, model, observed_targets
             )
             if hazard is None:
                 fusable.append(c)
@@ -345,6 +319,11 @@ def build_fusion_plan(
                 "dispatches_per_chunk_after": 1,
                 "est_dispatch_reduction": round(1.0 - 1.0 / (n * K), 4),
             })
+            if mesh is not None:
+                # the group's chunk program runs on the keys mesh when a
+                # member turns out key-shardable (parallel/keyshard.py
+                # keyed_shardable, a runtime predicate)
+                plan.groups[-1]["mesh"] = mesh
 
     _collect_shared_state(app, sym, model, consumers, plan)
     _collect_wire_specs(app, sym, model, plan, values)

@@ -809,16 +809,6 @@ class SiddhiAppRuntime:
     def _wire_fuse_candidate(self, junction, ep) -> None:
         """Register a FuseEndpoint on `junction` — staged during a
         hot-deploy build, exactly like _wire_subscribe."""
-        devices, axis = self._shard_conf
-        if devices >= 2 and axis == "keys":
-            # keyed-sharded state (parallel/keyshard.py) steps under its
-            # own shard_map program: a fused chunk body would bypass it.
-            # Runtime analog of the planner's H_KEYSHARD blocker.
-            from siddhi_tpu.parallel.keyshard import keyed_shardable
-
-            ok, _why = keyed_shardable(ep.qr)
-            if ok:
-                return
         if self._staged_wiring is not None:
             self._staged_wiring.append(
                 lambda _j=junction, _e=ep: _j.fuse_candidates.append(_e)
@@ -1055,10 +1045,17 @@ class SiddhiAppRuntime:
         )
         from siddhi_tpu.core.ingest import FuseEndpoint
 
+        # under @app:shard(axis='keys') an eligible grouped query joins the
+        # fused group like any other: its step and its [D] state are the
+        # KeyShardedGroupExec's (parallel/keyshard.py arms it at start,
+        # before any program is built), and the chunk program runs on the
+        # keys mesh (core/ingest.py _mesh_placement)
         self._wire_fuse_candidate(in_junction, FuseEndpoint(
             qr,
-            impl_factory=lambda _qr=qr: _qr._step_impl,
-            init_state=lambda now, _qr=qr: _qr.init_state(),
+            impl_factory=lambda _qr=qr: (_qr._keyshard or _qr)._step_impl,
+            init_state=lambda now, _qr=qr: (
+                _qr._keyshard or _qr
+            ).init_state(),
             latency_tracker=lt,
         ))
 

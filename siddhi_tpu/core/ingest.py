@@ -195,6 +195,14 @@ class FusedJunctionIngest:
         # — micro-batches round-robin across devices, outputs merged back
         # in batch order. None = one attribute check per send.
         self.shard_router = None
+        # key-sharded members (parallel/keyshard.py, @app:shard axis='keys'):
+        # set by _build when an endpoint's state lives on the keys mesh —
+        # (per-endpoint state shardings, replicated, the sharded members'
+        # KeyShardedGroupExecs). The chunk program then
+        # is one program over that mesh: [D] states sharded, everything
+        # else (wire, other members' states, packs) replicated. None = one
+        # device, one attribute check per chunk.
+        self._mesh_place = None
         # lineage (observability/lineage.py): True when any endpoint has a
         # recorder armed — the chunk program then returns stacked `__lin.*`
         # lanes consumed per micro-batch; False = one check per chunk
@@ -218,6 +226,7 @@ class FusedJunctionIngest:
             "pipeline_enabled": self.pipeline_enabled,
             "depth": self.pipeline_depth if self.pipeline_enabled else 0,
             "component": self.component,
+            "mesh_devices": self._mesh_devices(),
         }
         gr = self.group_report()
         if gr is not None:
@@ -242,6 +251,12 @@ class FusedJunctionIngest:
                 capacity=self.junction.batch_size,
             )
         return d
+
+    def _mesh_devices(self) -> int:
+        """Devices the chunk program runs on: the keys mesh's when a member
+        is key-sharded, else 1."""
+        place = self._mesh_place
+        return 1 if place is None else place[1].mesh.devices.size
 
     def force_full_width(self) -> None:
         """Pin the wire full-width permanently, discarding any chosen
@@ -372,6 +387,38 @@ class FusedJunctionIngest:
             else frozenset(n for n in schema.attr_names if n in used)
         )
         return self._keep
+
+    def _mesh_placement(self):
+        """(per-endpoint state shardings, replicated, sharded members'
+        execs) when an endpoint is key-sharded (its `impl` is
+        KeyShardedGroupExec's shard_map step over a `[D]` state), else None.
+        Share sets need a window and a key-shardable query has none, so a
+        sharded member is never in one."""
+        execs = [getattr(ep.qr, "_keyshard", None) for ep in self.endpoints]
+        sharded = [e for e in execs if e is not None]
+        if not sharded:
+            return None
+        repl = sharded[0].replicated
+        return (
+            tuple(repl if e is None else e.state_sharding for e in execs),
+            repl,
+            sharded,
+        )
+
+    def _place_on_mesh(self, arg0, tstates):
+        """The chunk program's state arguments where the mesh program wants
+        them: a no-op for states a dispatch of either path wrote back, a
+        transfer for a fresh or restored one (and for the unsharded members'
+        of a mixed group, which the per-batch path may have left on one
+        device)."""
+        state_sh, repl, _execs = self._mesh_place
+        states, shared = arg0 if self.share_sets else (arg0, None)
+        states = tuple(
+            jax.device_put(st, sh) for st, sh in zip(states, state_sh)
+        )
+        if shared is not None:
+            states = (states, jax.device_put(shared, repl))
+        return states, jax.device_put(tstates, repl)
 
     def _build(self, deliver_set: Optional[frozenset] = None):
         deliver = deliver_set is not None
@@ -551,7 +598,22 @@ class FusedJunctionIngest:
 
         # donate the per-endpoint states (exclusively owned); tstates may
         # alias read-only findables shared with other runtimes — not donated
-        prog = jax.jit(fused, donate_argnums=(0,))
+        place = self._mesh_place = self._mesh_placement()
+        if place is None:
+            prog = jax.jit(fused, donate_argnums=(0,))
+        else:
+            # a key-sharded member: ONE program over the keys mesh. Its [D]
+            # state stays sharded (in: _place_on_mesh, out: here), the rest
+            # is replicated — every device decodes the whole wire, runs the
+            # unsharded members and packs, so the drain reads one device
+            state_sh, repl, _execs = place
+            prog = jax.jit(
+                fused, donate_argnums=(0,),
+                out_shardings=(
+                    (state_sh, repl) if has_share else state_sh,
+                    repl, repl, repl, repl,
+                ),
+            )
         if deliver:
             self._fused_deliver = prog
             self._deliver_set = deliver_set
@@ -764,6 +826,11 @@ class FusedJunctionIngest:
                 drain_fn=self._drain,
             )
             pl.stats = getattr(self.junction, "pipeline_stats", None)
+        # the wire goes where the chunk program runs: replicated on the
+        # keys mesh when a member is key-sharded (set by _build, which every
+        # engaged send has been through by now)
+        place = self._mesh_place
+        pl.wire_sharding = None if place is None else place[1]
         return pl
 
     def close(self) -> None:
@@ -824,6 +891,10 @@ class FusedJunctionIngest:
                 ts_ep = ep.qr._collect_table_states()
                 ep_tids.append(list(ts_ep))
                 tstates.update(ts_ep)
+            if self._mesh_place is not None:
+                arg0, tstates = self._place_on_mesh(arg0, tstates)
+                for ks in self._mesh_place[2]:
+                    ks.path = "fused"
             span = (
                 tr.start_span(stream_span, int(counts.sum()))
                 if tr is not None
@@ -1271,7 +1342,10 @@ class FusedJunctionIngest:
                 encode, ts_arr, cols, c_off, c_end, B, K, slot.buf,
                 enc, wf, chunk,
             )
-        with stage("h2d", ps and ps.h2d, wf=wf, chunk=chunk):
+        with stage(
+            "h2d", ps and ps.h2d, wf=wf, chunk=chunk,
+            devices=self._mesh_devices(),
+        ):
             dev_wire = pl.ship(slot)
         return (
             (dev_wire, counts, bases, K, slot, wf, chunk), c_end, prog, encode
@@ -1301,10 +1375,10 @@ class FusedJunctionIngest:
                     tstates.update(ep.qr._collect_table_states())
                 # zero counts: every lane is invalid, no state is observable;
                 # the throwaway states are donated, the table states are not
-                prog(
-                    self._pack_arg0(list(states)), tstates, wire, counts,
-                    bases, np.int64(now),
-                )
+                arg0 = self._pack_arg0(list(states))
+                if self._mesh_place is not None:
+                    arg0, tstates = self._place_on_mesh(arg0, tstates)
+                prog(arg0, tstates, wire, counts, bases, np.int64(now))
         except Exception:
             import logging
 
@@ -1386,7 +1460,11 @@ class FusedJunctionIngest:
                     continue
                 layout, row_bytes = self._deliver_layout[i]
                 hdr_rows = -(-4 * K // row_bytes)
-                R = pack["buf"].shape[0] - hdr_rows
+                buf = pack["buf"]
+                if self._mesh_place is not None:
+                    # replicated over the mesh: read (and slice) one copy
+                    buf = buf.addressable_data(0)
+                R = buf.shape[0] - hdr_rows
 
                 def bucket(x: int) -> int:
                     return min(R, 1 << max(0, int(x - 1).bit_length()))
@@ -1405,7 +1483,7 @@ class FusedJunctionIngest:
                     wf=wf, wf_name="device" if first_get else "readback",
                 ):
                     head = np.ascontiguousarray(
-                        jax.device_get(pack["buf"][: hdr_rows + guess])
+                        jax.device_get(buf[: hdr_rows + guess])
                     )
                 first_get = False
                 cnts = head[:hdr_rows].reshape(-1)[: 4 * K].view(np.int32)
@@ -1420,7 +1498,7 @@ class FusedJunctionIngest:
                     with stage("readback", sync, wf=wf):
                         tail = np.ascontiguousarray(
                             jax.device_get(
-                                pack["buf"][hdr_rows + guess : hdr_rows + L]
+                                buf[hdr_rows + guess : hdr_rows + L]
                             )
                         )
                     host = np.concatenate([head[hdr_rows:], tail])
@@ -1438,13 +1516,20 @@ class FusedJunctionIngest:
         batch shard router's merged drain (segments interleaved back into
         global batch order, parallel/shard.py) — one delivery code path, so
         callback grouping/ordering semantics cannot drift between them.
-        The `decode` stage (lane views + host decode), one `callback` stage
-        per micro-batch and the `release` stage, in which the chunk's rows
-        are dropped (freeing a million `Event`s takes its time); together
-        the waterfall's `deliver`."""
+        Rows are decoded one micro-batch at a time, just before that
+        micro-batch's callbacks, and dropped right after them: the host
+        never holds more than one segment's `Event`s, so their memory is
+        reused from segment to segment instead of being mapped and unmapped
+        once per chunk (half a million rows are some 140 MB of small objects,
+        and the page faults they cost grow fewer as the process ages, so a
+        send's time drifted through a run). Stages: one
+        `decode` for the chunk's lane views, then per micro-batch a `decode`
+        (host decode of its rows), a `callback` and a `release` (dropping
+        what no callback kept); together the waterfall's `deliver`."""
         from siddhi_tpu.core.event import (
             KIND_CURRENT,
             KIND_EXPIRED,
+            events_from_arrays,
             rows_from_arrays,
         )
         from siddhi_tpu.query_api.execution import OutputEventsFor
@@ -1468,6 +1553,8 @@ class FusedJunctionIngest:
         fast = want is not OutputEventsFor.ALL and raw is not None and len(
             raw
         ) == len(qr.query_callbacks)
+        split = want is OutputEventsFor.ALL
+        expired = want is OutputEventsFor.EXPIRED
         with stage("decode", wf=wf, wf_name="deliver"):
             lanes = {}
             for name, dt, off in layout:
@@ -1475,72 +1562,43 @@ class FusedJunctionIngest:
                     host[:total, off : off + dt.itemsize]
                 ).view(dt)[:, 0]
             cols = {n: lanes[f"c.{n}"] for n in qr.out_schema.attr_names}
-            if fast:
-                from siddhi_tpu.core.event import events_from_arrays
-
-                events = events_from_arrays(
-                    qr.out_schema, lanes["ts"], cols, total, qr._interner
-                )
-            else:
-                kind = (
-                    lanes["kind"]
-                    if want is OutputEventsFor.ALL
-                    else int(
-                        KIND_CURRENT
-                        if want is not OutputEventsFor.EXPIRED
-                        else KIND_EXPIRED
-                    )
-                )
-                rows = rows_from_arrays(
-                    qr.out_schema, lanes["ts"], kind, cols, total,
-                    qr._interner,
-                )
-        if fast:
-            expired = want is OutputEventsFor.EXPIRED
-            off = 0
-            for k in range(len(cnts)):
-                c = int(cnts[k])
-                if c == 0:
-                    continue
-                seg = events[off : off + c]
-                off += c
-                ts = seg[-1][0]
-                with stage(
-                    "callback", wf=wf, wf_name="deliver", batch=k, rows=c
-                ):
-                    for cb in raw:
-                        if expired:
-                            cb(ts, None, seg)
-                        else:
-                            cb(ts, seg, None)
-            with stage("release", wf=wf, wf_name="deliver"):
-                # what no callback kept of the chunk's rows is freed here
-                events = seg = lanes = cols = None
-            return
-        split = want is OutputEventsFor.ALL
         off = 0
         for k in range(len(cnts)):
             c = int(cnts[k])
             if c == 0:
                 continue
-            seg = rows[off : off + c]
+            with stage("decode", wf=wf, wf_name="deliver"):
+                ts_k = lanes["ts"][off : off + c]
+                cols_k = {n: a[off : off + c] for n, a in cols.items()}
+                if fast:
+                    seg = events_from_arrays(
+                        qr.out_schema, ts_k, cols_k, c, qr._interner
+                    )
+                    ins, removed = (None, seg) if expired else (seg, None)
+                else:
+                    kind = (
+                        lanes["kind"][off : off + c]
+                        if split
+                        else int(KIND_EXPIRED if expired else KIND_CURRENT)
+                    )
+                    seg = rows_from_arrays(
+                        qr.out_schema, ts_k, kind, cols_k, c, qr._interner
+                    )
+                    if split:
+                        ins = [e for e in seg if e[1] == KIND_CURRENT] or None
+                        removed = [
+                            e for e in seg if e[1] == KIND_EXPIRED
+                        ] or None
+                    else:
+                        ins, removed = (None, seg) if expired else (seg, None)
             off += c
-            if split:
-                ins = [e for e in seg if e[1] == KIND_CURRENT]
-                removed = [e for e in seg if e[1] == KIND_EXPIRED]
-            elif want is OutputEventsFor.EXPIRED:
-                ins, removed = [], seg
-            else:
-                ins, removed = seg, []
-            if ins or removed:
-                ts = seg[-1][0]
-                with stage(
-                    "callback", wf=wf, wf_name="deliver", batch=k, rows=c
-                ):
-                    for cb in qr.query_callbacks:
-                        cb(ts, ins or None, removed or None)
-        with stage("release", wf=wf, wf_name="deliver"):
-            rows = seg = ins = removed = lanes = cols = None
+            ts = seg[-1][0]
+            with stage("callback", wf=wf, wf_name="deliver", batch=k, rows=c):
+                for cb in raw if fast else qr.query_callbacks:
+                    cb(ts, ins, removed)
+            with stage("release", wf=wf, wf_name="deliver"):
+                # what no callback kept of the segment's rows is freed here
+                seg = ins = removed = None
 
     def _probe_aux_keys(self, i: int) -> list:
         """Sorted non-timer aux keys for endpoint i, discovered by tracing

@@ -167,6 +167,10 @@ class IngestPipeline:
         # fn(packs, K, wf, ids, t_submit, tracker): the ingest's _drain
         self.drain_fn = drain_fn
         self.stats = None  # PipelineStats | None, set by the owner
+        # where ship() puts the wire: None = the default device; the owner
+        # sets the keys mesh's replicated sharding when the chunk program
+        # runs on it (a key-sharded member, parallel/keyshard.py)
+        self.wire_sharding = None
         self._pool: dict[tuple, dict] = {}  # (K, nb) -> {slots, next}
         self._cv = threading.Condition()
         self._inflight = 0  # submitted, not yet drained
@@ -234,10 +238,20 @@ class IngestPipeline:
         plain pinned device_put instead.)"""
         import jax
 
-        dev = jax.device_put(slot.buf)
+        if self.wire_sharding is None:
+            dev = jax.device_put(slot.buf)
+            copies = (dev,)
+        else:
+            # one copy per device of the mesh; the slot is free again only
+            # when every device has read its copy — `ref` is the whole
+            # array, whose readiness (or, aliased, the program's) is all of
+            # theirs
+            dev = jax.device_put(slot.buf, self.wire_sharding)
+            copies = tuple(s.data for s in dev.addressable_shards)
         try:
-            slot.aliased = (
-                dev.unsafe_buffer_pointer() == slot.buf.ctypes.data
+            host = slot.buf.ctypes.data
+            slot.aliased = any(
+                c.unsafe_buffer_pointer() == host for c in copies
             )
         except Exception:
             slot.aliased = True  # can't tell: assume the worst

@@ -201,8 +201,8 @@ class _AuxWarnPool:
             self.flush()
 
     def flush(self) -> None:
-        """Drain everything with ONE blocking device read for the whole
-        backlog (all runtimes, all flag kinds stacked into one vector)."""
+        """Drain everything with ONE blocking wait for the whole backlog
+        (all runtimes, all flag kinds; see `_drain`)."""
         import time as _time
 
         with self._lock:
@@ -218,53 +218,40 @@ class _AuxWarnPool:
 
     @staticmethod
     def _drain(pending: dict) -> None:
+        """Read every pending flag to the host and reduce there. No device
+        program runs: a stacked reduction is a program of its own per
+        backlog length and per placement (one device, a mesh), built or
+        loaded in the middle of traffic. Every transfer is started before
+        the first is waited for, so the drain still blocks once."""
+        import logging
+
         import numpy as np
 
-        plan = []  # (qr, [keys]) aligned with scalars
-        scalars = []
+        plan = []  # (qr, {flag kind: [device bools]})
         for _qid, (qr_ref, acc) in pending.items():
             qr = qr_ref()
             if qr is None:
                 continue  # app GC'd un-flushed: drop its backlog
-            keys = sorted(acc)
+            plan.append((qr, acc))
+            for vs in acc.values():
+                for v in vs:
+                    start = getattr(v, "copy_to_host_async", None)
+                    if start is not None:
+                        try:
+                            start()
+                        except Exception:
+                            pass  # the read below reports it
+        for qr, acc in plan:
             try:
-                qr_scalars = [
-                    jnp.stack(
-                        [jnp.asarray(v).astype(bool) for v in acc[k]]
-                    ).any()
-                    for k in keys
-                ]
-            except Exception:
-                import logging
-
-                logging.getLogger(__name__).debug(
-                    "aux flag coalesce failed", exc_info=True
-                )
-                continue  # drop this runtime whole: keeps plan/scalars aligned
-            scalars.extend(qr_scalars)
-            plan.append((qr, keys))
-        if not scalars:
-            return
-        try:
-            vals = np.asarray(jnp.stack(scalars))  # the cycle's single block
-        except Exception:
-            import logging
-
-            logging.getLogger(__name__).debug("aux flag drain failed", exc_info=True)
-            return
-        i = 0
-        for qr, keys in plan:
-            try:
-                qr._check_aux_flags(
-                    {k: bool(vals[i + j]) for j, k in enumerate(keys)}
-                )
+                host = jax.device_get(acc)
+                qr._check_aux_flags({
+                    k: any(bool(np.asarray(v).any()) for v in vs)
+                    for k, vs in host.items()
+                })
             except Exception:  # never let a warning path kill the app
-                import logging
-
                 logging.getLogger(__name__).debug(
-                    "aux flag check failed", exc_info=True
+                    "aux flag drain failed", exc_info=True
                 )
-            i += len(keys)
 
 
 _AUX_WORKER = _AuxWarnPool()
@@ -895,11 +882,11 @@ class QueryRuntime(BaseQueryRuntime):
         if self._unshare_guard is not None:
             self._unshare_guard()
         with self._receive_lock:
+            ks = self._keyshard
+            if ks is not None:
+                ks.path = "batch"
             if self.state is None:
-                ks = self._keyshard
-                self.state = self._fresh(
-                    ks.init_state() if ks is not None else self.init_state()
-                )
+                self.state = self._fresh((ks or self).init_state())
             tstates = self._collect_table_states()
             with self._step_stage() as clock:
                 self.state, tstates, out, aux = self._step(

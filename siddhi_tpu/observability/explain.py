@@ -258,6 +258,11 @@ def build_plan(app, runtime=None) -> dict:
                             "pipelined" if fi.pipeline_enabled else "serial"
                         )
                         counters["chunk_batches"] = fi.K
+                        # a key-sharded member (parallel/keyshard.py): the
+                        # chunk program runs on the keys mesh
+                        mesh_devices = fi._mesh_devices()
+                        if mesh_devices > 1:
+                            counters["mesh_devices"] = mesh_devices
                         # plan-driven group engine: the achieved-vs-predicted
                         # dispatch-reduction ledger (core/fusion_exec.py),
                         # under the cost model's component taxonomy
@@ -493,7 +498,7 @@ def _fmt_counters(c: Optional[dict]) -> str:
     parts = []
     for k in (
         "events", "rate_1m", "queue_depth", "fused", "chunk_batches",
-        "dispatches", "events_in", "events_out", "selectivity",
+        "mesh_devices", "dispatches", "events_in", "events_out", "selectivity",
         "device_ms", "device_share",
     ):
         if k in c:

@@ -29,6 +29,15 @@ windowless grouped query with no host-side ordering state. Everything
 else keeps the single-device step and is reported with a reason in
 `ShardRuntime.describe_state()["keyshard"]`.
 
+Both ingest paths run the same sharded step on the same `[D]` state: the
+per-batch path through the jitted `_step` that `arm()` installs, and the
+fused chunk program (core/ingest.py), whose scan body calls `_step_impl` as
+the endpoint's `impl` — one `jit_fused` over the mesh per chunk, state
+donated, wire replicated. `describe_state()["path"]` says which of the two
+the last dispatch took. Device scopes inside `q.<query>`: `filter` (the
+chain's), `keyshard.route` (owner hash and mask), `selector`,
+`keyshard.exchange` (the collectives).
+
 Snapshot SPI (core/persistence.py): `export_state` canonicalizes the
 `[D, G]` sharded group table into the SINGLE-device layout, so a snapshot
 taken on an 8-device mesh restores onto any mesh size — `import_state`
@@ -134,10 +143,12 @@ def keyed_shardable(qr) -> tuple[bool, Optional[str]]:
 class KeyShardedGroupExec:
     """Key-sharded execution of one eligible grouped query.
 
-    Owns the mesh, the jitted shard_map step (same 4-arg signature as
-    `QueryRuntime._step_impl`, so `receive()`'s timing/writeback path is
-    untouched), the `[D]`-stacked initial state, live per-device
-    key-occupancy gauges, and the snapshot canonicalize/re-hash pair."""
+    Owns the mesh, the shard_map step (same 4-arg signature as
+    `QueryRuntime._step_impl`: jitted for `receive()`, whose
+    timing/writeback path is untouched, and called un-jitted as the fused
+    chunk program's endpoint `impl`), the `[D]`-stacked initial state, live
+    per-device key-occupancy gauges, and the snapshot canonicalize/re-hash
+    pair."""
 
     def __init__(self, qr, devices):
         import jax
@@ -147,8 +158,11 @@ class KeyShardedGroupExec:
         self.devices = list(devices)
         self.n = len(self.devices)
         self.mesh = Mesh(np.array(self.devices), (KEY_AXIS,))
-        shard = NamedSharding(self.mesh, P(KEY_AXIS))
-        repl = NamedSharding(self.mesh, P())
+        shard = self.state_sharding = NamedSharding(self.mesh, P(KEY_AXIS))
+        repl = self.replicated = NamedSharding(self.mesh, P())
+        # which ingest path the last dispatch took: "fused" (the chunk
+        # program) or "batch" (`receive`); None before the first
+        self.path = None
         # donate_argnums matches the unsharded jit: the [D] state updates
         # in place (the first call's host-built state isn't donatable —
         # one ignorable warning, same as the partition mesh path)
@@ -208,15 +222,19 @@ class KeyShardedGroupExec:
             chain_state, flow = qr.chain.apply(st["chain"], flow)
             # the pre-mask flow batch == what the unsharded selector sees
             pre = flow.batch
-            key = qr.selector.group.key_of(flow.env())
-            mine = owner_of(key, D) == d
-            # key-routed pre-pass: CURRENT/EXPIRED rows advance state only
-            # on their owner; TIMER/RESET (and invalid) rows broadcast so
-            # group eras advance in lockstep on every device
-            keep = jnp.where(flow.sign != 0, mine, True)
-            masked = EventBatch(pre.ts, pre.kind, pre.valid & keep, pre.cols)
-            flow = dataclasses.replace(flow, batch=masked)
-            sel_state, out = qr.selector.apply(st["sel"], flow)
+            with jax.named_scope("keyshard.route"):
+                key = qr.selector.group.key_of(flow.env())
+                mine = owner_of(key, D) == d
+                # key-routed pre-pass: CURRENT/EXPIRED rows advance state
+                # only on their owner; TIMER/RESET (and invalid) rows
+                # broadcast so group eras advance in lockstep on every device
+                keep = jnp.where(flow.sign != 0, mine, True)
+                masked = EventBatch(
+                    pre.ts, pre.kind, pre.valid & keep, pre.cols
+                )
+                flow = dataclasses.replace(flow, batch=masked)
+            with jax.named_scope("selector"):
+                sel_state, out = qr.selector.apply(st["sel"], flow)
 
             # ---- exact positional merge (the psum tree fold) ----
             # `mine` partitions EVERY row across the mesh, so the masked
@@ -225,6 +243,9 @@ class KeyShardedGroupExec:
             # the only aggregator lanes that row's group ever touches.
             # (the device scope `keyshard.exchange`: the collectives)
             with jax.named_scope("keyshard.exchange"):
+                # the merge reads ownership from the hash itself, not from
+                # whatever the route masked (XLA folds the two into one)
+                mine = owner_of(key, D) == d
                 merged_valid = lax.psum(out.valid.astype(jnp.int32), KEY_AXIS) > 0
 
                 def merge_col(c):
@@ -274,19 +295,24 @@ class KeyShardedGroupExec:
                     aux_d[LIN + "gkey"] = out2.cols["__group_key__"]
 
             aux_out = {}
-            for k, v in flow.aux.items():
-                if k.startswith(LIN):
-                    aux_out[k] = v  # replicated provenance lanes
-                elif k == "next_timer":
-                    aux_out[k] = lax.pmin(jnp.min(jnp.asarray(v)), KEY_AXIS)
-                else:
-                    # host-warned flags stay SCALAR bools (_check_aux_flags)
-                    aux_out[k] = (
-                        lax.psum(
-                            jnp.asarray(v).astype(jnp.int32).sum(), KEY_AXIS
+            with jax.named_scope("keyshard.exchange"):
+                for k, v in flow.aux.items():
+                    if k.startswith(LIN):
+                        aux_out[k] = v  # replicated provenance lanes
+                    elif k == "next_timer":
+                        aux_out[k] = lax.pmin(
+                            jnp.min(jnp.asarray(v)), KEY_AXIS
                         )
-                        > 0
-                    )
+                    else:
+                        # host-warned flags stay SCALAR bools
+                        # (_check_aux_flags)
+                        aux_out[k] = (
+                            lax.psum(
+                                jnp.asarray(v).astype(jnp.int32).sum(),
+                                KEY_AXIS,
+                            )
+                            > 0
+                        )
 
             new_st = {"chain": chain_state, "sel": sel_state}
             return (
@@ -318,6 +344,7 @@ class KeyShardedGroupExec:
             "devices": self.n,
             "axis": KEY_AXIS,
             "group_capacity": g,
+            "path": self.path,
         }
         if qr.state is None:
             return d
@@ -460,13 +487,6 @@ def apply_keyshard(app_runtime, devices) -> dict:
     re-arms) are left with their live [D] state."""
     from siddhi_tpu.core.query_runtime import QueryRuntime
 
-    fused_members = set()
-    for j in app_runtime.junctions.values():
-        fi = getattr(j, "fused_ingest", None)
-        if fi is not None:
-            for ep in getattr(fi, "endpoints", ()):
-                fused_members.add(id(ep.qr))
-
     placed: dict = {}
     for qid, qr in list(app_runtime.queries.items()):
         if getattr(qr, "_keyshard", None) is not None:
@@ -482,16 +502,6 @@ def apply_keyshard(app_runtime, devices) -> dict:
             type(qr) is QueryRuntime
             and getattr(qr.selector, "group", None) is not None
         )
-        if ok and id(qr) in fused_members:
-            # belt-and-braces: the planner's H_KEYSHARD hazard and the
-            # runtime _wire_fuse_candidate veto keep eligible queries out
-            # of fused groups; if one slipped in, fused dispatch would
-            # bypass the sharded step entirely — refuse, loudly
-            ok, why = False, "member of a fused ingest group"
-            log.warning(
-                "query '%s': keyed sharding skipped — %s (fusion veto "
-                "missed; report this)", qid, why,
-            )
         if not ok:
             if grouped:
                 placed[qid] = {"sharded": False, "reason": why}

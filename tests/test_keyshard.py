@@ -18,7 +18,6 @@ import pytest
 
 from siddhi_tpu import SiddhiManager
 from siddhi_tpu.analysis import build_fusion_plan, compute_costs
-from siddhi_tpu.analysis.fusion import H_KEYSHARD
 from siddhi_tpu.parallel.keyshard import keyed_shardable, mix64, owner_of
 
 SYMS = ["WSO2", "IBM", "GOOG", "MSFT", "ORCL", "AAPL", "AMZN", "NVDA"]
@@ -30,6 +29,7 @@ GB_QL = """@app:batch(size='64')
 """
 
 KEYS8 = "@app:shard(devices='8', axis='keys')\n"
+KEYS4 = "@app:shard(devices='4', axis='keys')\n"
 
 
 def _mgr():
@@ -310,35 +310,127 @@ FUSE_QL = """@app:batch(size='64')
 """
 
 
-class TestFusionVeto:
-    def test_planner_names_the_hazard(self):
-        plan = build_fusion_plan(FUSE_QL.replace("{HEAD}", KEYS8))
-        hazards = {(b["query"], b["hazard"]) for b in plan.blockers}
-        assert ("q", H_KEYSHARD) in hazards
-        b = next(x for x in plan.blockers if x["query"] == "q")
-        assert "key-shards" in b["why"]
-        # without the keys axis the same query has no keyshard hazard
-        plan2 = build_fusion_plan(FUSE_QL.replace("{HEAD}", ""))
-        assert H_KEYSHARD not in {b["hazard"] for b in plan2.blockers}
+class TestFusedOnMesh:
+    """A key-sharded query is a member of its junction's fused group: the
+    chunk program runs its shard_map step on the keys mesh, on the same
+    [D] state the per-batch path steps."""
 
-    def test_fused_run_keeps_query_sharded_with_parity(self, monkeypatch):
+    def test_planner_names_no_keyshard_hazard(self):
+        plan = build_fusion_plan(FUSE_QL.replace("{HEAD}", KEYS8))
+        assert "keyshard-state" not in {b["hazard"] for b in plan.blockers}
+        assert "q" not in {b["query"] for b in plan.blockers}
+        group = next(g for g in plan.groups if g["stream"] == "S")
+        assert group["queries"] == ["f1", "q"]
+        # the plan says where the group's chunk program runs
+        assert group["mesh"] == {"devices": 8, "axis": "keys"}
+        plan2 = build_fusion_plan(FUSE_QL.replace("{HEAD}", ""))
+        assert "mesh" not in plan2.groups[0]
+
+    @pytest.mark.parametrize("devices", [2, 4, 8])
+    def test_fused_run_keeps_query_sharded_with_parity(
+        self, devices, monkeypatch
+    ):
         monkeypatch.setenv("SIDDHI_TPU_FUSE", "1")
+        head = f"@app:shard(devices='{devices}', axis='keys')\n"
         mgr, rt, got = _run(
-            FUSE_QL.replace("{HEAD}", KEYS8), names=("f1", "q"),
-            feeds=2, shard="8", monkeypatch=monkeypatch,
+            FUSE_QL.replace("{HEAD}", head), names=("f1", "q"),
+            feeds=2, shard=str(devices), monkeypatch=monkeypatch,
         )
         assert rt.queries["q"]._keyshard is not None
+        status = rt.snapshot_status()
+        text = rt.explain()
         rt.shutdown()
         mgr.shutdown()
+        pipe = status["streams"]["S"]["pipeline"]
+        assert pipe["enabled"] is True and pipe["mesh_devices"] == devices
+        placed = status["shard"]["keyshard"]["q"]
+        assert placed["sharded"] is True and placed["path"] == "fused"
+        assert sum(placed["per_device_keys"]) == placed["total_keys"] == 8
+        assert f"mesh_devices={devices}" in text
 
-        monkeypatch.setenv("SIDDHI_TPU_FUSE", "0")
         mgr2, rt2, got2 = _run(
             FUSE_QL.replace("{HEAD}", ""), names=("f1", "q"),
             feeds=2, shard="0", monkeypatch=monkeypatch,
         )
+        pipe2 = rt2.snapshot_status()["streams"]["S"]["pipeline"]
         rt2.shutdown()
         mgr2.shutdown()
-        assert got == got2
+        assert pipe2["enabled"] is True and pipe2["mesh_devices"] == 1
+        assert got["q"] and got == got2
+
+    @staticmethod
+    def _sends(head, sizes, monkeypatch, shard):
+        """One app, one `send_columns` per entry of `sizes`; what the two
+        callbacks got, and the key-sharded query's path after each send."""
+        monkeypatch.setenv("SIDDHI_TPU_SHARD", shard)
+        mgr, rt, got = _run(
+            FUSE_QL.replace("{HEAD}", head), names=("f1", "q"), feeds=0,
+        )
+        paths = []
+        for f, n in enumerate(sizes):
+            _feed(rt.get_input_handler("S"), n, 11 + f,
+                  base=1_700_000_000_000 + f * 10_000)
+            ks = rt.queries["q"]._keyshard
+            paths.append(ks.path if ks is not None else None)
+        rt.shutdown()
+        mgr.shutdown()
+        return got, paths
+
+    def test_chunk_plus_residual_rows_match_the_unsharded_run(
+        self, monkeypatch
+    ):
+        # 2 * B + 17 rows: two full micro-batches and a short third
+        sizes = [2 * 64 + 17]
+        got, paths = self._sends(KEYS4, sizes, monkeypatch, "4")
+        plain, _ = self._sends("", sizes, monkeypatch, "0")
+        assert paths == ["fused"]
+        assert len(got["q"]) == sizes[0] and got == plain
+
+    def test_fused_and_per_batch_sends_step_one_state(self, monkeypatch):
+        # a chunk, then rows below 2 * B through `receive`, then a chunk:
+        # both paths read and write the same [D] state
+        sizes = [5 * 64 + 17, 30, 2 * 64, 64 + 1, 4 * 64]
+        got, paths = self._sends(KEYS4, sizes, monkeypatch, "4")
+        plain, _ = self._sends("", sizes, monkeypatch, "0")
+        assert paths == ["fused", "batch", "fused", "batch", "fused"]
+        assert len(got["q"]) == sum(sizes) and got == plain
+
+    @pytest.mark.parametrize("dst", ["2", "0"])
+    def test_snapshot_after_fused_send_restores_on_a_smaller_mesh(
+        self, dst, monkeypatch
+    ):
+        def run(shard, snap=None):
+            monkeypatch.setenv("SIDDHI_TPU_SHARD", shard)
+            head = (
+                f"@app:shard(devices='{shard}', axis='keys')\n"
+                if shard != "0" else ""
+            )
+            mgr, rt, got = _run(GB_QL.replace("{HEAD}", head), feeds=0)
+            if snap is None:
+                _feed(rt.get_input_handler("S"), 256, 5)
+                out = rt.snapshot()
+            else:
+                rt.restore(snap)
+                got["q"].clear()
+                # a chunk, then a per-batch send, on the restored state
+                _feed(rt.get_input_handler("S"), 256, 6,
+                      base=1_700_000_001_000)
+                _feed(rt.get_input_handler("S"), 40, 7,
+                      base=1_700_000_002_000)
+                out = None
+            ks = rt.queries["q"]._keyshard
+            path = ks.path if ks is not None else None
+            res = list(got["q"])
+            rt.shutdown()
+            mgr.shutdown()
+            return res, out, path
+
+        _, snap, path = run("4")
+        assert path == "fused"
+        _, snap0, _ = run("0")
+        control, _, _ = run("0", snap=snap0)
+        cont, _, _ = run(dst, snap=snap)
+        assert cont and cont == control
 
 
 PAD_QL = """@app:batch(size='64')

@@ -1,0 +1,368 @@
+"""The benchmark's four-chip deployment `debs14-plug-keys4` (the smart-plug
+group-by key-sharded over the keys mesh, on the fused ingest path) held on
+the 8-device virtual CPU mesh: its rehearsal, its reference against the
+engine, its generator against the one-chip plug configuration's, the chunk
+program of the standing configurations (unchanged by the mesh path), and a
+compile of the sharded chunk program for a described v5e:2x2."""
+
+from __future__ import annotations
+
+import hashlib
+import json
+import os
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+BENCH = ROOT / "benchmark"
+for p in (str(ROOT), str(BENCH)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+import harness  # noqa: E402  (benchmark/harness.py)
+
+CONFIGS = BENCH / "configs"
+KEYS4 = "debs14-plug-keys4"
+
+
+def load(config: str):
+    cdir = CONFIGS / config
+    return (harness.load_module(cdir / "gen.py"),
+            harness.load_module(cdir / "reference.py"),
+            json.loads((cdir / "config.json").read_text()))
+
+
+def deploy(config: str, batch: int, callback=None):
+    """The configuration's app at `batch`, started, with a query callback."""
+    from siddhi_tpu import SiddhiManager
+
+    gen, _, cfg = load(config)
+    sizes = {**cfg["sizes"], **cfg.get("rehearse_sizes", {}), "batch": batch}
+    text = (CONFIGS / config / "app.siddhi").read_text().format(**sizes)
+    mgr = SiddhiManager()
+    for names in gen.STRINGS.values():
+        for s in names:
+            mgr.interner.intern(s)
+    rt = mgr.create_siddhi_app_runtime(text)
+    rt.add_callback(cfg["query"], callback or (lambda ts, ins, removed: None))
+    rt.start()
+    return mgr, rt, gen, cfg
+
+
+def chunk_program(rt, gen, cfg, batch: int):
+    """(fused ingest engine, its deliver-mode chunk program) with the wire
+    chosen from the configuration's own rows, nothing sent."""
+    from siddhi_tpu.core.wire import choose_encodings
+
+    fi = rt.junctions[cfg["stream"]].fused_ingest
+    n = -(-batch // getattr(gen, "CYCLE_ROWS", 1)) * getattr(gen, "CYCLE_ROWS", 1)
+    ts = gen.timestamps(0, n)
+    cols = gen.make(7, n)
+    index = {c: np.arange(1, len(v) + 1, dtype=np.int32)
+             for c, v in gen.STRINGS.items()}
+    cols = {k: (index[k][v] if k in index else v) for k, v in cols.items()}
+    cols = gen.with_index(cols, 0, n, ts)
+    fi._narrow = choose_encodings(
+        fi.junction.schema, fi._compute_keep(), fi.wire_spec, fi.wire_enabled,
+        ts[:batch], {k: np.asarray(v)[:batch] for k, v in cols.items()})
+    fi._build(deliver_set=frozenset({0}))
+    return fi, fi._fused_deliver
+
+
+def chunk_arguments(fi, K: int = 32, place=None):
+    """Shapes of one call of the chunk program; `place` = (state sharding,
+    replicated) puts them on a mesh."""
+    import jax
+    import jax.numpy as jnp
+
+    st_sh, repl = place or (None, None)
+
+    def shape(l, sh):
+        return jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=sh)
+
+    state = jax.eval_shape(lambda: fi.endpoints[0].init_state(0))
+    return (
+        (jax.tree_util.tree_map(lambda l: shape(l, st_sh), state),), {},
+        jax.ShapeDtypeStruct((K, fi._wire_bytes), jnp.uint8, sharding=repl),
+        jax.ShapeDtypeStruct((K,), jnp.int32, sharding=repl),
+        jax.ShapeDtypeStruct((K,), jnp.int64, sharding=repl),
+        jax.ShapeDtypeStruct((), jnp.int64, sharding=repl),
+    )
+
+
+def lowered_text(config: str) -> str:
+    """`jit_fused` of `config` at its rehearse sizes, lowered, without debug
+    metadata."""
+    _, _, cfg = load(config)
+    mgr, rt, gen, cfg = deploy(config, cfg["rehearse_sizes"]["batch"])
+    try:
+        fi, prog = chunk_program(rt, gen, cfg, cfg["rehearse_sizes"]["batch"])
+        assert fi._mesh_place is None and fi._mesh_devices() == 1
+        return prog.lower(*chunk_arguments(fi)).as_text()
+    finally:
+        rt.shutdown()
+        mgr.shutdown()
+
+
+# sha256 of `lowered_text`: the chunk program of the two standing
+# configurations as the parent of PR 26 lowered it (computed on a checkout of
+# that commit with this very function). The mesh path may not move it: an app
+# without @app:shard takes the same `jax.jit(fused, donate_argnums=(0,))`. A
+# PR that changes the chunk program on purpose replaces these, and knows by
+# that that the standing cells' device time may have moved.
+STANDING_PROGRAMS = {
+    "debs14-q1-plug": "51b485124ec34a1586e7bd7e229f309d0e8bdd3a77a90db647e833e212b97238",
+    "siddhi-simple-filter": "233fdbcf647ded2693ff29f1f81344e59ca926ef5e1a7feaac442c8f1f642fcc",
+}
+
+
+@pytest.mark.parametrize("config", sorted(STANDING_PROGRAMS))
+def test_standing_chunk_programs_lower_as_before(config):
+    text = lowered_text(config)
+    assert "sharding" not in text and "all_reduce" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == STANDING_PROGRAMS[config]
+
+
+def test_both_plug_configurations_generate_one_stream():
+    gen4, ref4, cfg4 = load(KEYS4)
+    gen1, ref1, cfg1 = load("debs14-q1-plug")
+    for seed in (5, 2**31 + 77):
+        n = 3 * gen1.CYCLE_ROWS
+        a, b = gen4.make(seed, n), gen1.make(seed, n)
+        assert sorted(a) == sorted(b)
+        for k in a:
+            assert a[k].dtype == b[k].dtype and np.array_equal(a[k], b[k]), k
+        ts = gen4.timestamps(17, 17 + n)
+        assert np.array_equal(ts, gen1.timestamps(17, 17 + n))
+        ia, ib = (g.with_index(c, 17, 17 + n, ts) for g, c in ((gen4, a), (gen1, b)))
+        assert all(np.array_equal(ia[k], ib[k]) for k in ib)
+        assert np.array_equal(ref4.kept(ia), ref1.kept(ib))
+    assert gen4.CYCLE_ROWS == gen1.CYCLE_ROWS and gen4.STRINGS == gen1.STRINGS
+    for k in ("plugs", "houses", "batch", "group_capacity"):
+        assert cfg4["sizes"][k] == cfg1["sizes"][k]
+
+
+def test_reference_by_hand_and_in_steps():
+    gen, ref, cfg = load(KEYS4)
+    plug = np.array([0, 1, 0, 0, 1, 0], dtype=np.int32)
+    cols = {
+        "ts": np.arange(6, dtype=np.int64),
+        "value": np.array([10, 20, 99, 5, 40, 50], dtype=np.float32),
+        "property": np.array([1, 1, 0, 1, 1, 1], dtype=bool),
+        "plug_id": plug, "household_id": np.zeros(6, np.int32), "house_id": plug,
+    }
+    out = ref.reference(np.arange(6, dtype=np.int64) * 1000, cols, {"houses": 2})
+    assert out["event_time"].tolist() == [0, 1000, 3000, 4000, 5000]
+    assert out["plug_id"].tolist() == [0, 1, 0, 1, 0]
+    assert out["maxLoad"].tolist() == [10.0, 20.0, 10.0, 40.0, 50.0]
+    assert out["n"].tolist() == [1, 1, 2, 2, 3]
+    assert out["maxLoad"].dtype == np.float32 and out["n"].dtype == np.int64
+    # carried along in steps of any length, emitting or not, it gives what
+    # one pass gives; negative and zero loads keep their order
+    n = 4 * gen.CYCLE_ROWS
+    ts = gen.timestamps(0, n)
+    cols = gen.with_index(gen.make(11, n), 0, n, ts)
+    cols["value"] = cols["value"] - np.float32(30.0)
+    whole = ref.reference(ts, cols, cfg["sizes"])
+    keep = ref.kept(cols)
+    kts, kcols = ts[keep], {k: v[keep] for k, v in cols.items()}
+    run, at = ref.Running(cfg["sizes"]), 0
+    for step, emit in [(700, False), (3000, True), (1, True), (2222, False),
+                       (len(kts), True)]:
+        upto = min(at + step, len(kts))
+        out = run.step(kts[at:upto], {k: v[at:upto] for k, v in kcols.items()},
+                       None, emit)
+        for lane, values in (out or {}).items():
+            assert np.array_equal(values, whole[lane][at:upto]), lane
+        at = upto
+    code = ref.plug_code(kcols)
+    for i in (0, 2125, 2126, len(kts) - 1):
+        mine = code[:i + 1] == code[i]
+        assert whole["maxLoad"][i] == kcols["value"][:i + 1][mine].max()
+        assert whole["n"][i] == mine.sum()
+
+
+def test_control_in_bfloat16_fails_maxload_alone():
+    gen, ref, cfg = load(KEYS4)
+    n = 12 * gen.CYCLE_ROWS
+    ts = gen.timestamps(0, n)
+    cols = gen.with_index(gen.make(2_900_000_001, n), 0, n, ts)
+    want = ref.reference(ts, cols, cfg["sizes"])
+    control = ref.reference(ts, cols, cfg["sizes"], control=True)
+    for name, rule in cfg["compare"].items():
+        assert rule["limit"] == 0
+        assert harness.lane_gap(want[name], want[name], rule) == 0
+        broken = harness.lane_gap(control[name], want[name], rule)
+        assert (broken > 0.9 * len(want[name])) if name == "maxLoad" else broken == 0
+
+
+def test_engine_against_the_reference_at_batch_512():
+    """Every emission of a seeded stream, fused sends and a per-batch one,
+    lane for lane against the configuration's NumPy reference."""
+    got = []
+    mgr, rt, gen, cfg = deploy(
+        KEYS4, 512, lambda ts, ins, removed: got.extend(ins or []))
+    _, ref, _ = load(KEYS4)
+    n = 10 * gen.CYCLE_ROWS
+    ts = gen.timestamps(0, n)
+    cols = gen.with_index(gen.make(2**31 + 12345, n), 0, n, ts)
+    handler = rt.get_input_handler(cfg["stream"])
+    paths = []
+    for lo, hi in ((0, 64 * 512), (64 * 512, 64 * 512 + 300), (64 * 512 + 300, n)):
+        handler.send_columns(ts[lo:hi], {k: v[lo:hi] for k, v in cols.items()})
+        paths.append(rt.queries[cfg["query"]]._keyshard.path)
+    status = rt.snapshot_status()
+    rt.shutdown()
+    mgr.shutdown()
+    assert paths == ["fused", "batch", "fused"]
+    placed = status["shard"]["keyshard"][cfg["query"]]
+    assert placed["sharded"] is True and placed["devices"] == 4
+    assert placed["total_keys"] == cfg["sizes"]["plugs"]
+    assert status["streams"][cfg["stream"]]["pipeline"]["mesh_devices"] == 4
+    want = ref.reference(ts, cols, cfg["sizes"])
+    assert len(got) == len(want["event_time"]) == n // 2
+    assert np.array_equal([e[0] for e in got], want["event_time"])
+    for k, name in enumerate(cfg["outputs"]):
+        lane = np.array([e[1][k] for e in got])
+        assert np.array_equal(lane, want[name]), name
+
+
+def test_new_readers_find_nothing_in_a_trace_without_the_mesh(tmp_path):
+    """The cell's per-layer readers on the chip trace the benchmark keeps of
+    an older tree (no chunk program, no span, no scope): each returns None
+    and none raises, which is what the parent's side of a check needs."""
+    import gzip
+
+    import trace_reduce
+
+    out = tmp_path / "bench_out" / "old.cell" / "trace" / "plugins" / "profile" / "t"
+    out.mkdir(parents=True)
+    packed = BENCH / "tests" / "data" / "trickle_0p3s.xplane.pb.gz"
+    (out / "t.xplane.pb").write_bytes(gzip.decompress(packed.read_bytes()))
+    trace = trace_reduce.load(str(out / "t.xplane.pb"))
+    cell = {"name": "old.cell", "bench_dir": tmp_path / "benchmark",
+            "config": {"stream": "S", "query": "q"}, "sizes": {},
+            "config_dir": CONFIGS / KEYS4}
+    spans = {"sends": np.zeros((12, 4))}
+    counters = {"status": {"streams": {"S": {}}}}
+    manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
+    mine = [m for m in manifest["per_layer"]
+            if m.get("workloads") == ["plug-keys4.bulk"]]
+    assert len(mine) == 20
+    assert {m["moves"] for m in mine} == {"events_per_s.filter"}
+    for m in mine:
+        reader = harness.load_module(harness.reader_file(BENCH, m["name"]))
+        assert reader.read(trace, spans, counters, cell) is None, m["name"]
+
+
+def rehearse(capsys):
+    import run as bench_run
+
+    rc = bench_run.main(
+        ["--workload", "plug-keys4.bulk", "--seed", str(2**31 + 26),
+         "--seconds", "1.5", "--trace", "0", "--rehearse"])
+    out = capsys.readouterr().out
+    assert rc == 0, out
+    return json.loads(out.strip().splitlines()[-1]), out
+
+
+def test_rehearsal_of_the_four_chip_cell_is_correct(capsys):
+    result, out = rehearse(capsys)
+    assert result["correct"] is True and result["failed"] == 0, out
+    assert result["attempted"] > 0 and result["metrics"] == {}
+    assert result["device"]["platform"] == "cpu"
+    for line in ("shard.devices = 4 (expected 4)",
+                 "shard.keyshard.load_peak.sharded = True (expected True)",
+                 "shard.keyshard.load_peak.total_keys = 2125 (expected 2125)",
+                 "streams.Plug.pipeline.enabled = True (expected True)",
+                 "streams.Plug.pipeline.chunk_batches = 32 (expected 32)",
+                 "compared maxLoad.gap = 0.0 (limit 0)",
+                 "compared n.gap = 0.0 (limit 0)"):
+        assert line in out, line
+
+
+def test_rehearsal_is_not_correct_when_route_and_merge_disagree(
+        capsys, monkeypatch):
+    """`owner_of` read twice per step, once to route and once to merge: let
+    the second reading name the next device, and rows come back from a
+    device that masked them away."""
+    import siddhi_tpu.parallel.keyshard as keyshard
+
+    real, calls = keyshard.owner_of, [0]
+
+    def disagreeing(keys, n_devices):
+        calls[0] += 1
+        own = real(keys, n_devices)
+        return own if calls[0] % 2 else (own + 1) % n_devices
+
+    monkeypatch.setattr(keyshard, "owner_of", disagreeing)
+    result, out = rehearse(capsys)
+    assert calls[0] >= 2 and calls[0] % 2 == 0
+    assert result["correct"] is False, out
+
+
+# ---- the sharded chunk program, compiled for the chip it is measured on ----
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def compile_for(topo, batch: int):
+    """The deployment's deliver-mode `jit_fused` at `batch`, K = 32, with
+    its keys mesh made of the described chips: (compiled, engine)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from siddhi_tpu.parallel.keyshard import KeyShardedGroupExec
+
+    # such a compile can be written to the persistent cache but not read back
+    # without a chip: keep it out
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    mgr, rt, gen, cfg = deploy(KEYS4, batch)
+    try:
+        qr = rt.queries[cfg["query"]]
+        assert qr._keyshard is not None and qr.state is None
+        qr._keyshard = KeyShardedGroupExec(qr, topo.devices)
+        fi, prog = chunk_program(rt, gen, cfg, batch)
+        assert fi._mesh_devices() == 4
+        place = (fi._mesh_place[0][0], fi._mesh_place[1])  # one endpoint
+        return prog.lower(*chunk_arguments(fi, place=place)).compile(), fi
+    finally:
+        rt.shutdown()
+        mgr.shutdown()
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+def test_sharded_chunk_program_compiles_for_v5e_2x2(topo):
+    compiled, fi = compile_for(topo, 2048)
+    text = compiled.as_text()
+    # the merge is the program's only traffic between chips
+    assert "all-reduce" in text
+    for scope in ("keyshard.route", "keyshard.exchange", "selector",
+                  "wire_decode", "deliver_pack"):
+        assert f"/{scope}" in text, scope
+    mem = compiled.memory_analysis()
+    per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                  + mem.temp_size_in_bytes)
+    assert 0 < per_device < 1e9
+
+
+@pytest.mark.slow
+def test_sharded_chunk_program_compiles_at_the_cell_size(topo):
+    compiled, fi = compile_for(topo, 32768)  # about two minutes
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes < 1e9

@@ -156,6 +156,44 @@ def test_fused_path_spans(tmp_path):
     assert by_name["siddhi:aux_drain"][0]["flags"] >= 1
 
 
+def test_the_drain_holds_one_micro_batch_of_events_at_a_time():
+    """`deliver_endpoint` decodes a micro-batch's rows just before its
+    callbacks and drops them right after: while callback k runs, the
+    `Event`s of the chunk's other micro-batches do not exist (a chunk's
+    worth of them, mapped and unmapped once per chunk, made a bulk send's
+    time drift; PERF.md §6, PR 26)."""
+    from siddhi_tpu.core.event import Event
+
+    mgr = SiddhiManager()
+    rt = mgr.create_siddhi_app_runtime(APP)
+    alive, rows = [], []
+
+    def events_alive():
+        return sum(1 for o in gc.get_objects() if type(o) is Event)
+
+    def callback(ts, ins, removed):
+        rows.append(len(ins))
+        alive.append(events_alive())
+
+    rt.add_callback("q", callback)
+    rt.start()
+    gc.collect()
+    before = events_alive()  # what earlier tests of this process still hold
+    n = 2 * B * K  # two chunks on the fused path, every row passes the filter
+    rt.get_input_handler("S").send_columns(
+        np.arange(n, dtype=np.int64),
+        {"k": (np.arange(n) % 5).astype(np.int32),
+         "v": np.full((n,), 50.0, dtype=np.float32)},
+    )
+    status = rt.snapshot_status()["streams"]["S"]["pipeline"]
+    rt.shutdown()
+    mgr.shutdown()
+    assert status["enabled"] and status["chunk_batches"] == K
+    assert rows == [B] * (2 * K)
+    # with a chunk-wide decode: K * B in every call
+    assert [a - before for a in alive] == rows
+
+
 def test_per_batch_path_spans(tmp_path):
     mgr, rt, got = _deploy()
     _send(rt, B, 0)  # one micro-batch: below 2 x batch, per-batch path
