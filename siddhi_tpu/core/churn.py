@@ -401,13 +401,16 @@ def _gate_streams(runtime, capacity: int, block_timeout_s: float) -> dict:
 def _tree_compatible(fresh, value) -> bool:
     """Structural compatibility of a snapshot element against a freshly
     initialized state tree: identical path sets, identical leaf shapes and
-    dtypes. Anything else starts cold (surfaced, never guessed at)."""
+    dtypes. Anything else starts cold (surfaced, never guessed at). A
+    snapshot holds the 64-bit lane where the state holds a U32Pair."""
+    import jax
     import numpy as np
 
     from siddhi_tpu.core.persistence import _flat_with_paths
+    from siddhi_tpu.ops.scatter import join_pairs
 
     try:
-        fa = _flat_with_paths(fresh)
+        fa = _flat_with_paths(jax.eval_shape(join_pairs, fresh))
         fb = _flat_with_paths(value)
     except Exception:
         return False
@@ -557,9 +560,10 @@ def _seed_query_state(runtime, qid: str, qr, seed) -> str:
     # fault-injection site `churn_restore`: a failing seed is a failing
     # splice — the caller rolls back to the pre-churn runtime
     _faults.hit("churn_restore", f"{runtime.name}:{qid}")
-    if not _tree_compatible(_fresh_state_of(qr), value):
+    fresh = _fresh_state_of(qr)
+    if not _tree_compatible(fresh, value):
         return "incompatible"
-    qr.state = _to_device(value)
+    qr.state = _to_device(value, fresh)
     return "seeded"
 
 

@@ -27,6 +27,8 @@ from typing import Optional
 import jax
 import numpy as np
 
+from siddhi_tpu.ops.scatter import U32Pair, is_pair, join_pairs
+
 
 # ---------------------------------------------------------------------------
 # persistence stores
@@ -143,17 +145,50 @@ def _to_host(tree):
     # zero-copy on CPU backends, leaving the snapshot (and the incremental
     # delta base kept in `_last_full`) viewing the live XLA buffer — which
     # the next DONATED dispatch frees out from under it (flaky reads, then
-    # a crash when the view outlives the backend)
-    return jax.tree_util.tree_map(lambda x: np.array(x, copy=True), tree)
+    # a crash when the view outlives the backend).
+    # A snapshot holds LOGICAL lanes: a 64-bit lane the live state keeps as
+    # a U32Pair (a sliding window's ring) is joined on the host, under the
+    # path it has always had, so snapshots do not depend on the layout
+    return join_pairs(
+        jax.tree_util.tree_map(lambda x: np.array(x, copy=True), tree)
+    )
 
 
-def _to_device(tree):
+def _to_device(tree, like=None):
+    """The snapshot tree on the device; where `like`, the component's live
+    or freshly initialized state, holds a U32Pair, the snapshot's 64-bit
+    lane at that path is split into one (on the host)."""
     import jax.numpy as jnp
 
-    # copy=True: jnp.asarray may alias the unpickled host buffer on CPU,
-    # and the restored state's first donated dispatch would then free
-    # memory numpy still owns (the restore-then-fused-send hazard)
-    return jax.tree_util.tree_map(lambda x: jnp.array(x, copy=True), tree)
+    wide = set()
+    if like is not None:
+        wide = {
+            jax.tree_util.keystr(path)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                like, is_leaf=is_pair
+            )[0]
+            if is_pair(leaf)
+        }
+
+    def put(path, x):
+        # copy=True: jnp.asarray may alias the unpickled host buffer on CPU,
+        # and the restored state's first donated dispatch would then free
+        # memory numpy still owns (the restore-then-fused-send hazard)
+        if jax.tree_util.keystr(path) in wide:
+            return jax.tree_util.tree_map(
+                jnp.array, U32Pair.split(np.asarray(x))
+            )
+        return jnp.array(x, copy=True)
+
+    return jax.tree_util.tree_map_with_path(put, tree)
+
+
+def _state_like(component):
+    """What a component's state looks like, for `_to_device`: the live
+    state, or the shapes of a fresh one while none is materialized."""
+    if component.state is not None:
+        return component.state
+    return jax.eval_shape(component.init_state)
 
 
 def _flat_with_paths(tree) -> dict:
@@ -265,7 +300,7 @@ class SnapshotService:
                         # re-hash the canonical group table onto THIS mesh
                         qr.state = ks.import_state(value)
                     else:
-                        qr.state = _to_device(value)
+                        qr.state = _to_device(value, _state_like(qr))
             elif kind == "rate":
                 qr = rt.queries.get(name)
                 rl = getattr(qr, "rate_limiter", None) if qr else None
@@ -278,7 +313,7 @@ class SnapshotService:
             elif kind == "window":
                 nw = rt.named_windows.get(name)
                 if nw is not None:
-                    nw.state = _to_device(value)
+                    nw.state = _to_device(value, _state_like(nw))
             elif kind == "aggregation":
                 ar = rt.aggregations.get(name)
                 if ar is not None:

@@ -38,7 +38,15 @@ from siddhi_tpu.core.event import (
 from siddhi_tpu.core.executor import Env, Scope, TS_ATTR, compile_expression
 from siddhi_tpu.ops.prefix import compact_front as _compact_front, cummax as _cummax
 from siddhi_tpu.ops.group import permute_by as _permute_by
-from siddhi_tpu.ops.scatter import compact_set_at as _compact_set_at, set_at as _set_at
+from siddhi_tpu.ops.scatter import (
+    U32Pair,
+    compact_set_at as _compact_set_at,
+    is_pair as _is_pair,
+    _is_wide,
+    join_pairs as _join_pairs,
+    set_at as _set_at,
+    split_like as _split_like,
+)
 from siddhi_tpu.core.flow import Flow
 from siddhi_tpu.core.types import AttrType
 from siddhi_tpu.query_api.definition import WindowSpec
@@ -103,8 +111,6 @@ class WindowStage:
         """Introspection snapshot of the live buffer: type, fill, capacity,
         oldest/newest stored timestamps. Pull-only (one host read per call);
         rides `view()` so every findable window gets it for free."""
-        import numpy as np
-
         d: dict = {"type": type(self).__name__}
         cap = getattr(self, "w", None)
         if cap is not None:
@@ -113,8 +119,7 @@ class WindowStage:
         if dur is not None:
             d["duration_ms"] = int(dur)
         try:
-            _cols, ts, mask = self.view(state)
-            m = np.asarray(mask)
+            fill, oldest, newest = self._fill_summary(state)
         except NotImplementedError:
             return d
         except Exception:
@@ -122,13 +127,21 @@ class WindowStage:
             # the buffers under us; introspection degrades, never raises
             d["fill"] = None
             return d
-        fill = int(m.sum())
-        d["fill"] = fill
+        d["fill"] = int(fill)
         if fill:
-            lived = np.asarray(ts)[m]
-            d["oldest_ts"] = int(lived.min())
-            d["newest_ts"] = int(lived.max())
+            d["oldest_ts"] = int(oldest)
+            d["newest_ts"] = int(newest)
         return d
+
+    def _fill_summary(self, state):
+        """(fill, oldest ts, newest ts) of the stored rows; the last two
+        mean nothing when fill is 0."""
+        _cols, ts, mask = self.view(state)
+        m = np.asarray(mask)
+        lived = np.asarray(ts)[m]
+        if not lived.size:
+            return 0, 0, 0
+        return lived.size, lived.min(), lived.max()
 
 
 # ---------------------------------------------------------------------------
@@ -186,7 +199,22 @@ class SlidingWindow(WindowStage):
         d = super().describe_state(state)
         if self.ring_step is not None:
             d["ring_step"] = self.ring_step
+        if any(map(_is_pair, jax.tree_util.tree_leaves(state, is_leaf=_is_pair))):
+            d["wide_lanes"] = "u32x2"
         return d
+
+    def _fill_summary(self, state):
+        # one reduction over two lanes: view() would sort and gather the
+        # whole ring, every column of it
+        return jax.device_get(_ring_summary(state["seq"], state["ts"]))
+
+    @staticmethod
+    def lanes(state) -> dict:
+        """The state with its logical lanes: every 64-bit ring lane (`ts`,
+        `wts`, `seq`, long columns), held as a U32Pair, joined to `int64`.
+        For readers of the whole ring; inside a program the join fuses
+        into what consumes it."""
+        return _join_pairs(state)
 
     def share_signature(self):
         if self.needs_scheduler:
@@ -196,12 +224,20 @@ class SlidingWindow(WindowStage):
         )
 
     def init_state(self):
-        w = self.w
+        """The ring. A lane whose dtype is 8 bytes wide is a U32Pair of two
+        [W] halves (ops/scatter.py): no 64-bit array of ring length is a
+        parameter or a result of any program that carries the state."""
+
+        def lane(dtype, fill=0):
+            if _is_wide(dtype):
+                return U32Pair.full((self.w,), fill, dtype)
+            return jnp.full((self.w,), fill, dtype)
+
         return {
-            "cols": {n: jnp.zeros((w,), a.dtype) for n, a in self.schema.empty_batch(1).cols.items()},
-            "ts": jnp.zeros((w,), jnp.int64),
-            "wts": jnp.zeros((w,), jnp.int64),
-            "seq": jnp.full((w,), -1, jnp.int64),
+            "cols": {n: lane(a.dtype) for n, a in self.schema.empty_batch(1).cols.items()},
+            "ts": lane(jnp.int64),
+            "wts": lane(jnp.int64),
+            "seq": lane(jnp.int64, -1),
             "total": jnp.zeros((), jnp.int64),
         }
 
@@ -305,7 +341,8 @@ class SlidingWindow(WindowStage):
 
         aux = dict(flow.aux)
         if self.needs_scheduler and self.t is not None:
-            surv_wts = jnp.where(new_state["seq"] >= 0, new_state["wts"], NO_TIMER - self.t)
+            kept = self.lanes(new_state)
+            surv_wts = jnp.where(kept["seq"] >= 0, kept["wts"], NO_TIMER - self.t)
             aux["next_timer"] = surv_wts.min() + self.t
 
         return new_state, Flow(
@@ -324,6 +361,7 @@ class SlidingWindow(WindowStage):
         element's length-eviction trigger: an element is evicted by the
         insertion of seq + W, which has rank `trig_rank` in this batch."""
         w = self.w
+        state = self.lanes(state)
         total = state["total"]
         seq = jnp.concatenate(
             [state["seq"], jnp.where(valid_cur, total + rank, np.int64(-1))]
@@ -366,7 +404,6 @@ class SlidingWindow(WindowStage):
         batch_evicted = evict[w:]
         insert = valid_cur & ~batch_evicted & (rank >= c - w)
         slots = jnp.where(insert, (total + rank) % w, np.int64(w)).astype(jnp.int32)
-        new_seq = jnp.where(ring_evicted, np.int64(-1), state["seq"])
         return {
             "cols": {
                 n: _place_ring(state["cols"][n], ring_evicted, slots, b.cols[n])
@@ -374,7 +411,7 @@ class SlidingWindow(WindowStage):
             },
             "ts": _place_ring(state["ts"], ring_evicted, slots, b.ts),
             "wts": _place_ring(state["wts"], ring_evicted, slots, bwts),
-            "seq": _set_at(new_seq, slots, ev.seq[w:]),
+            "seq": _place_ring(state["seq"], ring_evicted, slots, ev.seq[w:], -1),
             "total": total + c,
         }
 
@@ -493,6 +530,8 @@ class SlidingWindow(WindowStage):
                 valid_cur, {"cols": dict(b.cols), "ts": b.ts, "wts": bwts}
             )
             new["seq"] = total + jnp.arange(bsz, dtype=jnp.int64)
+            # the long lanes' new rows as halves, like the ring holds them
+            new_rows = _split_like(ring, new)
 
             # the run [start, start + B) mod W as two slices: `tail` from
             # s1 (start, held inside the lane) and the lane's first B rows;
@@ -508,6 +547,12 @@ class SlidingWindow(WindowStage):
                 return jax.lax.dynamic_slice(
                     jnp.concatenate([tail, lane[:bsz]]), (off,), (bsz,)
                 )
+
+            # the rows the run held: a long column's halves are joined
+            # here, B rows of them
+            expired = _join_pairs(
+                jax.tree_util.tree_map(run_of, ring["cols"], tails["cols"])
+            )
 
             n0 = jnp.clip(w - total, 0, c).astype(jnp.int32)
             pos = jnp.arange(2 * bsz, dtype=jnp.int32)
@@ -532,8 +577,7 @@ class SlidingWindow(WindowStage):
                 ),
                 valid=out_valid,
                 cols={
-                    n: emit(run_of(ring["cols"][n], tails["cols"][n]), col)
-                    for n, col in new["cols"].items()
+                    n: emit(expired[n], col) for n, col in new["cols"].items()
                 },
             )
             _, _, E = self._length_positions(total, c, bsz)
@@ -561,7 +605,7 @@ class SlidingWindow(WindowStage):
                     (0,),
                 )
 
-            new_state = jax.tree_util.tree_map(place, ring, tails, new)
+            new_state = jax.tree_util.tree_map(place, ring, tails, new_rows)
             new_state["total"] = total + c
         return new_state, Flow(
             batch=out,
@@ -579,30 +623,56 @@ class SlidingWindow(WindowStage):
         """THE ring-slot -> logical-insertion-order permutation, shared by
         view() and view_seq(): join lineage pairs view_seq's seq lane with
         view's cols/mask by position, so the two must never drift."""
-        mask = state["seq"] >= 0
+        seq = state["seq"].join()
+        mask = seq >= 0
         perm = jnp.argsort(
-            jnp.where(mask, state["seq"], jnp.iinfo(jnp.int64).max)
+            jnp.where(mask, seq, jnp.iinfo(jnp.int64).max)
         ).astype(jnp.int32)
         return mask, perm
 
     def view(self, state):
         mask, perm = self._view_perm(state)
-        cols = {n: c[perm] for n, c in state["cols"].items()}
-        return cols, state["ts"][perm], mask[perm]
+        lanes = self.lanes({k: state[k] for k in ("cols", "ts")})
+        cols = {n: c[perm] for n, c in lanes["cols"].items()}
+        return cols, lanes["ts"][perm], mask[perm]
 
     def view_seq(self, state):
         _mask, perm = self._view_perm(state)
-        return state["seq"][perm]
+        return state["seq"].join()[perm]
 
 
-def _place_ring(old, evicted, slots, vals):
-    # set_at: 64-bit lanes (ts/wts/seq/long cols) ride the int32-pair scatter
-    # (a raw 64-bit scatter-set serializes on TPU, ops/scatter.py).
-    # Zero typed to the lane dtype: a weak `0` literal promotes BOOL lanes
-    # to int64, which breaks the fused scan carry (bool cols reach the
-    # fused path since the bit-packed wire, core/wire.py)
+def _place_ring(old, evicted, slots, vals, empty=0):
+    """Ring lane `old` with its evicted slots set to `empty` and `vals`
+    scattered to `slots`. A long lane's halves (U32Pair) are scattered
+    where they are: a raw 64-bit scatter-set serializes on TPU
+    (ops/scatter.py), and joining them again would cost a pass over the
+    ring."""
+    if _is_pair(old):
+        v = U32Pair.split(vals.astype(old.dtype))
+        e = U32Pair.split(np.full((), empty, old.dtype))
+        return U32Pair(
+            _place_ring(old.lo, evicted, slots, v.lo, e.lo),
+            _place_ring(old.hi, evicted, slots, v.hi, e.hi),
+            old.dtype,
+        )
+    # `empty` typed to the lane dtype: a weak `0` literal promotes BOOL
+    # lanes to int64, which breaks the fused scan carry (bool cols reach
+    # the fused path since the bit-packed wire, core/wire.py)
     return _set_at(
-        jnp.where(evicted, jnp.zeros((), old.dtype), old), slots, vals
+        jnp.where(evicted, jnp.asarray(empty, old.dtype), old), slots, vals
+    )
+
+
+@jax.jit
+def _ring_summary(seq: U32Pair, ts: U32Pair):
+    """(fill, oldest ts, newest ts) of a ring, or of a [P, W] stack of
+    rings: a slot is live while its seq is not negative."""
+    live = seq.hi >= 0
+    t = ts.join()
+    return (
+        live.sum(),
+        jnp.where(live, t, jnp.iinfo(t.dtype).max).min(),
+        jnp.where(live, t, jnp.iinfo(t.dtype).min).max(),
     )
 
 

@@ -13,8 +13,11 @@ should be reformulated (sort + searchsorted) instead.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _is_wide(dtype) -> bool:
@@ -37,6 +40,72 @@ def _join64(lo: jnp.ndarray, hi: jnp.ndarray, dtype) -> jnp.ndarray:
     if jnp.issubdtype(jnp.dtype(dtype), jnp.integer):
         return xi.astype(dtype)
     return jax.lax.bitcast_convert_type(xi, dtype)
+
+
+@dataclasses.dataclass
+class U32Pair:
+    """A 64-bit lane held as its two halves, `lo` (uint32) and `hi` (int32,
+    the halves of `_split64`), each shaped like the lane. The chip has no
+    64-bit integers: XLA carries an `s64[N]` parameter or result of a
+    program as two `u32[N]` and converts the whole array at the program's
+    boundary (`X64SplitLow` / `X64SplitHigh` / `X64Combine`), whatever part
+    of it the program touches. State that lives across programs keeps its
+    long lanes as a pair instead, a pytree node of two 32-bit leaves, and
+    joins only the rows it reads. Never a `[N, 2]` array: a minor dimension
+    of 2 is padded to a full tile on the chip. Halves may be numpy arrays
+    (snapshots): `split` and `join` then stay on the host."""
+
+    lo: object
+    hi: object
+    dtype: np.dtype
+
+    @classmethod
+    def split(cls, x) -> "U32Pair":
+        dtype = np.dtype(x.dtype)
+        if isinstance(x, np.ndarray):
+            xi = x.view(np.int64)
+            return cls(
+                (xi & 0xFFFFFFFF).astype(np.uint32),
+                (xi >> 32).astype(np.int32),
+                dtype,
+            )
+        return cls(*_split64(x), dtype)
+
+    @classmethod
+    def full(cls, shape, fill, dtype) -> "U32Pair":
+        s = cls.split(np.full((), fill, dtype))
+        return cls(jnp.full(shape, s.lo), jnp.full(shape, s.hi), s.dtype)
+
+    def join(self):
+        if isinstance(self.lo, np.ndarray):
+            xi = (self.hi.astype(np.int64) << 32) | self.lo.astype(np.int64)
+            return xi.view(self.dtype)
+        return _join64(self.lo, self.hi, self.dtype)
+
+
+jax.tree_util.register_dataclass(
+    U32Pair, data_fields=["lo", "hi"], meta_fields=["dtype"]
+)
+
+
+def is_pair(x) -> bool:
+    return isinstance(x, U32Pair)
+
+
+def join_pairs(tree):
+    """`tree` with every U32Pair joined to its 64-bit lane."""
+    return jax.tree_util.tree_map(
+        lambda x: x.join() if is_pair(x) else x, tree, is_leaf=is_pair
+    )
+
+
+def split_like(like, tree):
+    """`tree` with the leaves split that `like`, a tree of the same
+    structure up to its pairs, holds as a U32Pair."""
+    return jax.tree_util.tree_map(
+        lambda held, x: U32Pair.split(x) if is_pair(held) else x,
+        like, tree, is_leaf=is_pair,
+    )
 
 
 def set_at(dst: jnp.ndarray, idx: jnp.ndarray, src: jnp.ndarray, *, mode: str = "drop") -> jnp.ndarray:

@@ -234,6 +234,9 @@ class TestSnapshotStatus:
         assert w["type"] == "SlidingWindow"
         assert w["capacity"] == 4 and w["fill"] == 3
         assert w["oldest_ts"] == 0 and w["newest_ts"] == 2
+        # static: which length step the program took, and how the ring
+        # holds its 64-bit lanes (ts, wts, seq, long columns)
+        assert w["ring_step"] == "scatter" and w["wide_lanes"] == "u32x2"
 
         # pattern NFA: per-state active instance counts
         pat = st["queries"]["pat"]
@@ -248,6 +251,7 @@ class TestSnapshotStatus:
         # named window fed by a query
         nw = st["windows"]["W"]
         assert nw["capacity"] == 8 and nw["fill"] == 3
+        assert nw["wide_lanes"] == "u32x2"
 
         # table row count + capacity
         tab = st["tables"]["Prices"]
@@ -255,6 +259,21 @@ class TestSnapshotStatus:
 
         # unfed stream still present, empty
         assert st["streams"]["T"]["queue_depth"] == 0
+        mgr.shutdown()
+
+    def test_a_batch_window_reports_no_wide_lanes(self):
+        # its lanes are plain int64 arrays: the field is a sliding ring's
+        mgr = SiddhiManager()
+        rt = mgr.create_siddhi_app_runtime("""
+            define stream S (symbol string, volume long);
+            @info(name='q') from S#window.lengthBatch(4)
+            select symbol, sum(volume) as v insert into Out;""")
+        rt.start()
+        rt.get_input_handler("S").send(("A", 2**40), timestamp=7)
+        w = rt.snapshot_status()["queries"]["q"]["window"]
+        assert w["type"] == "BatchWindow" and w["fill"] == 1
+        assert (w["oldest_ts"], w["newest_ts"]) == (7, 7)
+        assert "wide_lanes" not in w and "ring_step" not in w
         mgr.shutdown()
 
     def test_aggregation_buckets_and_watermark(self):

@@ -5,6 +5,9 @@ PersistenceTestCase.java and IncrementalPersistenceTestCase.java — snapshot,
 shutdown, recreate the app, restore, continue exactly where it left off.
 """
 
+import pickle
+
+import numpy as np
 import pytest
 
 from siddhi_tpu import SiddhiManager
@@ -55,6 +58,78 @@ class TestSnapshotRestore:
         rt2.shutdown()
         mgr.shutdown()
         mgr2.shutdown()
+
+    # volumes whose halves both matter: above 2**32, and negative
+    LONGS = [2**40 + 10, -(2**41) - 3, 2**33 + 1, 7, -(2**35)]
+
+    def _sums_after(self, restore_at, prime):
+        """Running sums of LONGS through length(3), the app torn down and
+        restored from its snapshot after `restore_at` events; with `prime`
+        the restoring app has stepped once (its state is live, not None)."""
+        mgr, rt, got = make()
+        for i, v in enumerate(self.LONGS[:restore_at]):
+            rt.get_input_handler("S").send(("A", 1.0, v), timestamp=i)
+        snap = rt.snapshot()
+        mgr.shutdown()
+        mgr2, rt2, got2 = make()
+        if prime:
+            rt2.get_input_handler("S").send(("A", 1.0, 1), timestamp=0)
+            del got2[:]
+        rt2.restore(snap)
+        for i, v in enumerate(self.LONGS[restore_at:], start=restore_at):
+            rt2.get_input_handler("S").send(("A", 1.0, v), timestamp=i)
+        mgr2.shutdown()
+        return snap, [total for _sym, total in got + got2]
+
+    @pytest.mark.parametrize("prime", [False, True])
+    def test_snapshot_holds_logical_long_lanes_and_round_trips(self, prime):
+        """The ring keeps its 64-bit lanes as u32 pairs; the snapshot keeps
+        them as int64 under the paths it always had, and a restore (onto a
+        state not yet materialized, or a live one) continues as an unbroken
+        run does."""
+        ls = self.LONGS
+        unbroken = [sum(ls[max(0, i - 2): i + 1]) for i in range(len(ls))]
+        snap, sums = self._sums_after(2, prime)
+        assert sums == unbroken
+        assert b"U32Pair" not in snap
+        chain = pickle.loads(snap)["elements"]["query:q"]["chain"]
+        assert sorted(chain) == ["cols", "seq", "total", "ts", "wts"]
+        for lane in (chain["ts"], chain["wts"], chain["seq"],
+                     chain["cols"]["volume"]):
+            assert isinstance(lane, np.ndarray)
+            assert lane.dtype == np.int64 and lane.shape == (3,)
+        assert chain["seq"].tolist() == [0, 1, -1]
+        assert chain["cols"]["volume"].tolist() == ls[:2] + [0]
+
+    def test_restores_a_snapshot_in_the_int64_layout_built_by_hand(self):
+        """A snapshot as the engine wrote it before the ring held pairs:
+        the window's lanes are plain int64 arrays, here built by hand. The
+        rows it holds expire with their own values after the restore."""
+        ls = self.LONGS
+        snap, _ = self._sums_after(2, False)
+        payload = pickle.loads(snap)
+        a = payload["interner"].index("A") + 1
+        payload["elements"]["query:q"]["chain"] = {
+            "cols": {
+                "symbol": np.array([a, a, 0], np.int32),
+                "price": np.array([1.0, 1.0, 0.0], np.float32),
+                "volume": np.array([ls[0], ls[1], 0], np.int64),
+            },
+            "ts": np.array([0, 1, 0], np.int64),
+            "wts": np.array([0, 1, 0], np.int64),
+            "seq": np.array([0, 1, -1], np.int64),
+            "total": np.array(2, np.int64),
+        }
+        mgr, rt, got = make()
+        rt.restore(pickle.dumps(payload))
+        for i, v in enumerate(ls[2:], start=2):
+            rt.get_input_handler("S").send(("A", 1.0, v), timestamp=i)
+        assert [t for _s, t in got] == [
+            sum(ls[max(0, i - 2): i + 1]) for i in range(2, len(ls))]
+        win = rt.snapshot_status()["queries"]["q"]["window"]
+        assert win["fill"] == 3 and win["wide_lanes"] == "u32x2"
+        assert (win["oldest_ts"], win["newest_ts"]) == (2, 4)
+        mgr.shutdown()
 
     def test_in_memory_store_revisions(self):
         store = InMemoryPersistenceStore()

@@ -95,34 +95,40 @@ def chunk_arguments(fi, K: int = 32, place=None):
 
 def lowered_text(config: str) -> str:
     """`jit_fused` of `config` at its rehearse sizes, lowered, without debug
-    metadata."""
+    metadata; a key-sharded deployment on the virtual CPU mesh."""
     _, _, cfg = load(config)
     mgr, rt, gen, cfg = deploy(config, cfg["rehearse_sizes"]["batch"])
     try:
         fi, prog = chunk_program(rt, gen, cfg, cfg["rehearse_sizes"]["batch"])
-        assert fi._mesh_place is None and fi._mesh_devices() == 1
-        return prog.lower(*chunk_arguments(fi)).as_text()
+        place = None
+        if fi._mesh_place is not None:
+            place = (fi._mesh_place[0][0], fi._mesh_place[1])  # one endpoint
+        return prog.lower(*chunk_arguments(fi, place=place)).as_text()
     finally:
         rt.shutdown()
         mgr.shutdown()
 
 
-# sha256 of `lowered_text`: the chunk program of the two standing
-# configurations as the parent of PR 26 lowered it (computed on a checkout of
-# that commit with this very function). The mesh path may not move it: an app
-# without @app:shard takes the same `jax.jit(fused, donate_argnums=(0,))`. A
-# PR that changes the chunk program on purpose replaces these, and knows by
-# that that the standing cells' device time may have moved.
+# sha256 of `lowered_text`: the chunk program of the standing configurations
+# (computed on a checkout of the commit named with this very function). The
+# mesh path may not move the one-chip ones: an app without @app:shard takes
+# the same `jax.jit(fused, donate_argnums=(0,))`. A PR that changes a chunk
+# program on purpose replaces its hash, and knows by that that the cell's
+# device time may have moved. The two windowless programs are as the parents
+# of PR 26 and PR 27 lowered them; PR 27 replaced the plug program's
+# (51b48512...: its window's ring now holds its 64-bit lanes as u32 pairs).
 STANDING_PROGRAMS = {
-    "debs14-q1-plug": "51b485124ec34a1586e7bd7e229f309d0e8bdd3a77a90db647e833e212b97238",
+    "debs14-q1-plug": "f999fb964eefd34044f2685e56ca2997e54aa25c2e2f5ad6a0d104ac5e1d7650",
     "siddhi-simple-filter": "233fdbcf647ded2693ff29f1f81344e59ca926ef5e1a7feaac442c8f1f642fcc",
+    KEYS4: "99929b7996b90e0c93d7cca8dcfa6e481f7c30a065f5acbc99682e656a9d2b94",
 }
 
 
 @pytest.mark.parametrize("config", sorted(STANDING_PROGRAMS))
 def test_standing_chunk_programs_lower_as_before(config):
     text = lowered_text(config)
-    assert "sharding" not in text and "all_reduce" not in text
+    on_mesh = config == KEYS4
+    assert ("sharding" in text and "all_reduce" in text) == on_mesh
     assert hashlib.sha256(text.encode()).hexdigest() == STANDING_PROGRAMS[config]
 
 

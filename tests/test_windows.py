@@ -282,9 +282,12 @@ _LANES = StreamSchema(
 
 
 def _lane_batch(rng, valid, base):
+    """Rows with every lane type; the long lanes (`l`, and `ts` from 2**33
+    up) hold values above 2**32 and `l` negative ones too, so a half of a
+    64-bit ring lane that went missing would show."""
     n = len(valid)
     return EventBatch(
-        ts=jnp.asarray(base + np.arange(n), jnp.int64),
+        ts=jnp.asarray(2**33 + base + np.arange(n), jnp.int64),
         kind=jnp.zeros((n,), jnp.int8),
         valid=jnp.asarray(valid),
         cols={
@@ -367,8 +370,11 @@ def test_length_step_matches_deque_and_scatter_state(w, bsz):
         ]
         assert got == [(kind, ts, row[1]) for kind, ts, row in want]
 
-        s = jax.tree_util.tree_map(np.asarray, state)
+        # the ring's long lanes are stored as halves: read the logical ones
+        s = jax.tree_util.tree_map(np.asarray, SlidingWindow.lanes(state))
         assert int(s["total"]) == total
+        assert {s[k].dtype for k in ("ts", "wts", "seq")} == {np.dtype("int64")}
+        assert s["cols"]["l"].dtype == np.int64
         live = {seq % w: (seq, row) for seq, row in held}
         for slot in range(w):
             if slot in live:
@@ -384,6 +390,67 @@ def test_length_step_matches_deque_and_scatter_state(w, bsz):
         assert _leaves_equal(state, ref_state)
     assert win.ring_step == ("slice" if w >= bsz else "scatter")
     assert ref_win.ring_step == "scatter"
+
+
+@pytest.mark.parametrize("w,dur", [(12, 20), (64, 20), (16, 5), (40, 3)])
+def test_time_window_matches_deque_with_long_lanes_as_pairs(w, dur):
+    """timeLength(dur, w) on the event's own ts against a deque: every due
+    row expires before the CURRENT that finds it due (also one that came in
+    the same batch), then the oldest row if the window is full; expired
+    rows carry the trigger's ts. The ring afterwards holds the deque, its
+    64-bit lanes as halves."""
+    bsz = 16
+    rng = np.random.default_rng(w * 17 + dur)
+    win = SlidingWindow(_LANES, "S", w, duration_ms=dur)
+    step = jax.jit(_length_step(win))
+    state = win.init_state()
+    held: collections.deque = collections.deque()  # (seq, ts, row)
+    total = 0
+    for k in range(12):
+        valid = rng.random(bsz) < (0.9 if k % 3 else 0.4)
+        host = jax.tree_util.tree_map(
+            np.asarray, _lane_batch(rng, valid, 24 * k))
+        want = []
+        for r in np.flatnonzero(valid):
+            row = tuple(host.cols[n][r] for n in host.cols)
+            while held and host.ts[r] - held[0][1] >= dur:
+                want.append((1, host.ts[r], held.popleft()[2]))
+            if len(held) == w:
+                want.append((1, host.ts[r], held.popleft()[2]))
+            held.append((total, host.ts[r], row))
+            total += 1
+            want.append((0, host.ts[r], row))
+
+        state, out = step(state, jax.tree_util.tree_map(jnp.asarray, host))
+        o = jax.tree_util.tree_map(np.asarray, out)
+        got = [
+            (int(o.kind[p]), o.ts[p], tuple(o.cols[n][p] for n in o.cols))
+            for p in np.flatnonzero(o.valid)
+        ]
+        assert got == want
+
+        assert all(
+            leaf.dtype.itemsize < 8 or leaf.ndim == 0
+            for leaf in jax.tree_util.tree_leaves(state)
+        )
+        s = jax.tree_util.tree_map(np.asarray, SlidingWindow.lanes(state))
+        assert int(s["total"]) == total
+        live = {seq % w: (seq, ts, row) for seq, ts, row in held}
+        for slot in range(w):
+            if slot in live:
+                seq, ts, row = live[slot]
+                assert (s["seq"][slot], s["ts"][slot], s["wts"][slot]) == (
+                    seq, ts, ts)
+                assert tuple(s["cols"][n][slot] for n in s["cols"]) == row
+            else:
+                assert s["seq"][slot] == -1
+        cols, ts, mask = jax.tree_util.tree_map(np.asarray, win.view(state))
+        assert list(ts[mask]) == [t for _, t, _ in held]
+        at = list(host.cols).index("l")
+        assert list(cols["l"][mask]) == [row[at] for _, _, row in held]
+        assert list(np.asarray(win.view_seq(state))[mask]) == [
+            seq for seq, _, _ in held]
+    assert total > 2 * w  # the ring wrapped
 
 
 def _ring_sized_eqns(jaxpr, least):
@@ -432,6 +499,69 @@ def test_length_step_touches_the_ring_through_slices_only():
     assert {"concatenate", "select_n", "scatter"} <= {
         p for p, _ in live_ring_eqns(scatter)
     }
+
+
+def test_no_64_bit_array_of_ring_length_crosses_the_step_boundary():
+    """The per-batch step of length(N >= batch) over a stream with a long
+    column: no parameter and no result of the lowered program that is as
+    long as the ring has a 64-bit element type (XLA:TPU would split or
+    combine all of it at the boundary, whatever the step touches), and
+    the ring's 32-bit leaves are there to be seen."""
+    w, bsz = 4096, 8
+    mgr, rt = run_app(f"""@app:batch(size='{bsz}')
+        define stream S (k string, v long, p float);
+        @info(name='q') from S#window.length({w})
+        select k, sum(v) as s, max(p) as m insert all events into O;""")
+    qr = rt.queries["q"]
+    lowered = qr._step.lower(
+        qr.init_state(), qr._collect_table_states(),
+        qr.in_schema.empty_batch(bsz), jnp.asarray(0, jnp.int64),
+    )
+    assert qr.chain.window.ring_step == "slice"
+    ring_long = [
+        (leaf.shape, np.dtype(leaf.dtype))
+        for leaf in jax.tree_util.tree_leaves(
+            (lowered.args_info, lowered.out_info))
+        if w in leaf.shape
+    ]
+    # cols k, p and two halves each of v, ts, wts, seq: once in, once out
+    assert len(ring_long) == 2 * (2 + 2 * 4), ring_long
+    assert all(dtype.itemsize == 4 for _, dtype in ring_long), ring_long
+    mgr.shutdown()
+
+
+@pytest.mark.parametrize("app", ["partition", "shared"])
+def test_long_lanes_in_partitioned_and_shared_rings(app):
+    """The pair layout under core/partition.py's vmap and in a ring two
+    queries share (core/ingest.py share sets): running sums of a long
+    column with values round +-2**40 against per-key (or one) deques."""
+    n_win = {"partition": 12, "shared": 16}[app]
+    mgr, rt = run_app(_RING_APPS[app])
+    got = []
+    rt.add_callback("q", lambda ts, ins, rem: got.extend(
+        (e.timestamp, tuple(e.data)) for e in ins or []))
+    rng = np.random.default_rng(11)
+    held: dict = collections.defaultdict(
+        lambda: collections.deque(maxlen=n_win))
+    want = []
+    t = 0
+    for _ in range(12):
+        n = int(rng.integers(1, 30))
+        rows = [
+            (str(rng.integers(0, 3)), int(rng.integers(-(2**40), 2**40)), 1.0)
+            for _ in range(n)
+        ]
+        rt.get_input_handler("S").send_many(
+            rows, timestamps=list(range(t, t + n)))
+        for i, (k, v, _p) in enumerate(rows):
+            ring = held[k if app == "partition" else ""]
+            ring.append(v)
+            want.append((t + i, sum(ring)))
+        t += n
+    win = rt.snapshot_status()["queries"]["q"]["window"]
+    assert win["wide_lanes"] == "u32x2" and win["ring_step"] == "slice"
+    mgr.shutdown()
+    assert sorted((ts, row[1]) for ts, row in got) == want
 
 
 _HEAD = "@app:batch(size='8')\ndefine stream S (k string, v long, p float);\n"
