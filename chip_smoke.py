@@ -35,8 +35,8 @@ prefix sums over the filtered stream with a row-count expiry): row count,
 plug, ts and sum(work) exactly; avgLoad within AVG_TOL, because the engine
 keeps running f32 sums by design and the reference sums in f64.
 
-Stage B — the five `BASELINE.json` programs (texts imported from bench.py)
-compile and run at the bench's width.
+Stage B — the five `BASELINE.json` programs (texts from
+`siddhi_tpu/testing/apps.py`) compile and run at a deployment's width.
 Stage C (`--shard N` runs it alone; never in the default run) — stage A's
 query without the window under `@app:shard(axis='keys')`, and a partitioned
 twin under `axis='part'`, on N chips: state on N distinct devices, emissions
@@ -46,7 +46,7 @@ native C++ ring.
 Stage E — `#log`, `convert(x, 'string')` and `UUID()` need host callbacks
 from inside a device program: the probe must find them and they must work.
 Stage F — the declared wire encodings (dict gather, delta cumsum, bit
-unpack; texts from bench.py plus a BOOL lane) decode inside the chunk
+unpack; texts from `testing/apps.py` plus a BOOL lane) decode inside the chunk
 program, and what is delivered equals a NumPy filter of what was sent.
 Stage G — device->host reads off the main thread while the chip is busy:
 the periodic aux-flag drain must fire from an `@async` worker and from a
@@ -340,22 +340,19 @@ def stage_a(SiddhiManager, sizes: dict, seed: int, log: _EngineLog) -> dict:
 # --------------------------------------------------------------------------
 
 def stage_b(SiddhiManager, sizes: dict, seed: int, log: _EngineLog) -> dict:
-    # bench.py imports no JAX at module level; its texts and generator are
-    # the bench's own, so the smoke cannot drift from what S0 will measure
-    import bench
+    from siddhi_tpu.testing import apps
 
     out = {}
-    for name, (ql, stream, _mult, batch_override) in bench.WORKLOADS.items():
+    for name, (ql, stream, batch_override) in apps.WORKLOADS.items():
         t_stage = time.perf_counter()
         B = sizes["join_batch"] if batch_override else sizes["batch"]
         n_send = B * SEND_BATCHES
-        data = bench._make_stock_data(2 * n_send, seed=seed)
+        data = apps.make_stock_data(2 * n_send, seed=seed)
         mgr = SiddhiManager()
         rt = mgr.create_siddhi_app_runtime(
             f"@app:statistics(reporter='none')\n@app:batch(size='{B}')\n" + ql
         )
-        for s in data["names"]:
-            mgr.interner.intern(str(s))
+        apps.prime_interner(mgr, data["names"])
         emitted = [0]
 
         def on_rows(ts, ins, removed, _n=emitted):
@@ -371,9 +368,8 @@ def stage_b(SiddhiManager, sizes: dict, seed: int, log: _EngineLog) -> dict:
             h.send_columns(data["ts"][lo:hi],
                            {k: v[lo:hi] for k, v in cols.items()})
             ledgers.append(_compile_ledger(rt))
-        # one state leaf per holder read back: everything queued completed
-        # (the value may be a null sentinel, so it is not inspected)
-        bench._truth_sync(rt)
+        # `q` has a callback, so each send returned only after its last
+        # chunk was read back and delivered: nothing is still queued
         for qr in rt.queries.values():
             qr.flush_aux_warnings()
         log.require_clean(f"B/{name}")
@@ -615,7 +611,7 @@ BITPACK_APP = ("""
 
 
 def stage_f(SiddhiManager, sizes: dict, seed: int, log: _EngineLog) -> dict:
-    import bench
+    from siddhi_tpu.testing.apps import WIRE_WORKLOADS
 
     B = sizes["batch"]
     n = 2 * B * SEND_BATCHES
@@ -629,12 +625,12 @@ def stage_f(SiddhiManager, sizes: dict, seed: int, log: _EngineLog) -> dict:
     #          reference = that column of the rows the filter keeps)
     cases = {
         "wire_dict": (
-            *bench.WIRE_WORKLOADS["wire_dict"],
+            *WIRE_WORKLOADS["wire_dict"],
             {"sym": rng.integers(1, 33, n).astype(np.int32),
              "price": rng.uniform(0, 100, n).astype(np.float32), "qty": qty},
             {"sym": "dict"}, 1, qty[qty > 10]),
         "wire_delta": (
-            *bench.WIRE_WORKLOADS["wire_delta"],
+            *WIRE_WORKLOADS["wire_delta"],
             {"seq": np.arange(n, dtype=np.int64) + 10**12, "v": v},
             {"seq": "delta"}, 0,
             (np.arange(n, dtype=np.int64) + 10**12)[v >= 0]),
@@ -737,8 +733,7 @@ def running_by_key(k: np.ndarray, v: np.ndarray) -> tuple:
 def _drain_period_s() -> float:
     """The aux-flag pool's periodic-drain cadence, read as the engine reads
     it when `siddhi_tpu` is imported (core/query_runtime.py); stage G has to
-    outwait it. Call it before `import bench`, which presets the variable
-    to 0 for its own leg processes."""
+    outwait it."""
     period = float(os.environ.get("SIDDHI_TPU_AUX_DRAIN_S", "5.0"))
     check(period > 0, "SIDDHI_TPU_AUX_DRAIN_S <= 0 turns the periodic drain "
           "off; stage G cannot run")

@@ -986,8 +986,7 @@ def check_costs(
             f"@app:ingestChunk(size='{model.chunk_batches}') predicts "
             f"{len(tails)} tail-variant compiles of every fused chunk "
             "program (core/ingest.py _chunk_K power-of-two ladder) — each "
-            "is a full XLA compile mid-traffic; lower the chunk size or "
-            "pre-warm with SIDDHI_TPU_PREWARM_TAIL=1",
+            "is a full XLA compile mid-traffic; lower the chunk size",
             getattr(ann, "line", None), getattr(ann, "col", None),
             severity=WARNING,
         ))

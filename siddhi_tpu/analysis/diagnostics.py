@@ -33,7 +33,7 @@ CODES: dict[str, str] = {
     "SA109": "duplicate attribute name in a definition",
     "SA110": "invalid @OnError action",
     "SA111": "reserved attribute name",
-    "SA112": "invalid @pipeline annotation (unknown key / bad depth / bad disable)",
+    "SA112": "invalid @pipeline annotation (unknown key / bad depth)",
     "SA113": "invalid @app:selfmon annotation (bad interval / unknown key / reserved stream name)",
     "SA114": "invalid @flightRecorder annotation (bad size / unknown key)",
     "SA115": "invalid partition key (OBJECT-typed key expression, or a "

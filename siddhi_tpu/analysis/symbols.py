@@ -78,7 +78,7 @@ def _attrs_schema(definition, diags: list[Diagnostic], what: str) -> Schema:
 def _check_pipeline_annotation(
     sid: str, d, ann, diags: list[Diagnostic]
 ) -> None:
-    """Validate `@pipeline(depth='N', disable='true|false')` — the fused
+    """Validate `@pipeline(depth='N')` — the fused
     ingest pipeline's stream-level config. One SA112 per malformed element,
     using the SAME rule set the runtime resolver enforces
     (core/pipeline.py iter_pipeline_annotation_problems)."""

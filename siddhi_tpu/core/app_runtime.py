@@ -331,14 +331,13 @@ class SiddhiAppRuntime:
                     + [("_error", _AttrType.STRING)],
                 )
 
-        # @pipeline(depth='N', disable='true') — per-stream config of the
-        # double-buffered fused-ingest pipeline (core/pipeline.py); resolved
-        # here (with the SIDDHI_TPU_PIPELINE env override) and applied when
-        # start() builds the junction's FusedJunctionIngest
+        # @pipeline(depth='N') — per-stream depth of the double-buffered
+        # fused-ingest pipeline (core/pipeline.py); resolved here and
+        # applied when start() builds the junction's FusedJunctionIngest
         from siddhi_tpu.core.pipeline import resolve_pipeline_annotation
         from siddhi_tpu.observability.flight import resolve_flight_annotation
 
-        self._pipeline_conf: dict[str, tuple[bool, int]] = {}
+        self._pipeline_conf: dict[str, int] = {}
         for sid, d in app.stream_definitions.items():
             self.stream_schemas[sid] = StreamSchema(
                 sid, [(a.name, a.type) for a in d.attributes]
@@ -1950,7 +1949,7 @@ class SiddhiAppRuntime:
             inferred = infer_wire_hints_for_app(self.app)
         for j in list(self.junctions.values()):
             sid = j.schema.stream_id
-            pipe_on, pipe_depth = self._pipeline_conf.get(
+            pipe_depth = self._pipeline_conf.get(
                 sid, resolve_pipeline_annotation(None)
             )
             # analyzer-chosen per-column wire encodings (core/wire.py):
@@ -1970,7 +1969,7 @@ class SiddhiAppRuntime:
             if cfg is not None:
                 j.fused_ingest = FusedJunctionIngest(
                     self, j, cfg["endpoints"], chunk_batches=chunk,
-                    pipeline_enabled=pipe_on, pipeline_depth=pipe_depth,
+                    pipeline_depth=pipe_depth,
                     component=cfg["component"], residual=cfg["residual"],
                     share_sets=cfg["share_sets"],
                     plan_group=cfg["plan_group"],
@@ -1979,7 +1978,7 @@ class SiddhiAppRuntime:
             elif j.fuse_candidates and len(j.fuse_candidates) == len(j.subscribers):
                 j.fused_ingest = FusedJunctionIngest(
                     self, j, j.fuse_candidates, chunk_batches=chunk,
-                    pipeline_enabled=pipe_on, pipeline_depth=pipe_depth,
+                    pipeline_depth=pipe_depth,
                     wire_spec=spec, wire_enabled=self._wire_enabled,
                 )
         if self._shard is not None:

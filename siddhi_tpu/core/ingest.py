@@ -26,9 +26,9 @@ Chunk stages (encode -> h2d -> dispatch -> drain) run double-buffered by
 default through core/pipeline.py: chunk N+1 is encoded into a pooled wire
 buffer and device_put while chunk N's donated-state dispatch is in flight,
 and deliver-mode readback+decode+callbacks run on a bounded background
-drain worker in chunk order. `@pipeline(disable='true')` (or
-SIDDHI_TPU_PIPELINE=0) restores the fully serial path; outputs and
-delivery order are identical either way.
+drain worker in chunk order. A re-entrant send (a callback or failure
+handler that sends again from inside a send) runs the same chunk loop
+against the pipeline's inline side: fresh buffers, drained on the caller.
 """
 
 from __future__ import annotations
@@ -102,7 +102,6 @@ class FusedJunctionIngest:
         junction,
         endpoints,
         chunk_batches: int = 32,
-        pipeline_enabled: bool = True,
         pipeline_depth: int = 2,
         component: str = None,
         residual=None,
@@ -179,12 +178,10 @@ class FusedJunctionIngest:
         # double-buffered chunk pipeline (core/pipeline.py): built lazily on
         # the first engaged send; senders serialize on _send_lock so the
         # pooled wire buffers and the drain queue see one producer
-        self.pipeline_enabled = bool(pipeline_enabled)
         self.pipeline_depth = max(1, int(pipeline_depth))
         self.pipeline = None
         self._send_lock = threading.Lock()
         self._sender = None  # thread holding _send_lock (re-entrancy guard)
-        self._prewarmed = False
         # compile-telemetry cause hints for the NEXT compiling dispatch,
         # keyed per program mode (deliver bool): a full-width rebuild
         # invalidates BOTH programs, and each must attribute its own
@@ -215,7 +212,7 @@ class FusedJunctionIngest:
         self._lin_pending = None
         ps = getattr(junction, "pipeline_stats", None)
         if ps is not None:
-            ps.depth = self.pipeline_depth if self.pipeline_enabled else 0
+            ps.depth = self.pipeline_depth
 
     def describe_state(self) -> dict:
         """Introspection: chunking, pipeline depth/occupancy, slots in
@@ -223,8 +220,8 @@ class FusedJunctionIngest:
         d: dict = {
             "chunk_batches": self.K,
             "enabled": not self._disabled,
-            "pipeline_enabled": self.pipeline_enabled,
-            "depth": self.pipeline_depth if self.pipeline_enabled else 0,
+            "pipeline_enabled": True,
+            "depth": self.pipeline_depth,
             "component": self.component,
             "mesh_devices": self._mesh_devices(),
         }
@@ -260,7 +257,7 @@ class FusedJunctionIngest:
 
     def force_full_width(self) -> None:
         """Pin the wire full-width permanently, discarding any chosen
-        encodings (bench's enc-vs-raw A/B and tests; the same state a
+        encodings (tests; the same state a
         runtime misfit fallback lands in). The next send rebuilds the
         programs against the wide codec; call between sends only."""
         with self._lock:
@@ -310,31 +307,6 @@ class FusedJunctionIngest:
                 for idxs in self.share_sets
             ]
         return rep
-
-    def wire_params(self):
-        """(capacity, keep, narrow) — the exact wire codec the built fused
-        program decodes; tools/bench must encode with the same triple."""
-        return self.junction.batch_size, self._keep, (self._narrow or {})
-
-    def staged_codec(self, ts_sample, cols_sample):
-        """Bench/tool entry: sample the narrow wire (if unchosen), build the
-        non-delivery fused program, and return (encode, wire_bytes) matching
-        the program exactly — the one place the staging handshake lives."""
-        with self._lock:
-            if self._narrow is None:
-                from siddhi_tpu.core.wire import choose_encodings
-
-                self._narrow = choose_encodings(
-                    self.junction.schema, self._compute_keep(),
-                    self.wire_spec, self.wire_enabled,
-                    ts_sample, cols_sample,
-                )
-            if self._fused is None:
-                self._build()
-            encode, _d, nb = self.junction.schema.wire_codec(
-                *self.wire_params()
-            )
-        return encode, nb
 
     # ---- eligibility (cheap dynamic checks, every send) ------------------
 
@@ -652,10 +624,7 @@ class FusedJunctionIngest:
         full K-iteration scan of empty batches. jax.jit retraces per wire
         shape, so each variant compiles once and is cached — a workload whose
         tail sizes alternate pays each variant's one-time compile the first
-        time that tail size appears mid-traffic (at most log2(K) compiles;
-        SIDDHI_TPU_PREWARM_TAIL=1 pre-compiles the smallest variant at first
-        engagement to take the worst of it off the traffic path, see
-        _prewarm_tail)."""
+        time that tail size appears mid-traffic (at most log2(K) compiles)."""
         if remaining_batches >= self.K:
             return self.K
         k = 2
@@ -719,8 +688,6 @@ class FusedJunctionIngest:
                 B, self._keep, self._narrow or {}
             )
 
-        if not self._prewarmed:
-            self._prewarm_tail(prog, now)
         if send_id is None:
             send_id = next(self.junction.send_ids)
         with stage(
@@ -734,8 +701,9 @@ class FusedJunctionIngest:
     def _send_engaged(
         self, prog, encode, deliver, dset, ts_arr, cols, n, B, now
     ) -> bool:
-        """The engaged send: program and codec are chosen, pick the chunk
-        loop (sharded, pipelined or serial) and commit the side records."""
+        """The engaged send: program and codec are chosen, pick who walks the
+        chunks (the shard router or the chunk loop, on the pipeline's worker
+        side or, re-entrant, its inline side) and commit the side records."""
 
         # flight recorder: the fused path never materializes an EventBatch
         # host-side, so record straight from the (host, physical) columns —
@@ -792,29 +760,30 @@ class FusedJunctionIngest:
             if sent is not None:
                 return record_flight(sent)
 
-        if self.pipeline_enabled:
-            pl = self._pipeline()
-            # a query callback that re-enters send_columns from the drain
-            # worker must not block on the pipeline it is draining; neither
+        def run(side) -> bool:
+            return record_flight(self._send_chunks(
+                prog, encode, deliver, dset, ts_arr, cols, n, B, now,
+                ds, tracked, tr, stream_span, side,
+            ))
+
+        pl = self._pipeline()
+        if (
+            pl.is_drain_thread()
+            or self._sender is threading.current_thread()
+        ):
+            # re-entrant: a query callback that sends again from the drain
+            # worker must not wait on the pipeline it is draining; neither
             # must the thread that already holds the send lock (a failure
-            # handler run on the sending thread can re-enter)
-            if (
-                not pl.is_drain_thread()
-                and self._sender is not threading.current_thread()
-            ):
-                with self._send_lock:
-                    self._sender = threading.current_thread()
-                    try:
-                        return record_flight(self._send_pipelined(
-                            prog, encode, deliver, dset, ts_arr, cols, n, B,
-                            now, ds, tracked, tr, stream_span, pl,
-                        ))
-                    finally:
-                        self._sender = None
-        return record_flight(self._send_serial(
-            prog, encode, deliver, dset, ts_arr, cols, n, B, now,
-            ds, tracked, tr, stream_span,
-        ))
+            # handler run on the sending thread). The pooled wire slots and
+            # the drain queue belong to the outer send, so this one runs
+            # the loop against the pipeline's inline side.
+            return run(pl.inline())
+        with self._send_lock:
+            self._sender = threading.current_thread()
+            try:
+                return run(pl)
+            finally:
+                self._sender = None
 
     def _pipeline(self):
         pl = self.pipeline
@@ -1136,102 +1105,19 @@ class FusedJunctionIngest:
             )
             j.dispatch_subset(decode(buf, np.int32(m)), now, self.residual)
 
-    def _send_serial(
-        self, prog, encode, deliver, dset, ts_arr, cols, n, B, now,
-        ds, tracked, tr, stream_span,
-    ) -> bool:
-        """The fully serial chunk loop (@pipeline(disable='true') or a
-        drain-worker re-entrant send): encode, dispatch, and drain the
-        previous chunk's outputs on the calling thread, in order."""
-        prof = self.junction.profiler
-        pending_drain = None  # previous chunk's packs, drained one chunk late
-        c_off = 0
-        while c_off < n:
-            K = self._chunk_K(-(-(n - c_off) // B))
-            c_end = min(c_off + K * B, n)
-            wf = (
-                prof.begin(self.junction.schema.stream_id, c_end - c_off)
-                if prof is not None
-                else None
-            )
-            chunk = next(self._chunk_ids)
-            try:
-                wire, counts, bases = self._encode_chunk(
-                    encode, ts_arr, cols, c_off, c_end, B, K,
-                    wf=wf, chunk=chunk,
-                )
-            except WireNarrowMisfit:
-                try:
-                    prog, encode = self._rebuild_full_width(deliver, dset)
-                except Exception as e:
-                    import logging
-
-                    logging.getLogger(__name__).warning(
-                        "fused ingest disabled for stream '%s' (full-width "
-                        "rebuild failed)", self.junction.schema.stream_id,
-                        exc_info=True,
-                    )
-                    self._disabled = True
-                    if c_off == 0:
-                        return False  # nothing ingested: per-batch fallback
-                    # earlier chunks are committed: deliver their parked
-                    # outputs, then honor the junction's failure policy for
-                    # the remainder (like a failing batch)
-                    if pending_drain is not None:
-                        self._drain_guarded(*pending_drain)
-                    handler = self.junction.exception_handler
-                    if handler is None:
-                        raise
-                    handler(e)
-                    return True
-                wire, counts, bases = self._encode_chunk(
-                    encode, ts_arr, cols, c_off, c_end, B, K,
-                    wf=wf, chunk=chunk,
-                )
-
-            packs, _completion = self._dispatch_chunk(
-                prog, wire, counts, bases, now, ds, tracked, tr, stream_span,
-                wf=wf, deliver=deliver, chunk=chunk,
-            )
-            if packs is not None and deliver:
-                # drain the PREVIOUS chunk now that this chunk's device work
-                # is launched: the host decode overlaps device compute, and
-                # callbacks still fire in order before send_columns returns
-                if pending_drain is not None:
-                    self._drain_guarded(*pending_drain)
-                pending_drain = (
-                    packs, K, wf, {"chunk": chunk}, time.perf_counter_ns()
-                )
-            else:
-                if prof is not None:
-                    prof.end(wf)
-            c_off = c_end
-        if pending_drain is not None:
-            self._drain_guarded(*pending_drain)
-        return True
-
-    def _drain_guarded(self, packs, K: int, *drain_args) -> None:
-        """Drain with the junction's failure machinery owning callback
-        errors (same contract on every ingest path — per-batch dispatch,
-        @async workers, pipelined drain): guarded junctions route the
-        failure, unguarded ones re-raise to the sender."""
-        try:
-            self._drain(packs, K, *drain_args)
-        except Exception as e:
-            j = self.junction
-            if j.exception_handler is None and j.fault_policy is None:
-                raise
-            j._on_worker_error(e, "fused drain")
-
-    def _send_pipelined(
+    def _send_chunks(
         self, prog, encode, deliver, dset, ts_arr, cols, n, B, now,
         ds, tracked, tr, stream_span, pl,
     ) -> bool:
-        """The double-buffered chunk loop (core/pipeline.py): chunk N+1 is
-        encoded into a pooled buffer and device_put while chunk N's dispatch
-        is in flight; deliver-mode drains run on the pipeline's bounded
-        worker in chunk order. Barriers on the drain before returning, so
-        callers observe the exact callback ordering of the serial path."""
+        """THE chunk loop of a fused send, written against the pipeline's
+        verbs (core/pipeline.py). `pl` decides where a chunk's wire buffer
+        comes from and where its drain runs. The IngestPipeline: chunk N+1
+        is encoded into a pooled buffer and device_put while chunk N's
+        dispatch is in flight, and deliver-mode drains run on its bounded
+        worker in chunk order. Its inline side (a re-entrant send): fresh
+        buffers, each chunk drained on this thread once the next one is
+        dispatched. Either way delivery is flushed before returning, so
+        callbacks fire in chunk order and complete before the send does."""
         ps = pl.stats
         wall0 = time.perf_counter_ns() if ps is not None else 0
         err = None
@@ -1252,8 +1138,8 @@ class FusedJunctionIngest:
                 pl.retire(slot, completion)
                 dispatched = True
                 if deliver and packs is not None:
-                    # hand the packs to the drain worker BEFORE staging the
-                    # next chunk: nothing downstream can lose them, and the
+                    # hand the packs to the drain BEFORE staging the next
+                    # chunk: nothing downstream can lose them, and the
                     # worker's readback+decode overlaps the encode below
                     pl.submit(packs, K, wf, chunk)
                 elif wf is not None:
@@ -1262,8 +1148,8 @@ class FusedJunctionIngest:
                         prof.end(wf)
                 if deliver and pl.pending_error():
                     # an unguarded delivery failure is waiting at the
-                    # barrier: stop ingesting further chunks, like the
-                    # serial path's drain raising mid-loop
+                    # barrier: stop ingesting further chunks (inline, the
+                    # drain raised out of submit() instead)
                     break
                 if c_off < n:
                     # overlap: this encode + h2d ride alongside the
@@ -1300,7 +1186,7 @@ class FusedJunctionIngest:
     def _stage_chunk(
         self, pl, prog, encode, deliver, dset, ts_arr, cols, c_off, n, B, ps
     ):
-        """Encode the next chunk into a pooled wire buffer and start its
+        """Encode the next chunk into a wire buffer of `pl` and start its
         async h2d transfer. Returns ((dev_wire, counts, bases, K, slot, wf,
         chunk), next_off, prog, encode) — prog/encode may have been swapped by a
         full-width rebuild on a narrow-wire misfit; the caller must
@@ -1351,52 +1237,13 @@ class FusedJunctionIngest:
             (dev_wire, counts, bases, K, slot, wf, chunk), c_end, prog, encode
         )
 
-    def _prewarm_tail(self, prog, now: int) -> None:
-        """Opt-in (SIDDHI_TPU_PREWARM_TAIL=1): compile the smallest tail
-        variant (K=2) at first engagement — on throwaway donated states and
-        an all-empty wire — so alternating tail sizes don't pay a cold
-        device compile mid-traffic (see _chunk_K). Off by default: it adds
-        one compile per engaged junction whether or not tails ever occur."""
-        import os
-
-        self._prewarmed = True
-        if self.K <= 2 or os.environ.get("SIDDHI_TPU_PREWARM_TAIL") != "1":
-            return
-        try:
-            wire = np.zeros((2, self._wire_bytes), dtype=np.uint8)
-            counts = np.zeros((2,), dtype=np.int32)
-            bases = np.zeros((2,), dtype=np.int64)
-            with self.app._process_lock:
-                states = tuple(
-                    ep.qr._fresh(ep.init_state(now)) for ep in self.endpoints
-                )
-                tstates = {}
-                for ep in self.endpoints:
-                    tstates.update(ep.qr._collect_table_states())
-                # zero counts: every lane is invalid, no state is observable;
-                # the throwaway states are donated, the table states are not
-                arg0 = self._pack_arg0(list(states))
-                if self._mesh_place is not None:
-                    arg0, tstates = self._place_on_mesh(arg0, tstates)
-                prog(arg0, tstates, wire, counts, bases, np.int64(now))
-        except Exception:
-            import logging
-
-            logging.getLogger(__name__).debug(
-                "tail-variant prewarm failed for stream '%s'",
-                self.junction.schema.stream_id, exc_info=True,
-            )
-
     def _encode_chunk(
-        self, encode, ts_arr, cols, c_off, c_end, B, K, out=None,
+        self, encode, ts_arr, cols, c_off, c_end, B, K, out,
         tracker=None, wf=None, chunk=None,
     ):
-        """Encode one K-batch chunk into the [K, bytes] wire stack; with
-        `out` (a pooled pipeline buffer) the rows are written in place
-        instead of allocating a fresh stack. The `encode` stage of both
-        chunk loops."""
+        """Encode one K-batch chunk into `out`, the [K, bytes] wire buffer
+        the pipeline handed out: the `encode` stage of the chunk loop."""
         with stage("encode", tracker, wf=wf, chunk=chunk):
-            bufs = [] if out is None else None
             counts = np.zeros((K,), dtype=np.int32)
             bases = np.zeros((K,), dtype=np.int64)
             for k in range(K):
@@ -1411,17 +1258,10 @@ class FusedJunctionIngest:
                         m,
                     )
                     bases[k] = base
-                    if out is None:
-                        bufs.append(buf)
-                    else:
-                        out[k, :] = buf
-                elif out is None:
-                    bufs.append(np.zeros_like(bufs[0]))
+                    out[k, :] = buf
                 else:
                     out[k, :] = 0
-            if out is not None:
-                return out, counts, bases  # [K, bytes]
-            return np.stack(bufs), counts, bases  # [K, bytes]
+            return out, counts, bases
 
     def _drain(
         self, packs, K: int, wf=None, ids=None, t_submit=0, tracker=None
@@ -1433,7 +1273,7 @@ class FusedJunctionIngest:
         query/output/callback/QueryCallback.java:52-105). `K` is the chunk's
         batch count (variable: short tails ride smaller-K programs).
 
-        The `drain` stage, on the drain worker or, serial, on the sender;
+        The `drain` stage, on the drain worker or, re-entrant, on the caller;
         `ids` are the sender's `send` and `chunk`, and `t_submit`
         (perf_counter_ns at the hand-off) gives the time the chunk waited,
         which no span can cross threads to show.

@@ -22,25 +22,33 @@ This module keeps those stages concurrently busy (the Hazelcast Jet
    with backpressure (at most `depth` undrained chunks in flight) so state
    donation stays safe and device memory for packed outputs is bounded.
 
-Ordering and failure semantics are preserved exactly:
+Ordering and failure semantics:
 
-* `try_send` still BARRIERS on the drain before returning, so callbacks
-  fire in chunk order and complete before `send_columns` returns — any
-  later per-batch `send` observes the same ordering as the serial path;
+* `try_send` BARRIERS on the drain before returning, so callbacks fire in
+  chunk order and complete before `send_columns` returns — any later
+  per-batch `send` observes the same ordering as the per-batch path;
 * a delivery failure on the drain worker goes through the junction's
   existing failure machinery (`_on_worker_error`: log + error stats +
   exception handler), mirroring the @async drain workers; when the
   junction has NO handler and NO @OnError policy the error is re-raised
-  to the sender at the barrier, like the serial path's in-line drain.
+  to the sender at the barrier.
 
-Configuration: the `@pipeline(depth='N', disable='true')` stream
-annotation, overridden process-wide by SIDDHI_TPU_PIPELINE=1 (force on) /
-SIDDHI_TPU_PIPELINE=0 (force off).
+This module owns ONE decision of the fused send: where a chunk's wire
+buffer comes from and where its drain runs. core/ingest.py's chunk loop is
+written once against the verbs `acquire` / `ship` / `retire` / `submit` /
+`pending_error` / `barrier`; IngestPipeline answers them with the pooled
+slots and the worker above, and `IngestPipeline.inline()` answers them for
+a RE-ENTRANT send (a query callback sending from the drain worker, or a
+failure handler on the thread that holds the send lock), which must not
+wait on the pipeline it runs inside: fresh unpooled buffers, each chunk
+drained on the calling thread one chunk late. Which side a send gets
+follows from which thread is calling, never from an option.
+
+Configuration: the `@pipeline(depth='N')` stream annotation.
 """
 
 from __future__ import annotations
 
-import os
 import queue
 import threading
 import time
@@ -53,23 +61,6 @@ from siddhi_tpu.testing import faults as _faults
 
 DEFAULT_DEPTH = 2
 _MAX_DEPTH = 8
-
-PIPELINE_ENV = "SIDDHI_TPU_PIPELINE"
-
-_TRUE = ("1", "on", "true", "force")
-_FALSE = ("0", "off", "false")
-
-
-def pipeline_env_override() -> Optional[bool]:
-    """Process-wide pipeline toggle: True (forced on), False (forced off),
-    or None (defer to the stream's @pipeline annotation)."""
-    v = os.environ.get(PIPELINE_ENV, "").strip().lower()
-    if v in _TRUE:
-        return True
-    if v in _FALSE:
-        return False
-    return None
-
 
 def iter_pipeline_annotation_problems(ann):
     """Yield one message per malformed `@pipeline` element — THE validation
@@ -87,36 +78,24 @@ def iter_pipeline_annotation_problems(ann):
                     f"@pipeline depth '{v}' must be an integer in "
                     f"1..{_MAX_DEPTH}"
                 )
-        elif k == "disable":
-            if str(v).strip().lower() not in ("true", "false"):
-                yield f"@pipeline disable '{v}' must be true or false"
         else:
             yield (
                 f"unknown @pipeline option '{k if k is not None else v}' "
-                "(expected depth, disable)"
+                "(expected depth)"
             )
 
 
-def resolve_pipeline_annotation(ann) -> tuple[bool, int]:
-    """(enabled, depth) for one stream from its `@pipeline` annotation (or
-    None) plus the SIDDHI_TPU_PIPELINE env override. Raises
-    SiddhiAppCreationError on malformed options — the runtime analog of the
-    analyzer's SA112 diagnostic."""
+def resolve_pipeline_annotation(ann) -> int:
+    """The pipeline depth of one stream from its `@pipeline` annotation (or
+    None). Raises SiddhiAppCreationError on malformed options — the runtime
+    analog of the analyzer's SA112 diagnostic."""
     from siddhi_tpu.core.errors import SiddhiAppCreationError
 
-    enabled = True
-    depth = DEFAULT_DEPTH
-    if ann is not None:
-        for problem in iter_pipeline_annotation_problems(ann):
-            raise SiddhiAppCreationError(problem)
-        depth = int(ann.element("depth", str(DEFAULT_DEPTH)))
-        enabled = (
-            str(ann.element("disable", "false")).strip().lower() != "true"
-        )
-    env = pipeline_env_override()
-    if env is not None:
-        enabled = env
-    return enabled, depth
+    if ann is None:
+        return DEFAULT_DEPTH
+    for problem in iter_pipeline_annotation_problems(ann):
+        raise SiddhiAppCreationError(problem)
+    return int(ann.element("depth", str(DEFAULT_DEPTH)))
 
 
 class _WireSlot:
@@ -328,14 +307,14 @@ class IngestPipeline:
         """True once an unguarded drain failure is stashed for barrier():
         the sender polls this per chunk and stops ingesting, bounding the
         extra chunks committed past a poisoned delivery to the pipeline
-        depth (the serial path's drain-one-late commits one extra)."""
+        depth (the inline side's drain-one-late commits one extra)."""
         with self._cv:
             return self._error is not None
 
     def barrier(self) -> None:
         """Wait until every submitted chunk has been delivered; re-raise a
-        drain failure here when the junction has no handler/policy to own it
-        (the pipelined analog of the serial path's in-line drain raising)."""
+        drain failure here when the junction has no handler/policy to own
+        it."""
         with self._cv:
             if self._inflight > 0:
                 with stage("barrier"):
@@ -380,18 +359,28 @@ class IngestPipeline:
         ps = self.stats
         self.drain_fn(packs, K, wf, ids, t_submit, ps and ps.drain)
 
-    def _on_drain_error(self, exc: Exception) -> None:
-        """A guarded junction's failure machinery owns the error — the same
-        machinery as the @async drain workers (log + error stats +
-        exception handler); on an unguarded junction the failure goes back
-        to the sender."""
+    def _junction_owns(self, exc: Exception, where: str) -> bool:
+        """Hand a delivery failure to a guarded junction's failure
+        machinery — the same as the @async drain workers' (log + error
+        stats + exception handler). False on an unguarded junction: the
+        failure goes back to the sender."""
         j = self.junction
-        if j.exception_handler is not None or j.fault_policy is not None:
-            j._on_worker_error(exc, "pipeline drain")
+        if j.exception_handler is None and j.fault_policy is None:
+            return False
+        j._on_worker_error(exc, where)
+        return True
+
+    def _on_drain_error(self, exc: Exception) -> None:
+        if self._junction_owns(exc, "pipeline drain"):
             return
         with self._cv:
             if self._error is None:
                 self._error = exc  # surfaces to the sender at barrier()
+
+    def inline(self) -> "_InlineDrain":
+        """The verbs of this pipeline for ONE re-entrant send (see the
+        module docstring)."""
+        return _InlineDrain(self)
 
     def close(self) -> None:
         """Flush nothing (callers barrier first); stop the drain worker."""
@@ -403,3 +392,52 @@ class IngestPipeline:
             self._q.put(None)
             t.join(timeout=2.0)
         self._thread = None
+
+
+class _InlineDrain:
+    """IngestPipeline's verbs for one re-entrant send, on the calling
+    thread: it takes no lock (the outer sender holds the send lock), starts
+    no thread and touches neither the pooled slots nor the drain queue,
+    which belong to the outer send and may be staging concurrently."""
+
+    stats = None  # a stage of the outer send already holds this time
+
+    def __init__(self, pl: IngestPipeline):
+        self._pl = pl
+        self._parked = None  # the last chunk's packs, drained one late
+
+    def acquire(self, K: int, wire_bytes: int, chunk=None) -> _WireSlot:
+        return _WireSlot((int(K), int(wire_bytes)))
+
+    def ship(self, slot: _WireSlot):
+        import jax
+
+        return jax.device_put(slot.buf, self._pl.wire_sharding)
+
+    def retire(self, slot: _WireSlot, completion) -> None:
+        """Nothing to gate: the buffer is never written again."""
+
+    def submit(self, packs, K: int, wf=None, chunk=None) -> None:
+        """Park this chunk and drain the PREVIOUS one now that this chunk's
+        device work is launched: the host decode overlaps device compute,
+        and callbacks still fire in order before the send returns."""
+        prev, self._parked = self._parked, (
+            packs, K, wf, {"chunk": chunk}, time.perf_counter_ns(),
+        )
+        if prev is not None:
+            self._drain(prev)
+
+    def pending_error(self) -> bool:
+        return False  # an unguarded failure raised out of submit()
+
+    def barrier(self) -> None:
+        prev, self._parked = self._parked, None
+        if prev is not None:
+            self._drain(prev)
+
+    def _drain(self, item) -> None:
+        try:
+            self._pl.drain_fn(*item, None)
+        except Exception as exc:
+            if not self._pl._junction_owns(exc, "fused drain"):
+                raise

@@ -167,8 +167,7 @@ class _AuxWarnPool:
         self._pending: dict = {}
         self._last_drain = _time.monotonic()
         # periodic-drain cadence; 0 or negative disables automatic drains
-        # (flush()/shutdown still drain) — benches that want no read inside
-        # a timed region set SIDDHI_TPU_AUX_DRAIN_S=0
+        # (flush()/shutdown still drain)
         try:
             self.drain_every_s = float(
                 os.environ.get("SIDDHI_TPU_AUX_DRAIN_S", "5.0")

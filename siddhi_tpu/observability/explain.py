@@ -254,9 +254,7 @@ def build_plan(app, runtime=None) -> dict:
                     counters["queue_depth"] = j.queued()
                     fi = j.fused_ingest
                     if fi is not None:
-                        counters["fused"] = (
-                            "pipelined" if fi.pipeline_enabled else "serial"
-                        )
+                        counters["fused"] = "pipelined"
                         counters["chunk_batches"] = fi.K
                         # a key-sharded member (parallel/keyshard.py): the
                         # chunk program runs on the keys mesh

@@ -27,8 +27,7 @@ from siddhi_tpu.observability.metrics import (
 
 class JunctionDeviceStats:
     """Device-budget trackers for one junction's dispatch path: fused-step
-    dispatch time, h2d wire traffic, and d2h truth-sync stalls (the engine's
-    live version of what bench.py's `timebudget` leg reconstructs offline)."""
+    dispatch time, h2d wire traffic, and d2h truth-sync stalls."""
 
     __slots__ = (
         "step", "h2d_bytes", "h2d_chunks", "h2d_events", "h2d_logical",
@@ -246,8 +245,7 @@ class StatisticsManager:
 
     def roofline(self) -> dict:
         """Live per-stream wire roofline: bytes/event over the fused h2d
-        path plus the 1-minute h2d throughput in MB/s — the always-on
-        version of bench r04's roofline attribution, the signal the
+        path plus the 1-minute h2d throughput in MB/s — the signal the
         compact-wire-encoding work targets. Keyed by component
         (`stream.<id>`); empty until a fused send ships bytes."""
         out: dict = {}

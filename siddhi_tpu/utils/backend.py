@@ -15,8 +15,8 @@ _CB_SUPPORT: Optional[bool] = None
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 # <checkout>/.jax_cache: fixed and inside the checkout (git-ignored), so
-# every process started from this tree — chip_smoke.py, bench.py's leg
-# children, the tests — finds what an earlier one compiled
+# every process started from this tree — chip_smoke.py, benchmark/run.py,
+# the tests — finds what an earlier one compiled
 _CHECKOUT_CACHE = str(Path(__file__).resolve().parents[2] / ".jax_cache")
 
 

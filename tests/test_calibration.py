@@ -21,7 +21,7 @@ from siddhi_tpu.observability.calibration import (
     _safe_ratio,
 )
 
-# the six-kind sentinel shape (mirrors bench.py --leg calibration): two
+# the six-kind sentinel shape: two
 # shared filter+window queries, one externalTimeBatch query, a declared
 # dict wire lane + an inferred delta lane, all fused under one group.
 # batch 256: a 64-entry dictionary must amortize under the wide int32

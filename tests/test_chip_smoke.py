@@ -23,9 +23,6 @@ def _run(args, cwd=ROOT, script=SMOKE, timeout=120):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("PYTHONPATH", None)
-    # importing bench.py presets this for its own legs; a test file that did
-    # so earlier in this worker must not decide what the smoke run sees
-    env.pop("SIDDHI_TPU_AUX_DRAIN_S", None)
     return subprocess.run(
         [sys.executable, script, *args], cwd=cwd, env=env,
         capture_output=True, text=True, timeout=timeout,

@@ -167,12 +167,10 @@ class TestPlanSnapshot:
         assert lint_main(["--plan", "--format=json", path]) == 0
         json.loads(capsys.readouterr().out)
 
-    def test_plan_over_bench_workloads(self, capsys):
-        import bench
+    def test_plan_over_baseline_workloads(self, capsys):
+        from siddhi_tpu.testing.apps import WORKLOADS
 
-        for name, (ql, _stream, _mult, _batch) in sorted(
-            bench.WORKLOADS.items()
-        ):
+        for name, (ql, _stream, _batch) in sorted(WORKLOADS.items()):
             plan = build_fusion_plan(ql).to_dict()
             assert plan["version"] == 3, name
             assert plan["costs"]["queries"], name

@@ -1,11 +1,12 @@
 """Pipelined fused ingest (core/pipeline.py) must be observationally
-identical to the serial fused path: byte-identical outputs, identical
-delivery order and per-chunk callback grouping, identical failure-policy
-semantics when delivery fails on the drain worker.
+identical to the per-batch path: byte-identical outputs, identical
+delivery order and per-micro-batch callback grouping, identical
+failure-policy semantics when delivery fails on the drain worker.
 
-Each parity case runs the same columnar feed twice — pipelined (the
-default) and serial (`@pipeline(disable='true')`) — plus configuration,
-error-routing, and observability coverage.
+Each parity case runs the same columnar feed twice — fused (the default)
+and per batch (`@app:fuse(disable='true')`, the reference of every fuse
+on/off parity in the suite) — plus configuration, error-routing, the
+re-entrant send (the pipeline's inline side) and observability coverage.
 """
 
 from __future__ import annotations
@@ -15,19 +16,16 @@ import pytest
 
 from siddhi_tpu import SiddhiManager
 
+
 @pytest.fixture(autouse=True)
-def _isolate_pipeline_env(monkeypatch):
-    """CI runs part of the suite under SIDDHI_TPU_PIPELINE=1; these tests
-    assert annotation-level behavior, so the outer override must not leak
-    in (tests that want the env toggle set it themselves)."""
-    monkeypatch.delenv("SIDDHI_TPU_PIPELINE", raising=False)
+def _isolate_fuse_env(monkeypatch):
+    """CI runs part of the suite under SIDDHI_TPU_FUSE=1|0, which beats the
+    annotation both ways; these tests choose the path themselves."""
+    monkeypatch.delenv("SIDDHI_TPU_FUSE", raising=False)
 
 
 HEAD = "@app:batch(size='64')\ndefine stream S (symbol string, price float, volume long);\n"
-SERIAL_HEAD = (
-    "@app:batch(size='64')\n@pipeline(disable='true')\n"
-    "define stream S (symbol string, price float, volume long);\n"
-)
+PER_BATCH_HEAD = "@app:fuse(disable='true')\n" + HEAD
 
 
 def _feed(n, seed=42):
@@ -73,10 +71,10 @@ CB_BODY = """@info(name='q') from S#window.length(16)
     select symbol, avg(price) as ap insert into Out;"""
 
 
-def test_pipelined_matches_serial_table():
+def test_pipelined_matches_per_batch_table():
     n = 64 * 40
     assert _run_rows(HEAD + TABLE_BODY, n) == _run_rows(
-        SERIAL_HEAD + TABLE_BODY, n
+        PER_BATCH_HEAD + TABLE_BODY, n
     )
 
 
@@ -99,13 +97,13 @@ def _run_cb(ql, n):
     return got
 
 
-def test_pipelined_delivery_matches_serial():
+def test_pipelined_delivery_matches_per_batch():
     """Drain-worker delivery: identical events, identical per-micro-batch
     grouping, identical order."""
     n = 64 * 40
     pipelined = _run_cb(HEAD + CB_BODY, n)
-    serial = _run_cb(SERIAL_HEAD + CB_BODY, n)
-    assert pipelined == serial
+    per_batch = _run_cb(PER_BATCH_HEAD + CB_BODY, n)
+    assert pipelined == per_batch
     assert sum(len(i) for _t, i, _r in pipelined) > 50
 
 
@@ -150,14 +148,20 @@ def test_pipeline_annotation_depth_and_disable():
         + CB_BODY
     )
     fi = _fused(rt)
-    assert fi.pipeline_enabled and fi.pipeline_depth == 3
+    assert fi.pipeline_depth == 3
+    assert fi.describe_state()["pipeline_enabled"] is True
     rt.shutdown()
     mgr.shutdown()
 
-    mgr, rt = _boot(SERIAL_HEAD + CB_BODY)
-    assert not _fused(rt).pipeline_enabled
-    rt.shutdown()
-    mgr.shutdown()
+    # no switch turns the pipeline off: `disable` is an unknown key
+    from siddhi_tpu.core.errors import SiddhiAppCreationError
+
+    with pytest.raises(SiddhiAppCreationError, match="unknown @pipeline"):
+        SiddhiManager().create_siddhi_app_runtime(
+            "@app:batch(size='64')\n@pipeline(disable='true')\n"
+            "define stream S (symbol string, price float, volume long);\n"
+            + CB_BODY
+        )
 
 
 def test_pipeline_annotation_rejects_bad_options():
@@ -172,27 +176,6 @@ def test_pipeline_annotation_rejects_bad_options():
                 "define stream S (symbol string, price float, volume long);\n"
                 + CB_BODY
             )
-
-
-def test_pipeline_env_override(monkeypatch):
-    monkeypatch.setenv("SIDDHI_TPU_PIPELINE", "0")
-    mgr, rt = _boot(HEAD + CB_BODY)
-    assert not _fused(rt).pipeline_enabled
-    rt.shutdown()
-    mgr.shutdown()
-
-    monkeypatch.setenv("SIDDHI_TPU_PIPELINE", "1")
-    mgr, rt = _boot(SERIAL_HEAD + CB_BODY)  # env wins over disable='true'
-    assert _fused(rt).pipeline_enabled
-    rt.shutdown()
-    mgr.shutdown()
-
-
-def test_prewarm_env_compiles_tail_variant(monkeypatch):
-    monkeypatch.setenv("SIDDHI_TPU_PREWARM_TAIL", "1")
-    got = _run_cb(HEAD + CB_BODY, 64 * 8)
-    monkeypatch.delenv("SIDDHI_TPU_PREWARM_TAIL")
-    assert got == _run_cb(HEAD + CB_BODY, 64 * 8)
 
 
 def test_wire_slot_reuse_gated_per_shipment():
@@ -280,11 +263,258 @@ def test_drain_error_with_onerror_policy_spares_sender():
 
 def test_drain_error_propagates_without_handler():
     """No handler, no @OnError policy: the failure surfaces to the sender
-    at the end of the call, like the serial path's in-line drain."""
+    at the end of the call."""
     mgr, rt = _boot(HEAD + CB_BODY, callback=_boom)
     ts, cols = _feed(64 * 8)
     with pytest.raises(RuntimeError, match="poisoned callback"):
         rt.get_input_handler("S").send_columns(ts, cols)
+    rt.shutdown()
+    mgr.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the re-entrant send: the same chunk loop on the pipeline's inline side
+# ---------------------------------------------------------------------------
+
+PASS_BODY = (
+    "@info(name='q') from S[price >= 0] select symbol, price, volume "
+    "insert into Out;"
+)
+
+
+def _inner_feed(n, base=1000.0):
+    ts, cols = _feed(n, seed=7)
+    cols["price"] = base + np.arange(n, dtype=np.float32)
+    return ts + 10_000, cols
+
+
+class _Reenter:
+    """A query callback that records the prices it receives and, on its
+    first call, sends `inner` on the same stream from where it runs."""
+
+    def __init__(self, inner, poison_inner=False):
+        self.rt = None  # set once the runtime exists
+        self.inner = inner
+        self.poison_inner = poison_inner
+        self.order = []
+        self.thread = None  # the thread the inner send was made from
+        self.seen_when_inner_returned = None
+        self.inner_error = None
+        self.around_inner = lambda send: send()
+
+    def __call__(self, ts, ins, rem):
+        prices = [e.data[1] for e in (ins or [])]
+        if self.poison_inner and prices and prices[0] >= 1000.0:
+            raise RuntimeError("poisoned callback")
+        self.order.extend(prices)
+        if self.thread is None:
+            import threading
+
+            self.thread = threading.current_thread().name
+            try:
+                self.around_inner(
+                    lambda: self.rt.get_input_handler("S").send_columns(
+                        *self.inner
+                    )
+                )
+            except Exception as e:
+                self.inner_error = e
+                raise
+            self.seen_when_inner_returned = len(self.order)
+
+
+def _boot_reentrant(ql, n_inner, **kw):
+    cb = _Reenter(_inner_feed(n_inner), **kw)
+    mgr, cb.rt = _boot(ql, callback=cb)
+    return mgr, cb.rt, cb
+
+
+def _outer_feed(n):
+    ts, cols = _feed(n)
+    cols["price"] = np.arange(n, dtype=np.float32)
+    return ts, cols
+
+
+def test_reentrant_send_from_drain_worker_delivers_in_order():
+    """A callback that re-enters send_columns on its own stream from the
+    drain worker returns (it must not wait on the pipeline it is draining),
+    and every row of the inner and the outer send is delivered, in order,
+    the inner send's callbacks complete before it returns."""
+    n_out, n_in = 64 * 8, 64 * 4
+    mgr, rt, cb = _boot_reentrant(HEAD + PASS_BODY, n_in)
+    rt.get_input_handler("S").send_columns(*_outer_feed(n_out))
+    assert cb.thread.startswith("siddhi-pipeline-")
+    # the outer's first micro-batch, then the whole inner send, delivered
+    # by the time it returned, then the rest of the outer
+    assert cb.seen_when_inner_returned == 64 + n_in
+    assert cb.order == (
+        list(range(64))
+        + [1000.0 + i for i in range(n_in)]
+        + list(range(64, n_out))
+    )
+    fi = _fused(rt)
+    assert fi.events_fused == n_out + n_in  # both rode the chunk loop
+    assert fi.chunks_dispatched == 2
+    rt.shutdown()
+    mgr.shutdown()
+
+
+def test_reentrant_send_into_table_matches_per_batch():
+    body = (
+        "@capacity(size='4096') define table T "
+        "(symbol string, price float);\n"
+        + PASS_BODY
+        + "\n@info(name='w') from S select symbol, price insert into T;"
+    )
+    rows = {}
+    for head in (HEAD, PER_BATCH_HEAD):
+        mgr, rt, cb = _boot_reentrant(head + body, 64 * 4)
+        rt.get_input_handler("S").send_columns(*_outer_feed(64 * 8))
+        rows[head] = sorted(map(repr, rt.query("from T select *")))
+        assert len(cb.order) == 64 * 12
+        rt.shutdown()
+        mgr.shutdown()
+    assert rows[HEAD] == rows[PER_BATCH_HEAD]
+    assert len(rows[HEAD]) == 64 * 12
+
+
+def test_reentrant_send_drains_one_chunk_late_off_the_pool():
+    """A re-entrant send of several chunks: each chunk is drained on the
+    calling thread once the next one is dispatched, and the outer send's
+    pooled wire slots are left alone (the inner's K=2 tail would have
+    added a pool entry)."""
+    mgr, rt, cb = _boot_reentrant(
+        "@app:ingestChunk(size='4')\n" + HEAD + PASS_BODY, 64 * 10
+    )
+    fi = _fused(rt)
+    log = []
+    dispatch, drain = fi._dispatch_chunk, fi._drain
+
+    def spy_dispatch(*a, **kw):
+        log.append(("dispatch", kw["chunk"]))
+        return dispatch(*a, **kw)
+
+    def spy_drain(packs, K, wf, ids, *rest):
+        log.append(("drain", ids["chunk"]))
+        return drain(packs, K, wf, ids, *rest)
+
+    fi._dispatch_chunk, fi._drain = spy_dispatch, spy_drain
+    slots = {}
+
+    def around(send):
+        pl = fi.pipeline
+        slots["before"] = pl.describe_state()["wire_slots"]
+        mark = len(log)
+        send()
+        slots["inner"] = [e for e in log[mark:] if e[1] >= 3]
+        slots["after"] = pl.describe_state()["wire_slots"]
+
+    cb.around_inner = around
+    # outer: 8 batches = chunks 1, 2 (K=4); inner: 10 = chunks 3, 4 (K=4)
+    # and the tail 5 (K=2)
+    rt.get_input_handler("S").send_columns(*_outer_feed(64 * 8))
+    assert slots["inner"] == [
+        ("dispatch", 3), ("dispatch", 4), ("drain", 3),
+        ("dispatch", 5), ("drain", 4), ("drain", 5),
+    ]
+    assert slots["before"] == slots["after"] == 2
+    assert len(cb.order) == 64 * 18
+    rt.shutdown()
+    mgr.shutdown()
+
+
+def test_reentrant_send_narrow_misfit_rebuilds_once_and_delivers():
+    mgr, rt, cb = _boot_reentrant(HEAD + PASS_BODY, 64 * 4)
+    cb.inner[1]["volume"] = cb.inner[1]["volume"] + 10**12
+    fi = _fused(rt)
+    rebuilds = []
+    rebuild = fi._rebuild_full_width
+    fi._rebuild_full_width = lambda *a: (rebuilds.append(a), rebuild(*a))[1]
+    narrow_before = []
+    cb.around_inner = lambda send: (
+        narrow_before.append(dict(fi._narrow)), send()
+    )
+    got = []
+    rt.add_callback("q", lambda t, ins, rem: got.extend(
+        e.data[2] for e in (ins or [])
+    ))
+    rt.get_input_handler("S").send_columns(*_outer_feed(64 * 8))
+    assert narrow_before[0].get("volume")  # the sampled wire was narrow
+    assert len(rebuilds) == 1 and fi._narrow == {}
+    assert len(cb.order) == 64 * 12
+    assert sorted(v for v in got if v > 10**12) == sorted(
+        int(v) for v in cb.inner[1]["volume"]
+    )
+    rt.shutdown()
+    mgr.shutdown()
+
+
+@pytest.mark.parametrize("policy", ["handler", "onerror", "none"])
+def test_reentrant_drain_error_follows_junction_policy(policy):
+    """A delivery failure inside a re-entrant send is drained on the
+    caller, and the junction's policy owns it as on the worker: a handler
+    or @OnError spares the sender, with neither it raises out of the inner
+    send (and so, here, out of the outer's callback and the outer send)."""
+    head = HEAD
+    if policy == "onerror":
+        head = (
+            "@app:statistics(reporter='none')\n@app:batch(size='64')\n"
+            "@OnError(action='LOG')\n"
+            "define stream S (symbol string, price float, volume long);\n"
+        )
+    mgr, rt, cb = _boot_reentrant(head + PASS_BODY, 64 * 4, poison_inner=True)
+    seen = []
+    if policy == "handler":
+        rt.set_exception_handler(seen.append)
+    h = rt.get_input_handler("S")
+    if policy == "none":
+        with pytest.raises(RuntimeError, match="poisoned callback"):
+            h.send_columns(*_outer_feed(64 * 8))
+        assert isinstance(cb.inner_error, RuntimeError)
+    else:
+        h.send_columns(*_outer_feed(64 * 8))  # must not raise
+        assert cb.inner_error is None
+        assert cb.order == list(range(64 * 8))  # the outer is whole
+        if policy == "handler":
+            assert seen and isinstance(seen[0], RuntimeError)
+        else:
+            assert rt.statistics_manager.error_tracker("stream.S").count > 0
+    rt.shutdown()
+    mgr.shutdown()
+
+
+def test_reentrant_send_from_failure_handler_on_sender_thread():
+    """The other re-entrant caller: an exception handler run on the
+    sending thread (it holds the send lock) that sends again. It takes the
+    inline side too instead of deadlocking on the lock it holds."""
+    import threading
+
+    from siddhi_tpu.testing import faults
+
+    got = []
+    mgr, rt = _boot(
+        HEAD + PASS_BODY,
+        callback=lambda t, ins, rem: got.extend(
+            e.data[1] for e in (ins or [])
+        ),
+    )
+    h = rt.get_input_handler("S")
+    threads = []
+
+    def handler(exc):
+        threads.append(threading.current_thread())
+        h.send_columns(*_inner_feed(64 * 4))
+
+    rt.set_exception_handler(handler)
+    faults.install(faults.parse_plan("device_dispatch:times=1"))
+    try:
+        h.send_columns(*_outer_feed(64 * 8))  # its one chunk fails
+    finally:
+        faults.uninstall()
+    assert threads == [threading.current_thread()]
+    assert got == [1000.0 + i for i in range(64 * 4)]
+    fi = _fused(rt)
+    assert fi.events_fused == 64 * 4 and fi.pipeline.in_flight() == 0
     rt.shutdown()
     mgr.shutdown()
 

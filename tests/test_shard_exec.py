@@ -399,19 +399,18 @@ class TestPartitionMesh:
 
 
 # ---------------------------------------------------------------------------
-# verify-suite parity sweep (the in-process slice of the CI diff)
+# verify-suite parity sweep (siddhi_tpu/testing/verify_cases.py)
 # ---------------------------------------------------------------------------
 
 
 class TestVerifyParity:
     def test_verify_cases_byte_identical_shard8_vs_off(self, monkeypatch):
-        import bench
+        from siddhi_tpu.testing.verify_cases import run_verify_cases
 
-        monkeypatch.setenv("SIDDHI_TPU_VERIFY_COLUMNAR", "1")
         results = {}
         for mode in ("8", "0"):
             monkeypatch.setenv("SIDDHI_TPU_SHARD", mode)
-            results[mode] = bench._leg_verify()["cases"]
+            results[mode] = run_verify_cases(columnar=True)["cases"]
         errors = {
             k: v
             for m in results

@@ -164,10 +164,14 @@ class TestAutoPersist:
         rt.start()
         rt.get_input_handler("S").send(("A", 10), timestamp=1)
         rt.get_input_handler("S").send(("A", 20), timestamp=2)
-        # wait for a checkpoint taken AFTER both sends (an earlier interval
-        # may have fired between them)
+        # wait for a checkpoint taken AFTER both sends: a cycle may be in
+        # flight right now with a snapshot from between them, and `persists`
+        # counts it only when it ends, so the first increment can be that
+        # stale one; the second began after it, hence after both sends
         p0 = rt._autopersist.persists
-        assert _wait_for(lambda: rt._autopersist.persists > p0, timeout=10)
+        assert _wait_for(
+            lambda: rt._autopersist.persists > p0 + 1, timeout=10
+        )
         mgr.shutdown()
 
         mgr2 = SiddhiManager()

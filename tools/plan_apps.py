@@ -1,5 +1,5 @@
-"""Emit the static FusionPlan for every analysis-corpus app and bench
-workload.
+"""Emit the static FusionPlan for every analysis-corpus app and every app of
+`siddhi_tpu/testing/apps.py`.
 
 CI (tier1.yml lint job) runs this and uploads the output directory as a
 workflow artifact, so every push carries the machine-readable plan the
@@ -42,17 +42,17 @@ def main() -> int:
         name = os.path.basename(path)[:-len(".siddhi")]
         jobs.append((f"corpus_{name}", open(path).read()))
 
-    import bench
+    from siddhi_tpu.testing import apps
 
-    for name, (ql, _stream, _mult, _batch) in sorted(bench.WORKLOADS.items()):
-        jobs.append((f"bench_{name}", ql))
-    # the timebudget leg's multi-query fused-group app: the one bench app
-    # whose plan actually FORMS a group (the headline legs are single-query)
-    jobs.append(("bench_fusedgroup", bench.FUSED_GROUP_QL))
-    # the wire leg's A/B apps — their plans carry the inferred wire lanes
-    # and value domains the `--leg wire` inference assertions rely on
-    for name, (ql, _stream) in sorted(bench.WIRE_WORKLOADS.items()):
-        jobs.append((f"bench_{name}", ql))
+    for name, (ql, _stream, _batch) in sorted(apps.WORKLOADS.items()):
+        jobs.append((f"app_{name}", ql))
+    # the one app here whose plan actually FORMS a group (the BASELINE.json
+    # configurations are single-query)
+    jobs.append(("app_fusedgroup", apps.FUSED_GROUP_QL))
+    # the wire apps: their plans carry the inferred wire lanes and value
+    # domains
+    for name, (ql, _stream) in sorted(apps.WIRE_WORKLOADS.items()):
+        jobs.append((f"app_{name}", ql))
 
     failures = 0
     index = []
