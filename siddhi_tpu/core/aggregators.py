@@ -67,9 +67,7 @@ class CompiledAggregator:
 
     def _run_sum(self, state, contrib, flow: FlowInfo):
         if flow.group is not None:
-            return keyed_running_sum(
-                contrib, flow.group.sorted, flow.reset, state, flow.group.slot
-            )
+            return keyed_running_sum(contrib, flow.group.sorted, state)
         run, carry = running_sum(contrib, flow.reset, state)
         return run, carry
 
@@ -200,14 +198,14 @@ class ExtremeAggregator(CompiledAggregator):
             masked = jnp.where(member, vals[None, :], ident)
             red = masked.min(axis=-1) if self.is_min else masked.max(axis=-1)
             return state, jnp.where(red == ident, _null_arr(self.type), red)
-        reset = jnp.zeros_like(flow.reset) if self.forever else flow.reset
         x = self.arg(env).astype(self.dtype)
         if flow.group is not None:
             run, carry = keyed_running_extreme(
-                x, flow.active, flow.group.sorted, reset, state,
-                flow.group.slot, self.is_min,
+                x, flow.active, flow.group.sorted, state, self.is_min,
+                forever=self.forever,
             )
         else:
+            reset = jnp.zeros_like(flow.reset) if self.forever else flow.reset
             run, carry = running_extreme(x, flow.active, reset, state, self.is_min)
         return carry, jnp.where(run == ident, _null_arr(self.type), run)
 

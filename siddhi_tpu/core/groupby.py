@@ -37,7 +37,8 @@ class GroupCtx:
 
     slot: jnp.ndarray    # [B] int32; == capacity for non-keyed rows
     key: jnp.ndarray     # [B] int64
-    sorted: SortedGroups  # lexsorted (era, key) view for segmented reductions
+    sorted: SortedGroups  # lexsorted (era, key) view for segmented reductions,
+    # with the step's read plan for the groups' carried values
     capacity: int
     key_of: Callable[[Env], jnp.ndarray]  # env -> int64 key column (any length)
     overflow: jnp.ndarray = None  # scalar bool
@@ -53,6 +54,9 @@ class CompiledGroupBy:
         if not group_by:
             raise SiddhiAppCreationError("empty group by")
         self.capacity = int(capacity)
+        # how the last trace read the groups' carried values: "segment" (once
+        # per segment of the sorted view) or "row"; None before the first trace
+        self.carry_read: Optional[str] = None
         self.keys: list[CompiledExpr] = [
             compile_expression(v, scope) for v in group_by
         ]
@@ -78,6 +82,7 @@ class CompiledGroupBy:
         keys, used, n, slot, grp, overflow = assign_slots(
             state["keys"], state["used"], state["n"], bk, active, reset=reset
         )
+        self.carry_read = grp.carry_read
         ctx = GroupCtx(
             slot=slot, key=bk, sorted=grp, capacity=self.capacity,
             key_of=self.key_of, overflow=overflow,

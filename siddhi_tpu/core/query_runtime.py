@@ -794,6 +794,9 @@ class QueryRuntime(BaseQueryRuntime):
         d = super().describe_state()
         if self._keyshard is not None:
             d["keyshard"] = self._keyshard.describe_state()
+        group = self.selector.group
+        if group is not None and group.carry_read is not None:
+            d["group"] = {"capacity": group.capacity, "carry_read": group.carry_read}
         win = self.chain.window
         if win is not None:
             # under the receive lock: the step donates the old state buffers,

@@ -10,6 +10,20 @@ Within a batch, keyed running values ride a SORTED view of the rows — one
 lexsort by (key, reset-era) turns every per-key reduction into a log-depth
 segmented scan (ops/prefix.py), replacing the earlier [B,B] masked-reduction
 formulation that allocated a 1G-element mask at B=32k.
+
+What the design rests on, measured on a TPU v5e at B = 65,536 rows and
+G = 4,096 slots (the deployment's flow and table; ledger, PR 28 breakdown, and
+the stand-alone microbenchmark of PR 29, PERF.md §6): a gather or scatter moves
+one element per step of the scalar core, 7.1 ns a row, whatever the rows hold
+— 0.47 ms for one flow-length gather of a 32-bit lane, 0.94 ms of a 64-bit
+one, 0.31 ms for a flow-length scatter — while a payload sort of the flow
+(key and one lane) takes 22 us, a segmented scan a few us, and a gather or
+scatter of G rows 12-18 us. So a value that is the same along a segment of the
+sorted view (the slot the segment's head was given, the group's carried
+aggregate) is read once per segment, by at most G rows, and spread along the
+segment by a segmented scan; never once per row of the flow. Where the flow is
+no longer than the table (B <= G) a row reads for itself, which is then the
+cheaper form: `SortedGroups.carry_read` says which form a program took.
 """
 
 from __future__ import annotations
@@ -22,7 +36,6 @@ import numpy as np
 
 from siddhi_tpu.ops.prefix import (
     extreme_identity,
-    last_reset_index,
     segmented_carry,
     segmented_cum_extreme,
     segmented_cumsum,
@@ -52,9 +65,10 @@ def mix_keys(cols: list[jnp.ndarray]) -> jnp.ndarray:
 
 def permute_by(key: jnp.ndarray, *lanes: jnp.ndarray) -> tuple:
     """Apply the permutation that sorts `key` ascending to every lane with ONE
-    multi-operand bitonic sort. XLA:TPU runs sorts on the vector units
-    (~1 ns/element) but gathers/scatters on the scalar core (~6.5 ns/element),
-    so `x[perm]` for a known permutation is ~6x cheaper as a payload sort.
+    multi-operand bitonic sort. XLA:TPU runs sorts on the vector units (22 us
+    for 65,536 rows of key and one lane, 53 us with three lanes) but
+    gathers/scatters on the scalar core (7.1 ns a row: 470 us), so `x[perm]`
+    for a known permutation is some twenty times cheaper as a payload sort.
     `key` must be a permutation-ranking (all distinct); lanes ride along."""
     res = jax.lax.sort((key, *lanes), num_keys=1, is_stable=False)
     return res[1:]
@@ -67,11 +81,48 @@ class SortedGroups:
     perm:      [B] int32 — sorted position -> original row
     inv:       [B] int32 — original row -> sorted position
     seg_start: [B] bool  — sorted position begins a (era, key) segment
+
+    and the step's read plan for the groups' carried values, made once by
+    `assign_slots` and shared by every aggregator lane (`keyed_running_sum`,
+    `keyed_running_extreme`):
+
+    reset:     [B] bool  — the RESET rows the eras were cut at
+    slot_s:    [B] int32 — each row's slot (G = none) in sorted order;
+                           constant along a segment
+    carried_s: [B] bool  — sorted rows whose group has a carried value: era 0
+                           (no reset at or before the row) and a live slot
+    writer_s:  [B] int32 — the slot, on the row that ends a live group's
+                           final-era segment (its running value is the
+                           group's new carry); G elsewhere
+    head_pos:  [G] int32 — sorted positions of the `carried_s` segment heads,
+                           moved to the front (B = none). In era 0 a live
+                           slot is one segment, so G places hold them all.
+                           None when B <= G
+    head_slot: [G] int32 — their slots
     """
 
     perm: jnp.ndarray
     inv: jnp.ndarray
     seg_start: jnp.ndarray
+    reset: jnp.ndarray = None
+    slot_s: jnp.ndarray = None
+    carried_s: jnp.ndarray = None
+    writer_s: jnp.ndarray = None
+    head_pos: jnp.ndarray | None = None
+    head_slot: jnp.ndarray | None = None
+
+    @property
+    def carry_read(self) -> str:
+        """How a row comes by its group's carried value: `segment` (read
+        once per segment head, spread by a segmented scan) or `row` (every
+        row gathers for itself: the cheaper form when B <= G). Chosen from
+        the shapes at trace time."""
+        return "row" if self.head_pos is None else "segment"
+
+    @property
+    def seg_end(self) -> jnp.ndarray:
+        """[B] bool — sorted position ends its segment."""
+        return jnp.concatenate([self.seg_start[1:], jnp.ones((1,), jnp.bool_)])
 
     def to_sorted(self, *lanes):
         """lanes[i][perm] for every lane — one payload sort, no gathers."""
@@ -80,6 +131,24 @@ class SortedGroups:
     def from_sorted(self, *lanes):
         """lanes[i][inv] (undo to_sorted) — one payload sort, no gathers."""
         return permute_by(self.perm, *lanes)
+
+    def carried(self, carry: jnp.ndarray, fill, every_era: bool = False):
+        """[B], sorted order: `carry[slot]` on `carried_s` rows, `fill` on
+        the others. By segment, G rows are gathered from the table, put at
+        their segment heads and carried along the segments. `every_era`
+        (maxForever / minForever ignore resets) takes rows of later eras in
+        too; their heads may outnumber G, so its rows read for themselves."""
+        g = carry.shape[0]
+        if every_era or self.head_pos is None:
+            has = (self.slot_s < g) if every_era else self.carried_s
+            return jnp.where(has, carry[jnp.clip(self.slot_s, 0, g - 1)], fill)
+        at_head = carry[jnp.clip(self.head_slot, 0, g - 1)]
+        heads = set_at(
+            jnp.zeros(self.seg_start.shape, carry.dtype), self.head_pos, at_head
+        )
+        return jnp.where(
+            self.carried_s, segmented_carry(heads, self.seg_start), fill
+        )
 
 
 def assign_slots(
@@ -136,39 +205,51 @@ def assign_slots(
     (inv,) = permute_by(perm, idx)
     grp = SortedGroups(perm=perm, inv=inv, seg_start=seg_start)
 
-    # first row (original index) holding each row's (era, key) — via the
-    # segment head carried across its segment, inverse-permuted
-    (first,) = grp.from_sorted(segmented_carry(perm, seg_start))
+    # rows that open their (era, key) segment, in original order
+    (is_head,) = grp.from_sorted(seg_start)
 
-    # ---- resolution against the old table (pre-reset gathers + no-reset case)
-    # dense [B, G] eq matrix: at G <= ~1k this is a fully vectorized compare +
-    # argmax the VPU eats (~0.5 ms at B=100k) — measured FASTER than a
-    # searchsorted probe, whose log G binary-search steps serialize into
-    # scalar-space gathers on TPU
+    # ---- resolution against the old table (pre-reset rows + no-reset case)
+    # dense [B, G] eq matrix: a fully vectorized compare + argmax on the VPU
+    # (1.2 ms at B=65,536, G=4,096: `iota_reduce_fusion`, ledger PR 28) —
+    # measured FASTER than a searchsorted probe, whose log G binary-search
+    # steps serialize into scalar-space gathers on TPU
     eq_t = used[None, :] & (table_keys[None, :] == batch_keys[:, None])  # [B,G]
     in_t = eq_t.any(axis=1) & active
-    t_slot = jnp.argmax(eq_t, axis=1).astype(jnp.int32)
+    t_slot = jnp.where(in_t, jnp.argmax(eq_t, axis=1).astype(jnp.int32), -1)
 
-    is_alloc = active & ~in_t & (first == idx)
+    is_alloc = active & ~in_t & is_head
     alloc_rank = (jnp.cumsum(is_alloc.astype(jnp.int32)) - is_alloc).astype(jnp.int32)
     slot_new = n_used + alloc_rank
     old_overflow = (jnp.where(is_alloc, slot_new, 0) >= g).any()
-    old_slot = jnp.where(in_t, t_slot, jnp.where(slot_new[first] < g, slot_new[first], g))
-    old_slot = jnp.where(active, old_slot, np.int32(g)).astype(jnp.int32)
 
-    # ---- fresh-table resolution for post-reset rows (first is era-local, so
-    # the same head works for the fresh allocation pass)
-    post_active = active & post
-    is_alloc_f = post_active & (first == idx)
+    # ---- fresh-table allocation for post-reset rows (a head is era-local,
+    # so the same heads serve the fresh allocation pass)
+    is_alloc_f = active & post & is_head
     rank_f = (jnp.cumsum(is_alloc_f.astype(jnp.int32)) - is_alloc_f).astype(jnp.int32)
     fresh_overflow = (jnp.where(is_alloc_f, rank_f, 0) >= g).any()
-    fresh_slot = jnp.where(
-        post_active & (rank_f[first] < g), rank_f[first], g
-    ).astype(jnp.int32)
 
-    slot = jnp.where(any_reset & post, fresh_slot, old_slot)
-    slot = jnp.where(active, slot, np.int32(g))
+    # ---- every row takes the slot its segment's head was given. "The value
+    # at my head" is one payload sort to the sorted view and one segmented
+    # carry there, not a gather per row; the slots come out in sorted order,
+    # which is where the aggregators' carried values are read
+    ts_s, sn_s, rf_s = grp.to_sorted(t_slot, slot_new, rank_f)
+    head_sn, head_rf = segmented_carry((sn_s, rf_s), seg_start)
+    post_s = perm > glr
+    old_s = jnp.where(ts_s >= 0, ts_s, jnp.where(head_sn < g, head_sn, g))
+    fresh_s = jnp.where(head_rf < g, head_rf, g)
+    slot_s = jnp.where(any_reset & post_s, fresh_s, old_s)
+    slot_s = jnp.where(sa, slot_s, np.int32(g)).astype(jnp.int32)
+    (slot,) = grp.from_sorted(slot_s)
     overflow = jnp.where(any_reset, fresh_overflow, old_overflow)
+
+    grp.reset, grp.slot_s = rst, slot_s
+    grp.carried_s = (se == 0) & (slot_s < g)
+    grp.writer_s = jnp.where(grp.seg_end & post_s, slot_s, np.int32(g))
+    if b > g:
+        # the <= G heads that have a value to read, moved to the front
+        head_at = jnp.where(seg_start & grp.carried_s, idx, np.int32(b))
+        pos, slots = jax.lax.sort((head_at, slot_s), num_keys=1, is_stable=False)
+        grp.head_pos, grp.head_slot = pos[:g], slots[:g]
 
     # ---- new table state (compact_set_at: sort the <=G live writers to the
     # front so the scatter touches G updates, not B — and int64 key scatters
@@ -191,88 +272,61 @@ def assign_slots(
     return new_keys, new_used, new_n, slot, grp, overflow
 
 
-def _final_segment_writers(grp: SortedGroups, slot, post):
-    """Sorted-space mask of rows that END a final-era (post-last-reset)
-    segment, with their slots — the one row per live group whose running
-    value IS the group's new carry. Lets 64-bit carries update via a
-    scatter-SET (int32-pair fast path) instead of a serialized 64-bit
-    scatter reduction."""
-    seg_end = jnp.concatenate([grp.seg_start[1:], jnp.ones((1,), jnp.bool_)])
-    slot_s, post_s = grp.to_sorted(slot, post)
-    return seg_end & post_s, slot_s
-
-
 def keyed_running_sum(
     contrib: jnp.ndarray,  # [B], 0 on inactive rows
     grp: SortedGroups,
-    reset: jnp.ndarray,    # [B]
     carry: jnp.ndarray,    # [G]
-    slot: jnp.ndarray,     # [B] int32 (G = inactive)
 ):
     """Per-event running sum within each group; returns ([B] run, [G] carry').
 
     The (era, key) segmentation bounds contributions to same-key rows j <= i
     with no reset in between — exactly the reference's per-key running state
-    with RESET zeroing every group."""
-    g = carry.shape[0]
+    with RESET zeroing every group. Per row the value is the segmented
+    running sum plus the group's carried value, in one add, whichever way
+    the carried value was fetched (`SortedGroups.carry_read`)."""
     (contrib_s,) = grp.to_sorted(contrib)
     run_s = segmented_cumsum(contrib_s, grp.seg_start)
-    (run,) = grp.from_sorted(run_s)
-    lr = last_reset_index(reset)
-    gathered = jnp.where(slot < g, carry[jnp.clip(slot, 0, g - 1)], 0)
-    run = run + jnp.where(lr < 0, gathered, jnp.zeros_like(gathered))
-
-    glr = lr[-1]
-    post = jnp.arange(contrib.shape[0], dtype=jnp.int32) > glr
-    base = jnp.where(reset.any(), jnp.zeros_like(carry), carry)
+    base = jnp.where(grp.reset.any(), jnp.zeros_like(carry), carry)
+    carried_s = grp.carried(carry, jnp.zeros((), carry.dtype))
     # in the final era each live group is exactly one sorted segment, so its
     # carry is base + the segment END's running sum — one unique writer per
     # group, compacted so the scatter costs G updates (B-update scatters and
-    # 64-bit scatter reductions both serialize on the TPU scalar core)
-    writer, slot_s = _final_segment_writers(grp, slot, post)
-    writer = writer & (slot_s < g)
-    newval = (
-        jnp.where(slot_s < g, base[jnp.clip(slot_s, 0, g - 1)], 0) + run_s
-    ).astype(carry.dtype)
-    new_carry = compact_set_at(base, jnp.where(writer, slot_s, g), newval)
-    return run, new_carry
+    # 64-bit scatter reductions both serialize on the TPU scalar core). A
+    # writer's base is what its segment carried: the group's value where no
+    # reset came (era 0 is then the final era), zero behind one
+    full_s = run_s + carried_s
+    (run,) = grp.from_sorted(full_s)
+    return run, compact_set_at(base, grp.writer_s, full_s.astype(carry.dtype))
 
 
 def keyed_running_extreme(
     values: jnp.ndarray,
     active: jnp.ndarray,
     grp: SortedGroups,
-    reset: jnp.ndarray,
     carry: jnp.ndarray,  # [G]
-    slot: jnp.ndarray,
     is_min: bool,
+    forever: bool = False,
 ):
-    """Per-event running min/max within each group (no removal)."""
+    """Per-event running min/max within each group (no removal). `forever`
+    takes no notice of resets: every row of a live slot starts from the
+    carried value, every segment end writes it, nothing is cleared."""
     g = carry.shape[0]
     ident = extreme_identity(values.dtype, is_min)
     op = jnp.minimum if is_min else jnp.maximum
     masked = jnp.where(active, values, ident)
     (masked_s,) = grp.to_sorted(masked)
     run_s = segmented_cum_extreme(masked_s, grp.seg_start, is_min)
-    (run,) = grp.from_sorted(run_s)
-    lr = last_reset_index(reset)
-    gathered = jnp.where(
-        (slot < g) & (lr < 0), carry[jnp.clip(slot, 0, g - 1)], ident
-    )
-    run = op(run, gathered)
-
-    post = jnp.arange(values.shape[0], dtype=jnp.int32) > lr[-1]
-    base = jnp.where(reset.any(), jnp.full_like(carry, ident), carry)
+    carried_s = grp.carried(carry, ident, every_era=forever)
+    (run,) = grp.from_sorted(op(run_s, carried_s))
     # one unique writer per live group (its final-era segment end), compacted
     # — see keyed_running_sum
-    writer, slot_s = _final_segment_writers(grp, slot, post)
-    writer = writer & (slot_s < g)
-    newval = op(
-        jnp.where(slot_s < g, base[jnp.clip(slot_s, 0, g - 1)], ident),
-        run_s,
-    ).astype(carry.dtype)
-    new_carry = compact_set_at(base, jnp.where(writer, slot_s, g), newval)
-    return run, new_carry
+    if forever:
+        base, writer_s = carry, jnp.where(grp.seg_end, grp.slot_s, np.int32(g))
+    else:
+        base = jnp.where(grp.reset.any(), jnp.full_like(carry, ident), carry)
+        writer_s = grp.writer_s
+    newval = op(carried_s, run_s).astype(carry.dtype)
+    return run, compact_set_at(base, writer_s, newval)
 
 
 def keep_last_in_sorted(
@@ -291,8 +345,7 @@ def keep_last_in_sorted(
     b = valid.shape[0]
     idx = jnp.arange(b, dtype=jnp.int32)
     sv, sk = grp.to_sorted(valid, kind.astype(jnp.int32))
-    seg_end = jnp.concatenate([grp.seg_start[1:], jnp.ones((1,), jnp.bool_)])
-    rev_start = seg_end[::-1]
+    rev_start = grp.seg_end[::-1]
 
     def last_of(kbit):
         marked = jnp.where(sv & (sk == kbit), grp.perm, np.int32(-1))

@@ -205,9 +205,18 @@ def segmented_cum_extreme(
     )
 
 
-def segmented_carry(vals: jnp.ndarray, seg_start: jnp.ndarray) -> jnp.ndarray:
-    """Propagate each segment's first value across the segment."""
-    return _segmented_scan(vals, seg_start, lambda a, b: a)
+def segmented_carry(vals, seg_start: jnp.ndarray):
+    """Propagate each segment's first value across the segment. `vals` is one
+    [B] lane or a tuple of them: a tuple rides one scan, with one flag lane."""
+    if not isinstance(vals, tuple):
+        return segmented_carry((vals,), seg_start)[0]
+
+    def combine(a, b):
+        restart = b[-1]
+        return (*(jnp.where(restart, y, x) for x, y in zip(a, b[:-1])),
+                a[-1] | restart)
+
+    return _blocked_scan((*vals, seg_start), combine)[:-1]
 
 
 def extreme_identity(dtype, is_min: bool) -> np.ndarray:

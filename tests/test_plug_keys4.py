@@ -23,6 +23,7 @@ for p in (str(ROOT), str(BENCH)):
         sys.path.insert(0, p)
 
 import harness  # noqa: E402  (benchmark/harness.py)
+from tests.test_group_segment_read import flow_gathers  # noqa: E402
 
 CONFIGS = BENCH / "configs"
 KEYS4 = "debs14-plug-keys4"
@@ -35,12 +36,14 @@ def load(config: str):
             json.loads((cdir / "config.json").read_text()))
 
 
-def deploy(config: str, batch: int, callback=None):
-    """The configuration's app at `batch`, started, with a query callback."""
+def deploy(config: str, batch: int, callback=None, rehearse: bool = True):
+    """The configuration's app at `batch`, started, with a query callback;
+    its other sizes the rehearsal's, or the configuration's own."""
     from siddhi_tpu import SiddhiManager
 
     gen, _, cfg = load(config)
-    sizes = {**cfg["sizes"], **cfg.get("rehearse_sizes", {}), "batch": batch}
+    small = cfg.get("rehearse_sizes", {}) if rehearse else {}
+    sizes = {**cfg["sizes"], **small, "batch": batch}
     text = (CONFIGS / config / "app.siddhi").read_text().format(**sizes)
     mgr = SiddhiManager()
     for names in gen.STRINGS.values():
@@ -93,20 +96,27 @@ def chunk_arguments(fi, K: int = 32, place=None):
     )
 
 
-def lowered_text(config: str) -> str:
-    """`jit_fused` of `config` at its rehearse sizes, lowered, without debug
-    metadata; a key-sharded deployment on the virtual CPU mesh."""
+def lowered(config: str, rehearse: bool = True):
+    """(`jit_fused` of `config` lowered, without debug metadata; the status of
+    its query), at its rehearse sizes or at the configuration's own, nothing
+    sent; a key-sharded deployment on the virtual CPU mesh."""
     _, _, cfg = load(config)
-    mgr, rt, gen, cfg = deploy(config, cfg["rehearse_sizes"]["batch"])
+    batch = (cfg["rehearse_sizes"] if rehearse else cfg["sizes"])["batch"]
+    mgr, rt, gen, cfg = deploy(config, batch, rehearse=rehearse)
     try:
-        fi, prog = chunk_program(rt, gen, cfg, cfg["rehearse_sizes"]["batch"])
+        fi, prog = chunk_program(rt, gen, cfg, batch)
         place = None
         if fi._mesh_place is not None:
             place = (fi._mesh_place[0][0], fi._mesh_place[1])  # one endpoint
-        return prog.lower(*chunk_arguments(fi, place=place)).as_text()
+        text = prog.lower(*chunk_arguments(fi, place=place)).as_text()
+        return text, rt.snapshot_status()["queries"][cfg["query"]]
     finally:
         rt.shutdown()
         mgr.shutdown()
+
+
+def lowered_text(config: str) -> str:
+    return lowered(config)[0]
 
 
 # sha256 of `lowered_text`: the chunk program of the standing configurations
@@ -117,10 +127,14 @@ def lowered_text(config: str) -> str:
 # device time may have moved. The two windowless programs are as the parents
 # of PR 26 and PR 27 lowered them; PR 27 replaced the plug program's
 # (51b48512...: its window's ring now holds its 64-bit lanes as u32 pairs).
+# PR 29 replaced both plug programs' (f999fb96..., 99929b79...): the group-by
+# reads its per-group values once per segment of its sorted view, so
+# `assign_slots` and the keyed running lanes lower without their per-row
+# gathers (ops/group.py). The filter has no group-by and keeps its hash.
 STANDING_PROGRAMS = {
-    "debs14-q1-plug": "f999fb964eefd34044f2685e56ca2997e54aa25c2e2f5ad6a0d104ac5e1d7650",
+    "debs14-q1-plug": "fddb6dddafcd20658de0a9161bbb1d5fa815185b9ea309798df22818c1c68fc8",
     "siddhi-simple-filter": "233fdbcf647ded2693ff29f1f81344e59ca926ef5e1a7feaac442c8f1f642fcc",
-    KEYS4: "99929b7996b90e0c93d7cca8dcfa6e481f7c30a065f5acbc99682e656a9d2b94",
+    KEYS4: "222eae96edfcc75a49d3562a5acf3c551fa07bf6c72bf12b7e4dad41f4acf06f",
 }
 
 
@@ -130,6 +144,27 @@ def test_standing_chunk_programs_lower_as_before(config):
     on_mesh = config == KEYS4
     assert ("sharding" in text and "all_reduce" in text) == on_mesh
     assert hashlib.sha256(text.encode()).hexdigest() == STANDING_PROGRAMS[config]
+
+
+@pytest.mark.parametrize("config", ["debs14-q1-plug", KEYS4])
+def test_plug_programs_read_per_group_values_by_segment(config):
+    """At the configuration's own sizes the group-by gathers nothing per row
+    of its flow (the batch, or CURRENT + EXPIRED behind the window): what is
+    left at that length is the wire decode's dictionary lookup. Where the
+    flow is no longer than the table, as at the rehearsal's sizes, a row
+    reads for itself."""
+    _, _, cfg = load(config)
+    for rehearse, want in ((False, "segment"), (True, "row")):
+        text, status = lowered(config, rehearse=rehearse)
+        batch = (cfg["rehearse_sizes"] if rehearse else cfg["sizes"])["batch"]
+        assert status["group"] == {
+            "capacity": cfg["sizes"]["group_capacity"], "carry_read": want}
+        assert (batch > cfg["sizes"]["group_capacity"]) == (want == "segment")
+        left = flow_gathers(text, {batch, 2 * batch})
+        if want == "segment":
+            assert left == [f"{batch}xui8"], left
+        else:
+            assert len(left) == 3 and f"{batch}xui8" in left, left
 
 
 def test_both_plug_configurations_generate_one_stream():
