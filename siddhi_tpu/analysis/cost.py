@@ -88,6 +88,11 @@ DEFAULT_COUNT_CAPACITY = 8
 DEFAULT_CHUNK_BATCHES = 32
 DEFAULT_GROUP_CAPACITY = 1024
 DEFAULT_AGG_GROUPS = 64
+# windows whose ring takes the time capacity (core/windows.py make_window)
+_TIME_CAPACITY_WINDOWS = frozenset(
+    f"window:{n}" for n in
+    ("time", "externaltime", "timebatch", "externaltimebatch", "cron")
+)
 
 # physical widths on device (core/types.py PHYSICAL_DTYPE)
 _NBYTES = {
@@ -451,6 +456,7 @@ def expr_signature(expr) -> str:
 def _window_cost(
     spec: WindowSpec, schema: Optional[dict], qid: Optional[str],
     facts: Optional[dict] = None,
+    time_capacity: int = DEFAULT_TIME_CAPACITY,
 ) -> OperatorCost:
     """Mirror core/windows.py make_window sizing for one window handler,
     reading the state-bound metadata WindowSpec itself carries."""
@@ -471,7 +477,7 @@ def _window_cost(
                     "frequent", "lossyfrequent"):
             # declared row bound is non-constant/missing: unknowable
             return OperatorCost(f"window:{name}", detail, [], None, line, col)
-        rows = DEFAULT_TIME_CAPACITY  # time-capacity ring family
+        rows = time_capacity  # time-capacity ring family (@app:timeCapacity)
 
     buffers = 2 if is_batch else 1  # batch windows carry cur + prev buckets
     tensors = []
@@ -494,6 +500,7 @@ def _source_operators(
     schema: Optional[dict],
     qid: str,
     facts: Optional[dict] = None,
+    time_capacity: int = DEFAULT_TIME_CAPACITY,
 ) -> tuple[list, bool]:
     """(operators, scheduler_armed) for one single-source handler chain.
     With `facts` (attr -> ValueFact), a filter whose predicate narrows a
@@ -521,7 +528,9 @@ def _source_operators(
                 getattr(h, "line", None), getattr(h, "col", None),
             ))
         elif isinstance(h, WindowHandler):
-            ops.append(_window_cost(h.window, schema, qid, facts))
+            ops.append(
+                _window_cost(h.window, schema, qid, facts, time_capacity)
+            )
             armed = armed or h.window.arms_scheduler
     return ops, armed
 
@@ -776,6 +785,9 @@ def _query_cost(
     consumed: list[str] = []
     armed = False
     kind = "single"
+    time_capacity = _capacity_annotation(
+        app, "app:timeCapacity", DEFAULT_TIME_CAPACITY
+    )
 
     def stream_facts(sid: str) -> Optional[dict]:
         # declared @app:wire range facts as the base; the value analysis
@@ -799,7 +811,7 @@ def _query_cost(
         )
         consumed.append(stream.stream_id)
         ops, armed = _source_operators(
-            stream, schema, qid, stream_facts(stream.stream_id)
+            stream, schema, qid, stream_facts(stream.stream_id), time_capacity
         )
         operators.extend(ops)
         extra = (1 if armed else 0) + (
@@ -822,7 +834,7 @@ def _query_cost(
             if sid in sym.streams:
                 consumed.append(sid)
             ops, side_armed = _source_operators(
-                s, schema, qid, stream_facts(sid)
+                s, schema, qid, stream_facts(sid), time_capacity
             )
             armed = armed or side_armed
             # a join side buffers its window content at join capacity
@@ -997,6 +1009,27 @@ def check_costs(
     # (add a hint) only when value analysis CANNOT prove the lane
     # encodable; when it can, SA138 says inference already compacts it.
     _check_wire_dominance(app, sym, model, diags, values)
+
+    # SA141: a time-bounded window left at the default capacity beside an
+    # @app:batch larger than it: one full micro-batch already holds more
+    # rows than the ring, so rows expire early from the first send on
+    if (
+        find_annotation(app.annotations, "app:timeCapacity") is None
+        and model.batch_size > DEFAULT_TIME_CAPACITY
+    ):
+        for qid, qc in sorted(model.queries.items()):
+            for op in qc.operators:
+                if op.op in _TIME_CAPACITY_WINDOWS:
+                    diags.append(Diagnostic(
+                        "SA141",
+                        f"{op.detail} keeps the default capacity of "
+                        f"{DEFAULT_TIME_CAPACITY} rows while @app:batch is "
+                        f"{model.batch_size}: rows that are still inside "
+                        "the window's time are expired early (flagged at "
+                        "run time, `window_early_expiry`); state the rows "
+                        "the window must hold with @app:timeCapacity(size='N')",
+                        op.line, op.col, severity=WARNING, query=qid,
+                    ))
 
     # SA122: @app:batch != 64 downstream of a query insert (re-published
     # slices arrive <= 64 rows: a second shape signature per program)

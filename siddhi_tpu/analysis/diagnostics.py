@@ -97,6 +97,9 @@ CODES: dict[str, str] = {
     "SA140": "invalid @app:blackbox annotation (bad window / unknown "
              "trigger / bad keep or ring / bad checkpoint.interval or "
              "debounce / unknown option)",
+    "SA141": "time-bounded window at the default capacity beside a larger "
+             "@app:batch: rows still inside the window's time are expired "
+             "early; state the rows it must hold with @app:timeCapacity",
     # typing
     "SA201": "incompatible comparison operand types",
     "SA202": "arithmetic on a non-numeric operand",

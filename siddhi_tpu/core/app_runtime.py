@@ -163,6 +163,13 @@ class SiddhiAppRuntime:
         batch_ann = find_annotation(app.annotations, "app:batch")
         self.batch_size = int(batch_ann.element("size", str(DEFAULT_BATCH))) if batch_ann else DEFAULT_BATCH
         self.group_capacity = self._capacity_annotation("app:groupCapacity", None)
+        # rows a time-bounded window's ring holds (time, externalTime, the
+        # time batches, cron): @app:timeCapacity(size='N')
+        from siddhi_tpu.core.windows import DEFAULT_TIME_CAPACITY
+
+        self.time_capacity = self._capacity_annotation(
+            "app:timeCapacity", DEFAULT_TIME_CAPACITY
+        )
         # whole-graph fusion escape hatch: @app:fuse(disable='true') /
         # SIDDHI_TPU_FUSE=1|0 (core/fusion_exec.py; malformed options raise
         # here — the runtime analog of the analyzer's SA125)
@@ -519,7 +526,7 @@ class SiddhiAppRuntime:
 
         self.named_windows: dict[str, NamedWindow] = {}
         for wid, wd in app.window_definitions.items():
-            nw = NamedWindow(wd, self.interner)
+            nw = NamedWindow(wd, self.interner, self.time_capacity)
             self.named_windows[wid] = nw
             in_j = StreamJunction(nw.schema, self.interner, self.batch_size)
             in_j.tracer = self.tracer
@@ -998,6 +1005,7 @@ class SiddhiAppRuntime:
             query, qid, in_schema, self.interner,
             group_capacity=self.group_capacity,
             tables=self.tables,
+            time_capacity=self.time_capacity,
         )
         self._wire_query_lineage(qr)
         self.queries[qid] = qr
@@ -1220,6 +1228,7 @@ class SiddhiAppRuntime:
             group_capacity=self.group_capacity, join_capacity=join_capacity,
             tables=self.tables,
             findables={**self.tables, **self.named_windows, **agg_findables},
+            time_capacity=self.time_capacity,
         )
         self._wire_query_lineage(qr)
         self.queries[qid] = qr

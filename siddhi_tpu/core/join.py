@@ -123,6 +123,7 @@ class JoinSide:
         stream: SingleInputStream,
         schema: StreamSchema,
         scope: Scope,
+        time_capacity: Optional[int] = None,
     ):
         self.stream_id = stream.stream_id
         self.ref = stream.ref
@@ -144,7 +145,10 @@ class JoinSide:
             elif isinstance(h, WindowHandler):
                 if self.window is not None:
                     raise SiddhiAppCreationError("only one window per join side")
-                self.window = make_window(h.window, schema, self.ref, side_scope)
+                self.window = make_window(
+                    h.window, schema, self.ref, side_scope,
+                    time_capacity=time_capacity,
+                )
             elif isinstance(h, StreamFunctionHandler):
                 raise SiddhiAppCreationError(
                     f"stream function '{h.name}' not supported on join sides yet"
@@ -191,6 +195,7 @@ class CompiledJoin:
         out_capacity: int = DEFAULT_JOIN_CAPACITY,
         output_expired: bool = False,
         tables: Optional[dict] = None,
+        time_capacity: Optional[int] = None,
     ):
         tables = tables or {}
 
@@ -198,7 +203,7 @@ class CompiledJoin:
             t = tables.get(stream.stream_id)
             if t is not None:
                 return TableSide(stream, t)
-            return JoinSide(stream, schema, scope)
+            return JoinSide(stream, schema, scope, time_capacity)
 
         self.left = make_side(join.left, left_schema)
         self.right = make_side(join.right, right_schema)
@@ -445,6 +450,7 @@ class JoinQueryRuntime(BaseQueryRuntime):
         join_capacity: int = DEFAULT_JOIN_CAPACITY,
         tables: Optional[dict] = None,
         findables: Optional[dict] = None,
+        time_capacity: Optional[int] = None,
     ):
         join = query.input_stream
         assert isinstance(join, JoinInputStream)
@@ -469,6 +475,7 @@ class JoinQueryRuntime(BaseQueryRuntime):
             out_capacity=join_capacity,
             output_expired=output_expired,
             tables=findables if findables is not None else tables,
+            time_capacity=time_capacity,
         )
         # findable join sides that are NOT app tables (named windows): their
         # live state is read-only threaded into the step

@@ -81,10 +81,12 @@ class PartitionedQueryRuntime(QueryRuntime):
         p_capacity: int,
         key_of: Optional[Callable],
         group_capacity=None,
+        time_capacity=None,
     ):
         super().__init__(
             query, query_id, in_schema, interner,
             group_capacity=group_capacity, tables={},
+            time_capacity=time_capacity,
         )
         self.p = int(p_capacity)
         # the DECLARED capacity: parallel/shard.py may pad self.p up to a
@@ -186,11 +188,12 @@ class PartitionedJoinQueryRuntime(JoinQueryRuntime):
         key_of_by_side: dict,  # side -> key fn
         group_capacity=None,
         join_capacity: int = 512,
+        time_capacity=None,
     ):
         super().__init__(
             query, query_id, left_schema, right_schema, interner,
             group_capacity=group_capacity, join_capacity=join_capacity,
-            tables={},
+            tables={}, time_capacity=time_capacity,
         )
         if self.needs_scheduler["l"] or self.needs_scheduler["r"]:
             raise SiddhiAppCreationError(
@@ -583,6 +586,7 @@ class PartitionRuntime:
             query, qid, in_schema, app.interner,
             p_capacity=self.p, key_of=key_of,
             group_capacity=app.group_capacity,
+            time_capacity=app.time_capacity,
         )
         self.queries.append(qr)
         app.queries[qid] = qr
@@ -734,6 +738,7 @@ class PartitionRuntime:
             p_capacity=self.p, key_of_by_side=key_by_side,
             group_capacity=app.group_capacity,
             join_capacity=app._capacity_annotation("app:joinCapacity", 512),
+            time_capacity=app.time_capacity,
         )
         self.queries.append(qr)
         app.queries[qid] = qr

@@ -162,6 +162,7 @@ def _to_device(tree, like=None):
 
     wide = set()
     if like is not None:
+        tree = _upgrade(tree, like)
         wide = {
             jax.tree_util.keystr(path)
             for path, leaf in jax.tree_util.tree_flatten_with_path(
@@ -181,6 +182,25 @@ def _to_device(tree, like=None):
         return jnp.array(x, copy=True)
 
     return jax.tree_util.tree_map_with_path(put, tree)
+
+
+def _upgrade(tree, like):
+    """A snapshot tree from before its component's state gained a key,
+    brought to the layout of `like`: a time-bounded window's ring that was
+    saved without its head (before PR 30) is laid out again from its live
+    rows (`core/windows.py` `ring_from_legacy`)."""
+    if isinstance(tree, dict) and isinstance(like, dict):
+        if "head" in like and "head" not in tree and "seq" in tree:
+            from siddhi_tpu.core.windows import ring_from_legacy
+
+            return ring_from_legacy(tree)
+        return {
+            k: _upgrade(v, like[k]) if k in like else v for k, v in tree.items()
+        }
+    if isinstance(tree, (list, tuple)) and isinstance(like, (list, tuple)) \
+            and len(tree) == len(like):
+        return type(tree)(_upgrade(t, l) for t, l in zip(tree, like))
+    return tree
 
 
 def _state_like(component):

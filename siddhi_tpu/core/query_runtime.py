@@ -107,6 +107,26 @@ class CompiledSingleChain:
     def init_state(self):
         return self.window.init_state() if self.window is not None else ()
 
+    def split_at_window(self):
+        """(stages before the window, the window's scope, stages after it),
+        for a caller that steps the window itself (`QueryRuntime`'s fifo
+        passes): each part is `flow -> flow`, under the stages' scopes."""
+        at = [kind for kind, _ in self.stages].index("window")
+
+        def part(lo, hi):
+            def run(flow):
+                for (kind, op), scope in zip(self.stages[lo:hi], self.scopes[lo:hi]):
+                    with jax.named_scope(scope):
+                        flow = (
+                            self._filter(flow, [op]) if kind == "filter"
+                            else op.apply(flow)
+                        )
+                return flow
+
+            return run
+
+        return part(0, at), self.scopes[at], part(at + 1, len(self.stages))
+
     def apply(self, state, flow: Flow):
         probe = self.lineage_probe
         for (kind, op), scope in zip(self.stages, self.scopes):
@@ -139,6 +159,31 @@ class CompiledSingleChain:
         import dataclasses
 
         return dataclasses.replace(flow, batch=batch)
+
+
+def _join_outputs(acc: EventBatch, out: EventBatch) -> EventBatch:
+    """The CURRENT rows of a step's earlier passes and of its next one as
+    one batch of the same capacity, in order, moved to the front: at most
+    one per input row, so they fit. The rare path: only a step with more
+    than one pass comes here."""
+    from siddhi_tpu.ops.prefix import compact_front
+
+    def packed(b):
+        keep = b.valid & (b.kind == KIND_CURRENT)
+        lanes = compact_front(keep, {"ts": b.ts, "kind": b.kind, "cols": b.cols})
+        return lanes, keep.sum(dtype=jnp.int32)
+
+    cap = acc.valid.shape[0]
+    (a, n_a), (o, n_o) = packed(acc), packed(out)
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    joined = jax.tree_util.tree_map(
+        lambda x, y: jnp.where(
+            pos < n_a, x,
+            jax.lax.dynamic_slice(jnp.pad(y, (cap, 0)), (cap - n_a,), (cap,)),
+        ),
+        a, o,
+    )
+    return EventBatch(joined["ts"], joined["kind"], pos < n_a + n_o, joined["cols"])
 
 
 class _AuxWarnPool:
@@ -506,8 +551,24 @@ class BaseQueryRuntime:
 
             logging.getLogger(__name__).warning(
                 "query '%s': window emission/key buffer overflowed; events "
-                "were dropped — reduce batch size or raise window capacity",
+                "were dropped — reduce batch size or raise window capacity "
+                "(a time batch's bucket: @app:timeCapacity(size='N'))",
                 self.query_id,
+            )
+        if (
+            not getattr(self, "_warned_window_early", False)
+            and "window_early_expiry" in aux
+            and bool(aux["window_early_expiry"])
+        ):
+            self._warned_window_early = True
+            import logging
+
+            logging.getLogger(__name__).warning(
+                "query '%s': a time window held more live rows than its "
+                "capacity (%d); the oldest were expired early — raise it "
+                "with @app:timeCapacity(size='N')",
+                self.query_id,
+                getattr(getattr(getattr(self, "chain", None), "window", None), "w", -1),
             )
         if (
             not self._warned_table_overflow
@@ -698,6 +759,7 @@ class QueryRuntime(BaseQueryRuntime):
         window_factory: Optional[Callable] = None,
         group_capacity: Optional[int] = None,
         tables: Optional[dict] = None,
+        time_capacity: Optional[int] = None,
     ):
         self.query = query
         self.query_id = query_id
@@ -718,7 +780,9 @@ class QueryRuntime(BaseQueryRuntime):
             from siddhi_tpu.core.windows import make_window
 
             def window_factory(spec, schema, ref, _scope=scope):
-                return make_window(spec, schema, ref, _scope)
+                return make_window(
+                    spec, schema, ref, _scope, time_capacity=time_capacity
+                )
 
         self.chain = CompiledSingleChain(stream, in_schema, scope, window_factory)
         self._scope = scope
@@ -737,18 +801,28 @@ class QueryRuntime(BaseQueryRuntime):
         # no membership-consuming aggregator (min/max/distinctCount). Halves
         # the flow length every selector op runs over.
         win = self.chain.window
-        if win is not None and win.is_batch and hasattr(win, "emit_expired"):
-            from siddhi_tpu.core.aggregators import (
-                DistinctCountAggregator,
-                ExtremeAggregator,
-            )
-            from siddhi_tpu.query_api.execution import OutputEventsFor
+        from siddhi_tpu.core.aggregators import (
+            DistinctCountAggregator,
+            ExtremeAggregator,
+        )
 
-            needs_member = any(
-                isinstance(a, DistinctCountAggregator)
-                or (isinstance(a, ExtremeAggregator) and not a.forever)
-                for a in self.selector.aggregators
-            )
+        needs_member = any(
+            isinstance(a, DistinctCountAggregator)
+            or (isinstance(a, ExtremeAggregator) and not a.forever)
+            for a in self.selector.aggregators
+        )
+        # a time-bounded window steps as a FIFO, in passes (`_step_passes`),
+        # where nothing reads what only the matrix step makes: the
+        # membership matrix, one window flow per batch (table writes), or
+        # an output that holds every EXPIRED row of a step (a query that
+        # publishes them: the passes' join keeps the CURRENT rows)
+        self._fifo_site = (
+            type(self) is QueryRuntime
+            and not needs_member
+            and self.table_op is None
+            and self.output_events is OutputEventsFor.CURRENT
+        )
+        if win is not None and win.is_batch and hasattr(win, "emit_expired"):
             if (
                 self.output_events is OutputEventsFor.CURRENT
                 and self.rate_limiter is None
@@ -849,6 +923,14 @@ class QueryRuntime(BaseQueryRuntime):
         )
 
     def _step_impl(self, state, tstates, batch: EventBatch, now):
+        win = self.chain.window
+        if (
+            self._fifo_site
+            and self.lineage is None
+            and win is not None
+            and win.takes_fifo(batch.capacity)
+        ):
+            return self._step_passes(state, tstates, batch, now)
         flow = Flow(batch=batch, ref=self.ref, now=now, tables=tstates)
         chain_state, flow = self.chain.apply(state["chain"], flow)
         with jax.named_scope("selector"):
@@ -872,6 +954,63 @@ class QueryRuntime(BaseQueryRuntime):
             if "__group_key__" in out.cols:
                 aux[LIN + "gkey"] = out.cols["__group_key__"]
         return {"chain": chain_state, "sel": sel_state}, tstates, out, flow.aux
+
+    def _step_passes(self, state, tstates, batch: EventBatch, now):
+        """The step behind a time-bounded window that steps as a FIFO
+        (`SlidingWindow.fifo_pass`): each pass hands the selector a flow of
+        2B rows, at most B of them EXPIRED. One pass is the whole step
+        unless more than B rows are due (the first batch after a gap in
+        event time): then further passes run, each reading on in the ring
+        and carrying the selector's state, until the batch's last CURRENT
+        row is out. Their CURRENT outputs are joined in order (the query
+        publishes no other kind: `_fifo_site`), so the aggregates stay exact
+        however many rows leave. One `while_loop`, so that the
+        selector is traced once; the ring is read inside it and written
+        after it."""
+        before, wscope, after = self.chain.split_at_window()
+        win = self.chain.window
+        wstate = state["chain"]
+        flow = before(Flow(batch=batch, ref=self.ref, now=now, tables=tstates))
+        with jax.named_scope(wscope):
+            plan = win.fifo_plan(wstate, flow)
+
+        def one_pass(cur, sel_state):
+            with jax.named_scope(wscope):
+                cur, wflow = win.fifo_pass(wstate, plan, cur, flow)
+            wflow = after(wflow)
+            with jax.named_scope("selector"):
+                sel_state, out = self.selector.apply(sel_state, wflow)
+            return cur, sel_state, out, wflow.aux
+
+        cur0 = win.fifo_cursor()
+        _, _, out0, aux0 = jax.eval_shape(one_pass, cur0, state["sel"])
+        zeros = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+            lambda x: jnp.zeros(x.shape, x.dtype), tree
+        )
+
+        def body(carry):
+            cur, sel_state, acc, aux = carry
+            first = cur["passes"] == 0
+            cur, sel_state, out, aux_p = one_pass(cur, sel_state)
+            acc = jax.lax.cond(
+                first, lambda: out, lambda: _join_outputs(acc, out)
+            )
+            aux = {
+                k: aux[k] | jnp.asarray(v).astype(bool).any()
+                for k, v in aux_p.items()
+            }
+            return cur, sel_state, acc, aux
+
+        aux_init = {k: jnp.zeros((), jnp.bool_) for k in aux0}
+        cur, sel_state, out, aux = jax.lax.while_loop(
+            lambda carry: carry[0]["more"],
+            body,
+            (cur0, state["sel"], zeros(out0), aux_init),
+        )
+        with jax.named_scope(wscope):
+            chain_state, flags = win.fifo_commit(wstate, plan, cur)
+        aux.update(flags)
+        return {"chain": chain_state, "sel": sel_state}, tstates, out, aux
 
     # ---- host side -------------------------------------------------------
 

@@ -32,7 +32,7 @@ class NamedWindow:
 
     is_named_window = True
 
-    def __init__(self, definition: WindowDefinition, interner):
+    def __init__(self, definition: WindowDefinition, interner, time_capacity=None):
         if definition.window is None:
             raise SiddhiAppCreationError(
                 f"window '{definition.id}' needs a window type, "
@@ -46,7 +46,8 @@ class NamedWindow:
         scope = Scope(interner)
         scope.add_stream(definition.id, self.schema.attr_types)
         self.stage = make_window(
-            definition.window, self.schema, definition.id, scope
+            definition.window, self.schema, definition.id, scope,
+            time_capacity=time_capacity,
         )
         self.out_events = definition.output_events  # current | expired | all
         self.state = self.stage.init_state()

@@ -36,13 +36,19 @@ from siddhi_tpu.core.event import (
     StreamSchema,
 )
 from siddhi_tpu.core.executor import Env, Scope, TS_ATTR, compile_expression
-from siddhi_tpu.ops.prefix import compact_front as _compact_front, cummax as _cummax
+from siddhi_tpu.ops.prefix import (
+    compact_front as _compact_front,
+    cummax as _cummax,
+    segmented_carry as _segmented_carry,
+)
 from siddhi_tpu.ops.group import permute_by as _permute_by
 from siddhi_tpu.ops.scatter import (
     U32Pair,
     compact_set_at as _compact_set_at,
     is_pair as _is_pair,
     _is_wide,
+    _join64,
+    _split64,
     join_pairs as _join_pairs,
     set_at as _set_at,
     split_like as _split_like,
@@ -56,6 +62,13 @@ BIG = jnp.iinfo(jnp.int32).max
 NO_TIMER = jnp.iinfo(jnp.int64).max
 
 DEFAULT_TIME_CAPACITY = 1024
+# "no window time yet": far below any event time, and `NO_TIME - T` still
+# does not wrap
+NO_TIME = -(1 << 62)
+# what a time-bounded ring carries beside its lanes: the seq of its oldest
+# live row, the running maximum of window time, and three counters
+_FIFO_COUNTERS = ("expired", "passes", "early")
+_FIFO_SCALARS = ("head", "wmax") + _FIFO_COUNTERS
 
 
 def _const_raw(spec: WindowSpec, i: int, what: str):
@@ -83,6 +96,11 @@ class WindowStage:
 
     def apply(self, state, flow: Flow):
         raise NotImplementedError
+
+    def takes_fifo(self, bsz: int) -> bool:
+        """Whether the caller may step this window in passes instead of
+        `apply` (`SlidingWindow.fifo_pass`); no other window can."""
+        return False
 
     def view(self, state):
         """Stored window contents for probing: `(cols, ts, mask)` with rows in
@@ -173,8 +191,17 @@ class SlidingWindow(WindowStage):
     live, the oldest are evicted EARLY — they are still emitted as EXPIRED (the
     capacity eviction rides the same candidate path), so downstream aggregates
     stay exactly consistent; only the expiry *time* is early. The reference has
-    no such bound (unbounded Java queues); raise DEFAULT_TIME_CAPACITY or the
-    per-window capacity if early expiry is observed."""
+    no such bound (unbounded Java queues): the step raises the aux flag
+    `window_early_expiry` and counts the rows (`early`), and the capacity
+    comes from the app, `@app:timeCapacity(size='N')`.
+
+    A time-bounded window is a FIFO, as the reference's is: it expires from
+    the head of its queue and stops at the first row not yet due. Two steps
+    keep that rule, chosen at trace time (`time_step`): `apply`, which sets
+    every ring row against every batch row (a `[W + B, B]` matrix, any
+    shape, TIMER rows, the membership matrix), and `fifo_plan` /
+    `fifo_pass` / `fifo_commit`, whose cost is the rows that enter and
+    leave, for the caller that can run passes (`takes_fifo`)."""
 
     def __init__(
         self,
@@ -184,6 +211,7 @@ class SlidingWindow(WindowStage):
         duration_ms: Optional[int] = None,
         time_attr: Optional[str] = None,
         use_scheduler: bool = False,
+        capacity_is_limit: bool = False,
     ):
         self.schema = schema
         self.ref = ref
@@ -191,9 +219,17 @@ class SlidingWindow(WindowStage):
         self.t = duration_ms
         self.time_attr = time_attr
         self.needs_scheduler = use_scheduler
+        # time / externalTime: the capacity is the engine's bound on the live
+        # rows, not the query's (timeLength states its own), so a row that
+        # leaves for want of room is flagged (`window_early_expiry`)
+        self.capacity_is_limit = capacity_is_limit
         # which length step the last trace took: "slice" (O(batch)) or
         # "scatter"; None for time-bounded windows and before the first trace
         self.ring_step: Optional[str] = None
+        # which step a time-bounded window's last trace took: "fifo" (the
+        # rows that enter and leave, in passes: `fifo_plan`) or "matrix"
+        # (every ring row against every batch row: `apply`)
+        self.time_step: Optional[str] = None
 
     def describe_state(self, state) -> dict:
         d = super().describe_state(state)
@@ -201,12 +237,26 @@ class SlidingWindow(WindowStage):
             d["ring_step"] = self.ring_step
         if any(map(_is_pair, jax.tree_util.tree_leaves(state, is_leaf=_is_pair))):
             d["wide_lanes"] = "u32x2"
+        if self.t is not None:
+            if self.time_step is not None:
+                d["time_step"] = self.time_step
+            try:
+                n = jax.device_get({k: state[k] for k in _FIFO_COUNTERS})
+            except Exception:  # donated under us: see WindowStage
+                return d
+            # rows expired; passes beyond a step's first, taken because a
+            # flow was full; rows that left for want of capacity
+            d["expired_rows"] = int(np.sum(n["expired"]))
+            d["extra_passes"] = int(np.sum(n["passes"]))
+            d["early_expired"] = int(np.sum(n["early"]))
         return d
 
     def _fill_summary(self, state):
         # one reduction over two lanes: view() would sort and gather the
         # whole ring, every column of it
-        return jax.device_get(_ring_summary(state["seq"], state["ts"]))
+        return jax.device_get(
+            _ring_summary(state["seq"], state["ts"], state.get("head"))
+        )
 
     @staticmethod
     def lanes(state) -> dict:
@@ -233,13 +283,28 @@ class SlidingWindow(WindowStage):
                 return U32Pair.full((self.w,), fill, dtype)
             return jnp.full((self.w,), fill, dtype)
 
-        return {
+        state = {
             "cols": {n: lane(a.dtype) for n, a in self.schema.empty_batch(1).cols.items()},
             "ts": lane(jnp.int64),
             "wts": lane(jnp.int64),
             "seq": lane(jnp.int64, -1),
             "total": jnp.zeros((), jnp.int64),
         }
+        if self.t is not None:
+            # a time window is a FIFO (reference: ExternalTimeWindowProcessor
+            # walks its queue from the head and stops at the first row not
+            # yet due): the live rows are seq in [head, total), and `wts`
+            # holds the running maximum of window time, so it is sorted
+            state.update({k: jnp.zeros((), jnp.int64) for k in _FIFO_SCALARS})
+            state["wmax"] = jnp.full((), NO_TIME, jnp.int64)
+        return state
+
+    def _window_time(self, b: EventBatch):
+        """Window time of each batch row: the event ts, or externalTime's
+        attribute."""
+        if self.time_attr is not None:
+            return b.cols[self.time_attr].astype(jnp.int64)
+        return b.ts
 
     def apply(self, state, flow: Flow):
         b = flow.batch
@@ -249,11 +314,7 @@ class SlidingWindow(WindowStage):
 
         valid_cur = b.valid & (b.kind == KIND_CURRENT)
         is_timer = b.valid & (b.kind == KIND_TIMER)
-        # window-time of each batch row
-        if self.time_attr is not None:
-            bwts = b.cols[self.time_attr].astype(jnp.int64)
-        else:
-            bwts = b.ts
+        bwts = self._window_time(b)
         rank = jnp.cumsum(valid_cur.astype(jnp.int32)) - valid_cur.astype(jnp.int32)
         c = valid_cur.sum(dtype=jnp.int32)
 
@@ -274,6 +335,16 @@ class SlidingWindow(WindowStage):
             )
             return step(state, flow, valid_cur, bwts, rank, c)
 
+        self.time_step = "matrix"
+        trigger_ok = valid_cur | is_timer
+        # window time as the reference's loop meets it: it expires from the
+        # head of its queue and stops at the first row not yet due, so a row
+        # leaves once the running maximum of the triggers' time has passed
+        # the running maximum of the rows' up to it, in any order of arrival
+        bwts = jnp.maximum(
+            state["wmax"],
+            _cummax(jnp.where(trigger_ok, bwts, np.int64(NO_TIME))),
+        )
         ev = self._element_view(state, b, bwts, valid_cur, rank, c)
         elem_ts, elem_seq, elem_cols, present = ev.ts, ev.seq, ev.cols, ev.present
         own_row = jnp.concatenate(
@@ -283,7 +354,6 @@ class SlidingWindow(WindowStage):
             ev.len_trig_valid, ev.perm[jnp.clip(ev.trig_rank, 0, bsz - 1)], BIG
         )
 
-        trigger_ok = valid_cur | is_timer
         due = (
             trigger_ok[None, :]
             & present[:, None]
@@ -338,11 +408,25 @@ class SlidingWindow(WindowStage):
         member, member_env = self._member(flow, ev, birth_pos, death_pos, k + bsz)
 
         new_state = self._ring_state(state, evict, valid_cur, rank, c, b, bwts, ev)
+        # the rows that left are the oldest live ones, by either rule
+        early = evict & (trig_row_len < trig_row_time)
+        left = evict.sum(dtype=jnp.int64)
+        new_state.update(
+            head=state["head"] + left,
+            wmax=bwts[-1],
+            expired=state["expired"] + left,
+            passes=state["passes"],
+            early=state["early"] + early.sum(dtype=jnp.int64),
+        )
 
         aux = dict(flow.aux)
-        if self.needs_scheduler and self.t is not None:
+        if self.capacity_is_limit:
+            aux["window_early_expiry"] = early.any()
+        if self.needs_scheduler:
             kept = self.lanes(new_state)
-            surv_wts = jnp.where(kept["seq"] >= 0, kept["wts"], NO_TIMER - self.t)
+            surv_wts = jnp.where(
+                kept["seq"] >= new_state["head"], kept["wts"], NO_TIMER - self.t
+            )
             aux["next_timer"] = surv_wts.min() + self.t
 
         return new_state, Flow(
@@ -367,6 +451,10 @@ class SlidingWindow(WindowStage):
             [state["seq"], jnp.where(valid_cur, total + rank, np.int64(-1))]
         )
         present = seq >= 0
+        if "head" in state:
+            # a time-bounded ring: the fifo step moves the head on and
+            # leaves the slots behind it as they were
+            present &= seq >= state["head"]
         trig_rank = (seq + w - total).astype(jnp.int32)
         return _ElementView(
             ts=jnp.concatenate([state["ts"], b.ts]),
@@ -536,23 +624,16 @@ class SlidingWindow(WindowStage):
             # the run [start, start + B) mod W as two slices: `tail` from
             # s1 (start, held inside the lane) and the lane's first B rows;
             # the run begins `off` rows into their concatenation
-            start = (total % w).astype(jnp.int32)
-            s1 = jnp.minimum(start, np.int32(w - bsz))
-            off = start - s1
+            s1, off = _run_start(total, w, bsz)
             tails = jax.tree_util.tree_map(
                 lambda lane: jax.lax.dynamic_slice(lane, (s1,), (bsz,)), ring
             )
-
-            def run_of(lane, tail):
-                return jax.lax.dynamic_slice(
-                    jnp.concatenate([tail, lane[:bsz]]), (off,), (bsz,)
-                )
-
             # the rows the run held: a long column's halves are joined
             # here, B rows of them
-            expired = _join_pairs(
-                jax.tree_util.tree_map(run_of, ring["cols"], tails["cols"])
-            )
+            expired = _join_pairs(jax.tree_util.tree_map(
+                lambda lane, tail: _run_read(lane, tail, off),
+                ring["cols"], tails["cols"],
+            ))
 
             n0 = jnp.clip(w - total, 0, c).astype(jnp.int32)
             pos = jnp.arange(2 * bsz, dtype=jnp.int32)
@@ -589,23 +670,12 @@ class SlidingWindow(WindowStage):
 
         with jax.named_scope("ring_update"):
             written = (pos >= off) & (pos - off < c)
-
-            def place(lane, tail, vals):
-                vals = jax.lax.dynamic_slice(
-                    jnp.pad(vals, (bsz, bsz)), (bsz - off,), (2 * bsz,)
-                )
-                lane = jax.lax.dynamic_update_slice(
-                    lane, jnp.where(written[:bsz], vals[:bsz], tail), (s1,)
-                )
-                # the head is read again: it overlaps the tail when the
-                # run starts inside the lane's first B rows
-                return jax.lax.dynamic_update_slice(
-                    lane,
-                    jnp.where(written[bsz:], vals[bsz:], lane[:bsz]),
-                    (0,),
-                )
-
-            new_state = jax.tree_util.tree_map(place, ring, tails, new_rows)
+            new_state = jax.tree_util.tree_map(
+                lambda lane, tail, vals: _run_write(
+                    lane, tail, vals, written, s1, off
+                ),
+                ring, tails, new_rows,
+            )
             new_state["total"] = total + c
         return new_state, Flow(
             batch=out,
@@ -618,6 +688,235 @@ class SlidingWindow(WindowStage):
             tables=flow.tables,
         )
 
+    # ---- the time-bounded step in O(batch): a FIFO, taken in passes ------
+
+    def takes_fifo(self, bsz: int) -> bool:
+        """Whether a step over batches of `bsz` rows can be the fifo one: an
+        event-time window (no TIMER rows arrive) whose ring holds a batch.
+        A window shorter than that keeps `apply`'s matrix, as `length(N <
+        batch)` keeps its scatter step; so does the caller whose selector
+        reads the membership matrix (min / max / distinctCount)."""
+        return self.t is not None and not self.needs_scheduler and self.w >= bsz
+
+    def fifo_plan(self, state, flow: Flow) -> dict:
+        """What the passes of one step share: the batch's CURRENT rows moved
+        to the front, their window time as a running maximum (sorted), and
+        each one's threshold. The logical queue of the step is the ring's
+        live rows, seq head..total-1, then these."""
+        b = flow.batch
+        bsz = b.capacity
+        self.time_step = "fifo"
+        valid_cur = b.valid & (b.kind == KIND_CURRENT)
+        bwts = self._window_time(b)
+        big = np.int64(NO_TIMER)  # never due: pads the sorted lanes
+        with jax.named_scope("ring_expire"):
+            mwts = jnp.maximum(
+                state["wmax"],
+                _cummax(jnp.where(valid_cur, bwts, np.int64(NO_TIME))),
+            )
+        with jax.named_scope("ring_emit"):
+            new = _compact_front(
+                valid_cur, {"cols": dict(b.cols), "ts": b.ts, "wts": mwts}
+            )
+        c = valid_cur.sum(dtype=jnp.int32)
+        in_c = jnp.arange(bsz, dtype=jnp.int32) < c
+        return {
+            "new": new,
+            "c": c,
+            "wts": jnp.where(in_c, new["wts"], big),
+            "theta": jnp.where(in_c, new["wts"] - self.t, big),
+            "wmax": mwts[-1],
+            "live": (state["total"] - state["head"]).astype(jnp.int32),
+        }
+
+    @staticmethod
+    def fifo_cursor() -> dict:
+        """Where a step's passes stand: `k` rows of the queue have left,
+        `r` CURRENT rows are out."""
+        return {
+            "k": jnp.zeros((), jnp.int32),
+            "r": jnp.zeros((), jnp.int32),
+            "passes": jnp.zeros((), jnp.int32),
+            "early": jnp.zeros((), jnp.int32),
+            "more": jnp.ones((), jnp.bool_),
+        }
+
+    def fifo_pass(self, state, plan: dict, cur: dict, flow: Flow):
+        """One pass: the next B rows of the queue against the batch's
+        thresholds. Row k of the queue leaves at the first rank r whose
+        threshold has reached its window time, or whose insertion needs its
+        slot (r = k + W - live: the length rule, and a time window's early
+        expiry). Both are sorted in k and in r, so "how many rows has rank r
+        let go" is a merge of two sorted lanes: one sort of 2B keys, no
+        [W, B] matrix. A rank at which the queue's next row, the one behind
+        this pass, is due too lets go of more than the pass holds: it and
+        the ranks behind it wait for the next pass, which reads on from
+        where this one stopped. The flow, 2B rows,
+        is the pass's EXPIRED rows, each before the CURRENT row that let it
+        go, by one payload sort on positions that follow by arithmetic."""
+        bsz = plan["wts"].shape[0]
+        w = self.w
+        new, c, live = plan["new"], plan["c"], plan["live"]
+        k0, r0 = cur["k"], cur["r"]
+        j = jnp.arange(bsz, dtype=jnp.int32)
+        with jax.named_scope("ring_expire"):
+            # the ring's run from head + k0, as two slices (see the length
+            # step); behind the live rows, the batch's own
+            s1, off = _run_start(state["head"] + k0, w, bsz)
+            run = _join_pairs(jax.tree_util.tree_map(
+                lambda lane: _run_read(
+                    lane, jax.lax.dynamic_slice(lane, (s1,), (bsz,)), off
+                ),
+                {"cols": state["cols"], "wts": state["wts"]},
+            ))
+            n_ring = jnp.clip(live - k0, -bsz, bsz)
+
+            def behind(x):  # batch rank (j - n_ring) at place j
+                return jax.lax.dynamic_slice(
+                    jnp.pad(x, (bsz, bsz)), (bsz - n_ring,), (bsz,)
+                )
+
+            from_ring = j < n_ring
+            cand_cols = {
+                n: jnp.where(from_ring, run["cols"][n], behind(col))
+                for n, col in new["cols"].items()
+            }
+            n_cand = jnp.clip(live + c - k0, 0, bsz)
+            cand_wts = jnp.where(
+                j < n_cand,
+                jnp.where(from_ring, run["wts"], behind(plan["wts"])),
+                np.int64(NO_TIMER),
+            )
+            first_t, held_t = _merge_counts(cand_wts, plan["theta"])
+            # the length rule, in 64 bits: W may be near 2**31
+            room = np.int64(w) - live
+            first_l = jnp.minimum(k0 + j + room, BIG).astype(jnp.int32)
+            first = jnp.where(j < n_cand, jnp.minimum(first_t, first_l), BIG)
+            held = jnp.maximum(
+                held_t, jnp.clip(j - k0 - room + 1, 0, n_cand).astype(jnp.int32)
+            )
+            # a rank is whole in this pass unless the queue's next row, the
+            # one behind the pass, is due at it too
+            q = k0 + bsz
+            slot = ((state["head"] + q) % w).astype(jnp.int32)
+            next_wts = jnp.where(
+                q < live,
+                _join64(*(
+                    jax.lax.dynamic_index_in_dim(half, slot, keepdims=False)
+                    for half in (state["wts"].lo, state["wts"].hi)
+                ), jnp.int64),
+                jax.lax.dynamic_index_in_dim(
+                    plan["wts"], jnp.clip(q - live, 0, bsz - 1), keepdims=False
+                ),
+            )
+            next_first = jnp.minimum(
+                (plan["theta"] < next_wts).sum(dtype=jnp.int32),
+                jnp.minimum(q + room, BIG).astype(jnp.int32),
+            )
+            r1 = jnp.where(q < live + c, jnp.minimum(c, next_first), c)
+            due = first < c
+            n_due = due.sum(dtype=jnp.int32)
+            early = (due & (first_l < first_t)).sum(dtype=jnp.int32)
+
+        with jax.named_scope("ring_emit"):
+            n_cur = r1 - r0
+            # places past the flow's end, all different, for what stays
+            pos_exp = jnp.where(
+                due, j + jnp.clip(jnp.minimum(first, r1) - r0, 0, bsz),
+                2 * bsz + j,
+            )
+            pos_cur = jnp.where(
+                (j >= r0) & (j < r1), j - r0 + held, 3 * bsz + j
+            )
+            flowed = _permute_tree(
+                jnp.concatenate([pos_exp, pos_cur]),
+                {
+                    "cols": {
+                        n: jnp.concatenate([cand_cols[n], col])
+                        for n, col in new["cols"].items()
+                    },
+                    "ts": jnp.concatenate(
+                        [jnp.zeros((bsz,), jnp.int64), new["ts"]]
+                    ),
+                    "cur": jnp.concatenate(
+                        [jnp.zeros((bsz,), jnp.bool_), jnp.ones((bsz,), jnp.bool_)]
+                    ),
+                },
+            )
+            pos = jnp.arange(2 * bsz, dtype=jnp.int32)
+            valid = pos < n_due + n_cur
+            is_cur = flowed["cur"] & valid
+            # an EXPIRED row carries its trigger's ts: the next CURRENT row
+            # of the flow, or rank r1, which waits for the next pass
+            lo, hi = _split64(flowed["ts"])
+            back = is_cur[::-1]
+            lo, hi = _segmented_carry((lo[::-1], hi[::-1]), back)
+            follows = _cummax(back.astype(jnp.int32))[::-1] > 0
+            waiting = jax.lax.dynamic_index_in_dim(
+                new["ts"], jnp.clip(r1, 0, bsz - 1), keepdims=False
+            )
+            out = EventBatch(
+                ts=jnp.where(
+                    follows, _join64(lo[::-1], hi[::-1], jnp.int64), waiting
+                ),
+                kind=jnp.where(
+                    is_cur, np.int8(KIND_CURRENT), np.int8(KIND_EXPIRED)
+                ),
+                valid=valid,
+                cols=flowed["cols"],
+            )
+        cur = {
+            "k": k0 + n_due,
+            "r": r1,
+            "passes": cur["passes"] + 1,
+            "early": cur["early"] + early,
+            "more": r1 < c,
+        }
+        return cur, Flow(
+            batch=out,
+            ref=flow.ref,
+            now=flow.now,
+            extra_cols={},
+            aux=dict(flow.aux),
+            tables=flow.tables,
+        )
+
+    def fifo_commit(self, state, plan: dict, cur: dict):
+        """The ring after the step's last pass: the batch's CURRENT rows
+        written as one run from total % W (the length step's write), the
+        head moved past the rows that left; and the step's aux flags."""
+        bsz = plan["wts"].shape[0]
+        w = self.w
+        total, c = state["total"], plan["c"]
+        ring = {k: state[k] for k in ("cols", "ts", "wts", "seq")}
+        with jax.named_scope("ring_update"):
+            new_rows = _split_like(ring, {
+                **plan["new"], "seq": total + jnp.arange(bsz, dtype=jnp.int64)
+            })
+            s1, off = _run_start(total, w, bsz)
+            pos = jnp.arange(2 * bsz, dtype=jnp.int32)
+            written = (pos >= off) & (pos - off < c)
+            new_state = jax.tree_util.tree_map(
+                lambda lane, vals: _run_write(
+                    lane, jax.lax.dynamic_slice(lane, (s1,), (bsz,)), vals,
+                    written, s1, off,
+                ),
+                ring, new_rows,
+            )
+        left = cur["k"].astype(jnp.int64)
+        new_state.update(
+            total=total + c,
+            head=state["head"] + left,
+            wmax=plan["wmax"],
+            expired=state["expired"] + left,
+            passes=state["passes"] + (cur["passes"] - 1),
+            early=state["early"] + cur["early"],
+        )
+        aux = {}
+        if self.capacity_is_limit:
+            aux["window_early_expiry"] = cur["early"] > 0
+        return new_state, aux
+
     @staticmethod
     def _view_perm(state):
         """THE ring-slot -> logical-insertion-order permutation, shared by
@@ -625,6 +924,8 @@ class SlidingWindow(WindowStage):
         view's cols/mask by position, so the two must never drift."""
         seq = state["seq"].join()
         mask = seq >= 0
+        if "head" in state:  # a time-bounded ring: see _element_view
+            mask &= seq >= state["head"]
         perm = jnp.argsort(
             jnp.where(mask, seq, jnp.iinfo(jnp.int64).max)
         ).astype(jnp.int32)
@@ -639,6 +940,41 @@ class SlidingWindow(WindowStage):
     def view_seq(self, state):
         _mask, perm = self._view_perm(state)
         return state["seq"].join()[perm]
+
+
+def _run_start(seq, w: int, bsz: int):
+    """Where the run of B ring slots that starts at slot seq % W lies, as
+    two slices: (`s1`, the start of a slice of B rows held inside the lane;
+    `off`, how far into that slice and the lane's first B rows, laid end to
+    end, the run begins)."""
+    start = (seq % w).astype(jnp.int32)
+    s1 = jnp.minimum(start, np.int32(w - bsz))
+    return s1, start - s1
+
+
+def _run_read(lane, tail, off):
+    """The run's B rows of `lane`; `tail` is the lane's slice at `s1`."""
+    bsz = tail.shape[0]
+    return jax.lax.dynamic_slice(
+        jnp.concatenate([tail, lane[:bsz]]), (off,), (bsz,)
+    )
+
+
+def _run_write(lane, tail, vals, written, s1, off):
+    """`lane` with the run's rows set to `vals` where `written` ([2B], in
+    the places of the two slices) says so."""
+    bsz = tail.shape[0]
+    vals = jax.lax.dynamic_slice(
+        jnp.pad(vals, (bsz, bsz)), (bsz - off,), (2 * bsz,)
+    )
+    lane = jax.lax.dynamic_update_slice(
+        lane, jnp.where(written[:bsz], vals[:bsz], tail), (s1,)
+    )
+    # the head is read again: it overlaps the tail when the run starts
+    # inside the lane's first B rows
+    return jax.lax.dynamic_update_slice(
+        lane, jnp.where(written[bsz:], vals[bsz:], lane[:bsz]), (0,)
+    )
 
 
 def _place_ring(old, evicted, slots, vals, empty=0):
@@ -663,11 +999,99 @@ def _place_ring(old, evicted, slots, vals, empty=0):
     )
 
 
+def ring_from_legacy(snap: dict) -> dict:
+    """A time-bounded ring saved before it kept a head (a host tree of
+    logical lanes, or a [P, W] stack of them), in today's layout: its live
+    rows (seq >= 0) in seq order take the seqs total - n .. total - 1 and the
+    slots that go with them, `wts` becomes their running maximum, the head
+    is the oldest, the counters start at 0."""
+    seq = np.asarray(snap["seq"])
+    if seq.ndim > 1:
+        rings = [
+            ring_from_legacy(jax.tree_util.tree_map(lambda x: np.asarray(x)[p], snap))
+            for p in range(seq.shape[0])
+        ]
+        return jax.tree_util.tree_map(lambda *xs: np.stack(xs), *rings)
+    w = seq.shape[0]
+    total = np.int64(snap["total"])
+    order = np.argsort(np.where(seq >= 0, seq, np.iinfo(np.int64).max), kind="stable")
+    order = order[: int((seq >= 0).sum())]
+    head = total - len(order)
+    slots = (head + np.arange(len(order))) % w
+
+    def relaid(rows, like, fill=0):
+        out = np.full_like(np.asarray(like), fill)
+        out[slots] = rows
+        return out
+
+    def moved(lane):
+        return relaid(np.asarray(lane)[order], lane)
+
+    wts = np.maximum.accumulate(np.asarray(snap["wts"])[order])
+    return {
+        "cols": {n: moved(c) for n, c in snap["cols"].items()},
+        "ts": moved(snap["ts"]),
+        "wts": relaid(wts, snap["wts"]),
+        "seq": relaid(head + np.arange(len(order)), seq, -1),
+        "total": total,
+        "head": np.int64(head),
+        "wmax": np.int64(wts[-1] if len(order) else NO_TIME),
+        **{k: np.int64(0) for k in _FIFO_COUNTERS},
+    }
+
+
+def _merge_counts(a, b):
+    """For two ascending int64 lanes, each padded with the type's maximum:
+    (for every a[j], how many of b are below it; for every b[r], how many of
+    a are no higher), int32. One sort of both lanes' keys, a before b where
+    they tie, then each lane's entries taken out again in their order: a
+    64-bit key rides as its two halves."""
+    n, m = a.shape[0], b.shape[0]
+    lo, hi = _split64(jnp.concatenate([a, b]))
+    of_b = jnp.concatenate([jnp.zeros((n,), jnp.int32), jnp.ones((m,), jnp.int32)])
+    of_b = jax.lax.sort((hi, lo, of_b), num_keys=3)[2] > 0
+    b_before = jnp.cumsum(of_b.astype(jnp.int32))
+    a_before = jnp.arange(1, n + m + 1, dtype=jnp.int32) - b_before
+    return (
+        _compact_front(~of_b, b_before)[:n],
+        _compact_front(of_b, a_before)[:m],
+    )
+
+
+def _permute_tree(key, tree):
+    """Every lane of `tree` in the order that sorts `key`, whose values are
+    all different: payload sorts of at most six 32-bit lanes each (one sort
+    of many operands compiles for minutes, PERF.md PR 25), a 64-bit lane as
+    its halves and a bool lane as int32."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    parts = []
+    for x in leaves:
+        if _is_wide(x.dtype):
+            parts.extend(_split64(x))
+        else:
+            parts.append(x.astype(jnp.int32) if x.dtype == jnp.bool_ else x)
+    moved = []
+    for i in range(0, len(parts), 6):
+        moved.extend(_permute_by(key, *parts[i:i + 6]))
+    moved = iter(moved)
+    out = []
+    for x in leaves:
+        if _is_wide(x.dtype):
+            lo, hi = next(moved), next(moved)
+            out.append(_join64(lo, hi, x.dtype))
+        else:
+            out.append(next(moved).astype(x.dtype))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
 @jax.jit
-def _ring_summary(seq: U32Pair, ts: U32Pair):
+def _ring_summary(seq: U32Pair, ts: U32Pair, head=None):
     """(fill, oldest ts, newest ts) of a ring, or of a [P, W] stack of
-    rings: a slot is live while its seq is not negative."""
+    rings: a slot is live while its seq is not negative and, in a
+    time-bounded ring, not behind the head."""
     live = seq.hi >= 0
+    if head is not None:
+        live &= seq.join() >= jnp.asarray(head)[..., None]
     t = ts.join()
     return (
         live.sum(),
@@ -1053,6 +1477,13 @@ class BatchWindow(WindowStage):
         }
 
         aux = dict(flow.aux)
+        if self.n is None:
+            # a time bucket holds @app:timeCapacity rows: what does not fit
+            # is dropped from it, and says so
+            aux["window_overflow"] = (
+                (remaining & (rem_slot >= w)).any()
+                | (in_last & (lb_slot_b >= w)).any()
+            )
         if self.timeout_ms is not None:
             # wall-clock idle deadline: every arriving CURRENT event pushes
             # it forward; with an empty open bucket there is none. A stale
@@ -1109,9 +1540,12 @@ def make_window(
     schema: StreamSchema,
     ref: str,
     scope: Scope,
-    time_capacity: int = DEFAULT_TIME_CAPACITY,
+    time_capacity: Optional[int] = None,
 ) -> WindowStage:
-    """Reference: SingleInputStreamParser.generateProcessor window dispatch."""
+    """Reference: SingleInputStreamParser.generateProcessor window dispatch.
+    `time_capacity`: the rows a time-bounded ring or bucket holds
+    (`@app:timeCapacity`; None = the default)."""
+    time_capacity = time_capacity or DEFAULT_TIME_CAPACITY
     name = spec.name.lower() if spec.namespace is None else f"{spec.namespace}:{spec.name}"
     if name == "length":
         n = _const_param(spec, 0, "length")
@@ -1119,7 +1553,8 @@ def make_window(
     if name == "time":
         t = _const_param(spec, 0, "duration")
         return SlidingWindow(
-            schema, ref, capacity=time_capacity, duration_ms=t, use_scheduler=True
+            schema, ref, capacity=time_capacity, duration_ms=t,
+            use_scheduler=True, capacity_is_limit=True,
         )
     if name == "timelength":
         t = _const_param(spec, 0, "duration")
@@ -1132,7 +1567,8 @@ def make_window(
         scope.record_key((ref, None, attr))
         t = _const_param(spec, 1, "duration")
         return SlidingWindow(
-            schema, ref, capacity=time_capacity, duration_ms=t, time_attr=attr
+            schema, ref, capacity=time_capacity, duration_ms=t, time_attr=attr,
+            capacity_is_limit=True,
         )
     if name == "lengthbatch":
         n = _const_param(spec, 0, "length")
