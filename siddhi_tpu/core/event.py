@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 from typing import Any, Sequence
 
 import jax
@@ -24,6 +25,7 @@ from siddhi_tpu.core.types import (
     InternTable,
     null_value,
 )
+from siddhi_tpu.native import event_builder
 from siddhi_tpu.observability.profiler import stage
 
 # ComplexEvent.Type equivalents (reference: core/event/ComplexEvent.java:48-53).
@@ -517,15 +519,69 @@ def events_from_arrays(
     schema, ts: np.ndarray, cols: dict, n: int, interner
 ) -> list:
     """Vectorized host decode straight to Event objects (single-kind fused
-    egress fast path — skips the triple intermediate entirely)."""
+    egress fast path — skips the triple intermediate entirely): one call
+    into the native builder where it is loaded (native/decode.cpp, built at
+    deploy), else the Python body below. The same eager `Event`s either
+    way."""
     if n <= 0:
         return []
-    import functools
+    build = event_builder()
+    if build is None:
+        return events_from_arrays_py(schema, ts, cols, n, interner)
+    ts = np.asarray(ts)[:n]
+    if ts.dtype != np.int64:
+        ts = ts.astype(np.int64)
+    lanes, atomic = _native_lanes(schema, cols, n, interner)
+    return build(Event, ts, lanes, n, atomic)
 
+
+def events_from_arrays_py(
+    schema, ts: np.ndarray, cols: dict, n: int, interner
+) -> list:
+    """`events_from_arrays` in Python: the fallback where the native builder
+    did not load, and the tests' reference for it."""
     col_lists = column_lists(schema, cols, n, interner)
     ts_l = np.asarray(ts)[:n].tolist()
     mk = functools.partial(tuple.__new__, Event)
     return list(map(mk, zip(ts_l, zip(*col_lists))))
+
+
+# how the native builder reads a lane (the enum of native/decode.cpp), the
+# element dtypes it reads as they are, and the one any other is widened to
+_LANE_INT, _LANE_FLOAT, _LANE_BOOL, _LANE_ID = range(4)
+_LANE_READS = {
+    _LANE_INT: (np.dtype(np.int32), np.dtype(np.int64)),
+    _LANE_FLOAT: (np.dtype(np.float32), np.dtype(np.float64)),
+    _LANE_BOOL: (np.dtype(np.bool_),),
+    _LANE_ID: (np.dtype(np.int32), np.dtype(np.int64)),
+}
+
+
+def _native_lanes(schema, cols: dict, n: int, interner) -> tuple[tuple, bool]:
+    """`(kind, array, null sentinel, id table)` per attribute for the native
+    builder, by the attribute's type and its lane's dtype, and whether every
+    attribute is atomic (no OBJECT: an Event of this schema can be in no
+    reference cycle, so the builder untracks it)."""
+    lanes = []
+    atomic = True
+    for name, t in schema.attrs:
+        arr = np.asarray(cols[name])[:n]
+        null, table = 0, None
+        if t in (AttrType.STRING, AttrType.OBJECT):
+            kind, table = _LANE_ID, interner.id_table()
+            atomic = atomic and t is AttrType.STRING
+        elif t is AttrType.BOOL:
+            kind = _LANE_BOOL
+        elif t in (AttrType.FLOAT, AttrType.DOUBLE):
+            kind = _LANE_FLOAT
+        else:
+            # the sentinel as the lane's own dtype holds it, as above
+            kind, null = _LANE_INT, int(np.asarray(null_value(t), arr.dtype))
+        reads = _LANE_READS[kind]
+        if arr.dtype not in reads:
+            arr = arr.astype(reads[-1])
+        lanes.append((kind, arr, null, table))
+    return tuple(lanes), atomic
 
 
 def decode_value(v, t: AttrType, interner: InternTable):

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from siddhi_tpu.core.event import WireNarrowMisfit
+from siddhi_tpu.native import event_builder, load_event_builder
 from siddhi_tpu.observability.profiler import (
     CAUSE_DELIVER_SET,
     CAUSE_FULL_WIDTH,
@@ -163,6 +164,10 @@ class FusedJunctionIngest:
         self._chunk_ids = itertools.count(1)
         self.batches_fused = 0
         self.events_fused = 0
+        # the drain's Event builder is compiled here, where the engine is
+        # built, so that no send ever waits for a compiler
+        load_event_builder()
+        self.decode_native_rows = 0
         self._fused = None
         self._fused_deliver = None
         self._disabled = False
@@ -224,6 +229,11 @@ class FusedJunctionIngest:
             "depth": self.pipeline_depth,
             "component": self.component,
             "mesh_devices": self._mesh_devices(),
+            # which body `events_from_arrays` runs for a query callback's
+            # rows (native/decode.cpp or Python), and how many the native
+            # builder has made
+            "decode": "python" if event_builder() is None else "native",
+            "decode_native_rows": self.decode_native_rows,
         }
         gr = self.group_report()
         if gr is not None:
@@ -1395,19 +1405,23 @@ class FusedJunctionIngest:
         ) == len(qr.query_callbacks)
         split = want is OutputEventsFor.ALL
         expired = want is OutputEventsFor.EXPIRED
+        native = fast and event_builder() is not None
+        impl = "native" if native else "python"
         with stage("decode", wf=wf, wf_name="deliver"):
             lanes = {}
             for name, dt, off in layout:
-                lanes[name] = np.ascontiguousarray(
-                    host[:total, off : off + dt.itemsize]
-                ).view(dt)[:, 0]
+                # a view of the packed rows (strided, unaligned), no copy:
+                # the decode reads each row's lanes where they lie
+                lanes[name] = host[:total, off : off + dt.itemsize].view(
+                    dt
+                )[:, 0]
             cols = {n: lanes[f"c.{n}"] for n in qr.out_schema.attr_names}
         off = 0
         for k in range(len(cnts)):
             c = int(cnts[k])
             if c == 0:
                 continue
-            with stage("decode", wf=wf, wf_name="deliver"):
+            with stage("decode", wf=wf, wf_name="deliver", impl=impl):
                 ts_k = lanes["ts"][off : off + c]
                 cols_k = {n: a[off : off + c] for n, a in cols.items()}
                 if fast:
@@ -1432,6 +1446,8 @@ class FusedJunctionIngest:
                     else:
                         ins, removed = (None, seg) if expired else (seg, None)
             off += c
+            if native:
+                self.decode_native_rows += c
             ts = seg[-1][0]
             with stage("callback", wf=wf, wf_name="deliver", batch=k, rows=c):
                 for cb in raw if fast else qr.query_callbacks:

@@ -128,5 +128,12 @@ class InternTable:
                 )
         return table[np.asarray(ids, dtype=np.int64)].tolist()
 
+    def id_table(self) -> list:
+        """The id -> value list itself, for the native Event builder
+        (native/decode.cpp), which indexes it holding the GIL. Append-only:
+        an id read from a lane was interned before the lane was made."""
+        with self._lock:
+            return self._from_id
+
     def __len__(self) -> int:
         return len(self._from_id)

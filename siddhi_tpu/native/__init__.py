@@ -1,26 +1,47 @@
-"""Native host components: the C++ ingress ring with ctypes bindings.
+"""Native host components, compiled on first use with the system toolchain.
 
-The ring (ring.cpp) is the native analog of the reference's LMAX Disruptor
-substrate (StreamJunction.java:262-298): a lock-free bounded MPSC queue of
-fixed-width numeric rows, drained by one consumer into columnar batches. It
-compiles on first use with the system toolchain; environments without g++
-fall back to the pure-Python queue path transparently.
+Two sources live here, each built once into `_build/` under a name made of
+its source's hash, written through a temp file and `os.replace`, and loaded
+once under a lock:
+
+* ring.cpp — the C++ ingress ring with ctypes bindings: the native analog of
+  the reference's LMAX Disruptor substrate (StreamJunction.java:262-298), a
+  lock-free bounded MPSC queue of fixed-width numeric rows, drained by one
+  consumer into columnar batches. Environments without g++ fall back to the
+  pure-Python queue path transparently.
+* decode.cpp — the fused drain's `Event` builder: one call per micro-batch
+  turns the segment's lane arrays into the list of `Event`s that
+  `core/event.py` `events_from_arrays` hands to the callbacks, and takes
+  them out of the cyclic collector's sight where the schema holds only
+  atomic values. It is bound to the CPython C API: compiled against the
+  interpreter's headers, named by its `SOABI` too, and loaded with
+  `ctypes.PyDLL` so that the GIL is held across the call. It is built when a
+  fused engine is built (deploy), never inside a send; without a compiler or
+  `Python.h` one WARNING is logged and the Python body stays
+  (`snapshot_status().streams.<S>.pipeline.decode` says which runs).
 """
 
 from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
 import subprocess
+import sysconfig
 import threading
 from typing import Optional
 
 import numpy as np
 
+logger = logging.getLogger(__name__)
+
+_COMPILER = "g++"
 _LIB_LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _LIB_FAILED = False
+_DECODE_LIB: Optional[ctypes.PyDLL] = None
+_DECODE_FAILED = False
 
 
 def _build_dir() -> str:
@@ -29,28 +50,34 @@ def _build_dir() -> str:
     return d
 
 
+def _build(source: str, stem: str, *flags: str, abi: str = "") -> str:
+    """Path of `source`'s binary, compiling it first where `_build/` has
+    none. The binary is named by the source it was built from: `_build/` is
+    git-ignored, so a copied tree can carry a binary of some other source,
+    and an mtime says nothing about that."""
+    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), source)
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    out = os.path.join(_build_dir(), f"lib{stem}_{digest}{abi}.so")
+    if not os.path.exists(out):
+        tmp = f"{out}.{os.getpid()}.tmp"
+        subprocess.run(
+            [_COMPILER, "-O2", "-shared", "-fPIC", "-std=c++17", *flags,
+             "-o", tmp, src],
+            check=True, capture_output=True,
+        )
+        os.replace(tmp, out)  # never expose a half-written binary
+    return out
+
+
 def load_ring_library() -> Optional[ctypes.CDLL]:
     """Compile (once) and load the ring library; None when no toolchain."""
     global _LIB, _LIB_FAILED
     with _LIB_LOCK:
         if _LIB is not None or _LIB_FAILED:
             return _LIB
-        src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "ring.cpp")
         try:
-            # the binary is named by the source it was built from: _build/
-            # is git-ignored, so a copied tree can carry a binary of some
-            # other ring.cpp, and an mtime says nothing about that
-            with open(src, "rb") as f:
-                digest = hashlib.sha256(f.read()).hexdigest()[:16]
-            out = os.path.join(_build_dir(), f"libsiddhi_ring_{digest}.so")
-            if not os.path.exists(out):
-                tmp = f"{out}.{os.getpid()}.tmp"
-                subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", tmp, src],
-                    check=True, capture_output=True,
-                )
-                os.replace(tmp, out)  # never expose a half-written binary
-            lib = ctypes.CDLL(out)
+            lib = ctypes.CDLL(_build("ring.cpp", "siddhi_ring"))
         except Exception:
             _LIB_FAILED = True
             return None
@@ -72,6 +99,44 @@ def load_ring_library() -> Optional[ctypes.CDLL]:
         lib.ring_size.argtypes = [ctypes.c_void_p]
         _LIB = lib
         return _LIB
+
+
+def load_event_builder():
+    """Compile (once) and load decode.cpp; its `siddhi_build_events`, or
+    None (and one WARNING) where no compiler or no `Python.h` is found.
+    Called where a fused engine is built; a send only asks `event_builder`."""
+    global _DECODE_LIB, _DECODE_FAILED
+    with _LIB_LOCK:
+        if _DECODE_LIB is None and not _DECODE_FAILED:
+            try:
+                paths = sysconfig.get_paths()
+                lib = ctypes.PyDLL(_build(
+                    "decode.cpp", "siddhi_decode",
+                    *(f"-I{paths[k]}" for k in ("include", "platinclude")),
+                    abi="." + (sysconfig.get_config_var("SOABI") or "abi"),
+                ))
+                lib.siddhi_build_events.restype = ctypes.py_object
+                lib.siddhi_build_events.argtypes = [
+                    ctypes.py_object, ctypes.py_object, ctypes.py_object,
+                    ctypes.c_ssize_t, ctypes.c_int,
+                ]
+                _DECODE_LIB = lib
+            except Exception as e:
+                _DECODE_FAILED = True
+                detail = getattr(e, "stderr", None) or e
+                if isinstance(detail, bytes):
+                    detail = detail.decode(errors="replace")
+                logger.warning(
+                    "native Event builder unavailable, the fused drain "
+                    "decodes in Python: %s", str(detail).strip()[-400:],
+                )
+    return event_builder()
+
+
+def event_builder():
+    """The loaded `siddhi_build_events`, or None; never compiles."""
+    lib = _DECODE_LIB
+    return None if lib is None else lib.siddhi_build_events
 
 
 class NativeIngressRing:

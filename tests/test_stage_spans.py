@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import gc
 import glob
+import sys
 import time
 
 import jax
@@ -151,25 +152,41 @@ def test_fused_path_spans(tmp_path):
         1 for e in got if e[0] < 10_000
     )
     assert {c["batch"] for c in calls} == set(range(K))
+    # a chunk's first decode span cuts the lane views; each micro-batch's
+    # says which body built its Events (native/decode.cpp, built at deploy)
+    decodes = by_name["siddhi:decode"]
+    assert [d["impl"] for d in decodes if "impl" in d] == (
+        ["native"] * len(calls)
+    )
+    assert len(decodes) == len(calls) + len(drains)
     assert any(g["generation"] == 2 for g in by_name["siddhi:gc"])
     assert all("collected" in g for g in by_name["siddhi:gc"])
     assert by_name["siddhi:aux_drain"][0]["flags"] >= 1
 
 
-def test_the_drain_holds_one_micro_batch_of_events_at_a_time():
+@pytest.mark.parametrize("impl", ["native", "python"])
+def test_the_drain_holds_one_micro_batch_of_events_at_a_time(
+    impl, monkeypatch
+):
     """`deliver_endpoint` decodes a micro-batch's rows just before its
     callbacks and drops them right after: while callback k runs, the
     `Event`s of the chunk's other micro-batches do not exist (a chunk's
     worth of them, mapped and unmapped once per chunk, made a bulk send's
     time drift; PERF.md §6, PR 26)."""
+    import siddhi_tpu.native as native
     from siddhi_tpu.core.event import Event
 
+    if impl == "python":
+        monkeypatch.setattr(native, "_DECODE_LIB", None)
+        monkeypatch.setattr(native, "_DECODE_FAILED", True)
     mgr = SiddhiManager()
     rt = mgr.create_siddhi_app_runtime(APP)
     alive, rows = [], []
 
     def events_alive():
-        return sum(1 for o in gc.get_objects() if type(o) is Event)
+        # an instance of a heap type holds a reference to it; the native
+        # builder's Events are not in the collector's lists to be counted
+        return sys.getrefcount(Event)
 
     def callback(ts, ins, removed):
         rows.append(len(ins))
@@ -189,6 +206,8 @@ def test_the_drain_holds_one_micro_batch_of_events_at_a_time():
     rt.shutdown()
     mgr.shutdown()
     assert status["enabled"] and status["chunk_batches"] == K
+    assert status["decode"] == impl
+    assert status["decode_native_rows"] == (n if impl == "native" else 0)
     assert rows == [B] * (2 * K)
     # with a chunk-wide decode: K * B in every call
     assert [a - before for a in alive] == rows
