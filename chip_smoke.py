@@ -82,10 +82,15 @@ LOAD_MEAN_W = 60.0
 # 3.3e-6 in CPU runs at batch 512 and 4096 (PR 21).
 AVG_TOL = 5e-5
 
-# part_batch: a partitioned query's output batch is partitionCapacity x batch
-# row slots; at 4096 x 32768 its per-batch readback pack asked for 19 GB of
-# HBM on the one-chip run (PR 21, ROADMAP S7), so stage C's partitioned twin
-# runs at a batch the design can hold
+# part_batch: before PR 32 a partitioned query's output batch was
+# partitionCapacity x batch row slots; at 4096 x 32768 its per-batch readback
+# pack asked for 19 GB of HBM on the one-chip run (PR 21). The routed step
+# (PR 32) emits a flat batch of the rows sent and runs that size on one chip
+# (the benchmark's `q1-part.trickle`), but its program across four chips
+# (`apply_partition_mesh`: the state's [P] axis on the mesh, the [P, B']
+# sub-batches and the merge's sorts left to the partitioner) has not run on
+# the chip at that size: stage C's partitioned twin keeps the smaller batch
+# until one `--shard 4` call at 32768 has (ROADMAP M1)
 REAL = {"batch": 32768, "join_batch": 8192, "part_batch": 2048,
         "async_rows": 4096}
 DRY = {"batch": 512, "join_batch": 256, "part_batch": 256, "async_rows": 512}
@@ -513,7 +518,7 @@ def stage_c(SiddhiManager, sizes: dict, seed: int, n_dev: int,
         }
         if B != sizes["batch"]:
             out[axis]["reduced"] = {
-                "batch": f"{B} of {sizes['batch']}, ROADMAP S7"}
+                "batch": f"{B} of {sizes['batch']}, ROADMAP M1"}
         print(f"stage C/{axis} ok: {json.dumps(out[axis])}", flush=True)
     return out
 

@@ -246,10 +246,16 @@ class QueryCost:
     rate_limited: bool = False
     in_partition: bool = False
     consumed_streams: list = dataclasses.field(default_factory=list)
+    # inside a partition: the slots, how the step visits them (`routed`:
+    # [P, B'] sub-batches; `masked`: every slot steps the whole batch) and
+    # the rows of one step's flow over all slots
+    partition: Optional[dict] = None
 
     @property
     def state_bytes(self) -> int:
-        return sum(o.state_bytes for o in self.operators)
+        one = sum(o.state_bytes for o in self.operators)
+        # every slot holds the inner query's whole state
+        return one * (self.partition["capacity"] if self.partition else 1)
 
     @property
     def predicted_compiles(self) -> int:
@@ -273,6 +279,7 @@ class QueryCost:
             "scheduler_armed": self.scheduler_armed,
             "rate_limited": self.rate_limited,
             "in_partition": self.in_partition,
+            **({"partition": dict(self.partition)} if self.partition else {}),
             "consumed_streams": list(self.consumed_streams),
             "operators": [o.to_dict() for o in self.operators],
             "programs": [p.to_dict() for p in self.programs],
@@ -457,9 +464,12 @@ def _window_cost(
     spec: WindowSpec, schema: Optional[dict], qid: Optional[str],
     facts: Optional[dict] = None,
     time_capacity: int = DEFAULT_TIME_CAPACITY,
+    held_cols=None,
 ) -> OperatorCost:
     """Mirror core/windows.py make_window sizing for one window handler,
-    reading the state-bound metadata WindowSpec itself carries."""
+    reading the state-bound metadata WindowSpec itself carries.
+    `held_cols`: make_window's (a `length` ring that holds those columns
+    alone, and no ordering lane)."""
     name = spec.key
     line, col = getattr(spec, "line", None), getattr(spec, "col", None)
     params = spec.parameters
@@ -478,6 +488,14 @@ def _window_cost(
             # declared row bound is non-constant/missing: unknowable
             return OperatorCost(f"window:{name}", detail, [], None, line, col)
         rows = time_capacity  # time-capacity ring family (@app:timeCapacity)
+
+    if held_cols is not None and schema is not None:
+        held = {n: t for n, t in schema.items() if n in held_cols}
+        return OperatorCost(
+            f"window:{name}", detail,
+            _schema_tensors(held, rows, prefix="ring", facts=facts),
+            _SEL["window:sliding"], line, col,
+        )
 
     buffers = 2 if is_batch else 1  # batch windows carry cur + prev buckets
     tensors = []
@@ -501,6 +519,7 @@ def _source_operators(
     qid: str,
     facts: Optional[dict] = None,
     time_capacity: int = DEFAULT_TIME_CAPACITY,
+    held_cols=None,
 ) -> tuple[list, bool]:
     """(operators, scheduler_armed) for one single-source handler chain.
     With `facts` (attr -> ValueFact), a filter whose predicate narrows a
@@ -529,7 +548,9 @@ def _source_operators(
             ))
         elif isinstance(h, WindowHandler):
             ops.append(
-                _window_cost(h.window, schema, qid, facts, time_capacity)
+                _window_cost(
+                    h.window, schema, qid, facts, time_capacity, held_cols
+                )
             )
             armed = armed or h.window.arms_scheduler
     return ops, armed
@@ -785,6 +806,7 @@ def _query_cost(
     consumed: list[str] = []
     armed = False
     kind = "single"
+    held_cols = None
     time_capacity = _capacity_annotation(
         app, "app:timeCapacity", DEFAULT_TIME_CAPACITY
     )
@@ -810,8 +832,13 @@ def _query_cost(
             stream.stream_id
         )
         consumed.append(stream.stream_id)
+        if in_partition:
+            from siddhi_tpu.core.partition import ring_columns
+
+            held_cols = ring_columns(q)
         ops, armed = _source_operators(
-            stream, schema, qid, stream_facts(stream.stream_id), time_capacity
+            stream, schema, qid, stream_facts(stream.stream_id), time_capacity,
+            held_cols,
         )
         operators.extend(ops)
         extra = (1 if armed else 0) + (
@@ -903,6 +930,31 @@ def _query_cost(
                 getattr(sel, "line", None), getattr(sel, "col", None),
             ))
 
+    partition = None
+    if in_partition:
+        from siddhi_tpu.core.partition import (
+            DEFAULT_PARTITIONS,
+            sub_batch_rows,
+        )
+
+        p = _capacity_annotation(
+            app, "app:partitionCapacity", DEFAULT_PARTITIONS
+        )
+        routed = kind == "single"
+        sub = sub_batch_rows(B, p) if routed else B
+        if held_cols is not None:
+            # a ring that holds some columns only is stepped by slices: a
+            # sub-batch no longer than the window (core/partition.py)
+            w = stream.handlers[-1].window.length_bound()
+            if w is not None:
+                sub = min(sub, w)
+        partition = {
+            "capacity": p,
+            "step": "routed" if routed else "masked",
+            "sub_batch": sub,
+            "flow_rows": p * sub,
+        }
+
     return QueryCost(
         qid=qid,
         kind=kind,
@@ -912,6 +964,7 @@ def _query_cost(
         rate_limited=q.output_rate is not None,
         in_partition=in_partition,
         consumed_streams=consumed,
+        partition=partition,
     )
 
 

@@ -245,6 +245,11 @@ class DistinctCountAggregator(CompiledAggregator):
         return state, firsts.sum(axis=-1).astype(jnp.int64)
 
 
+# the aggregators that read the window's membership view, by their lowered
+# names (a non-forever `ExtremeAggregator`, `DistinctCountAggregator`)
+MEMBER_AGGREGATORS = frozenset({"min", "max", "distinctcount"})
+
+
 def build_aggregator(
     name: str, args: list[CompiledExpr], group: Optional[CompiledGroupBy] = None
 ) -> CompiledAggregator:

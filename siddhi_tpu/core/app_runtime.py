@@ -1861,13 +1861,18 @@ class SiddhiAppRuntime:
                 )
 
             qr.query_callbacks.append(qcb)
-            # raw-callback registry: the fused egress drain builds Event
-            # lists once and invokes user callbacks directly, skipping the
-            # triple->Event re-extraction (only valid while the two lists
-            # stay in 1:1 correspondence; the drain checks)
+            # raw-callback registry: the fused egress drain and the
+            # per-batch path's `route_output` build Event lists once and
+            # invoke user callbacks directly, skipping the triple->Event
+            # re-extraction (only valid while the two lists stay in 1:1
+            # correspondence; both check)
             if not hasattr(qr, "raw_query_callbacks"):
                 qr.raw_query_callbacks = []
             qr.raw_query_callbacks.append(callback)
+            # built here, at deploy, so that no send compiles
+            from siddhi_tpu.native import load_event_builder
+
+            load_event_builder()
             return
         if name in self.stream_schemas:
             j = self._junction(name)

@@ -469,6 +469,36 @@ class StreamSchema:
                 interner,
             )
 
+    def events_from_batch(
+        self, batch: EventBatch, interner: InternTable, *stall_trackers,
+        wf=None,
+    ):
+        """`from_batch` for a query's callbacks: the same `readback`, then
+        the valid rows decoded straight to `Event`s, as the fused drain
+        delivers them (`events_from_arrays`: the native builder where it is
+        loaded), with no `(timestamp, kind, data)` triple in between.
+        Returns (the last valid row's timestamp, CURRENT events, EXPIRED
+        events), None where no row is valid."""
+        pack, unpack, _total = self.d2h_codec(batch.capacity)
+        with stage("readback", *stall_trackers, wf=wf):
+            buf = np.asarray(pack(batch))
+        impl = "python" if event_builder() is None else "native"
+        with stage("decode", impl=impl):
+            ts, kind, valid, host_cols = unpack(buf)
+            idx = np.nonzero(valid)[0]
+            if idx.size == 0:
+                return None
+            kind = kind[idx]
+            by_kind = []
+            for k in (KIND_CURRENT, KIND_EXPIRED):
+                rows = kind == k
+                at = idx if rows.all() else idx[rows]
+                by_kind.append(events_from_arrays(
+                    self, ts[at], {n: c[at] for n, c in host_cols.items()},
+                    at.size, interner,
+                ))
+            return int(ts[idx[-1]]), by_kind[0], by_kind[1]
+
 
 def column_lists(schema, cols: dict, n: int, interner) -> list[list]:
     """Vectorized host decode of n packed rows into per-attribute Python

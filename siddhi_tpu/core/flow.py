@@ -23,6 +23,15 @@ class Flow:
           (ref, None, attr) in `extra`; primary single-stream cols live in
           batch.cols under plain attr names for ref `ref`)
     member/member_env: window membership view (see aggregators.FlowInfo)
+    cause: per row, the row of the step's input batch whose arrival put it
+          in the flow (an EXPIRED row's is its trigger's), int32; None
+          unless the caller asked by setting it on the flow it starts
+          (core/partition.py, to put a partition's output back in arrival
+          order). A stage that builds a new flow and cannot say drops it.
+    slot_rows: None, or, where the step runs under `vmap` over a partition's
+          slots, each with a state of its own (core/partition.py), the most
+          rows all slots' flows hold together (static): a length window
+          then reads and writes its ring by places, not by slices.
     """
 
     batch: EventBatch
@@ -35,6 +44,8 @@ class Flow:
     aux: dict = dataclasses.field(default_factory=dict)
     # live table states keyed by table id (for `in <table>` conditions)
     tables: dict = dataclasses.field(default_factory=dict)
+    cause: Optional[jnp.ndarray] = None
+    slot_rows: Optional[int] = None
 
     def env(self) -> Env:
         cols: dict[VarKey, jnp.ndarray] = {

@@ -194,6 +194,11 @@ def _upgrade(tree, like):
             from siddhi_tpu.core.windows import ring_from_legacy
 
             return ring_from_legacy(tree)
+        if "total" in like and "seq" in tree and "seq" not in like:
+            # a length window's whole ring, saved before a partitioned
+            # query's ring held the aggregated columns alone (PR 32)
+            return {"cols": {n: tree["cols"][n] for n in like["cols"]},
+                    "total": tree["total"]}
         return {
             k: _upgrade(v, like[k]) if k in like else v for k, v in tree.items()
         }

@@ -696,7 +696,29 @@ class BaseQueryRuntime:
             released = self.rate_limiter.process(rows4, now)
             self._deliver(released, now)
             return
-        if self.query_callbacks:
+        raw = getattr(self, "raw_query_callbacks", None)
+        if raw and len(raw) == len(self.query_callbacks):
+            # every callback came through `add_callback`, which keeps the
+            # user's own beside its wrapper: decode straight to `Event`s and
+            # call those, as the fused drain does (core/ingest.py
+            # `deliver_endpoint`), instead of triples that each wrapper
+            # turns into `Event`s again
+            got = self.out_schema.events_from_batch(
+                out, self._interner, self.sync_stall_tracker,
+                wf=self._tls_wf(),
+            )
+            if got is not None:
+                ts, ins, removed = got
+                want = self.output_events
+                if want is OutputEventsFor.CURRENT:
+                    removed = []
+                elif want is OutputEventsFor.EXPIRED:
+                    ins = []
+                if ins or removed:
+                    with stage("callback", rows=len(ins) + len(removed)):
+                        for cb in raw:
+                            cb(ts, ins or None, removed or None)
+        elif self.query_callbacks:
             events = self._timed_decode(decode, self.out_schema, out)
             if events:
                 ins = [e for e in events if e[1] == KIND_CURRENT]
@@ -760,7 +782,10 @@ class QueryRuntime(BaseQueryRuntime):
         group_capacity: Optional[int] = None,
         tables: Optional[dict] = None,
         time_capacity: Optional[int] = None,
+        held_cols=None,
     ):
+        # `held_cols`: `make_window`'s, for the subclass that knows nobody
+        # reads its window's EXPIRED rows beyond them (core/partition.py)
         self.query = query
         self.query_id = query_id
         self.in_schema = in_schema
@@ -781,7 +806,8 @@ class QueryRuntime(BaseQueryRuntime):
 
             def window_factory(spec, schema, ref, _scope=scope):
                 return make_window(
-                    spec, schema, ref, _scope, time_capacity=time_capacity
+                    spec, schema, ref, _scope, time_capacity=time_capacity,
+                    held_cols=held_cols,
                 )
 
         self.chain = CompiledSingleChain(stream, in_schema, scope, window_factory)

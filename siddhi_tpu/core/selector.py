@@ -61,6 +61,40 @@ def _lift_aggregators(expr: Expression, found: list[AttributeFunction]) -> Expre
     return expr
 
 
+def _variables(expr, found: set) -> set:
+    """Attribute names of every Variable inside `expr`."""
+    if isinstance(expr, Variable):
+        found.add(expr.attribute)
+    elif dataclasses.is_dataclass(expr):
+        for f in dataclasses.fields(expr):
+            v = getattr(expr, f.name)
+            for x in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(x, Expression):
+                    _variables(x, found)
+    return found
+
+
+def aggregate_calls(selector: Selector) -> list[AttributeFunction]:
+    """The aggregator calls of a selector's projections and `having`."""
+    found: list[AttributeFunction] = []
+    for oa in selector.selection_list:
+        _lift_aggregators(oa.expression, found)
+    if selector.having is not None:
+        _lift_aggregators(selector.having, found)
+    return found
+
+
+def aggregate_reads(selector: Selector) -> frozenset:
+    """What a row is read for where its own output is not: the attributes
+    in the aggregators' arguments and in the group key (an EXPIRED row of
+    a query that publishes CURRENT rows alone is read for nothing else)."""
+    return frozenset().union(
+        *(_variables(p, set()) for call in aggregate_calls(selector)
+          for p in call.parameters),
+        *(_variables(g, set()) for g in selector.group_by),
+    )
+
+
 class CompiledSelector:
     """Stateful selector stage: (state, Flow) -> (state, output EventBatch)."""
 

@@ -37,6 +37,7 @@ from siddhi_tpu.core.event import (
 )
 from siddhi_tpu.core.executor import Env, Scope, TS_ATTR, compile_expression
 from siddhi_tpu.ops.prefix import (
+    PICK_ROWS as _PICK_ROWS,
     compact_front as _compact_front,
     cummax as _cummax,
     segmented_carry as _segmented_carry,
@@ -50,6 +51,7 @@ from siddhi_tpu.ops.scatter import (
     _join64,
     _split64,
     join_pairs as _join_pairs,
+    ring_swap as _ring_swap,
     set_at as _set_at,
     split_like as _split_like,
 )
@@ -90,6 +92,10 @@ class WindowStage:
     is_batch = False
     # cron-driven windows schedule fire times host-side (CronSchedule)
     cron_schedule = None
+    # the most rows one arriving row can put in the flow, itself included,
+    # where that is bounded; None where a trigger may release any number
+    # (core/partition.py sizes a partition's flat output by it)
+    emits_per_row: Optional[int] = None
 
     def init_state(self):
         raise NotImplementedError
@@ -212,6 +218,7 @@ class SlidingWindow(WindowStage):
         time_attr: Optional[str] = None,
         use_scheduler: bool = False,
         capacity_is_limit: bool = False,
+        held_cols=None,
     ):
         self.schema = schema
         self.ref = ref
@@ -230,9 +237,28 @@ class SlidingWindow(WindowStage):
         # rows that enter and leave, in passes: `fifo_plan`) or "matrix"
         # (every ring row against every batch row: `apply`)
         self.time_step: Optional[str] = None
+        # the columns the ring holds where that is not all of them; then
+        # it holds no `ts`, `wts` or `seq` lane either. For the caller whose
+        # EXPIRED rows are read for nothing else (a length window that is
+        # its chain's last stage, behind a query that publishes its CURRENT
+        # rows alone; core/partition.py), and who steps the window by
+        # `_apply_length_slice` only. Every lane less is a scatter less
+        # per step
+        self.held_cols: Optional[frozenset] = None
+        if held_cols is not None:
+            assert self.t is None
+            self.held_cols = frozenset(held_cols) & set(schema.attr_names)
+
+    @property
+    def emits_per_row(self) -> Optional[int]:
+        # a length window: the row and the one it pushes out
+        return 2 if self.t is None else None
 
     def describe_state(self, state) -> dict:
         d = super().describe_state(state)
+        if self.held_cols is not None:
+            d["held_cols"] = sorted(self.held_cols)
+            d.pop("oldest_ts", None), d.pop("newest_ts", None)
         if self.ring_step is not None:
             d["ring_step"] = self.ring_step
         if any(map(_is_pair, jax.tree_util.tree_leaves(state, is_leaf=_is_pair))):
@@ -252,6 +278,11 @@ class SlidingWindow(WindowStage):
         return d
 
     def _fill_summary(self, state):
+        if self.held_cols is not None:
+            # no `seq` lane: a ring (or each of a [P] stack) holds what it
+            # was sent, up to its capacity
+            sent = np.asarray(jax.device_get(state["total"]))
+            return int(np.minimum(sent, self.w).sum()), 0, 0
         # one reduction over two lanes: view() would sort and gather the
         # whole ring, every column of it
         return jax.device_get(
@@ -267,7 +298,7 @@ class SlidingWindow(WindowStage):
         return _join_pairs(state)
 
     def share_signature(self):
-        if self.needs_scheduler:
+        if self.needs_scheduler or self.held_cols is not None:
             return None  # timer-armed: host scheduling owns per-query state
         return (
             "SlidingWindow", self.w, self.t, self.time_attr,
@@ -283,8 +314,14 @@ class SlidingWindow(WindowStage):
                 return U32Pair.full((self.w,), fill, dtype)
             return jnp.full((self.w,), fill, dtype)
 
+        cols = {n: lane(a.dtype) for n, a in self.schema.empty_batch(1).cols.items()}
+        if self.held_cols is not None:
+            return {
+                "cols": {n: c for n, c in cols.items() if n in self.held_cols},
+                "total": jnp.zeros((), jnp.int64),
+            }
         state = {
-            "cols": {n: lane(a.dtype) for n, a in self.schema.empty_batch(1).cols.items()},
+            "cols": cols,
             "ts": lane(jnp.int64),
             "wts": lane(jnp.int64),
             "seq": lane(jnp.int64, -1),
@@ -429,6 +466,11 @@ class SlidingWindow(WindowStage):
             )
             aux["next_timer"] = surv_wts.min() + self.t
 
+        cause = None
+        if flow.cause is not None:
+            # an EXPIRED row's is its trigger's, a CURRENT row's its own
+            own = jnp.clip(o_elem - w, 0, bsz - 1)
+            cause = flow.cause[jnp.where(o_exp, o_trig_row, own)]
         return new_state, Flow(
             batch=out,
             ref=flow.ref,
@@ -438,6 +480,7 @@ class SlidingWindow(WindowStage):
             member_env=member_env,
             aux=aux,
             tables=flow.tables,
+            cause=cause,
         )
 
     def _element_view(self, state, b, bwts, valid_cur, rank, c):
@@ -577,6 +620,13 @@ class SlidingWindow(WindowStage):
                 out_cols[n] = _set_at(out_cols[n], cur_dst, b.cols[n])
             out = EventBatch(ts=out_ts, kind=out_kind, valid=out_valid, cols=out_cols)
             member, member_env = self._length_member(flow, ev, valid_cur, rank, c, E)
+            cause = None
+            if flow.cause is not None:
+                # as the ts lane: an EXPIRED row takes its trigger's
+                trig = flow.cause[perm[jnp.clip(ranks, 0, bsz - 1)]]
+                cause = jnp.zeros((n_out,), jnp.int32)
+                cause = cause.at[exp_dst].set(trig, mode="drop")
+                cause = cause.at[cur_dst].set(flow.cause, mode="drop")
 
         with jax.named_scope("ring_update"):
             new_state = self._ring_state(
@@ -591,6 +641,7 @@ class SlidingWindow(WindowStage):
             member_env=member_env,
             aux=dict(flow.aux),
             tables=flow.tables,
+            cause=cause,
         )
 
     def _pick_ring_step(self, bsz: int) -> str:
@@ -612,28 +663,47 @@ class SlidingWindow(WindowStage):
         bsz = b.capacity
         w = self.w
         total = state["total"]
-        ring = {k: state[k] for k in ("cols", "ts", "wts", "seq")}
+        ring = {k: state[k] for k in ("cols", "ts", "wts", "seq") if k in state}
+        # under `vmap` over a partition's slots, each with a ring of its
+        # own (core/partition.py), the run is read and written by places
+        per_slot = flow.slot_rows
         with jax.named_scope("ring_emit"):
             new = _compact_front(
                 valid_cur, {"cols": dict(b.cols), "ts": b.ts, "wts": bwts}
             )
             new["seq"] = total + jnp.arange(bsz, dtype=jnp.int64)
             # the long lanes' new rows as halves, like the ring holds them
-            new_rows = _split_like(ring, new)
+            if self.held_cols is None:
+                new_rows = _split_like(ring, new)
+            else:
+                new_rows = _split_like(ring, {
+                    "cols": {n: new["cols"][n] for n in ring["cols"]}
+                })
 
-            # the run [start, start + B) mod W as two slices: `tail` from
-            # s1 (start, held inside the lane) and the lane's first B rows;
-            # the run begins `off` rows into their concatenation
-            s1, off = _run_start(total, w, bsz)
-            tails = jax.tree_util.tree_map(
-                lambda lane: jax.lax.dynamic_slice(lane, (s1,), (bsz,)), ring
-            )
-            # the rows the run held: a long column's halves are joined
-            # here, B rows of them
-            expired = _join_pairs(jax.tree_util.tree_map(
-                lambda lane, tail: _run_read(lane, tail, off),
-                ring["cols"], tails["cols"],
-            ))
+            if per_slot:
+                # the run's places, rank by rank: read and written at once
+                ranks = jnp.arange(bsz, dtype=jnp.int32)
+                run = ((total % w).astype(jnp.int32) + ranks) % w
+                held, new_state = _ring_swap(per_slot)(
+                    ring, jnp.where(ranks < c, run, np.int32(w)), new_rows
+                )
+                expired = _join_pairs(held["cols"])
+            else:
+                # the run [start, start + B) mod W as two slices: `tail`
+                # from s1 (start, held inside the lane) and the lane's
+                # first B rows; the run begins `off` rows into their
+                # concatenation
+                s1, off = _run_start(total, w, bsz)
+                tails = jax.tree_util.tree_map(
+                    lambda lane: jax.lax.dynamic_slice(lane, (s1,), (bsz,)),
+                    ring,
+                )
+                # the rows the run held: a long column's halves are joined
+                # here, B rows of them
+                expired = _join_pairs(jax.tree_util.tree_map(
+                    lambda lane, tail: _run_read(lane, tail, off),
+                    ring["cols"], tails["cols"],
+                ))
 
             n0 = jnp.clip(w - total, 0, c).astype(jnp.int32)
             pos = jnp.arange(2 * bsz, dtype=jnp.int32)
@@ -642,12 +712,34 @@ class SlidingWindow(WindowStage):
             is_exp = out_valid & ~fill & ((pos - n0) % 2 == 0)
 
             def emit(expired, current):
+                if per_slot and bsz <= _PICK_ROWS:
+                    return emit_by_rank(expired, current)
                 pairs = jnp.stack([expired, current], axis=1).reshape(-1)
                 pairs = jax.lax.dynamic_slice(
                     jnp.pad(pairs, (0, bsz)), (n0,), (2 * bsz,)
                 )
                 lane = jnp.where(fill, jnp.pad(current, (0, bsz)), pairs)
                 return jnp.where(out_valid, lane, jnp.zeros((), lane.dtype))
+
+            def emit_by_rank(expired, current):
+                """The same lane where the step runs under `vmap` over
+                slots: a shift by a per-slot `n0` would lower on the chip
+                to a loop over the slots (7 ms a lane at 4,096 slots), so
+                each place picks its rank's row through a one-hot
+                [2B', B'] select, where sub-batches are short (many slots);
+                long ones (few slots) keep the shift."""
+                rank = jnp.where(fill, pos, n0 + (pos - n0) // 2)
+                hot = rank[:, None] == jnp.arange(bsz, dtype=jnp.int32)[None, :]
+                both = jnp.where(
+                    (is_exp[:, None] & hot)[None], _pick_bits(expired),
+                    _pick_bits(current),
+                )
+                lane = jnp.where(hot[None], both, 0).sum(
+                    axis=-1, dtype=jnp.int32)
+                return jnp.where(
+                    out_valid, _unpick_bits(lane, current.dtype),
+                    jnp.zeros((), current.dtype),
+                )
 
             out = EventBatch(
                 # an EXPIRED row carries its trigger row's ts: the CURRENT
@@ -658,24 +750,35 @@ class SlidingWindow(WindowStage):
                 ),
                 valid=out_valid,
                 cols={
-                    n: emit(expired[n], col) for n, col in new["cols"].items()
+                    # a column the ring does not hold is read of no
+                    # EXPIRED row (`held_cols`): its own stands in
+                    n: emit(expired.get(n, col), col)
+                    for n, col in new["cols"].items()
                 },
             )
-            _, _, E = self._length_positions(total, c, bsz)
-            member, member_env = self._length_member(
-                flow,
-                self._element_view(state, b, bwts, valid_cur, rank, c),
-                valid_cur, rank, c, E,
-            )
+            member = member_env = None
+            if self.held_cols is None:
+                _, _, E = self._length_positions(total, c, bsz)
+                member, member_env = self._length_member(
+                    flow,
+                    self._element_view(state, b, bwts, valid_cur, rank, c),
+                    valid_cur, rank, c, E,
+                )
+            cause = None
+            if flow.cause is not None:
+                # as the ts lane: an EXPIRED row takes its trigger's
+                by_rank = _compact_front(valid_cur, flow.cause)
+                cause = emit(by_rank, by_rank)
 
         with jax.named_scope("ring_update"):
-            written = (pos >= off) & (pos - off < c)
-            new_state = jax.tree_util.tree_map(
-                lambda lane, tail, vals: _run_write(
-                    lane, tail, vals, written, s1, off
-                ),
-                ring, tails, new_rows,
-            )
+            if not per_slot:
+                written = (pos >= off) & (pos - off < c)
+                new_state = jax.tree_util.tree_map(
+                    lambda lane, tail, vals: _run_write(
+                        lane, tail, vals, written, s1, off
+                    ),
+                    ring, tails, new_rows,
+                )
             new_state["total"] = total + c
         return new_state, Flow(
             batch=out,
@@ -686,6 +789,7 @@ class SlidingWindow(WindowStage):
             member_env=member_env,
             aux=dict(flow.aux),
             tables=flow.tables,
+            cause=cause,
         )
 
     # ---- the time-bounded step in O(batch): a FIFO, taken in passes ------
@@ -932,6 +1036,8 @@ class SlidingWindow(WindowStage):
         return mask, perm
 
     def view(self, state):
+        if self.held_cols is not None:
+            raise NotImplementedError("a ring that holds some columns only")
         mask, perm = self._view_perm(state)
         lanes = self.lanes({k: state[k] for k in ("cols", "ts")})
         cols = {n: c[perm] for n, c in lanes["cols"].items()}
@@ -940,6 +1046,26 @@ class SlidingWindow(WindowStage):
     def view_seq(self, state):
         _mask, perm = self._view_perm(state)
         return state["seq"].join()[perm]
+
+
+def _pick_bits(x):
+    """A [B] lane as [halves, 1, B] 32-bit integers, whatever its dtype: a
+    one-hot select-and-sum over them moves a row bit for bit."""
+    if _is_wide(x.dtype):
+        lo, hi = _split64(x)
+        return jnp.stack([lo.astype(jnp.int32), hi])[:, None, :]
+    if x.dtype == jnp.float32:
+        x = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return x.astype(jnp.int32)[None, None, :]
+
+
+def _unpick_bits(bits, dtype):
+    """`_pick_bits` undone on the picked [halves, K] rows."""
+    if _is_wide(dtype):
+        return _join64(bits[0].astype(jnp.uint32), bits[1], dtype)
+    if dtype == jnp.float32:
+        return jax.lax.bitcast_convert_type(bits[0], jnp.float32)
+    return bits[0].astype(dtype)
 
 
 def _run_start(seq, w: int, bsz: int):
@@ -1058,11 +1184,10 @@ def _merge_counts(a, b):
     )
 
 
-def _permute_tree(key, tree):
-    """Every lane of `tree` in the order that sorts `key`, whose values are
-    all different: payload sorts of at most six 32-bit lanes each (one sort
-    of many operands compiles for minutes, PERF.md PR 25), a 64-bit lane as
-    its halves and a bool lane as int32."""
+def _lanes32(tree):
+    """(`tree`'s lanes as operands a sort carries at full speed: a 64-bit
+    lane as its halves, a bool lane as int32; the function that makes the
+    tree again of such lanes)."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     parts = []
     for x in leaves:
@@ -1070,18 +1195,31 @@ def _permute_tree(key, tree):
             parts.extend(_split64(x))
         else:
             parts.append(x.astype(jnp.int32) if x.dtype == jnp.bool_ else x)
+
+    def rejoin(moved):
+        moved = iter(moved)
+        out = []
+        for x in leaves:
+            if _is_wide(x.dtype):
+                lo, hi = next(moved), next(moved)
+                out.append(_join64(lo, hi, x.dtype))
+            else:
+                out.append(next(moved).astype(x.dtype))
+        return jax.tree_util.tree_unflatten(treedef, out)
+
+    return parts, rejoin
+
+
+def _permute_tree(key, tree):
+    """Every lane of `tree` in the order that sorts `key`, whose values are
+    all different: payload sorts of at most six 32-bit lanes each (one sort
+    of many operands compiles for minutes, PERF.md PR 25), a 64-bit lane as
+    its halves and a bool lane as int32."""
+    parts, rejoin = _lanes32(tree)
     moved = []
     for i in range(0, len(parts), 6):
         moved.extend(_permute_by(key, *parts[i:i + 6]))
-    moved = iter(moved)
-    out = []
-    for x in leaves:
-        if _is_wide(x.dtype):
-            lo, hi = next(moved), next(moved)
-            out.append(_join64(lo, hi, x.dtype))
-        else:
-            out.append(next(moved).astype(x.dtype))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    return rejoin(moved)
 
 
 @jax.jit
@@ -1541,15 +1679,18 @@ def make_window(
     ref: str,
     scope: Scope,
     time_capacity: Optional[int] = None,
+    held_cols=None,
 ) -> WindowStage:
     """Reference: SingleInputStreamParser.generateProcessor window dispatch.
     `time_capacity`: the rows a time-bounded ring or bucket holds
-    (`@app:timeCapacity`; None = the default)."""
+    (`@app:timeCapacity`; None = the default). `held_cols`: the columns a
+    `length` window's ring holds where its caller reads no other of an
+    EXPIRED row (`SlidingWindow.held_cols`; None = all)."""
     time_capacity = time_capacity or DEFAULT_TIME_CAPACITY
     name = spec.name.lower() if spec.namespace is None else f"{spec.namespace}:{spec.name}"
     if name == "length":
         n = _const_param(spec, 0, "length")
-        return SlidingWindow(schema, ref, capacity=n)
+        return SlidingWindow(schema, ref, capacity=n, held_cols=held_cols)
     if name == "time":
         t = _const_param(spec, 0, "duration")
         return SlidingWindow(

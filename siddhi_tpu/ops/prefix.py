@@ -49,6 +49,24 @@ def window_mask(reset: jnp.ndarray) -> jnp.ndarray:
     return (idx[None, :] <= idx[:, None]) & (idx[None, :] > lr[:, None])
 
 
+# the longest lane read through a one-hot select instead of a gather
+PICK_ROWS = 256
+
+
+def take_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """`x[idx]` for a [B] lane. A short lane is read through a one-hot
+    [B, B] select, on the vector units: under `vmap` over partition slots
+    (core/partition.py steps [P, B'] sub-batches of a few dozen rows) a
+    gather lowers to a batched gather that the chip takes 2.7 ms for at
+    4,096 slots x 64 rows, as long as for one lane of 262,144 rows."""
+    n = x.shape[0]
+    if n > PICK_ROWS:
+        return x[idx]
+    hot = idx[:, None] == jnp.arange(n, dtype=idx.dtype)[None, :]
+    return jnp.where(hot, x[None, :], jnp.zeros((), x.dtype)).sum(
+        axis=1, dtype=x.dtype)
+
+
 def running_sum(
     contrib: jnp.ndarray, reset: jnp.ndarray, base: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -62,7 +80,9 @@ def running_sum(
     """
     csum = cumsum(contrib)
     lr = last_reset_index(reset)
-    at_lr = jnp.where(lr >= 0, csum[jnp.clip(lr, 0)], jnp.zeros_like(csum[0]))
+    at_lr = jnp.where(
+        lr >= 0, take_rows(csum, jnp.clip(lr, 0)), jnp.zeros_like(csum[0])
+    )
     run = csum - at_lr
     no_reset_yet = lr < 0
     run = run + jnp.where(no_reset_yet, base, jnp.zeros_like(base))
@@ -272,3 +292,33 @@ def compact_front(valid: jnp.ndarray, lanes):
         valid = comes | (valid & ~goes)
         step *= 2
     return lanes
+
+
+def spread_back(valid: jnp.ndarray, way: jnp.ndarray, lanes):
+    """`compact_front` the other way round: every `valid` row of the pytree's
+    [N] lanes moves `way` places towards the end, in row order; returns
+    (which places hold a row now, the lanes). What lies in the other places
+    is unspecified.
+
+    `way` must not shrink from one valid row to the next, and the last row
+    must stay inside the lanes. Then the rows can take their way one binary
+    digit at a time, highest first: cut to its high digits the way still
+    never shrinks along the rows, so their order holds after every pass and
+    no two meet. log2(N) passes of a shifted read and a select, as there."""
+    n = valid.shape[0]
+
+    def behind(x, step):
+        return jnp.concatenate([jnp.zeros((step,), x.dtype), x[:-step]])
+
+    step = 1
+    while step * 2 < n:
+        step *= 2
+    while step >= 1:
+        goes = valid & ((way & step) != 0)
+        comes = behind(goes, step)
+        lanes, way = jax.tree_util.tree_map(
+            lambda x: jnp.where(comes, behind(x, step), x), (lanes, way)
+        )
+        valid = comes | (valid & ~goes)
+        step //= 2
+    return valid, lanes

@@ -14,6 +14,7 @@ should be reformulated (sort + searchsorted) instead.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +107,80 @@ def split_like(like, tree):
         lambda held, x: U32Pair.split(x) if is_pair(held) else x,
         like, tree, is_leaf=is_pair,
     )
+
+
+@functools.lru_cache(maxsize=None)
+def ring_swap(live_bound: int):
+    """`swap(lanes, at, vals) -> (old, lanes')` for a pytree of [W] ring
+    lanes: the rows they held at the [S] places `at`, and the lanes with
+    `vals` ([S] each) written there. A place of W or more is not touched
+    and reads as unspecified; no place occurs twice.
+
+    Under `vmap` over partition slots ([P, W] lanes, each slot its own
+    places) the swap stays element gathers and scatters by (slot, place),
+    and of the live places alone: the chip's scalar core takes a gather or
+    a scatter one index at a time, dropped ones included, so the live rows, at most `live_bound` over all slots (the rows of the
+    batch the slots' sub-batches were routed from), are first moved to the
+    front (`compact_front`), and what was read is moved back to its place
+    (`spread_back`). A slice at a per-slot offset (`dynamic_slice` under
+    `vmap`) lowers on the chip to a loop over the slots and a transpose of
+    the whole [P, W] lane."""
+    from siddhi_tpu.ops.prefix import compact_front, spread_back
+
+    tmap = jax.tree_util.tree_map
+
+    @jax.custom_batching.custom_vmap
+    def swap(lanes, at, vals):
+        w = jax.tree_util.tree_leaves(lanes)[0].shape[0]
+        read = jnp.clip(at, 0, w - 1)
+        return (
+            tmap(lambda lane: lane[read], lanes),
+            tmap(lambda lane, v: lane.at[at].set(
+                v, mode="drop", unique_indices=True), lanes, vals),
+        )
+
+    @swap.def_vmap
+    def swap_slots(axis_size, in_batched, lanes, at, vals):
+        lanes, at, vals = (
+            tmap(lambda x, b: x if b else jnp.broadcast_to(
+                x, (axis_size, *x.shape)), tree, batched)
+            for tree, batched in zip((lanes, at, vals), in_batched)
+        )
+        p, s = at.shape
+        w = jax.tree_util.tree_leaves(lanes)[0].shape[1]
+        row = jnp.broadcast_to(
+            jnp.arange(p, dtype=jnp.int32)[:, None], (p, s))
+        n_all = p * s
+        if n_all <= live_bound:
+            read = jnp.clip(at, 0, w - 1)
+            old = tmap(lambda lane: lane[row, read], lanes)
+            new = tmap(lambda lane, v: lane.at[row, at].set(
+                v, mode="drop", unique_indices=True), lanes, vals)
+        else:
+            flat = lambda x: x.reshape(-1)  # noqa: E731
+            live = flat(at) < w
+            front = compact_front(live, {
+                "row": flat(row), "at": flat(at),
+                "src": jnp.arange(n_all, dtype=jnp.int32),
+                "vals": tmap(flat, vals),
+            })
+            front = tmap(lambda x: x[:live_bound], front)
+            k = jnp.arange(live_bound, dtype=jnp.int32)
+            ok = k < live.sum(dtype=jnp.int32)
+            r, a = front["row"], jnp.where(ok, front["at"], np.int32(w))
+            read = jnp.clip(a, 0, w - 1)
+            old = tmap(lambda lane: lane[r, read], lanes)
+            new = tmap(lambda lane, v: lane.at[r, a].set(
+                v, mode="drop", unique_indices=True), lanes, front["vals"])
+            # what was read, back at the place it was asked from
+            wide = lambda x: jnp.pad(x, (0, n_all - live_bound))  # noqa: E731
+            _, old = spread_back(
+                wide(ok), wide(front["src"] - k), tmap(wide, old))
+            old = tmap(lambda x: x.reshape(p, s), old)
+        batched = lambda tree: tmap(lambda _: True, tree)  # noqa: E731
+        return (old, new), (batched(old), batched(new))
+
+    return swap
 
 
 def set_at(dst: jnp.ndarray, idx: jnp.ndarray, src: jnp.ndarray, *, mode: str = "drop") -> jnp.ndarray:

@@ -75,21 +75,21 @@ def shard_partitioned_query(
     """Jit a PartitionedQueryRuntime's outer step with its [P] partition axis
     sharded over `mesh`.
 
-    routed=True (default): the BATCH AXIS is sharded too. A replicated
-    routing pre-pass (key extraction + slot assignment over the small [B]
-    batch) computes each event's owning device by STRIPING slots across the
-    mesh — device = slot % D, local state row = slot // D, so the first D
-    live keys land on D different chips instead of filling device 0's block
-    first — packs per-device sub-batches [D, B] sharded on the mesh axis,
-    and a shard_map advances each device's LOCAL partition slice against
-    only its own events — each chip decodes B rows, not D*B (the TPU-native
+    routed=True (default): a replicated pre-pass (key extraction + slot
+    assignment over the small [B] batch) gives each event its slot, slots
+    are STRIPED across the mesh — device = slot % D, local state row =
+    slot // D, so the first D live keys land on D different chips instead
+    of filling device 0's block first — and a shard_map has each device
+    run the runtime's own routed step (`PartitionedQueryRuntime._routed`:
+    its rows laid out as [P/D, B'] sub-batches, in passes, TIMER rows to
+    every slot) over its LOCAL slots and its own events (the TPU-native
     analog of the reference's per-key routing,
-    PartitionStreamReceiver.java:81-140).
-    Timer rows are broadcast to every device, interleaved at their original
-    row positions so time-driven operators fire in the unsharded order.
+    PartitionStreamReceiver.java:81-140). The devices' flat outputs are
+    merged into one flat batch in arrival order, as the unsharded step's.
 
-    routed=False replicates the batch to every device (the r3 behavior;
-    correctness baseline).
+    routed=False hands the whole step to the partitioner with the state's
+    [P] axis on the mesh and everything else replicated (what
+    parallel/shard.py `apply_partition_mesh` deploys).
 
     The partition capacity (@app:partitionCapacity) must be divisible by the
     mesh size so every device holds an equal slice of partition slots.
@@ -117,10 +117,21 @@ def shard_partitioned_query(
         repl,
     )
     if not routed:
+        def replicated_step(ptable, states, batch, now):
+            # the runtime's own step (rows routed to their slot's [P, B']
+            # sub-batch, core/partition.py) with the state's [P] axis on
+            # the mesh; its output is the merged flat batch
+            counters = {"extra_passes": jnp.zeros((), jnp.int64),
+                        "max_rows": jnp.zeros((), jnp.int32)}
+            ptable, states, _, flat, _slot, aux = qr._pstep_outer_impl(
+                ptable, states, counters, batch, now
+            )
+            return ptable, states, flat, aux
+
         fn = jax.jit(
-            qr._pstep_outer_impl,
+            replicated_step,
             in_shardings=(repl, shard, repl, repl),
-            out_shardings=(repl, shard, shard, repl),
+            out_shardings=(repl, shard, repl, repl),
         )
         return ShardedPartitionedQuery(qr, mesh, axis, fn, ptable0, state0)
 
@@ -137,19 +148,15 @@ def _make_routed_step(qr, mesh, axis: str, n_dev: int):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from siddhi_tpu.core.event import (
-        EventBatch,
-        KIND_CURRENT,
-        KIND_TIMER,
-    )
+    from siddhi_tpu.core.event import EventBatch, KIND_CURRENT
     from siddhi_tpu.core.executor import Env, TS_ATTR
+    from siddhi_tpu.core.partition import BIG, _in_order
     from siddhi_tpu.ops.group import assign_slots
 
     D = n_dev
     PL = qr.p // D  # local partition slots per device
 
     def routed_step(ptable, states, batch: EventBatch, now):
-        B = batch.ts.shape[0]
         cols = {(qr.ref, None, n): c for n, c in batch.cols.items()}
         cols[(qr.ref, None, TS_ATTR)] = batch.ts
         env = Env(cols, now=now)
@@ -158,92 +165,55 @@ def _make_routed_step(qr, mesh, axis: str, n_dev: int):
         pk, pu, pn, slot, _grp, povf = assign_slots(
             ptable["keys"], ptable["used"], ptable["n"], keys, active
         )
-        is_timer = batch.valid & (batch.kind == KIND_TIMER)
+        active = active & (slot < qr.p_logical)
 
-        # ---- route the batch axis: device d owns slots {s : s % D == d}
-        # (STRIPED, not blocked — first-seen slot allocation hands out low
-        # slot numbers first, so a block map slot//PL leaves high devices
-        # idle until >PL live keys exist; striping spreads the first D keys
-        # across all D devices, the analog of key-hash routing in the
-        # reference's PartitionStreamReceiver.java:81-140). Slot s's state
-        # lives at block-sharded state row (s % D)*PL + s//D, i.e. device
-        # s % D, local row s // D.
-        # Each device's sub-batch = its own active rows UNION all timer rows,
-        # kept in ORIGINAL row order (a [D, B] mask + per-row cumsum), so
-        # timer-driven operators see timers interleaved exactly as the
-        # unsharded path does. |actives_d ∪ timers| <= B always, so the
-        # sub-batch capacity B can never overflow.
-        idx = jnp.arange(B, dtype=jnp.int32)
-        dev_of = jnp.where(active & (slot < qr.p), slot % D, D)
-        take = (dev_of[None, :] == jnp.arange(D)[:, None]) | is_timer[None, :]
-        rank = jnp.cumsum(take.astype(jnp.int32), axis=1) - 1  # [D, B]
-        dst = jnp.where(take, jnp.arange(D)[:, None] * B + rank, D * B)
-        routed = (
-            jnp.full((D * B,), B, jnp.int32)
-            .at[dst.reshape(-1)]
-            .set(jnp.broadcast_to(idx[None, :], (D, B)).reshape(-1),
-                 mode="drop")
-            .reshape(D, B)
-        )
-        pad = routed >= B
-        ri = jnp.clip(routed, 0, B - 1)
-
-        def lane(x, fill=0):
-            return jnp.where(pad, np.asarray(fill, x.dtype), x[ri])
-
-        r_ts = lane(batch.ts)
-        r_kind = lane(batch.kind)
-        r_valid = ~pad
-        r_cols = {n: lane(c) for n, c in batch.cols.items()}
-        r_slot = lane(jnp.where(active, slot, qr.p), fill=qr.p)
-
-        # ---- per-device local advance over its own sub-batch
-        def local(states_sl, ts_sl, kind_sl, valid_sl, cols_sl, slot_sl, now_):
+        # device d owns slots {s : s % D == d} (STRIPED, not blocked —
+        # first-seen slot allocation hands out low slot numbers first, so a
+        # block map slot // PL leaves high devices idle until > PL live keys
+        # exist). Slot s's state lives at block-sharded state row
+        # (s % D) * PL + s // D, i.e. device s % D, local row s // D. Every
+        # device is handed the whole batch and routes its own rows (and the
+        # TIMER rows, which reach every slot) to its local slots' sub-
+        # batches: the same [B'] rows a slot is sent by the unsharded step.
+        def local(states_sl, batch_, slot_, active_, now_):
             d = lax.axis_index(axis)
-            ts1 = ts_sl[0]
-            kind1 = kind_sl[0]
-            valid1 = valid_sl[0]
-            cols1 = {n: c[0] for n, c in cols_sl.items()}
-            slot1 = slot_sl[0]
-            is_t = valid1 & (kind1 == KIND_TIMER)
-
-            def one(state, p_local):
-                gp = p_local * D + d
-                v = (valid1 & (slot1 == gp)) | is_t
-                b2 = EventBatch(ts1, kind1, v, cols1)
-                st, _ts, out, aux = qr._step_impl(state, {}, b2, now_)
-                return st, out, aux
-
-            states2, outs, auxs = jax.vmap(one)(
-                states_sl, jnp.arange(PL)
+            counters = {"extra_passes": jnp.zeros((), jnp.int64),
+                        "max_rows": jnp.zeros((), jnp.int32)}
+            states2, _, flat, lslot, cause, aux = qr._routed(
+                states_sl, counters, batch_, slot_ // D,
+                active_ & (slot_ % D == d), now_, p=PL,
             )
             aux_red = {
-                k: lax.psum(
-                    jnp.asarray(v).astype(jnp.int32).sum(), axis
-                )
-                > 0
-                for k, v in auxs.items()
-                if k != "next_timer"
+                k: lax.psum(jnp.asarray(v).astype(jnp.int32), axis) > 0
+                for k, v in aux.items() if k != "next_timer"
             }
-            if "next_timer" in auxs:
-                aux_red["next_timer"] = lax.pmin(
-                    jnp.min(auxs["next_timer"]), axis
-                )
-            return states2, outs, aux_red
+            if "next_timer" in aux:
+                aux_red["next_timer"] = lax.pmin(aux["next_timer"], axis)
+            emitted = flat.valid.sum(dtype=jnp.int32)[None]
+            return states2, (flat, lslot * D + d, cause, emitted), aux_red
 
-        local_sharded = jax.shard_map(
+        states2, (flat, gslot, cause, emitted), aux = jax.shard_map(
             local,
             mesh=mesh,
-            in_specs=(
-                P(axis), P(axis), P(axis), P(axis), P(axis), P(axis), P()
-            ),
+            in_specs=(P(axis), P(), P(), P(), P()),
             out_specs=(P(axis), P(axis), P()),
             check_vma=False,
+        )(states, batch, slot, active, now)
+        # the devices' flat outputs, each in arrival order, as one: by the
+        # input row that caused each (every slot's answers to one TIMER row
+        # by slot), cut to what the unsharded step's flat output holds
+        rows = qr._flat_rows(
+            batch.capacity, qr.p, flat.valid.shape[0] // D // PL)
+        key, lanes, lost = _in_order(
+            jnp.where(flat.valid, cause, BIG),
+            {"ts": flat.ts, "kind": flat.kind, "cols": flat.cols},
+            rows, gslot,
         )
-        states2, outs, aux = local_sharded(
-            states, r_ts, r_kind, r_valid, r_cols, r_slot, now
-        )
+        outs = EventBatch(lanes["ts"], lanes["kind"], key != BIG, lanes["cols"])
         aux = dict(aux)
+        # [D]: the rows each device's slots emitted in this step
+        aux["emitted_per_device"] = emitted
+        aux["window_overflow"] = aux.get("window_overflow", False) | lost
         aux["partition_overflow"] = (
             jnp.asarray(aux.get("partition_overflow", False)) | povf
         )

@@ -447,8 +447,8 @@ def apply_partition_mesh(app_runtime, devices) -> dict:
     """Place every plain `PartitionedQueryRuntime`'s `[P]` state axis on a
     mesh over `devices`, swapping the runtime's outer jitted step for one
     with explicit in/out shardings (the replicated-batch mode: each device
-    advances only its own partition slots; emission positions — and so
-    delivery order — are bit-identical to the unsharded vmap). Returns
+    advances only its own partition slots; the merged flat output — and so
+    delivery order — is bit-identical to the unsharded step's). Returns
     qid -> placement info for `/status.json` and explain()."""
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -505,10 +505,14 @@ def apply_partition_mesh(app_runtime, devices) -> dict:
             # largest tensor set in the system and must update in place
             # (the first call's host-built state isn't donatable — one
             # ignorable warning — every later call donates sharded buffers)
+            # (ptable, states, route counters, batch, now) ->
+            # (ptable, states, route counters, flat output, its slots, aux):
+            # the routed [P, B'] sub-batches and the [P, K'] emissions
+            # follow the state's sharding inside the program
             qr._pstep_outer = jax.jit(
                 qr._pstep_outer_impl,
-                in_shardings=(repl, shard, repl, repl),
-                out_shardings=(repl, shard, shard, repl),
+                in_shardings=(repl, shard, repl, repl, repl),
+                out_shardings=(repl, shard, repl, repl, repl, repl),
                 donate_argnums=(1,),
             )
             placed[qid] = {

@@ -23,6 +23,8 @@ import json
 import os
 import contextlib
 
+import jax
+import numpy as np
 import pytest
 
 from siddhi_tpu import SiddhiManager
@@ -203,6 +205,91 @@ class TestPlannerSemantics:
         }
         assert ("partition0_query0", "partition") in hazards
         assert any(d.code == "SA124" for d in r.warnings)
+
+    def test_partitioned_query_is_sized_by_slots_and_sub_batches(self):
+        """A partitioned query's state is P x what a slot holds, its flow
+        P x B' rows where the step routes rows to their slot and P x B
+        where every slot steps the whole batch under a mask. A slot of a
+        query that publishes CURRENT rows alone, its `length` window the
+        chain's last stage, holds the aggregated columns and no ordering
+        lane, and its B' is no longer than the window."""
+        text = """
+        @app:batch(size='4096')
+        @app:partitionCapacity(size='256')
+        define stream S (k int, v float);
+        define stream T (k int, w float);
+        {body}
+        """
+        inner = ("from S#window.length(50) select k, avg(v) as a "
+                 "insert {events} into Out;")
+        (one,) = compute_costs(
+            text.format(body=inner.format(events="all events"))).queries.values()
+        part = compute_costs(text.format(body=f"""
+        partition with (k of S, k of T) begin
+        @info(name='all') {inner.format(events="all events")}
+        @info(name='cur') {inner.format(events="")}
+        @info(name='behind')
+        from S#window.length(50)[k > 0] select k, avg(v) as a insert into Out3;
+        @info(name='j') from S#window.length(2) join T#window.length(2)
+        on S.k == T.k select S.k, T.w insert into Out2;
+        end;""")).queries
+        assert one.partition is None
+        ring = 50 * (4 + 4 + 3 * 8)  # k, v and the ts / wts / seq lanes
+        (win,) = [o for o in one.operators if o.op.startswith("window")]
+        assert win.state_bytes == ring
+        for q in ("all", "behind"):  # the whole ring in every slot
+            assert part[q].state_bytes == 256 * one.state_bytes
+            assert part[q].partition == {
+                "capacity": 256, "step": "routed", "sub_batch": 64,
+                "flow_rows": 256 * 64}
+        # `v` alone, and sub-batches of the window's 50 rows
+        assert part["cur"].state_bytes == 256 * (
+            one.state_bytes - ring + 50 * 4)
+        assert part["cur"].partition == {
+            "capacity": 256, "step": "routed", "sub_batch": 50,
+            "flow_rows": 256 * 50}
+        assert part["j"].partition == {
+            "capacity": 256, "step": "masked", "sub_batch": 4096,
+            "flow_rows": 256 * 4096}
+        assert part["all"].to_dict()["partition"]["flow_rows"] == 16384
+
+    @pytest.mark.parametrize("events,behind,held,sub", [
+        ("", "", ["v"], 50), ("all events", "", None, 64),
+        ("", "[k > 0]", None, 64)])
+    def test_partition_cost_follows_the_deployed_ring(
+            self, events, behind, held, sub):
+        """What the cost model says of a partitioned query's ring and B'
+        is what the deployed runtime holds and traces."""
+        text = f"""
+        @app:batch(size='4096')
+        @app:partitionCapacity(size='256')
+        define stream S (k int, v float);
+        partition with (k of S) begin
+        @info(name='q') from S#window.length(50){behind}
+        select k, avg(v) as a insert {events} into Out;
+        end;"""
+        cost = compute_costs(text).queries["q"]
+        mgr = SiddhiManager()
+        rt = mgr.create_siddhi_app_runtime(text)
+        try:
+            rt.start()
+            rt.get_input_handler("S").send_columns(
+                np.arange(8, dtype=np.int64),
+                {"k": np.arange(8, dtype=np.int32) % 3,
+                 "v": np.ones(8, np.float32)})
+            status = rt.snapshot_status()["queries"]["q"]
+            assert status["window"].get("held_cols") == held
+            assert status["partition"]["sub_batch"] == sub
+            assert cost.partition["sub_batch"] == sub
+            ring = rt.queries["q"].state["chain"]
+            ring = {k: v for k, v in ring.items() if k != "total"}
+            held_bytes = sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(ring))
+            (win,) = [o for o in cost.operators if o.op.startswith("window")]
+            assert 256 * win.state_bytes == held_bytes
+        finally:
+            rt.shutdown()
+            mgr.shutdown()
 
     def test_ordering_hazard_intra_group_chain(self):
         plan = build_fusion_plan("""

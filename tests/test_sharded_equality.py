@@ -81,9 +81,13 @@ def _run(qr, mgr, sharded: bool, feed):
             "used": jnp.zeros((qr.p,), jnp.bool_),
             "n": jnp.zeros((), jnp.int32),
         }
+        counters = {"extra_passes": jnp.zeros((), jnp.int64),
+                    "max_rows": jnp.zeros((), jnp.int32)}
 
-        def step(batch, now, _box=[ptable, state]):
-            _box[0], _box[1], outs, aux = fn(_box[0], _box[1], batch, np.int64(now))
+        def step(batch, now, _box=[ptable, state, counters]):
+            # the routed step: its output is the merged flat batch
+            _box[0], _box[1], _box[2], outs, _slot, aux = fn(
+                _box[0], _box[1], _box[2], batch, np.int64(now))
             return outs, aux
 
     rows = []
