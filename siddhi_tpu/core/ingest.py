@@ -87,6 +87,41 @@ class _RebuildFailed(Exception):
         self.cause = cause
 
 
+def _front_words(dv, lanes: dict) -> dict:
+    """{lane: its words} of one micro-batch's deliver lanes with the `dv`
+    rows moved to the front in row order (`compact_front`): a 64-bit lane as
+    its two 32-bit halves, low first (the chip has no 64-bit moves; the order
+    is the lane's bytes on the host), a bool as u8, every other lane as
+    itself. Lanes narrower than 32 bits make the moves as int32."""
+    from siddhi_tpu.ops.prefix import compact_front
+    from siddhi_tpu.ops.scatter import U32Pair
+
+    words = {}
+    for name, lane in lanes.items():
+        if lane.dtype == jnp.bool_:
+            lane = lane.astype(jnp.uint8)
+        if lane.dtype.itemsize == 8:
+            pair = U32Pair.split(lane)
+            words[name] = (pair.lo, pair.hi)
+        else:
+            words[name] = (lane,)
+
+    def narrow(x) -> bool:
+        return x.dtype.itemsize < 4 and jnp.issubdtype(x.dtype, jnp.integer)
+
+    tmap = jax.tree_util.tree_map
+    front = compact_front(
+        dv, tmap(lambda x: x.astype(jnp.int32) if narrow(x) else x, words)
+    )
+    return tmap(lambda f, x: f.astype(x.dtype), front, words)
+
+
+def _as_bytes(word) -> jnp.ndarray:
+    """[R, itemsize] u8: the bytes of a packed [R] word, row by row."""
+    u8 = lax.bitcast_convert_type(word, jnp.uint8)
+    return u8[:, None] if u8.ndim == 1 else u8
+
+
 def _needs_scheduler(qr) -> bool:
     ns = getattr(qr, "needs_scheduler", False)
     if isinstance(ns, dict):
@@ -234,6 +269,9 @@ class FusedJunctionIngest:
             # builder has made
             "decode": "python" if event_builder() is None else "native",
             "decode_native_rows": self.decode_native_rows,
+            # how the chunk program places delivered rows in its packed
+            # buffer: each micro-batch's as one run (`_build`, deliver_pack)
+            "pack": "slice",
         }
         gr = self.group_report()
         if gr is not None:
@@ -484,11 +522,11 @@ class FusedJunctionIngest:
                         if k.startswith("__lin")
                     })
                     if deliver and ei in deliver_set:
-                        # ship the raw lanes + a deliverable-row mask; the
-                        # post-scan pack compacts ALL K iterations with one
-                        # cumsum + scatter (per-iteration argsort compaction
-                        # measured ~2x slower). Kind-filter device-side when
-                        # the query emits only one kind.
+                        # this micro-batch's deliverable rows move to the
+                        # front here and are written behind the scan as one
+                        # run: 2.8 ms a chunk of 32 x 65,536 rows where the
+                        # chunk-wide scatter took 78 (PERF.md, PR 33). Kind-
+                        # filter device-side when the query emits one kind.
                         from siddhi_tpu.core.event import (
                             KIND_CURRENT as _KC,
                             KIND_EXPIRED as _KE,
@@ -517,7 +555,11 @@ class FusedJunctionIngest:
                                 if n in out_names[ei]
                             }
                         )
-                        outs.append((lanes, dv))
+                        with jax.named_scope("deliver_pack"):
+                            outs.append((
+                                _front_words(dv, lanes),
+                                dv.sum(dtype=jnp.int32),
+                            ))
                 return (
                     ((tuple(new_states), tuple(new_shr)), tst),
                     (tuple(auxes), tuple(lins), tuple(outs)),
@@ -539,40 +581,55 @@ class FusedJunctionIngest:
             # ROW-MAJOR byte buffer [R, row_bytes]: the host drains exactly
             # the filled row prefix with a single contiguous slice transfer
             # (per-lane buffers would need one transfer each)
-            from siddhi_tpu.ops.scatter import set_at
-
             packs = []
             with jax.named_scope("deliver_pack"):
-                for stacked, dv in out_stack:
-                    K = dv.shape[0]  # shape-driven: one traced fn serves any K
-                    cap = dv.shape[1]
-                    R = K * cap
-                    flat = dv.reshape(R)  # [K, cap] row-major = arrival order
-                    rank = jnp.cumsum(flat.astype(jnp.int32)) - flat.astype(
-                        jnp.int32
+                for stacked, cnt in out_stack:
+                    # micro-batch k's `cap` rows go as one run to the sum of
+                    # the counts before it, in order: a run overwrites what
+                    # lay behind the delivered rows of the one before, and
+                    # ends inside R (its start is at most k * cap). Rows
+                    # past the chunk's total are unspecified.
+                    K = cnt.shape[0]  # shape-driven: one traced fn serves any K
+                    start = jnp.cumsum(cnt) - cnt
+
+                    def put(k, bufs):
+                        return jax.tree_util.tree_map(
+                            lambda buf, runs: lax.dynamic_update_slice(
+                                buf,
+                                lax.dynamic_index_in_dim(
+                                    runs, k, keepdims=False
+                                ),
+                                (start[k],),
+                            ),
+                            bufs, stacked,
+                        )
+
+                    packed = lax.fori_loop(
+                        0, K, put,
+                        jax.tree_util.tree_map(
+                            lambda runs: jnp.zeros((runs.size,), runs.dtype),
+                            stacked,
+                        ),
                     )
-                    dst = jnp.where(flat, rank, R)
-                    segs = []
-                    for name in sorted(stacked):
-                        arr = stacked[name].reshape(R)
-                        if arr.dtype == jnp.bool_:
-                            arr = arr.astype(jnp.uint8)
-                        packed = set_at(jnp.zeros((R,), arr.dtype), dst, arr)
-                        u8 = jax.lax.bitcast_convert_type(packed, jnp.uint8)
-                        if u8.ndim == 1:  # already byte-wide lanes
-                            u8 = u8[:, None]
-                        segs.append(u8)
-                    data_buf = jnp.concatenate(segs, axis=1)
+                    data_buf = jnp.concatenate(
+                        [
+                            _as_bytes(word)
+                            for name in sorted(packed)
+                            for word in packed[name]
+                        ],
+                        axis=1,
+                    )
                     W = data_buf.shape[1]
                     # header rows carry the per-iteration counts INSIDE the
                     # buffer: the steady-state drain is then ONE d2h transfer
                     # (each is a blocking host round trip of its own)
                     cnt_u8 = jax.lax.bitcast_convert_type(
-                        dv.sum(axis=1, dtype=jnp.int32), jnp.uint8
+                        cnt, jnp.uint8
                     ).reshape(-1)  # [4K]
                     hdr_rows = -(-cnt_u8.shape[0] // W)
-                    hdr = jnp.zeros((hdr_rows * W,), jnp.uint8)
-                    hdr = hdr.at[: cnt_u8.shape[0]].set(cnt_u8).reshape(hdr_rows, W)
+                    hdr = jnp.pad(
+                        cnt_u8, (0, hdr_rows * W - cnt_u8.shape[0])
+                    ).reshape(hdr_rows, W)
                     packs.append(
                         {"buf": jnp.concatenate([hdr, data_buf], axis=0)}
                     )

@@ -104,10 +104,56 @@ DELIVER_CASES = {
     "all_events_cb": HEAD
     + """@info(name='q') from S#window.length(8)
         select symbol, price insert all events into Out;""",
+    # the deliver pack (core/ingest.py `_build`, deliver_pack): each
+    # micro-batch's delivered rows moved to the front, then written as one run
+    # behind the rows of the micro-batches before it
+    "every_row_cb": HEAD
+    + "@info(name='q') from S select symbol, price, volume insert into Out;",
+    "expired_only_cb": HEAD
+    + """@info(name='q') from S#window.length(8)
+        select symbol, price insert expired events into Out;""",
+}
+FILTER_CB = DELIVER_CASES["filter_cb"]
+WIDE_LANES_CB = """@app:batch(size='64')
+    define stream S (symbol string, price double, volume long);
+    @info(name='q') from S[volume != 0]
+    select volume, price, price > 0.0 as up, symbol insert into Out;"""
+# (app, rows sent, the least rows delivered, what the feed's prices become)
+DELIVER_FEEDS = {
+    name: (ql, 64 * 40, 50, None) for name, ql in DELIVER_CASES.items()
 }
 
 
-def _run_cb(ql, n, fused: bool):
+def _none_in_batch_5(cols):
+    cols["price"][64 * 5 : 64 * 6] = 1.0
+
+
+def _last_batch_alone(cols):
+    cols["price"][: 64 * 39] = 1.0
+    cols["price"][64 * 39 :] = 99.0
+
+
+def _both_halves(cols):
+    # 64-bit lanes whose high and low words both carry bits: longs beyond
+    # 2**32 of either sign, doubles of either sign
+    n = len(cols["volume"])
+    rng = np.random.default_rng(7)
+    cols["volume"] = rng.integers(-(2**62), 2**62, size=n).astype(np.int64)
+    cols["volume"][::7] = np.int64(2**32 + 1)
+    cols["price"] = rng.uniform(-1e18, 1e18, size=n).astype(np.float64)
+
+
+DELIVER_FEEDS.update({
+    "empty_mid_batch_cb": (FILTER_CB, 64 * 40, 50, _none_in_batch_5),
+    "last_batch_alone_cb": (FILTER_CB, 64 * 40, 64, _last_batch_alone),
+    # a send that takes the K = 2 and the K = 4 variant of the program
+    "short_tail_k2_cb": (FILTER_CB, 64 * 2, 20, None),
+    "short_tail_k4_cb": (FILTER_CB, 64 * 3, 30, None),
+    "wide_lanes_cb": (WIDE_LANES_CB, 64 * 40, 50, _both_halves),
+})
+
+
+def _run_cb(ql, n, fused: bool, alter=None):
     mgr = SiddhiManager()
     rt = mgr.create_siddhi_app_runtime(ql)
     got = []
@@ -116,33 +162,72 @@ def _run_cb(ql, n, fused: bool):
         lambda ts, ins, rem: got.append(
             (
                 ts,
-                [tuple(e.data) for e in (ins or [])],
-                [tuple(e.data) for e in (rem or [])],
+                [(e.timestamp, *e.data) for e in (ins or [])],
+                [(e.timestamp, *e.data) for e in (rem or [])],
             )
         ),
     )
     for s in ["A", "B", "C", "D"]:
         mgr.interner.intern(s)
     rt.start()
+    engine = rt.junctions["S"].fused_ingest
     if not fused:
         for j in rt.junctions.values():
             j.fused_ingest = None
     else:
-        assert rt.junctions["S"].fused_ingest is not None
+        assert engine is not None
     ts, cols = _feed(n)
+    if alter is not None:
+        alter(cols)
     rt.get_input_handler("S").send_columns(ts, cols)
+    if fused:
+        assert engine.events_fused == n  # the whole send took the fused path
     rt.shutdown()
     mgr.shutdown()
     return got
 
 
-@pytest.mark.parametrize("name", sorted(DELIVER_CASES))
+@pytest.mark.parametrize("name", sorted(DELIVER_FEEDS))
 def test_fused_delivery_matches_per_batch(name):
     """Query callbacks on the fused path: identical events, identical
     per-micro-batch grouping, identical order."""
-    ql = DELIVER_CASES[name]
-    n = 64 * 40
-    fused = _run_cb(ql, n, fused=True)
-    per_batch = _run_cb(ql, n, fused=False)
+    ql, n, least, alter = DELIVER_FEEDS[name]
+    fused = _run_cb(ql, n, fused=True, alter=alter)
+    per_batch = _run_cb(ql, n, fused=False, alter=alter)
     assert fused == per_batch
-    assert sum(len(i) for _t, i, _r in fused) > 50
+    assert sum(len(i) + len(r) for _t, i, r in fused) >= least
+
+
+def test_deliver_pack_lowers_without_an_element_scatter():
+    """The chunk program of a filter-only app places its delivered rows by
+    shifted reads and one run per micro-batch: as lowered it holds no scatter
+    of single elements (the scatter form held four, `c.price`, `c.symbol` and
+    the halves of `ts`, over all K x batch output rows), and the status says
+    which pack the program takes."""
+    import jax
+    import jax.numpy as jnp
+
+    mgr = SiddhiManager()
+    rt = mgr.create_siddhi_app_runtime(FILTER_CB)
+    rt.add_callback("q", lambda ts, ins, rem: None)
+    rt.start()
+    try:
+        engine = rt.junctions["S"].fused_ingest
+        engine._narrow = {}  # the full-width wire, nothing sent
+        engine._build(deliver_set=frozenset({0}))
+        K = 32
+        state = jax.eval_shape(lambda: engine.endpoints[0].init_state(0))
+        text = engine._fused_deliver.lower(
+            (state,), {},
+            jax.ShapeDtypeStruct((K, engine._wire_bytes), jnp.uint8),
+            jax.ShapeDtypeStruct((K,), jnp.int32),
+            jax.ShapeDtypeStruct((K,), jnp.int64),
+            jax.ShapeDtypeStruct((), jnp.int64),
+        ).as_text()
+        pack = rt.snapshot_status()["streams"]["S"]["pipeline"]["pack"]
+    finally:
+        rt.shutdown()
+        mgr.shutdown()
+    assert "inserted_window_dims = [0]" not in text
+    assert "stablehlo.dynamic_update_slice" in text
+    assert pack == "slice"

@@ -130,11 +130,15 @@ def lowered_text(config: str) -> str:
 # PR 29 replaced both plug programs' (f999fb96..., 99929b79...): the group-by
 # reads its per-group values once per segment of its sorted view, so
 # `assign_slots` and the keyed running lanes lower without their per-row
-# gathers (ops/group.py). The filter has no group-by and keeps its hash.
+# gathers (ops/group.py). The filter has no group-by and kept its hash.
+# PR 33 replaced all three (fddb6ddd..., 233fdbcf..., 222eae96...): the
+# deliver pack, which every one of them ends with, places each micro-batch's
+# rows by shifted reads and one run per micro-batch where it scattered every
+# 32-bit word of the chunk's output rows (core/ingest.py `_build`).
 STANDING_PROGRAMS = {
-    "debs14-q1-plug": "fddb6dddafcd20658de0a9161bbb1d5fa815185b9ea309798df22818c1c68fc8",
-    "siddhi-simple-filter": "233fdbcf647ded2693ff29f1f81344e59ca926ef5e1a7feaac442c8f1f642fcc",
-    KEYS4: "222eae96edfcc75a49d3562a5acf3c551fa07bf6c72bf12b7e4dad41f4acf06f",
+    "debs14-q1-plug": "4e35c5a0c7ad704a8cf692c6fbddbe211676642de599e0b217e9fdc41c52d662",
+    "siddhi-simple-filter": "e57dc6766097200e83d5fedc19d003b611a6acb4532be6940bfab8eaf418132b",
+    KEYS4: "07b60a94e0e0712645c43c0832a0b3a3f12e9f7773f0c03a0f1d5a2757a4ad7b",
 }
 
 
