@@ -28,6 +28,7 @@ from siddhi_tpu.core.flow import Flow
 from siddhi_tpu.core.selector import CompiledSelector
 from siddhi_tpu.core.types import AttrType, InternTable
 from siddhi_tpu.observability.profiler import stage
+from siddhi_tpu.ops.group import PROBE
 from siddhi_tpu.query_api.annotation import find_annotation
 from siddhi_tpu.query_api.execution import (
     Filter,
@@ -896,7 +897,8 @@ class QueryRuntime(BaseQueryRuntime):
             d["keyshard"] = self._keyshard.describe_state()
         group = self.selector.group
         if group is not None and group.carry_read is not None:
-            d["group"] = {"capacity": group.capacity, "carry_read": group.carry_read}
+            d["group"] = {"capacity": group.capacity, "carry_read": group.carry_read,
+                          "probe": PROBE}
         win = self.chain.window
         if win is not None:
             # under the receive lock: the step donates the old state buffers,

@@ -5,7 +5,8 @@ looked up per event by a generated string key
 (reference: query/selector/GroupByKeyGenerator.java,
 query/selector/attribute/processor/executor/GroupByAggregationAttributeExecutor.java).
 TPU-shaped equivalent: group state is a fixed-capacity `[G]` array indexed by a
-slot; slot assignment is a vectorized probe of a persistent int64 key table.
+slot; a row finds its slot by a sort-merge of the batch's keys with a
+persistent int64 key table (`probe_table`).
 Within a batch, keyed running values ride a SORTED view of the rows — one
 lexsort by (key, reset-era) turns every per-key reduction into a log-depth
 segmented scan (ops/prefix.py), replacing the earlier [B,B] masked-reduction
@@ -24,6 +25,19 @@ aggregate) is read once per segment, by at most G rows, and spread along the
 segment by a segmented scan; never once per row of the flow. Where the flow is
 no longer than the table (B <= G) a row reads for itself, which is then the
 cheaper form: `SortedGroups.carry_read` says which form a program took.
+
+The key table is probed the same way (PR 39, PERF.md §6). Up to PR 33 every
+row was compared with every slot: a dense `[B, G]` equality matrix and two
+reductions over it, 1.18 ms for the `argmax` and 0.31 ms for the `any`,
+1.49 ms of every step of the 65,536-row flow and its longest operation. A
+`searchsorted` probe was measured slower still: its log G binary-search steps
+are dependent gathers, each at the scalar core's 7.1 ns a row. The sort-merge
+has no gather and nothing of size B x G: one sort of the B + G keys (the
+64-bit key as two 32-bit words, and a tag), one segmented carry and one
+payload sort back take 0.21 ms together where the matrix took 1.49
+(`jit__step_impl` 2.79 -> 1.52 ms a send, my traced runs, PR 39; PERF.md §6
+has them by operation), and G is no longer bounded by what a matrix of
+B x G can hold.
 """
 
 from __future__ import annotations
@@ -41,6 +55,11 @@ from siddhi_tpu.ops.prefix import (
     segmented_cumsum,
 )
 from siddhi_tpu.ops.scatter import compact_set_at, set_at
+
+# How `assign_slots` finds a row's slot in the key table: `probe_table`'s
+# sort-merge, the one form there is. Static, reported as
+# `snapshot_status()["queries"][q]["group"]["probe"]` and `["partition"]["probe"]`.
+PROBE = "merge"
 
 # 64-bit mixing constants (splitmix64 finalizer) for combining composite keys.
 _MIX1 = np.int64(-7046029254386353131)  # 0x9E3779B97F4A7C15 as signed
@@ -151,6 +170,41 @@ class SortedGroups:
         )
 
 
+def probe_table(
+    table_keys: jnp.ndarray,  # [G] int64
+    used: jnp.ndarray,        # [G] bool
+    batch_keys: jnp.ndarray,  # [B] int64
+) -> jnp.ndarray:
+    """[B] int32: the slot of the used table entry that holds each batch
+    row's key, -1 where none does. A sort-merge: the table's G keys and the
+    batch's B are sorted together on (key, tag), the entry's slot is carried
+    along its run of equal keys, and one payload sort on the tag brings the
+    rows back; no gather, and nothing of size B x G.
+
+    The tag says who a merged row is and puts a run in order: the unused
+    entries first (G of them may hold key 0, which is a legal key; tag
+    j - G < 0), then the one used entry of that key (its slot j), then the
+    batch's rows (G + i). A carry segment opens at every table entry, so a
+    batch row is handed what the last entry ahead of it in its run holds: the
+    used one's slot if the key is in the table, else an unused one's -1; and a
+    run that opens with a batch row has no entry, -1."""
+    g = table_keys.shape[0]
+    b = batch_keys.shape[0]
+    slots = jnp.arange(g, dtype=jnp.int32)
+    tag = jnp.concatenate(
+        [jnp.where(used, slots, slots - g), jnp.arange(g, g + b, dtype=jnp.int32)]
+    )
+    mk, mt = jax.lax.sort(
+        (jnp.concatenate([table_keys, batch_keys]), tag), num_keys=2, is_stable=False
+    )
+    is_entry = mt < g
+    run_start = jnp.concatenate([jnp.ones((1,), jnp.bool_), mk[1:] != mk[:-1]])
+    held = jnp.where(is_entry & (mt >= 0), mt, np.int32(-1))
+    found = segmented_carry(held, is_entry | run_start)
+    (back,) = permute_by(mt, found)
+    return back[g:]
+
+
 def assign_slots(
     table_keys: jnp.ndarray,  # [G] int64
     used: jnp.ndarray,        # [G] bool
@@ -208,14 +262,10 @@ def assign_slots(
     # rows that open their (era, key) segment, in original order
     (is_head,) = grp.from_sorted(seg_start)
 
-    # ---- resolution against the old table (pre-reset rows + no-reset case)
-    # dense [B, G] eq matrix: a fully vectorized compare + argmax on the VPU
-    # (1.2 ms at B=65,536, G=4,096: `iota_reduce_fusion`, ledger PR 28) —
-    # measured FASTER than a searchsorted probe, whose log G binary-search
-    # steps serialize into scalar-space gathers on TPU
-    eq_t = used[None, :] & (table_keys[None, :] == batch_keys[:, None])  # [B,G]
-    in_t = eq_t.any(axis=1) & active
-    t_slot = jnp.where(in_t, jnp.argmax(eq_t, axis=1).astype(jnp.int32), -1)
+    # ---- resolution against the old table (pre-reset rows + no-reset case;
+    # the lookup takes no notice of eras)
+    t_slot = jnp.where(active, probe_table(table_keys, used, batch_keys), -1)
+    in_t = t_slot >= 0
 
     is_alloc = active & ~in_t & is_head
     alloc_rank = (jnp.cumsum(is_alloc.astype(jnp.int32)) - is_alloc).astype(jnp.int32)

@@ -1,8 +1,10 @@
 """The group-by's per-group reads, once per segment of the sorted view
 (`ops/group.py`, PR 29), against the row-gather formulation they replaced,
-bit for bit. The reference below is the parent's `assign_slots`,
-`keyed_running_sum` and `keyed_running_extreme` as they stood: every row
-gathers its segment head's slot and its group's carried value for itself."""
+bit for bit. The reference below is `assign_slots`, `keyed_running_sum` and
+`keyed_running_extreme` as they stood before PR 29: every row gathers its
+segment head's slot and its group's carried value for itself, and finds its
+key in the table by the dense `[B, G]` compare that `probe_table`'s
+sort-merge replaced in PR 39 (`MERGE_CASES`)."""
 
 from __future__ import annotations
 
@@ -184,8 +186,13 @@ NEW = (new_assign_slots, new_running_sum, new_running_extreme)
 REF = (ref_assign_slots, ref_running_sum, ref_running_extreme)
 
 
-def make_batch(rng, b, n_keys, resets=(), active_share=0.8, owner=None):
-    keys = rng.integers(1, n_keys + 1, b).astype(np.int64) * 1_000_003
+def make_batch(rng, b, n_keys, resets=(), active_share=0.8, owner=None,
+               pool=None):
+    """`pool`: the int64 keys the rows draw from, where the case names them."""
+    if pool is None:
+        keys = rng.integers(1, n_keys + 1, b).astype(np.int64) * 1_000_003
+    else:
+        keys = np.asarray(pool, np.int64)[rng.integers(0, len(pool), b)]
     sign = rng.choice([1, 1, 1, -1], b).astype(np.int32)
     sign[rng.random(b) >= active_share] = 0
     if owner is not None:  # the owner mask of @app:shard(axis='keys')
@@ -214,9 +221,10 @@ def compare(tree_a, tree_b, what):
         same_bits(a, b, f"{what}: leaf {i} of {ta}")
 
 
-def run_batches(b, g, batches):
+def run_batches(b, g, batches, table=None):
     """Three consecutive batches through both formulations, jitted, the state
-    of each carried on its own; every lane and every table compared."""
+    of each carried on its own; every lane and every table compared (and the
+    table against `table`, a `TableByHand`, where one is given)."""
     new_step = jax.jit(lambda s, bt: step(NEW, s, bt)[:2])
     ref_step = jax.jit(lambda s, bt: step(REF, s, bt)[:2])
     s_new, s_ref = init(g), init(g)
@@ -225,7 +233,39 @@ def run_batches(b, g, batches):
         s_ref, rows_ref = ref_step(s_ref, bt)
         compare(rows_new, rows_ref, f"rows of batch {i}")
         compare(s_new, s_ref, f"state after batch {i}")
+        if table is not None:
+            table.step(bt)
+            table.check(s_new, rows_new, f"table after batch {i}")
     return s_new, rows_new
+
+
+class TableByHand:
+    """The key table as a Python list in first-appearance order: what
+    `keys[:n]`, `used`, `n` and `overflow` must read after each batch,
+    reckoned from the rows alone (no probe of either kind)."""
+
+    def __init__(self, g):
+        self.g, self.keys, self.overflow, self.history = g, [], False, []
+
+    def step(self, bt):
+        active, reset = bt["sign"] != 0, bt["reset"]
+        last = int(np.flatnonzero(reset).max()) if reset.any() else -1
+        if last >= 0:
+            self.keys = []
+        seen = set(self.keys)
+        arrive = bt["key"][last + 1:][active[last + 1:]].tolist()
+        new = [k for k in dict.fromkeys(arrive) if k not in seen]
+        self.overflow = len(self.keys) + len(new) > self.g
+        self.keys = (self.keys + new)[:self.g]
+        # (n, rows that found their key in the table, active rows, overflow)
+        self.history.append((len(self.keys), sum(k in seen for k in arrive),
+                             len(arrive), self.overflow))
+
+    def check(self, state, rows, what):
+        n = len(self.keys)
+        assert int(state["n"]) == n and bool(rows["overflow"]) == self.overflow, what
+        assert np.asarray(state["keys"])[:n].tolist() == self.keys, what
+        assert np.asarray(state["used"]).tolist() == [True] * n + [False] * (self.g - n), what
 
 
 CASES = {
@@ -246,8 +286,58 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
+I64 = np.iinfo(np.int64)
+_A = np.arange(1, 41, dtype=np.int64)
+
+# What the sort-merge probe has to get right where the dense compare could
+# not go wrong (PR 39): an unused entry holds key 0 and 0 is a legal key; a
+# 64-bit key is compared as two 32-bit words on the chip; the merged sort is
+# B + G rows long. name: (B, G, the keys each of the batches draws from,
+# resets of batch 0 / 1 / 2, active share, what the table must show:
+# "all_hit" / "none_hit" of batches 1 and 2's rows, "full" from batch 0 on)
+MERGE_CASES = {
+    "merge_key_zero_into_an_empty_table":
+        (1024, 64, ([0, 5, 9, -3],) * 3, ((), (), (300,)), 0.8, None),
+    "merge_key_zero_into_a_part_used_table":
+        (1024, 64, ([5, 9, -3], [0, 5, 11], [0, 9, 12]), ((), (), ()), 0.8, None),
+    "merge_key_zero_behind_a_reset_while_the_old_table_holds_it":
+        (1024, 64, ([0, 5, 9], [0, 5, 11], [0, 9, 12]), ((), (400,), (0, 1000)), 0.8, None),
+    "merge_int64_min_and_max_beside_each_other":
+        (1024, 64, ([I64.min, I64.min + 1, I64.max - 1, I64.max, -1, 0, 1],) * 3,
+         ((), (), (512,)), 0.8, None),
+    "merge_keys_that_differ_in_their_high_words_only":
+        (1024, 64, (((_A - 20) << 32) | 5,) * 3, ((), (700,), ()), 0.8, None),
+    "merge_keys_that_differ_in_their_low_words_only":
+        (1024, 64, ((7 << 32) | (_A - 20),) * 3, ((), (700,), ()), 0.8, None),
+    "merge_keys_whose_words_are_swapped":
+        (1024, 64, (np.concatenate([_A[:20] << 32, _A[:20]]),) * 3, ((), (), ()), 0.8, None),
+    "merge_table_full_before_the_batch":
+        (1024, 32, (_A[:32], _A, _A), ((), (), (600,)), 0.9, "full"),
+    "merge_table_fills_inside_the_batch":
+        (1024, 32, (_A[:20], _A, _A[10:]), ((), (), ()), 0.9, None),
+    "merge_every_row_hits_the_table":
+        (1024, 64, (_A, _A, _A), ((), (), ()), 0.8, "all_hit"),
+    "merge_no_row_hits_the_table":
+        (1024, 128, (_A, _A + 40, _A + 80), ((), (), ()), 0.8, "none_hit"),
+    "merge_b_plus_g_one_below_a_power_of_two":
+        (959, 64, (_A,) * 3, ((), (500,), ()), 0.8, None),
+    "merge_b_plus_g_a_power_of_two":
+        (960, 64, (_A,) * 3, ((), (500,), ()), 0.8, None),
+    "merge_b_plus_g_one_above_a_power_of_two":
+        (961, 64, (_A,) * 3, ((), (500,), ()), 0.8, None),
+    "merge_b_below_g": (48, 128, (_A, _A + 20, _A), ((), (), (20,)), 0.8, None),
+    # 2 x 32,768 rows behind the window, 2,125 plugs; two batches, since the
+    # reference's matrix is 268 M compares a batch
+    "merge_the_plug_cells_own_shapes":
+        (65536, 4096, (np.arange(2125, dtype=np.int64) * 7 - 5000,) * 2,
+         ((), (40000,)), 0.5, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES) + sorted(MERGE_CASES))
 def test_segment_read_equals_the_row_gather_bit_for_bit(case):
+    if case in MERGE_CASES:
+        return check_merge_case(case)
     b, g, n_keys, resets, share, owner = CASES[case]
     rng = np.random.default_rng(sorted(CASES).index(case) + 2_900_000_000)
     batches = [make_batch(rng, b, n_keys, r, share, owner) for r in resets]
@@ -256,6 +346,29 @@ def test_segment_read_equals_the_row_gather_bit_for_bit(case):
         assert bool(rows["overflow"]) and int(state["n"]) == g
     elif share > 0 and batches[-1]["reset"][-1] == 0:
         assert 0 < int(state["n"]) <= min(g, n_keys)
+
+
+def check_merge_case(case):
+    b, g, pools, resets, share, shows = MERGE_CASES[case]
+    rng = np.random.default_rng(sorted(MERGE_CASES).index(case) + 3_900_000_000)
+    batches = [make_batch(rng, b, 0, r, share, pool=p)
+               for r, p in zip(resets, pools)]
+    for p, bt in zip(pools, batches):
+        if len(p) <= b // 16:  # every key the case names did come, active
+            assert set(np.asarray(p).tolist()) <= set(bt["key"][bt["sign"] != 0].tolist())
+    table = TableByHand(g)
+    run_batches(b, g, batches, table=table)
+    n, hits, active, overflow = zip(*table.history)
+    if shows == "all_hit":
+        assert hits[1:] == active[1:] and n == (len(pools[0]),) * 3
+    elif shows == "none_hit":
+        assert hits == (0, 0, 0) and n == (40, 80, 120)
+    elif shows == "full":  # old keys keep their slots, new ones find none
+        assert n == (g, g, g) and overflow[1] and 0 < hits[1] < active[1]
+    elif "fills_inside" in case:
+        assert n[0] < g == n[1] and overflow[1:] == (True, True)
+    if "key_zero" in case:
+        assert 0 in table.keys
 
 
 @pytest.mark.parametrize("b,g,want", [
@@ -327,3 +440,40 @@ def flow_gathers(stablehlo: str, rows) -> list:
     found = re.findall(
         r'stablehlo\.gather"?\(.*?-> tensor<(\d+)x([a-z0-9]+)>', stablehlo)
     return [f"{n}x{t}" for n, t in found if int(n) in rows]
+
+
+def test_status_names_the_probe_of_the_group_table_and_of_the_partition_table():
+    """`queries.<q>.group.probe` and `queries.<q>.partition.probe` of a
+    deployed app, a group-by outside a partition and one inside."""
+    from siddhi_tpu import SiddhiManager
+
+    mgr = SiddhiManager()
+    rt = mgr.create_siddhi_app_runtime("""
+    @app:batch(size='64')
+    @app:partitionCapacity(size='8')
+    @app:groupCapacity(size='16')
+    define stream S (k int, g int, v float);
+    @info(name='plain') from S select g, sum(v) as s group by g insert into Plain;
+    partition with (k of S) begin
+    @info(name='inner') from S#window.length(4) select k, g, sum(v) as s group by g
+    insert into Out;
+    end;
+    """)
+    got = []
+    rt.add_callback("Out", got.extend)
+    rt.start()
+    try:
+        i = np.arange(10, dtype=np.int32)
+        rt.get_input_handler("S").send_columns(
+            i.astype(np.int64), {"k": i % 3, "g": i % 2, "v": np.ones(10, np.float32)})
+        queries = rt.snapshot_status()["queries"]
+    finally:
+        rt.shutdown()
+        mgr.shutdown()
+    assert len(got) == 10
+    assert queries["plain"]["group"] == {
+        "capacity": 16, "carry_read": "segment", "probe": "merge"}
+    assert "partition" not in queries["plain"]
+    assert queries["inner"]["group"]["probe"] == "merge"
+    assert queries["inner"]["partition"]["probe"] == "merge"
+    assert queries["inner"]["partition"]["used"] == 3

@@ -347,8 +347,8 @@ def test_status_block():
     mgr, rt, _ = deploy(batch=64, cap=8, w=40)
     try:
         before = rt.snapshot_status()["queries"]["q"]["partition"]
-        assert before == {"capacity": 8, "step": "routed", "used": 0,
-                          "extra_passes": 0, "max_rows_per_slot": 0}
+        assert before == {"capacity": 8, "step": "routed", "probe": "merge",
+                          "used": 0, "extra_passes": 0, "max_rows_per_slot": 0}
         feed(rt, keys, vals, 50)
         status = rt.snapshot_status()["queries"]["q"]
         part = status["partition"]
@@ -389,7 +389,7 @@ def test_joins_and_patterns_say_that_they_stay_on_masks():
         queries = rt.snapshot_status()["queries"]
         for q in ("j", "p"):
             assert queries[q]["partition"] == {
-                "capacity": 4, "step": "masked", "used": 0}
+                "capacity": 4, "step": "masked", "probe": "merge", "used": 0}
     finally:
         rt.shutdown()
         mgr.shutdown()

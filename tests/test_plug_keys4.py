@@ -62,8 +62,8 @@ def chunk_program(rt, gen, cfg, batch: int):
 
     fi = rt.junctions[cfg["stream"]].fused_ingest
     n = -(-batch // getattr(gen, "CYCLE_ROWS", 1)) * getattr(gen, "CYCLE_ROWS", 1)
+    cols = gen.make(7, n)  # first: the time stream's seconds come from its pool
     ts = gen.timestamps(0, n)
-    cols = gen.make(7, n)
     index = {c: np.arange(1, len(v) + 1, dtype=np.int32)
              for c, v in gen.STRINGS.items()}
     cols = {k: (index[k][v] if k in index else v) for k, v in cols.items()}
@@ -135,10 +135,14 @@ def lowered_text(config: str) -> str:
 # deliver pack, which every one of them ends with, places each micro-batch's
 # rows by shifted reads and one run per micro-batch where it scattered every
 # 32-bit word of the chunk's output rows (core/ingest.py `_build`).
+# PR 39 replaced both plug programs' (4e35c5a0..., 07b60a94...): `assign_slots`
+# finds a row's slot by a sort-merge with the key table where it compared
+# every row with every slot (ops/group.py `probe_table`). The filter has no
+# group-by and kept its hash: the control.
 STANDING_PROGRAMS = {
-    "debs14-q1-plug": "4e35c5a0c7ad704a8cf692c6fbddbe211676642de599e0b217e9fdc41c52d662",
+    "debs14-q1-plug": "7a92bb520de3b788489c97aa5f432bf20b83e97e26a7bfde0ea83e07a8989938",
     "siddhi-simple-filter": "e57dc6766097200e83d5fedc19d003b611a6acb4532be6940bfab8eaf418132b",
-    KEYS4: "07b60a94e0e0712645c43c0832a0b3a3f12e9f7773f0c03a0f1d5a2757a4ad7b",
+    KEYS4: "50b02c340189d849666e40535fed124dacc5d30d5911035482b1c44bd9e4ef2b",
 }
 
 
@@ -162,13 +166,85 @@ def test_plug_programs_read_per_group_values_by_segment(config):
         text, status = lowered(config, rehearse=rehearse)
         batch = (cfg["rehearse_sizes"] if rehearse else cfg["sizes"])["batch"]
         assert status["group"] == {
-            "capacity": cfg["sizes"]["group_capacity"], "carry_read": want}
+            "capacity": cfg["sizes"]["group_capacity"], "carry_read": want,
+            "probe": "merge"}
         assert (batch > cfg["sizes"]["group_capacity"]) == (want == "segment")
         left = flow_gathers(text, {batch, 2 * batch})
         if want == "segment":
             assert left == [f"{batch}xui8"], left
         else:
             assert len(left) == 3 and f"{batch}xui8" in left, left
+
+
+def table_slots(sizes) -> int:
+    return sizes.get("group_capacity", sizes.get("partition_capacity"))
+
+
+def dense_probes(stablehlo: str, sizes) -> list:
+    """The tensor types of a lowered program that have one element per pair
+    of a flow's row and a key table's slot: `[B, G]`, or `[2B, G]` behind a
+    window, as `assign_slots`' compare made them before PR 39."""
+    import re
+
+    flows = {n * sizes["batch"] for n in (1, 2)}
+    table = table_slots(sizes)
+    return sorted({
+        m.group(0) for m in re.finditer(r"tensor<(\d+)x(\d+)x\w+>", stablehlo)
+        if int(m.group(1)) in flows and int(m.group(2)) == table})
+
+
+def lowered_partition_step(config: str) -> tuple:
+    """(`jit__pstep_outer_impl` of `config` lowered at the configuration's
+    own sizes, the status of its query): nothing sent, no state made."""
+    import jax
+    import jax.numpy as jnp
+
+    _, _, cfg = load(config)
+    mgr, rt, gen, cfg = deploy(config, cfg["sizes"]["batch"], rehearse=False)
+    try:
+        qr = rt.queries[cfg["query"]]
+        shapes = jax.eval_shape(lambda: (
+            qr.partition_runtime.ptable, qr._fresh(qr.init_state()),
+            {"extra_passes": jnp.zeros((), jnp.int64),
+             "max_rows": jnp.zeros((), jnp.int32)},
+            rt.junctions[cfg["stream"]].schema.empty_batch(cfg["sizes"]["batch"]),
+            jnp.zeros((), jnp.int64)))
+        return (qr._pstep_outer.lower(*shapes).as_text(),
+                rt.snapshot_status()["queries"][cfg["query"]])
+    finally:
+        rt.shutdown()
+        mgr.shutdown()
+
+
+@pytest.mark.parametrize("config", [
+    "debs14-q1-plug", "debs14-q1-time", KEYS4, "debs14-q1-partition"])
+def test_no_program_compares_every_row_with_every_slot(config):
+    """At the configurations' own sizes no program holds a tensor with a
+    flow's rows along one axis and the key table's slots along the other
+    (65,536 x 4,096 behind a window, 32,768 x 4,096 without): the probe is a
+    sort-merge, and the status says so."""
+    _, _, cfg = load(config)
+    if "partition_capacity" in cfg["sizes"]:
+        text, status = lowered_partition_step(config)
+        assert status["partition"]["probe"] == "merge"
+    else:
+        text, status = lowered(config, rehearse=False)
+        assert status["group"]["probe"] == "merge"
+    assert dense_probes(text, cfg["sizes"]) == []
+    # the merged sort is there, B + G rows long (2B + G behind a window)
+    merged = {n * cfg["sizes"]["batch"] + table_slots(cfg["sizes"]) for n in (1, 2)}
+    assert any(f"tensor<{n}xi64>" in text for n in merged)
+
+
+def test_the_scan_for_dense_probes_finds_the_matrix_where_there_is_one():
+    import jax
+
+    from tests.test_group_segment_read import REF, init, make_batch, step
+
+    sizes = {"batch": 2048, "group_capacity": 128}
+    bt = make_batch(np.random.default_rng(39), 2 * sizes["batch"], 100)
+    text = jax.jit(lambda s, x: step(REF, s, x)[:2]).lower(init(128), bt).as_text()
+    assert "tensor<4096x128xi1>" in dense_probes(text, sizes)
 
 
 def test_both_plug_configurations_generate_one_stream():
