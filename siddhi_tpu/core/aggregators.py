@@ -55,6 +55,16 @@ class CompiledAggregator:
 
     type: AttrType
     group: Optional[CompiledGroupBy] = None
+    # set by the selector on the one aggregator whose count of rows tells a
+    # table that takes slots back which groups are empty (ops/group.py
+    # `keyed_running_sum(rows=True)`); it then runs before the others
+    counts_rows: bool = False
+
+    @staticmethod
+    def rows_of(state):
+        """The [G] lane of `state` that counts each group's rows of the
+        window, where the aggregator keeps one (count, avg, stdDev)."""
+        return None
 
     def _shape(self):
         return (self.group.capacity,) if self.group is not None else ()
@@ -65,9 +75,9 @@ class CompiledAggregator:
     def apply(self, state, flow: FlowInfo, env: Env):  # -> (state', [B] col)
         raise NotImplementedError
 
-    def _run_sum(self, state, contrib, flow: FlowInfo):
+    def _run_sum(self, state, contrib, flow: FlowInfo, rows: bool = False):
         if flow.group is not None:
-            return keyed_running_sum(contrib, flow.group.sorted, state)
+            return keyed_running_sum(contrib, flow.group.sorted, state, rows=rows)
         run, carry = running_sum(contrib, flow.reset, state)
         return run, carry
 
@@ -108,8 +118,14 @@ class CountAggregator(CompiledAggregator):
     def init(self):
         return jnp.zeros(self._shape(), dtype=jnp.int64)
 
+    @staticmethod
+    def rows_of(state):
+        return state
+
     def apply(self, state, flow: FlowInfo, env: Env):
-        return _swap(self._run_sum(state, flow.sign.astype(jnp.int64), flow))
+        return _swap(self._run_sum(
+            state, flow.sign.astype(jnp.int64), flow, rows=self.counts_rows
+        ))
 
 
 def _swap(t):
@@ -131,13 +147,21 @@ class AvgAggregator(CompiledAggregator):
         z = jnp.zeros(self._shape(), dtype=jnp.float32)
         return {"sum": z, "count": z}
 
+    @staticmethod
+    def rows_of(state):
+        return state["count"]
+
     def apply(self, state, flow: FlowInfo, env: Env):
         x = self.arg(env).astype(jnp.float32)
         sgn = flow.sign.astype(jnp.float32)
+        # the count first: where it tells the table which groups are empty,
+        # the sum writes its identity there
+        c_run, c_carry = self._run_sum(
+            state["count"], sgn, flow, rows=self.counts_rows
+        )
         s_run, s_carry = self._run_sum(
             state["sum"], jnp.where(flow.sign != 0, x * sgn, 0.0), flow
         )
-        c_run, c_carry = self._run_sum(state["count"], sgn, flow)
         out = jnp.where(c_run != 0, s_run / jnp.where(c_run != 0, c_run, 1.0), jnp.nan)
         return {"sum": s_carry, "count": c_carry}, out
 
@@ -156,12 +180,16 @@ class StdDevAggregator(CompiledAggregator):
         z = jnp.zeros(self._shape(), dtype=jnp.float32)
         return {"sum": z, "sumsq": z, "count": z}
 
+    @staticmethod
+    def rows_of(state):
+        return state["count"]
+
     def apply(self, state, flow: FlowInfo, env: Env):
         x = self.arg(env).astype(jnp.float32)
         sgn = flow.sign.astype(jnp.float32)
+        c_run, c_c = self._run_sum(state["count"], sgn, flow, rows=self.counts_rows)
         s_run, s_c = self._run_sum(state["sum"], jnp.where(flow.sign != 0, x * sgn, 0.0), flow)
         q_run, q_c = self._run_sum(state["sumsq"], jnp.where(flow.sign != 0, x * x * sgn, 0.0), flow)
-        c_run, c_c = self._run_sum(state["count"], sgn, flow)
         safe_n = jnp.where(c_run != 0, c_run, 1.0)
         mean = s_run / safe_n
         var = jnp.maximum(q_run / safe_n - mean * mean, 0.0)

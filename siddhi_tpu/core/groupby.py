@@ -12,12 +12,24 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from siddhi_tpu.core.errors import SiddhiAppCreationError
 from siddhi_tpu.core.executor import CompiledExpr, Env, Scope, compile_expression
 from siddhi_tpu.core.types import AttrType
-from siddhi_tpu.ops.group import SortedGroups, assign_slots, mix_keys
+from siddhi_tpu.ops.group import (
+    PROBE,
+    RECLAIM_NONE,
+    RECLAIM_OWN,
+    SortedGroups,
+    assign_slots,
+    free_stack,
+    keyed_running_sum,
+    mix_keys,
+    release_slots,
+)
 from siddhi_tpu.query_api.expression import Variable
 
 DEFAULT_GROUP_CAPACITY = 1024
@@ -57,6 +69,13 @@ class CompiledGroupBy:
         # how the last trace read the groups' carried values: "segment" (once
         # per segment of the sorted view) or "row"; None before the first trace
         self.carry_read: Optional[str] = None
+        # how the table learns that a group holds no row of the window any
+        # more (ops/group.py RECLAIM_*). The selector sets it where a window
+        # hands it EXPIRED rows; then a group's slot is unused again at the
+        # end of the step in which its last row left (reference: Siddhi 5
+        # drops a group's state once its aggregators say canDestroy()), and
+        # the capacity is of the groups alive at once, not of all ever seen
+        self.reclaim: str = RECLAIM_NONE
         self.keys: list[CompiledExpr] = [
             compile_expression(v, scope) for v in group_by
         ]
@@ -71,20 +90,71 @@ class CompiledGroupBy:
 
     def init_state(self):
         g = self.capacity
-        return {
+        state = {
             "keys": jnp.zeros((g,), jnp.int64),
             "used": jnp.zeros((g,), jnp.bool_),
-            "n": jnp.zeros((), jnp.int32),
+            "n": jnp.zeros((), jnp.int32),  # groups held: used slots
         }
+        if self.reclaim != RECLAIM_NONE:
+            # the stack of unused slots (its first G - n places), slots given
+            # back and rows that found no slot since deploy
+            state["free"] = free_stack(g)
+            state["freed"] = jnp.zeros((), jnp.int64)
+            state["lost"] = jnp.zeros((), jnp.int64)
+        if self.reclaim == RECLAIM_OWN:
+            state["rows"] = jnp.zeros((g,), jnp.int32)
+        return state
 
-    def assign(self, state, env: Env, active: jnp.ndarray, reset: jnp.ndarray = None):
+    def describe_state(self, state=None) -> dict:
+        """The `group` block of a query's status: the static fields and,
+        from the table's `state`, the groups it holds now (`used`), the slots
+        it has given back (`freed`) and the rows that found none
+        (`overflow_rows`; None where the table takes no slot back and so
+        keeps no count). Summed: a sharded table's counts lead with [D]."""
+        d = {"capacity": self.capacity, "carry_read": self.carry_read,
+             "probe": PROBE, "reclaim": self.reclaim}
+        if state is not None:
+            def total(k, absent):
+                return int(np.asarray(state[k]).sum()) if k in state else absent
+
+            d.update(used=total("n", 0), freed=total("freed", 0),
+                     overflow_rows=total("lost", None))
+        return d
+
+    def assign(self, state, env: Env, active: jnp.ndarray,
+               reset: jnp.ndarray = None, sign: jnp.ndarray = None):
+        """`sign` (+1 CURRENT, -1 EXPIRED, 0 other rows) feeds the table's
+        own row count where no aggregator keeps one."""
         bk = self.key_of(env)
         keys, used, n, slot, grp, overflow = assign_slots(
-            state["keys"], state["used"], state["n"], bk, active, reset=reset
+            state["keys"], state["used"], state["n"], bk, active, reset=reset,
+            free=state.get("free"),
         )
         self.carry_read = grp.carry_read
         ctx = GroupCtx(
             slot=slot, key=bk, sorted=grp, capacity=self.capacity,
             key_of=self.key_of, overflow=overflow,
         )
-        return {"keys": keys, "used": used, "n": n}, ctx
+        new = {"keys": keys, "used": used, "n": n}
+        if self.reclaim != RECLAIM_NONE:
+            lost = (active & (slot >= self.capacity)).sum(dtype=jnp.int32)
+            new.update(free=grp.free, freed=state["freed"],
+                       lost=state["lost"] + lost.astype(jnp.int64))
+        if self.reclaim == RECLAIM_OWN:
+            with jax.named_scope("group.reclaim"):
+                _, new["rows"] = keyed_running_sum(
+                    sign.astype(jnp.int32), grp, state["rows"], rows=True
+                )
+        return new, ctx
+
+    def release(self, state, ctx: GroupCtx, rows: jnp.ndarray):
+        """The end of a reclaiming table's step, once every lane has run:
+        the slots of the groups that hold no row any more (`rows`, the [G]
+        lane that counts them, as the step leaves it) go back on the stack
+        and read unused."""
+        with jax.named_scope("group.reclaim"):
+            free, n, freed = release_slots(state["free"], state["n"], ctx.sorted)
+            return {
+                **state, "free": free, "n": n, "used": rows > 0,
+                "freed": state["freed"] + freed.astype(jnp.int64),
+            }

@@ -44,7 +44,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from siddhi_tpu.core.event import WireNarrowMisfit
-from siddhi_tpu.native import event_builder, load_event_builder
+from siddhi_tpu.native import (
+    event_builder,
+    keep_host_blocks,
+    load_event_builder,
+)
 from siddhi_tpu.observability.profiler import (
     CAUSE_DELIVER_SET,
     CAUSE_FULL_WIDTH,
@@ -202,6 +206,8 @@ class FusedJunctionIngest:
         # the drain's Event builder is compiled here, where the engine is
         # built, so that no send ever waits for a compiler
         load_event_builder()
+        # and the allocator is told here to keep the chunks' host buffers
+        self.host_blocks = keep_host_blocks()
         self.decode_native_rows = 0
         self._fused = None
         self._fused_deliver = None
@@ -269,6 +275,9 @@ class FusedJunctionIngest:
             # builder has made
             "decode": "python" if event_builder() is None else "native",
             "decode_native_rows": self.decode_native_rows,
+            # whether glibc's allocator keeps what the chunks' large host
+            # buffers free (`native.keep_host_blocks`): kept / as_set / default
+            "host_blocks": self.host_blocks,
             # how the chunk program places delivered rows in its packed
             # buffer: each micro-batch's as one run (`_build`, deliver_pack)
             "pack": "slice",

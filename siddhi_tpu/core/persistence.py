@@ -188,8 +188,14 @@ def _upgrade(tree, like):
     """A snapshot tree from before its component's state gained a key,
     brought to the layout of `like`: a time-bounded window's ring that was
     saved without its head (before PR 30) is laid out again from its live
-    rows (`core/windows.py` `ring_from_legacy`)."""
+    rows (`core/windows.py` `ring_from_legacy`); a group-by's key table that
+    was saved before it took slots back (before PR 40) gets the stack of
+    its unused slots (`ops/group.py` `table_from_legacy`)."""
     if isinstance(tree, dict) and isinstance(like, dict):
+        if "free" in like and "free" not in tree and "used" in tree:
+            from siddhi_tpu.ops.group import table_from_legacy
+
+            return table_from_legacy(tree, own_lane="rows" in like)
         if "head" in like and "head" not in tree and "seq" in tree:
             from siddhi_tpu.core.windows import ring_from_legacy
 

@@ -28,7 +28,7 @@ from siddhi_tpu.core.flow import Flow
 from siddhi_tpu.core.selector import CompiledSelector
 from siddhi_tpu.core.types import AttrType, InternTable
 from siddhi_tpu.observability.profiler import stage
-from siddhi_tpu.ops.group import PROBE
+from siddhi_tpu.ops.group import RECLAIM_NONE
 from siddhi_tpu.query_api.annotation import find_annotation
 from siddhi_tpu.query_api.execution import (
     Filter,
@@ -507,12 +507,17 @@ class BaseQueryRuntime:
             self._warned_overflow = True
             import logging
 
+            group = self.selector.group
             logging.getLogger(__name__).error(
-                "query '%s': group-by slot table overflowed (capacity %d); "
-                "overflowed keys lose their cross-batch carry — raise it "
-                "with @app:groupCapacity(size='N')",
+                "query '%s': group-by slot table overflowed (capacity %d%s); "
+                "rows of overflowed keys lose their cross-batch carry "
+                "(`overflow_rows` of the query's `group` status counts them) "
+                "— raise it with @app:groupCapacity(size='N')",
                 self.query_id,
-                self.selector.group.capacity if self.selector.group else -1,
+                group.capacity if group else -1,
+                ", of the groups alive in the window at once"
+                if group and group.reclaim != RECLAIM_NONE
+                else ", of all groups seen: no window ahead takes any back",
             )
         if (
             not getattr(self, "_warned_pattern_overflow", False)
@@ -819,6 +824,9 @@ class QueryRuntime(BaseQueryRuntime):
             self.chain.out_attrs,  # includes stream-function appended attrs
             batch_mode=self.chain.window is not None and self.chain.window.is_batch,
             group_capacity=group_capacity,
+            # a sliding window hands the selector what it lets go
+            reclaim=self.chain.window is not None
+            and not self.chain.window.is_batch,
         )
 
         self._setup_output(query, query_id)
@@ -897,8 +905,9 @@ class QueryRuntime(BaseQueryRuntime):
             d["keyshard"] = self._keyshard.describe_state()
         group = self.selector.group
         if group is not None and group.carry_read is not None:
-            d["group"] = {"capacity": group.capacity, "carry_read": group.carry_read,
-                          "probe": PROBE}
+            with self._receive_lock:  # as the window's, below
+                d["group"] = group.describe_state(
+                    (self.state or {}).get("sel", {}).get("group"))
         win = self.chain.window
         if win is not None:
             # under the receive lock: the step donates the old state buffers,

@@ -15,7 +15,12 @@ import dataclasses
 
 import jax.numpy as jnp
 
-from siddhi_tpu.core.aggregators import CompiledAggregator, FlowInfo, build_aggregator
+from siddhi_tpu.core.aggregators import (
+    CompiledAggregator,
+    ExtremeAggregator,
+    FlowInfo,
+    build_aggregator,
+)
 from siddhi_tpu.core.errors import SiddhiAppCreationError
 from siddhi_tpu.core.event import EventBatch, KIND_CURRENT, KIND_EXPIRED
 from siddhi_tpu.core.executor import (
@@ -28,7 +33,13 @@ from siddhi_tpu.core.executor import (
 from siddhi_tpu.core.flow import Flow
 from siddhi_tpu.core.groupby import CompiledGroupBy
 from siddhi_tpu.core.types import AttrType
-from siddhi_tpu.ops.group import keep_last_in_sorted, keep_last_per_group
+from siddhi_tpu.ops.group import (
+    RECLAIM_COUNT,
+    RECLAIM_NONE,
+    RECLAIM_OWN,
+    keep_last_in_sorted,
+    keep_last_per_group,
+)
 from siddhi_tpu.query_api.execution import OutputAttribute, Selector
 from siddhi_tpu.query_api.expression import AttributeFunction, Expression, Variable
 
@@ -105,7 +116,11 @@ class CompiledSelector:
         input_attrs: list[tuple[str, AttrType]] | None = None,
         batch_mode: bool = False,
         group_capacity: int | None = None,
+        reclaim: bool = False,
     ):
+        # `reclaim`: a window ahead hands this selector the EXPIRED rows of
+        # what it lets go, so a group can be seen to empty and its slot in
+        # the table be taken back (`_pick_reclaim`)
         self.selector = selector
         self.batch_mode = batch_mode
         sel_list = list(selector.selection_list)
@@ -172,6 +187,10 @@ class CompiledSelector:
             if self.having.type is not AttrType.BOOL:
                 raise SiddhiAppCreationError("having must be a boolean expression")
 
+        self._rows_agg: int | None = None
+        if self.group is not None and reclaim:
+            self._pick_reclaim()
+
         # order-by: keys resolve against output attrs first, then input streams
         # (reference: OrderByEventComparator over output stream attributes)
         self.order_by: list[tuple[CompiledExpr, bool]] = []
@@ -193,6 +212,24 @@ class CompiledSelector:
         self.limit = selector.limit
         self.offset = selector.offset
 
+    def _pick_reclaim(self) -> None:
+        """Where the table's count of a group's rows comes from: the first
+        aggregator that keeps one, else a lane of the table's own. A group
+        under minForever / maxForever is never done (the reference's
+        canDestroy() is false for them): that table takes nothing back."""
+        no_lane = CompiledAggregator.rows_of
+        if any(isinstance(a, ExtremeAggregator) and a.forever
+               for a in self.aggregators):
+            return
+        self._rows_agg = next(
+            (i for i, a in enumerate(self.aggregators)
+             if type(a).rows_of is not no_lane), None)
+        if self._rows_agg is None:
+            self.group.reclaim = RECLAIM_OWN
+        else:
+            self.group.reclaim = RECLAIM_COUNT
+            self.aggregators[self._rows_agg].counts_rows = True
+
     def init_state(self):
         st = {"aggs": [a.init() for a in self.aggregators]}
         if self.group is not None:
@@ -206,7 +243,7 @@ class CompiledSelector:
         ctx = None
         if self.group is not None:
             group_state, ctx = self.group.assign(
-                group_state, env, keyed_rows, reset=flow.reset
+                group_state, env, keyed_rows, reset=flow.reset, sign=flow.sign
             )
             # surfaced to the host, which warns on slot-table exhaustion
             flow.aux["groupby_overflow"] = ctx.overflow
@@ -218,12 +255,23 @@ class CompiledSelector:
             member_env=flow.member_env,
             group=ctx,
         )
-        new_aggs = []
+        order = list(range(len(self.aggregators)))
+        if self._rows_agg is not None:  # says which groups are empty: first
+            order.insert(0, order.pop(self._rows_agg))
+        new_aggs = [None] * len(order)
         agg_cols: dict = {}
-        for i, agg in enumerate(self.aggregators):
-            s, col = agg.apply(state["aggs"][i], info, env)
-            new_aggs.append(s)
+        for i in order:
+            new_aggs[i], col = self.aggregators[i].apply(
+                state["aggs"][i], info, env
+            )
             agg_cols[(_AGG_REF, None, f"a{i}")] = col
+        if ctx is not None and self.group.reclaim != RECLAIM_NONE:
+            k = self._rows_agg
+            group_state = self.group.release(
+                group_state, ctx,
+                group_state["rows"] if k is None
+                else self.aggregators[k].rows_of(new_aggs[k]),
+            )
         env2 = Env({**env.columns, **agg_cols}, now=flow.now, tables=env.tables)
 
         out_cols = {}

@@ -19,6 +19,10 @@ once under a lock:
   fused engine is built (deploy), never inside a send; without a compiler or
   `Python.h` one WARNING is logged and the Python body stays
   (`snapshot_status().streams.<S>.pipeline.decode` says which runs).
+
+Beside them `keep_host_blocks`, which compiles nothing: it tells glibc's
+allocator, once per process, to keep what the engine's large host buffers
+free (below).
 """
 
 from __future__ import annotations
@@ -42,6 +46,7 @@ _LIB: Optional[ctypes.CDLL] = None
 _LIB_FAILED = False
 _DECODE_LIB: Optional[ctypes.PyDLL] = None
 _DECODE_FAILED = False
+_HOST_BLOCKS: Optional[str] = None
 
 
 def _build_dir() -> str:
@@ -137,6 +142,62 @@ def event_builder():
     """The loaded `siddhi_build_events`, or None; never compiles."""
     lib = _DECODE_LIB
     return None if lib is None else lib.siddhi_build_events
+
+
+# glibc's mallopt parameters (malloc.h) and what `keep_host_blocks` sets them
+# to: the largest `M_MMAP_THRESHOLD` it takes (half a thread arena's 64 MiB
+# heap), so that a block below it comes from a heap and not from a mapping of
+# its own; more than a heap as `M_TOP_PAD` (an empty heap is unmapped only
+# where the one before it could take `top_pad` more, which none can); and a
+# top that is trimmed only beyond 1 GiB. The values the chip runs were made
+# with (PERF.md, PR 40).
+_M_TRIM_THRESHOLD, _M_TOP_PAD, _M_MMAP_THRESHOLD = -1, -2, -3
+_HEAP_BYTES = 64 << 20
+_KEEP = (
+    (_M_MMAP_THRESHOLD, _HEAP_BYTES // 2),
+    (_M_TOP_PAD, 2 * _HEAP_BYTES),
+    (_M_TRIM_THRESHOLD, 1 << 30),
+)
+# the operator's own word on the same parameters: then they stay as set
+_MALLOC_ENV = ("MALLOC_MMAP_THRESHOLD_", "MALLOC_TOP_PAD_",
+               "MALLOC_TRIM_THRESHOLD_")
+
+
+def keep_host_blocks() -> str:
+    """Tell glibc's allocator to keep and reuse the memory of the large host
+    buffers that are made anew for every chunk of a fused send (the packed
+    readback, 16-30 MB at a chunk of 32 x 32,768 rows: the wire buffers on
+    the way in are pooled; and the caller's own columns beside it), instead
+    of handing it back to the kernel and mapping it again. Left to itself the
+    allocator moves its thresholds with what it has seen freed, trims the
+    top of its heap and unmaps a thread's heap whenever one falls empty, so
+    whether a chunk's buffers come back already mapped or are faulted in
+    page by page depends on what else happens to lie in the same heap: the
+    same process ran a 2,097,152-row send in 0.36 s or in 0.40 s from some
+    chunk on, and with every large block mapped anew in 0.50 s
+    (PERF.md, PR 40). Called where a fused engine is built, once per
+    process; returns what `snapshot_status().streams.<S>.pipeline.
+    host_blocks` reports: `kept`; `as_set` where the environment sets one of
+    glibc's own `MALLOC_*_` variables for these parameters or
+    `GLIBC_TUNABLES` names `glibc.malloc`, which then stand; `default`
+    where the C library has no `mallopt` or refuses a value."""
+    global _HOST_BLOCKS
+    with _LIB_LOCK:
+        if _HOST_BLOCKS is None:
+            if any(k in os.environ for k in _MALLOC_ENV) or (
+                "glibc.malloc" in os.environ.get("GLIBC_TUNABLES", "")
+            ):
+                _HOST_BLOCKS = "as_set"
+            else:
+                try:
+                    mallopt = ctypes.CDLL(None).mallopt
+                    mallopt.argtypes = [ctypes.c_int, ctypes.c_int]
+                    mallopt.restype = ctypes.c_int
+                    done = [mallopt(k, v) for k, v in _KEEP]
+                except (OSError, AttributeError):
+                    done = [0]
+                _HOST_BLOCKS = "kept" if all(done) else "default"
+    return _HOST_BLOCKS
 
 
 class NativeIngressRing:

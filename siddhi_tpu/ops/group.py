@@ -61,6 +61,13 @@ from siddhi_tpu.ops.scatter import compact_set_at, set_at
 # `snapshot_status()["queries"][q]["group"]["probe"]` and `["partition"]["probe"]`.
 PROBE = "merge"
 
+# How a table learns that a group holds no row of the window any more, so
+# that its slot can be taken back: from an aggregator's lane that counts the
+# rows already (`count()`, `avg`'s count), from a lane of its own, or not at
+# all (no window hands it EXPIRED rows: nothing ever leaves a group). Static,
+# `snapshot_status()["queries"][q]["group"]["reclaim"]`.
+RECLAIM_COUNT, RECLAIM_OWN, RECLAIM_NONE = "count_lane", "own_lane", "none"
+
 # 64-bit mixing constants (splitmix64 finalizer) for combining composite keys.
 _MIX1 = np.int64(-7046029254386353131)  # 0x9E3779B97F4A7C15 as signed
 _MIX2 = np.int64(-4658895280553007687)  # 0xBF58476D1CE4E5B9 as signed
@@ -118,6 +125,16 @@ class SortedGroups:
                            slot is one segment, so G places hold them all.
                            None when B <= G
     head_slot: [G] int32 — their slots
+
+    and, where the table takes slots back (`assign_slots(free=...)`):
+
+    free:      [G] int32 — the table's stack of unused slots once the step's
+                           new keys have taken theirs
+    freed_s:   [B] bool  — sorted rows that write a group's carry (`writer_s`)
+                           whose group holds no row of the window any more:
+                           set by the lane that counts rows
+                           (`keyed_running_sum(rows=True)`), read by every
+                           lane behind it, which writes its identity there
     """
 
     perm: jnp.ndarray
@@ -129,6 +146,8 @@ class SortedGroups:
     writer_s: jnp.ndarray = None
     head_pos: jnp.ndarray | None = None
     head_slot: jnp.ndarray | None = None
+    free: jnp.ndarray | None = None
+    freed_s: jnp.ndarray | None = None
 
     @property
     def carry_read(self) -> str:
@@ -205,6 +224,88 @@ def probe_table(
     return back[g:]
 
 
+def free_stack(g: int) -> jnp.ndarray:
+    """[G] int32: the unused slots of an empty table, as a stack whose top is
+    its last live place. Place i holds slot G - 1 - i, so a table that has
+    given no slot back hands them out in rising order, as the bump pointer of
+    a table that takes none back does."""
+    return jnp.arange(g - 1, -1, -1, dtype=jnp.int32)
+
+
+def _pop_slots(free, n_free, is_alloc):
+    """[B] int32: on the r-th row of `is_alloc` the r-th slot from the top of
+    the stack, `free[n_free - 1 - r]`, or G where the stack has run out; what
+    the other rows hold is unspecified. The top of the stack is read as one
+    slice, and the slots reach their rows by two sorts of the batch (the rows
+    that allocate first, the slots laid beside them, and back): no gather."""
+    g, b = free.shape[0], is_alloc.shape[0]
+    take = min(b, g)
+    start = jnp.maximum(n_free - take, 0)
+    top = jax.lax.dynamic_slice(free, (start,), (take,))
+    m = n_free - start  # how many of `top` are unused slots
+    cand = jax.lax.dynamic_slice(
+        jnp.pad(top[::-1], (0, take), constant_values=g), (take - m,), (take,)
+    )
+    cand = jnp.pad(cand, (0, b - take), constant_values=g)
+    idx = jnp.arange(b, dtype=jnp.int32)
+    (first,) = jax.lax.sort((jnp.where(is_alloc, idx, idx + b),), num_keys=1)
+    (slot_new,) = permute_by(jnp.where(first >= b, first - b, first), cand)
+    return slot_new
+
+
+def release_slots(free, n_used, grp: "SortedGroups"):
+    """Push the slots of `grp.freed_s` (each once: a writer row per group)
+    onto the stack of a table that holds `n_used` groups, the step's new
+    ones included. Returns (stack, groups held now, slots freed). The slots
+    are moved to the front of the batch by one sort and written as one run
+    at the stack's top, G - n_used; the run is blended into a window of the
+    stack that lies inside it, so it costs the batch and not the table."""
+    g, b = free.shape[0], grp.freed_s.shape[0]
+    take = min(b, g)
+    pos = jnp.arange(b, dtype=jnp.int32)
+    _, slots = jax.lax.sort(
+        (jnp.where(grp.freed_s, pos, pos + b), grp.slot_s), num_keys=1,
+        is_stable=False,
+    )
+    f = grp.freed_s.sum(dtype=jnp.int32)
+    top = g - n_used
+    at = jnp.clip(top, 0, g - take)
+    d = top - at
+    j = jnp.arange(take, dtype=jnp.int32)
+    run = jax.lax.dynamic_slice(
+        jnp.pad(slots[:take], (take, 0)), (take - d,), (take,)
+    )
+    window = jnp.where(
+        (j >= d) & (j - d < f), run, jax.lax.dynamic_slice(free, (at,), (take,))
+    )
+    return jax.lax.dynamic_update_slice(free, window, (at,)), n_used - f, f
+
+
+def table_from_legacy(snap: dict, own_lane: bool) -> dict:
+    """A key table saved before it took slots back (`keys`, `used`, `n`),
+    with the stack of its unused slots, lowest on top, and the counters at
+    zero (on the host). A table that counts rows in a lane of its own cannot
+    know how many each saved group holds: they are given more than any
+    window lets go, and keep their slot as they did when saved."""
+    used = np.asarray(snap["used"]).astype(bool)
+    g = used.shape[-1]
+
+    def stack(u):
+        unused = np.flatnonzero(~u)[::-1].astype(np.int32)
+        return np.concatenate([unused, np.zeros(g - len(unused), np.int32)])
+
+    lead = used.shape[:-1]
+    flat = used.reshape(-1, g)
+    out = dict(snap)
+    out["free"] = np.stack([stack(u) for u in flat]).reshape(*lead, g)
+    out["n"] = flat.sum(axis=-1).astype(np.int32).reshape(lead)
+    out["freed"] = np.zeros(lead, np.int64)
+    out["lost"] = np.zeros(lead, np.int64)
+    if own_lane:
+        out["rows"] = np.where(used, np.int32(1 << 30), np.int32(0))
+    return out
+
+
 def assign_slots(
     table_keys: jnp.ndarray,  # [G] int64
     used: jnp.ndarray,        # [G] bool
@@ -212,9 +313,16 @@ def assign_slots(
     batch_keys: jnp.ndarray,  # [B] int64
     active: jnp.ndarray,      # [B] bool — rows that carry a group key
     reset: jnp.ndarray | None = None,  # [B] bool — RESET rows clear the table
+    free: jnp.ndarray | None = None,   # [G] int32 — the stack of unused slots
 ):
     """Map each active row to a stable slot in [0, G); allocate new slots in
     first-appearance order. Inactive rows get slot == G (scatter-drop lane).
+
+    A table that takes slots back (`free`, `free_stack`; its live places are
+    the first G - n_used) hands a new key the slot on top of the stack where
+    the other counts on from `n_used`; the stack behind the step's pops comes
+    back as `SortedGroups.free`, and `release_slots` pushes what the step
+    freed once the lane that counts rows has said which groups are empty.
 
     RESET semantics: a reset kills every group's carried state, so rows after
     the batch's last reset re-allocate into a FRESH table (bounding table
@@ -264,12 +372,17 @@ def assign_slots(
 
     # ---- resolution against the old table (pre-reset rows + no-reset case;
     # the lookup takes no notice of eras)
-    t_slot = jnp.where(active, probe_table(table_keys, used, batch_keys), -1)
+    with jax.named_scope("group.probe"):
+        t_slot = jnp.where(active, probe_table(table_keys, used, batch_keys), -1)
     in_t = t_slot >= 0
 
     is_alloc = active & ~in_t & is_head
     alloc_rank = (jnp.cumsum(is_alloc.astype(jnp.int32)) - is_alloc).astype(jnp.int32)
-    slot_new = n_used + alloc_rank
+    if free is None:
+        slot_new = n_used + alloc_rank
+    else:
+        with jax.named_scope("group.reclaim"):
+            slot_new = _pop_slots(free, g - n_used, is_alloc)
     old_overflow = (jnp.where(is_alloc, slot_new, 0) >= g).any()
 
     # ---- fresh-table allocation for post-reset rows (a head is era-local,
@@ -319,15 +432,32 @@ def assign_slots(
     new_keys = jnp.where(any_reset, keys_f, keys_old)
     new_used = jnp.where(any_reset, used_f, used_old)
     new_n = jnp.where(any_reset, n_f, n_old)
+    if free is not None:
+        # a reset empties the table: its fresh allocations count up from 0,
+        # which is what an empty stack's top holds
+        grp.free = jnp.where(any_reset, free_stack(g), free)
     return new_keys, new_used, new_n, slot, grp, overflow
+
+
+def _kept(grp: SortedGroups, value_s: jnp.ndarray, ident):
+    """What the writer rows write: `value_s`, or the lane's identity on the
+    rows whose group the step freed."""
+    if grp.freed_s is None:
+        return value_s
+    return jnp.where(grp.freed_s, np.asarray(ident, value_s.dtype), value_s)
 
 
 def keyed_running_sum(
     contrib: jnp.ndarray,  # [B], 0 on inactive rows
     grp: SortedGroups,
     carry: jnp.ndarray,    # [G]
+    rows: bool = False,
 ):
     """Per-event running sum within each group; returns ([B] run, [G] carry').
+
+    `rows`: this lane counts the rows of the window that each group holds
+    (+1 a CURRENT row, -1 an EXPIRED one), for a table that takes slots
+    back: a group whose writer row reads none is freed (`grp.freed_s`).
 
     The (era, key) segmentation bounds contributions to same-key rows j <= i
     with no reset in between — exactly the reference's per-key running state
@@ -346,7 +476,11 @@ def keyed_running_sum(
     # reset came (era 0 is then the final era), zero behind one
     full_s = run_s + carried_s
     (run,) = grp.from_sorted(full_s)
-    return run, compact_set_at(base, grp.writer_s, full_s.astype(carry.dtype))
+    if rows:
+        grp.freed_s = (grp.writer_s < carry.shape[0]) & (full_s <= 0)
+    return run, compact_set_at(
+        base, grp.writer_s, _kept(grp, full_s.astype(carry.dtype), 0)
+    )
 
 
 def keyed_running_extreme(
@@ -375,7 +509,7 @@ def keyed_running_extreme(
     else:
         base = jnp.where(grp.reset.any(), jnp.full_like(carry, ident), carry)
         writer_s = grp.writer_s
-    newval = op(carried_s, run_s).astype(carry.dtype)
+    newval = _kept(grp, op(carried_s, run_s).astype(carry.dtype), ident)
     return run, compact_set_at(base, writer_s, newval)
 
 

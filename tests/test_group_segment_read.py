@@ -471,8 +471,14 @@ def test_status_names_the_probe_of_the_group_table_and_of_the_partition_table():
         rt.shutdown()
         mgr.shutdown()
     assert len(got) == 10
+    # no window ahead of `plain`: its table takes no slot back and counts
+    # neither freed slots nor lost rows; `inner` stands behind one, and sum
+    # keeps no count of rows, so its table counts them in a lane of its own
     assert queries["plain"]["group"] == {
-        "capacity": 16, "carry_read": "segment", "probe": "merge"}
+        "capacity": 16, "carry_read": "segment", "probe": "merge",
+        "reclaim": "none", "used": 2, "freed": 0, "overflow_rows": None}
+    assert queries["inner"]["group"]["reclaim"] == "own_lane"
+    assert queries["inner"]["group"]["overflow_rows"] == 0
     assert "partition" not in queries["plain"]
     assert queries["inner"]["group"]["probe"] == "merge"
     assert queries["inner"]["partition"]["probe"] == "merge"

@@ -10,6 +10,7 @@ from __future__ import annotations
 import gc
 import logging
 import os
+import platform
 import subprocess
 import sys
 
@@ -337,3 +338,78 @@ def test_processes_that_build_at_once_all_load_a_whole_binary(tmp_path):
     built = sorted(p.name for p in tmp_path.iterdir())
     assert len(built) == 1 and built[0].endswith(".so"), built
     assert ".cpython-" in built[0] or ".abi" in built[0]
+
+
+# ---- the allocator's word on the chunks' host buffers (native.keep_host_blocks)
+
+glibc = pytest.mark.skipif(
+    platform.libc_ver()[0] != "glibc", reason="mallopt is glibc's"
+)
+
+
+@glibc
+def test_a_fused_engine_tells_the_allocator_to_keep_its_host_blocks():
+    """In a process of its own (the setting is the process's, for good): a
+    16 MiB block is a mapping of its own before an engine is built and comes
+    from a heap after, so freeing it unmaps nothing."""
+    code = (
+        "import ctypes, sys\n"
+        "sys.path.insert(0, sys.argv[1])\n"
+        "libc = ctypes.CDLL(None)\n"
+        "class MI(ctypes.Structure):\n"
+        "    _fields_ = [(n, ctypes.c_size_t) for n in 'arena ordblks smblks "
+        "hblks hblkhd usmblks fsmblks uordblks fordblks keepcost'.split()]\n"
+        "libc.mallinfo2.restype = MI\n"
+        "libc.malloc.restype = ctypes.c_void_p\n"
+        "libc.malloc.argtypes = [ctypes.c_size_t]\n"
+        "libc.free.argtypes = [ctypes.c_void_p]\n"
+        "def mapped_by(n):\n"
+        "    before = libc.mallinfo2().hblks\n"
+        "    p = libc.malloc(n)\n"
+        "    got = libc.mallinfo2().hblks - before\n"
+        "    libc.free(p)\n"
+        "    return got\n"
+        "first = mapped_by(16 << 20)\n"
+        "from tests.test_native_decode import _send_app\n"
+        "_calls, status = _send_app(fused=True)\n"
+        "print(first, mapped_by(16 << 20), status['host_blocks'])\n"
+    )
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("MALLOC_") and k != "GLIBC_TUNABLES"}
+    p = subprocess.run(
+        [sys.executable, "-c", code, REPO], cwd=REPO, env=env,
+        capture_output=True, text=True, timeout=600,
+    )
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.split()[-3:] == ["1", "0", "kept"], p.stdout
+
+
+@pytest.mark.parametrize("env, no_mallopt, want", [
+    ({"MALLOC_TRIM_THRESHOLD_": "262144"}, False, "as_set"),
+    ({"MALLOC_TOP_PAD_": "0"}, False, "as_set"),
+    ({"MALLOC_MMAP_THRESHOLD_": "1048576"}, False, "as_set"),
+    ({"GLIBC_TUNABLES": "glibc.malloc.arena_max=1"}, False, "as_set"),
+    ({"GLIBC_TUNABLES": "glibc.pthread.rseq=0"}, True, "default"),
+    ({}, True, "default"),
+])
+def test_the_allocator_is_left_as_the_operator_or_the_platform_has_it(
+    monkeypatch, env, no_mallopt, want
+):
+    """Neither branch calls `mallopt`: glibc's own environment variables for
+    the same parameters stand, and a C library without it is left alone."""
+    monkeypatch.setattr(native, "_HOST_BLOCKS", None)
+    for k in native._MALLOC_ENV + ("GLIBC_TUNABLES",):
+        monkeypatch.delenv(k, raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+
+    def cdll(_name):
+        assert no_mallopt, "mallopt must not be reached"
+        raise OSError("no such library")
+
+    with monkeypatch.context() as m:
+        m.setattr(native.ctypes, "CDLL", cdll)
+        assert native.keep_host_blocks() == want
+    assert native.keep_host_blocks() == want  # decided once per process
+    _calls, status = _send_app(fused=True)
+    assert status["host_blocks"] == want

@@ -102,7 +102,9 @@ class TestProfilerUnit:
         import time
 
         prof = Profiler(gate=_Gate(), top_k=2)
-        for i, dt in enumerate((0.003, 0.001, 0.006)):
+        # far enough apart that a loaded machine cannot reorder them (the
+        # 1 ms sleep read longer than the 3 ms one under six test workers)
+        for i, dt in enumerate((0.03, 0.001, 0.06)):
             wf = prof.begin("S", 10)
             wf.stage("encode", int(dt * 1e9))
             time.sleep(dt)
