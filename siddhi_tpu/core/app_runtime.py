@@ -1006,6 +1006,7 @@ class SiddhiAppRuntime:
             group_capacity=self.group_capacity,
             tables=self.tables,
             time_capacity=self.time_capacity,
+            batch_size=self.batch_size,
         )
         self._wire_query_lineage(qr)
         self.queries[qid] = qr

@@ -406,11 +406,12 @@ def _tree_compatible(fresh, value) -> bool:
     import jax
     import numpy as np
 
-    from siddhi_tpu.core.persistence import _flat_with_paths
+    from siddhi_tpu.core.persistence import _flat_with_paths, without_indexes
     from siddhi_tpu.ops.scatter import join_pairs
 
     try:
-        fa = _flat_with_paths(jax.eval_shape(join_pairs, fresh))
+        fa = _flat_with_paths(
+            jax.eval_shape(join_pairs, without_indexes(fresh)))
         fb = _flat_with_paths(value)
     except Exception:
         return False

@@ -20,14 +20,17 @@ from siddhi_tpu.core.errors import SiddhiAppCreationError
 from siddhi_tpu.core.executor import CompiledExpr, Env, Scope, compile_expression
 from siddhi_tpu.core.types import AttrType
 from siddhi_tpu.ops.group import (
-    PROBE,
+    PROBE_BUCKET,
     RECLAIM_NONE,
     RECLAIM_OWN,
     SortedGroups,
     assign_slots,
+    empty_index,
     free_stack,
     keyed_running_sum,
     mix_keys,
+    probe_for,
+    release_index,
     release_slots,
 )
 from siddhi_tpu.query_api.expression import Variable
@@ -62,10 +65,16 @@ class CompiledGroupBy:
         group_by: list[Variable],
         scope: Scope,
         capacity: int = DEFAULT_GROUP_CAPACITY,
+        flow_rows: Optional[int] = None,
     ):
         if not group_by:
             raise SiddhiAppCreationError("empty group by")
         self.capacity = int(capacity)
+        # how a row finds its slot (ops/group.py PROBE_*): by the merge, or,
+        # where the table is much larger than the flow the selector is built
+        # for (`flow_rows`; None where the caller keeps no index), through
+        # the bucket index in the table's state
+        self.probe: str = probe_for(self.capacity, flow_rows)
         # how the last trace read the groups' carried values: "segment" (once
         # per segment of the sorted view) or "row"; None before the first trace
         self.carry_read: Optional[str] = None
@@ -103,6 +112,8 @@ class CompiledGroupBy:
             state["lost"] = jnp.zeros((), jnp.int64)
         if self.reclaim == RECLAIM_OWN:
             state["rows"] = jnp.zeros((g,), jnp.int32)
+        if self.probe == PROBE_BUCKET:
+            state["index"] = empty_index(g)
         return state
 
     def describe_state(self, state=None) -> dict:
@@ -110,15 +121,29 @@ class CompiledGroupBy:
         from the table's `state`, the groups it holds now (`used`), the slots
         it has given back (`freed`) and the rows that found none
         (`overflow_rows`; None where the table takes no slot back and so
-        keeps no count). Summed: a sharded table's counts lead with [D]."""
+        keeps no count). Summed: a sharded table's counts lead with [D].
+        Of a bucket index: `index_buckets`, the keys in its fullest bucket
+        now (`index_max_fill`, of the 128 a bucket holds), whether a bucket
+        ever had no lane for a new key (`index_overflow`, sticky: the table
+        is probed by the merge from then on) and the head tiles looked up
+        since deploy (`probe_tiles`)."""
         d = {"capacity": self.capacity, "carry_read": self.carry_read,
-             "probe": PROBE, "reclaim": self.reclaim}
+             "probe": self.probe, "reclaim": self.reclaim}
         if state is not None:
             def total(k, absent):
                 return int(np.asarray(state[k]).sum()) if k in state else absent
 
             d.update(used=total("n", 0), freed=total("freed", 0),
                      overflow_rows=total("lost", None))
+            index = state.get("index")
+            if index is not None:
+                d.update(
+                    index_buckets=int(index["slot"].shape[-2]),
+                    index_max_fill=int(
+                        (jnp.asarray(index["slot"]) >= 0).sum(axis=-1).max()),
+                    index_overflow=int(np.asarray(index["full"]).any()),
+                    probe_tiles=int(np.asarray(index["tiles"]).sum()),
+                )
         return d
 
     def assign(self, state, env: Env, active: jnp.ndarray,
@@ -128,7 +153,7 @@ class CompiledGroupBy:
         bk = self.key_of(env)
         keys, used, n, slot, grp, overflow = assign_slots(
             state["keys"], state["used"], state["n"], bk, active, reset=reset,
-            free=state.get("free"),
+            free=state.get("free"), index=state.get("index"),
         )
         self.carry_read = grp.carry_read
         ctx = GroupCtx(
@@ -145,6 +170,8 @@ class CompiledGroupBy:
                 _, new["rows"] = keyed_running_sum(
                     sign.astype(jnp.int32), grp, state["rows"], rows=True
                 )
+        if grp.index is not None:
+            new["index"] = grp.index
         return new, ctx
 
     def release(self, state, ctx: GroupCtx, rows: jnp.ndarray):
@@ -154,7 +181,11 @@ class CompiledGroupBy:
         and read unused."""
         with jax.named_scope("group.reclaim"):
             free, n, freed = release_slots(state["free"], state["n"], ctx.sorted)
-            return {
+            state = {
                 **state, "free": free, "n": n, "used": rows > 0,
                 "freed": state["freed"] + freed.astype(jnp.int64),
             }
+        if "index" in state:  # its upkeep is the probe's cost, not the stack's
+            with jax.named_scope("group.probe"):
+                state["index"] = release_index(state["index"], ctx.sorted)
+        return state

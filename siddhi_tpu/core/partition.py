@@ -48,7 +48,7 @@ from siddhi_tpu.core.query_runtime import QueryRuntime
 from siddhi_tpu.core.selector import aggregate_calls, aggregate_reads
 from siddhi_tpu.core.types import AttrType
 from siddhi_tpu.core.windows import SlidingWindow, _lanes32
-from siddhi_tpu.ops.group import PROBE, assign_slots
+from siddhi_tpu.ops.group import PROBE_MERGE, assign_slots
 from siddhi_tpu.ops.prefix import compact_front, cummax, spread_back
 from siddhi_tpu.query_api.execution import (
     InsertIntoStream,
@@ -115,8 +115,10 @@ def _in_order(key, lanes, rows: int, *then):
 def _partition_block(qr, step: str) -> dict:
     """`snapshot_status()["queries"][q]["partition"]`: the key table's
     capacity and the keys it has seen, which step the query takes and how
-    the key table is probed (`ops/group.py` `PROBE`)."""
-    part = {"capacity": getattr(qr, "p_logical", qr.p), "step": step, "probe": PROBE}
+    the key table is probed (`ops/group.py` `PROBE_MERGE`: a partition's
+    table keeps no bucket index)."""
+    part = {"capacity": getattr(qr, "p_logical", qr.p), "step": step,
+            "probe": PROBE_MERGE}
     pr = getattr(qr, "partition_runtime", None)
     if pr is not None:
         try:

@@ -150,8 +150,23 @@ def _to_host(tree):
     # a U32Pair (a sliding window's ring) is joined on the host, under the
     # path it has always had, so snapshots do not depend on the layout
     return join_pairs(
-        jax.tree_util.tree_map(lambda x: np.array(x, copy=True), tree)
+        jax.tree_util.tree_map(
+            lambda x: np.array(x, copy=True), without_indexes(tree))
     )
+
+
+def without_indexes(tree):
+    """`tree` without the bucket index of any group-by key table in it: the
+    table's `keys` and `used` are the truth and a snapshot holds them alone;
+    restore lays the index out again (`_upgrade`)."""
+    if isinstance(tree, dict):
+        return {
+            k: without_indexes(v) for k, v in tree.items()
+            if not (k == "index" and "used" in tree)
+        }
+    if isinstance(tree, (list, tuple)):
+        return type(tree)(without_indexes(v) for v in tree)
+    return tree
 
 
 def _to_device(tree, like=None):
@@ -190,12 +205,19 @@ def _upgrade(tree, like):
     saved without its head (before PR 30) is laid out again from its live
     rows (`core/windows.py` `ring_from_legacy`); a group-by's key table that
     was saved before it took slots back (before PR 40) gets the stack of
-    its unused slots (`ops/group.py` `table_from_legacy`)."""
+    its unused slots (`ops/group.py` `table_from_legacy`); a key table that
+    keeps a bucket index (since PR 41) gets it laid out from the saved `keys`
+    and `used`, whenever it was saved (`index_from_table`)."""
     if isinstance(tree, dict) and isinstance(like, dict):
-        if "free" in like and "free" not in tree and "used" in tree:
-            from siddhi_tpu.ops.group import table_from_legacy
+        if "used" in tree and "used" in like:
+            from siddhi_tpu.ops.group import index_from_table, table_from_legacy
 
-            return table_from_legacy(tree, own_lane="rows" in like)
+            if "free" in like and "free" not in tree:
+                tree = table_from_legacy(tree, own_lane="rows" in like)
+            if "index" in like:
+                tree = {**tree,
+                        "index": index_from_table(tree["keys"], tree["used"])}
+            return tree
         if "head" in like and "head" not in tree and "seq" in tree:
             from siddhi_tpu.core.windows import ring_from_legacy
 

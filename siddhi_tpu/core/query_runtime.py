@@ -789,7 +789,10 @@ class QueryRuntime(BaseQueryRuntime):
         tables: Optional[dict] = None,
         time_capacity: Optional[int] = None,
         held_cols=None,
+        batch_size: Optional[int] = None,
     ):
+        # `batch_size`: the app's micro-batch, where the query's group table
+        # may keep a bucket index (a plain query: `CompiledGroupBy.probe`)
         # `held_cols`: `make_window`'s, for the subclass that knows nobody
         # reads its window's EXPIRED rows beyond them (core/partition.py)
         self.query = query
@@ -827,6 +830,9 @@ class QueryRuntime(BaseQueryRuntime):
             # a sliding window hands the selector what it lets go
             reclaim=self.chain.window is not None
             and not self.chain.window.is_batch,
+            # a window's flow holds what a batch brings and what it pushes out
+            flow_rows=None if batch_size is None
+            else batch_size * (1 if self.chain.window is None else 2),
         )
 
         self._setup_output(query, query_id)

@@ -117,7 +117,11 @@ class CompiledSelector:
         batch_mode: bool = False,
         group_capacity: int | None = None,
         reclaim: bool = False,
+        flow_rows: int | None = None,
     ):
+        # `flow_rows`: the length of the flows this selector is built for,
+        # where the caller knows it and its group table may keep a bucket
+        # index (`CompiledGroupBy.probe`).
         # `reclaim`: a window ahead hands this selector the EXPIRED rows of
         # what it lets go, so a group can be seen to empty and its slot in
         # the table be taken back (`_pick_reclaim`)
@@ -134,10 +138,12 @@ class CompiledSelector:
         if selector.group_by:
             if group_capacity is not None:
                 self.group = CompiledGroupBy(
-                    selector.group_by, scope, capacity=group_capacity
+                    selector.group_by, scope, capacity=group_capacity,
+                    flow_rows=flow_rows,
                 )
             else:
-                self.group = CompiledGroupBy(selector.group_by, scope)
+                self.group = CompiledGroupBy(
+                    selector.group_by, scope, flow_rows=flow_rows)
 
         # lift aggregator calls out of the selection expressions
         agg_calls: list[AttributeFunction] = []

@@ -6,7 +6,8 @@ looked up per event by a generated string key
 query/selector/attribute/processor/executor/GroupByAggregationAttributeExecutor.java).
 TPU-shaped equivalent: group state is a fixed-capacity `[G]` array indexed by a
 slot; a row finds its slot by a sort-merge of the batch's keys with a
-persistent int64 key table (`probe_table`).
+persistent int64 key table (`probe_table`) or, where the table is much larger
+than the flow, through a hashed bucket index kept beside it (`_probe_heads`).
 Within a batch, keyed running values ride a SORTED view of the rows — one
 lexsort by (key, reset-era) turns every per-key reduction into a log-depth
 segmented scan (ops/prefix.py), replacing the earlier [B,B] masked-reduction
@@ -38,6 +39,26 @@ payload sort back take 0.21 ms together where the matrix took 1.49
 (`jit__step_impl` 2.79 -> 1.52 ms a send, my traced runs, PR 39; PERF.md §6
 has them by operation), and G is no longer bounded by what a matrix of
 B x G can hold.
+
+But the merge sorts the table: at G = 2,228,224 slots (NEXMark query 5's live
+auctions) its two sorts of B + G = 2,293,760 rows took 5.56 + 2.93 ms of every
+selector pass, 12.0 of the 19.2 ms of a micro-batch (ledger, PR 40), to find
+the slots of 65,536 rows that sit on some 4,300 distinct keys. Such a table
+keeps a hashed bucket index (PR 41, PERF.md sections 3 and 6): `[NB, 128]`
+lanes of (key, slot), looked up once per segment head of the sorted view, a
+tile of 8,192 heads at a time, so that a pass costs the batch's distinct keys
+and not the table. The primitive it rests on, measured on a TPU v5e (my chip
+runs, PR 41): a gather of 8,192 rows of 128 32-bit words out of a
+`[65536, 128]` lane (32 MB) takes 0.10 ms in the chunk program, 12.4 ns a row
+(0.04 ms out of a 16 MB lane alone in a loop; three lanes apart 0.121, one
+`[NB, 384]` lane 0.108: not worth a layout of its own); the dense compare of
+the tile's `[8192, 128]` rows 0.013 ms and its reductions 0.009; a scatter
+of 8,192 elements into such a lane 0.106 ms, 13 ns an element. A whole table
+step (`assign_slots`, the count lane, the release) at B = 65,536 then takes
+3.5-4.05 ms whatever the table holds, where the merge's takes 3.18 ms at
+G = 65,536, 3.64 at 262,144 (the index: 3.67), 4.78 at 524,288 (3.63), 6.89 at
+1,048,576 and 11.61 at 2,228,224 (4.05): the crossover lies at four slots a
+row of the flow, and `BUCKET_SLOTS_PER_ROW` engages the index from eight.
 """
 
 from __future__ import annotations
@@ -54,12 +75,34 @@ from siddhi_tpu.ops.prefix import (
     segmented_cum_extreme,
     segmented_cumsum,
 )
-from siddhi_tpu.ops.scatter import compact_set_at, set_at
+from siddhi_tpu.ops.scatter import U32Pair, compact_set_at, set_at
 
 # How `assign_slots` finds a row's slot in the key table: `probe_table`'s
-# sort-merge, the one form there is. Static, reported as
-# `snapshot_status()["queries"][q]["group"]["probe"]` and `["partition"]["probe"]`.
-PROBE = "merge"
+# sort-merge of the batch with the whole table, or, where the table is much
+# larger than the flow, the hashed bucket index kept beside it (`probe_for`
+# decides, from the shapes alone, when the table is built). Static, reported
+# as `snapshot_status()["queries"][q]["group"]["probe"]` and
+# `["partition"]["probe"]`.
+PROBE_MERGE, PROBE_BUCKET = "merge", "bucket"
+
+# A table takes the bucket index where it has at least this many slots for
+# every row of the flow its selector is built for. Measured on a TPU v5e at
+# a flow of B = 65,536 rows (my chip runs, PR 41: the module's docstring has
+# the figures): a table step by the merge and one through the index cost the
+# same at four slots a row (3.64 and 3.67 ms); at eight the index is ahead
+# by 1.15 ms of 4.78, at one the merge by 0.35 of 3.53. Under the crossover a
+# sort of the few rows there are beats any gather, and a small table wants
+# no index state at all.
+BUCKET_SLOTS_PER_ROW = 8
+
+# The index: [NB, INDEX_LANES] lanes (a key's two 32-bit words and its slot,
+# -1 where the lane is empty), NB the power of two with NB x INDEX_LANES >= 2 G
+# (65,536 x 128 for 2,228,224 slots: 100 MB beside 1.9 GB of state), so a
+# bucket holds 32 to 64 keys of a full table on average and 128 at most
+# (eight standard deviations above). The sorted view's segment heads are
+# looked up PROBE_TILE at a time.
+INDEX_LANES = 128
+PROBE_TILE = 8192
 
 # How a table learns that a group holds no row of the window any more, so
 # that its slot can be taken back: from an aggregator's lane that counts the
@@ -135,6 +178,13 @@ class SortedGroups:
                            set by the lane that counts rows
                            (`keyed_running_sum(rows=True)`), read by every
                            lane behind it, which writes its identity there
+
+    and, where the table keeps a bucket index (`assign_slots(index=...)`):
+
+    index:     the index with the step's new keys in it (`release_index`
+               takes out those of the groups the step freed)
+    lane_s:    [B] int32 — sorted order: the flat place of the index lane
+                           that holds the row's key (NB x S: none)
     """
 
     perm: jnp.ndarray
@@ -148,6 +198,8 @@ class SortedGroups:
     head_slot: jnp.ndarray | None = None
     free: jnp.ndarray | None = None
     freed_s: jnp.ndarray | None = None
+    index: dict | None = None
+    lane_s: jnp.ndarray | None = None
 
     @property
     def carry_read(self) -> str:
@@ -222,6 +274,238 @@ def probe_table(
     found = segmented_carry(held, is_entry | run_start)
     (back,) = permute_by(mt, found)
     return back[g:]
+
+
+# ---- the hashed bucket index ------------------------------------------------
+
+def probe_for(g: int, flow_rows: int | None) -> str:
+    """How a table of `g` slots is probed behind a selector whose flow is
+    `flow_rows` long (None: a caller that keeps no index: partitions, the
+    keys mesh, joins, patterns)."""
+    if flow_rows and g >= BUCKET_SLOTS_PER_ROW * flow_rows:
+        return PROBE_BUCKET
+    return PROBE_MERGE
+
+
+def index_buckets(g: int) -> int:
+    nb = 1
+    while nb * INDEX_LANES < 2 * g:
+        nb *= 2
+    return nb
+
+
+def bucket_of(keys, nb: int, xp=jnp):
+    """int32 bucket of each int64 key: the high bits of splitmix64's
+    finalizer (`mix_keys` passes a single column through as it is, so the
+    raw key's bits say nothing). `xp=np` computes the same on the host."""
+    bits = nb.bit_length() - 1
+    if bits == 0:
+        return xp.zeros(keys.shape, xp.int32)
+    z = keys.astype(xp.uint64) + xp.uint64(0x9E3779B97F4A7C15)
+    z = (z ^ (z >> xp.uint64(30))) * xp.uint64(0xBF58476D1CE4E5B9)
+    z = (z ^ (z >> xp.uint64(27))) * xp.uint64(0x94D049BB133111EB)
+    z = z ^ (z >> xp.uint64(31))
+    return (z >> xp.uint64(64 - bits)).astype(xp.int32)
+
+
+def empty_index(g: int) -> dict:
+    """The index of an empty table of `g` slots: the lanes, `full` (sticky:
+    a bucket had no lane left for a new key, so the index no longer holds
+    every key and `assign_slots` probes by the merge from then on) and
+    `tiles` (head tiles looked up since deploy)."""
+    shape = (index_buckets(g), INDEX_LANES)
+    return {
+        "lo": jnp.zeros(shape, jnp.uint32),
+        "hi": jnp.zeros(shape, jnp.int32),
+        "slot": jnp.full(shape, -1, jnp.int32),
+        "full": jnp.zeros((), jnp.bool_),
+        "tiles": jnp.zeros((), jnp.int64),
+    }
+
+
+def index_from_table(keys, used) -> dict:
+    """The index of a saved table, laid out on the host from its `keys` and
+    `used` lanes, which are the truth (a snapshot holds no index: restore
+    builds it, as `table_from_legacy` builds the stack). Leading axes pass
+    through."""
+    keys, used = np.asarray(keys), np.asarray(used).astype(bool)
+    g = used.shape[-1]
+    nb, lead = index_buckets(g), used.shape[:-1]
+
+    def one(k, u):
+        slots = np.flatnonzero(u).astype(np.int32)
+        bk = bucket_of(k[slots], nb, np)
+        order = np.argsort(bk, kind="stable")
+        bk, slots = bk[order], slots[order]
+        lane = np.arange(len(bk)) - np.searchsorted(bk, bk)
+        ok = lane < INDEX_LANES
+        words = U32Pair.split(k[slots[ok]].astype(np.int64))
+        out = {"lo": np.zeros((nb, INDEX_LANES), np.uint32),
+               "hi": np.zeros((nb, INDEX_LANES), np.int32),
+               "slot": np.full((nb, INDEX_LANES), -1, np.int32)}
+        at = (bk[ok], lane[ok])
+        out["lo"][at], out["hi"][at], out["slot"][at] = words.lo, words.hi, slots[ok]
+        return {**out, "full": not ok.all()}
+
+    tables = [one(k, u) for k, u in zip(keys.reshape(-1, g), used.reshape(-1, g))]
+    index = {
+        name: np.stack([t[name] for t in tables]).reshape(
+            lead + np.shape(tables[0][name]))
+        for name in ("lo", "hi", "slot", "full")
+    }
+    index["tiles"] = np.zeros(lead, np.int64)
+    return index
+
+
+def _nth_set_bit(words: tuple, n: jnp.ndarray) -> jnp.ndarray:
+    """[B] int32: the place of the n-th set bit (from 0, lowest first) of the
+    bit string whose 32-bit words are `words` ([B] uint32 each, lowest
+    first), -1 where it has no more than n. Element-wise: the word by the
+    running population count, the bit by halving."""
+    count = jax.lax.population_count
+    word = jnp.zeros_like(words[0])
+    base = jnp.zeros_like(n)
+    rest, done = n, jnp.zeros(n.shape, jnp.bool_)
+    for i, w in enumerate(words):
+        c = count(w).astype(jnp.int32)
+        here = ~done & (rest < c)
+        word = jnp.where(here, w, word)
+        base = jnp.where(here, np.int32(32 * i), base)
+        rest = jnp.where(done | here, rest, rest - c)
+        done = done | here
+    at = jnp.zeros_like(n)
+    for width in (16, 8, 4, 2, 1):
+        low = (word >> at.astype(jnp.uint32)) & np.uint32((1 << width) - 1)
+        c = count(low).astype(jnp.int32)
+        skip = rest >= c
+        rest = jnp.where(skip, rest - c, rest)
+        at = jnp.where(skip, at + width, at)
+    return jnp.where(done, base + at, np.int32(-1))
+
+
+def _tiles(n, b: int):
+    """(tile length, how many tiles hold the first `n` of `b` rows, the
+    start of tile t): the last tile of a length that the tile does not
+    divide starts early and takes some rows again."""
+    tile = min(PROBE_TILE, b)
+    return tile, (n + (tile - 1)) // tile, lambda t: jnp.minimum(t * tile, b - tile)
+
+
+def _probe_heads(index: dict, bucket_h, lo_h, hi_h, n_heads):
+    """The lookup of the first `n_heads` rows (the segment heads, moved to
+    the front: their bucket and their key's words), a tile at a time under
+    a `while_loop`, so the trips follow the distinct keys the batch really
+    holds. A tile gathers its heads' bucket rows `[T, S]` and compares them
+    densely. Returns, [B] each and meaningful on those first rows: the slot
+    of the key (-1: not in the index), the flat place of its lane (-1), the
+    bucket's empty lanes as a bit string (S / 32 words, lowest lane first);
+    and the trips."""
+    nb, s = index["slot"].shape
+    b = bucket_h.shape[0]
+    tile, trips, start = _tiles(n_heads, b)
+    lane = jnp.arange(s, dtype=jnp.int32)
+    bit = np.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
+
+    def look_up(carry):
+        t, found, at, empty = carry
+        off = start(t)
+        bk, lo, hi = (
+            jax.lax.dynamic_slice(x, (off,), (tile,)) for x in (bucket_h, lo_h, hi_h)
+        )
+        row = jnp.minimum(bk, nb - 1)  # rows behind the heads: bucket NB
+        slots = index["slot"][row]
+        hit = (
+            (slots >= 0) & (index["lo"][row] == lo[:, None])
+            & (index["hi"][row] == hi[:, None]) & (bk < nb)[:, None]
+        )
+        f = jnp.max(jnp.where(hit, slots, np.int32(-1)), axis=1)
+        a = jnp.max(jnp.where(hit, row[:, None] * s + lane, np.int32(-1)), axis=1)
+        e = jnp.sum(
+            jnp.where((slots < 0).reshape(tile, s // 32, 32), bit, np.uint32(0)),
+            axis=2, dtype=jnp.uint32,
+        )
+
+        def put(x, v):
+            return jax.lax.dynamic_update_slice(x, v, (off,))
+
+        return (t + 1, put(found, f), put(at, a),
+                tuple(put(x, e[:, w]) for w, x in enumerate(empty)))
+
+    none = jnp.full((b,), -1, jnp.int32)
+    _, found, at, empty = jax.lax.while_loop(
+        lambda carry: carry[0] < trips, look_up,
+        (jnp.zeros((), jnp.int32), none, none,
+         tuple(jnp.zeros((b,), jnp.uint32) for _ in range(s // 32))),
+    )
+    return found, at, empty, trips
+
+
+def _write_lanes(lanes: dict, first, at, vals: dict) -> dict:
+    """`lanes[name]` ([NB, S] each) with `vals[name][i]` written at the flat
+    place `at[i]` on the rows of `first` ([B] bool; no place twice). The
+    writers are moved to the front by one sort and scattered a tile at a
+    time, so the cost follows their number."""
+    nb, s = next(iter(lanes.values())).shape
+    b = at.shape[0]
+    pos = jnp.arange(b, dtype=jnp.int32)
+    names = sorted(lanes)
+    _, at_c, *vals_c = jax.lax.sort(
+        (jnp.where(first, pos, pos + b), jnp.where(first, at, np.int32(nb * s)),
+         *(vals[name] for name in names)),
+        num_keys=1, is_stable=False,
+    )
+    tile, trips, start = _tiles(first.sum(dtype=jnp.int32), b)
+
+    def write(carry):
+        t, held = carry
+        off = start(t)
+        a = jax.lax.dynamic_slice(at_c, (off,), (tile,))
+        row, col = a // s, a % s  # past the writers: row NB, dropped
+        return t + 1, tuple(
+            lane.at[row, col].set(
+                jax.lax.dynamic_slice(v, (off,), (tile,)), mode="drop",
+                unique_indices=True)
+            for lane, v in zip(held, vals_c)
+        )
+
+    _, held = jax.lax.while_loop(
+        lambda carry: carry[0] < trips, write,
+        (jnp.zeros((), jnp.int32), tuple(lanes[name] for name in names)),
+    )
+    return dict(zip(names, held))
+
+
+def _index_insert(index: dict, bucket_h, lo_h, hi_h, empty_h, slot_h):
+    """The index with the step's new keys: `slot_h` is the slot a head's key
+    was given, -1 on the other rows (all in the heads' order, by bucket). A
+    new key takes its bucket's r-th empty lane, r its rank among the new
+    keys of that bucket. Returns (lanes, [B] flat place of each new key's
+    lane (NB x S: none), whether a bucket had no lane left)."""
+    nb, s = index["slot"].shape
+    new = slot_h >= 0
+    run_start = jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_), bucket_h[1:] != bucket_h[:-1]])
+    rank = segmented_cumsum(new.astype(jnp.int32), run_start) - new
+    lane = _nth_set_bit(empty_h, rank)
+    fits = new & (lane >= 0)
+    at = jnp.where(fits, bucket_h * s + lane, np.int32(nb * s))
+    lanes = _write_lanes(
+        {name: index[name] for name in ("lo", "hi", "slot")}, fits, at,
+        {"lo": lo_h, "hi": hi_h, "slot": slot_h},
+    )
+    return lanes, at, (new & ~fits).any()
+
+
+def release_index(index: dict, grp: "SortedGroups") -> dict:
+    """The index without the keys of the groups the step freed
+    (`grp.freed_s`): their lanes, where the probe found them or the step put
+    them (`grp.lane_s`), read empty again."""
+    freed = grp.freed_s
+    lanes = _write_lanes(
+        {"slot": index["slot"]}, freed, grp.lane_s,
+        {"slot": jnp.full(freed.shape, -1, jnp.int32)},
+    )
+    return {**index, **lanes}
 
 
 def free_stack(g: int) -> jnp.ndarray:
@@ -314,6 +598,7 @@ def assign_slots(
     active: jnp.ndarray,      # [B] bool — rows that carry a group key
     reset: jnp.ndarray | None = None,  # [B] bool — RESET rows clear the table
     free: jnp.ndarray | None = None,   # [G] int32 — the stack of unused slots
+    index: dict | None = None,         # the table's bucket index, if it keeps one
 ):
     """Map each active row to a stable slot in [0, G); allocate new slots in
     first-appearance order. Inactive rows get slot == G (scatter-drop lane).
@@ -323,6 +608,14 @@ def assign_slots(
     the other counts on from `n_used`; the stack behind the step's pops comes
     back as `SortedGroups.free`, and `release_slots` pushes what the step
     freed once the lane that counts rows has said which groups are empty.
+
+    A table with a bucket index (`index`, `empty_index`) finds the slots
+    through it, once per segment head of the sorted view (`_probe_heads`),
+    and keeps it up: the step's new keys are written into their buckets and
+    the index comes back as `SortedGroups.index`; `release_index` takes out
+    the keys of the groups the step freed. `table_keys` and `used` stay the
+    truth: once a bucket has had no lane for a new key (`index["full"]`,
+    sticky), the table is probed by the merge again, which reads them.
 
     RESET semantics: a reset kills every group's carried state, so rows after
     the batch's last reset re-allocate into a FRESH table (bounding table
@@ -372,8 +665,43 @@ def assign_slots(
 
     # ---- resolution against the old table (pre-reset rows + no-reset case;
     # the lookup takes no notice of eras)
-    with jax.named_scope("group.probe"):
-        t_slot = jnp.where(active, probe_table(table_keys, used, batch_keys), -1)
+    if index is None:
+        with jax.named_scope("group.probe"):
+            t_slot = jnp.where(active, probe_table(table_keys, used, batch_keys), -1)
+    else:
+        with jax.named_scope("group.probe"):
+            # the heads to the front, by bucket: H order. `pos_h` is the
+            # sorted position a row of H came from
+            nb, lanes = index["slot"].shape
+            head_s = seg_start & sa
+            words = U32Pair.split(sk)
+            bucket_h, pos_h, lo_h, hi_h = jax.lax.sort(
+                (jnp.where(head_s, bucket_of(sk, nb), np.int32(nb)), idx,
+                 words.lo, words.hi),
+                num_keys=2, is_stable=False,
+            )
+
+            def by_index(_):
+                return _probe_heads(
+                    index, bucket_h, lo_h, hi_h, head_s.sum(dtype=jnp.int32))
+
+            def by_merge(_):
+                # every bucket reads full, so nothing more is written
+                (h_of,) = permute_by(pos_h, idx)
+                (t_s,) = grp.to_sorted(probe_table(table_keys, used, batch_keys))
+                (t_h,) = permute_by(h_of, t_s)
+                none = jnp.full((b,), -1, jnp.int32)
+                return (t_h, none, (jnp.zeros((b,), jnp.uint32),) * (lanes // 32),
+                        jnp.zeros((), jnp.int32))
+
+            found_h, at_h, empty_h, trips = jax.lax.cond(
+                index["full"], by_merge, by_index, None)
+            # back to the sorted view: a head's answer is its segment's.
+            # `h_of` is the place in H of a row of the sorted view
+            found_s, at_s, h_of = permute_by(pos_h, found_h, at_h, idx)
+            found_s, at_s = segmented_carry((found_s, at_s), seg_start)
+            probed_s = jnp.where(sa, found_s, np.int32(-1))
+            (t_slot,) = grp.from_sorted(probed_s)
     in_t = t_slot >= 0
 
     is_alloc = active & ~in_t & is_head
@@ -395,7 +723,10 @@ def assign_slots(
     # at my head" is one payload sort to the sorted view and one segmented
     # carry there, not a gather per row; the slots come out in sorted order,
     # which is where the aggregators' carried values are read
-    ts_s, sn_s, rf_s = grp.to_sorted(t_slot, slot_new, rank_f)
+    if index is None:
+        ts_s, sn_s, rf_s = grp.to_sorted(t_slot, slot_new, rank_f)
+    else:
+        ts_s, (sn_s, rf_s) = probed_s, grp.to_sorted(slot_new, rank_f)
     head_sn, head_rf = segmented_carry((sn_s, rf_s), seg_start)
     post_s = perm > glr
     old_s = jnp.where(ts_s >= 0, ts_s, jnp.where(head_sn < g, head_sn, g))
@@ -436,6 +767,38 @@ def assign_slots(
         # a reset empties the table: its fresh allocations count up from 0,
         # which is what an empty stack's top holds
         grp.free = jnp.where(any_reset, free_stack(g), free)
+    if index is not None:
+        with jax.named_scope("group.probe"):
+            # the heads whose key the new table holds and the old index did
+            # not: the step's allocations, or, behind a reset, which empties
+            # the index as it empties the table, the fresh table's keys
+            new_old = seg_start & sa & (ts_s < 0) & (sn_s < g)
+            if has_reset:
+                new_s = jnp.where(
+                    any_reset, seg_start & sa & post_s & (rf_s < g), new_old)
+                given_s = jnp.where(any_reset, rf_s, sn_s)
+                index = {**index, "slot": jnp.where(
+                    any_reset, np.int32(-1), index["slot"])}
+                empty_h = tuple(
+                    jnp.where(any_reset, np.uint32(0xFFFFFFFF), e) for e in empty_h)
+            else:
+                new_s, given_s = new_old, sn_s
+            (slot_h,) = permute_by(h_of, jnp.where(new_s, given_s, np.int32(-1)))
+            written, put_h, no_lane = _index_insert(
+                index, bucket_h, lo_h, hi_h, empty_h, slot_h)
+            # where each row's key lies in the index now, for `release_index`
+            none = np.int32(nb * lanes)
+            (put_s,) = permute_by(pos_h, put_h)
+            put_s = segmented_carry(put_s, seg_start)
+            found_at = jnp.where(at_s >= 0, at_s, none)
+            grp.lane_s = jnp.where(
+                sa, jnp.where(any_reset | (ts_s < 0), put_s, found_at), none)
+            # an index built afresh behind a reset holds every key again
+            grp.index = {
+                **written,
+                "full": no_lane | (index["full"] & ~any_reset),
+                "tiles": index["tiles"] + trips.astype(jnp.int64),
+            }
     return new_keys, new_used, new_n, slot, grp, overflow
 
 

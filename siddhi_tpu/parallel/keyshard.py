@@ -185,6 +185,11 @@ class KeyShardedGroupExec:
                 f"query '{qr.query_id}': cannot key-shard after state "
                 "materialized"
             )
+        # a device's share of the keys is probed by the merge: the bucket
+        # index (ops/group.py) has no form that import_state could re-hash
+        from siddhi_tpu.ops.group import PROBE_MERGE
+
+        qr.selector.group.probe = PROBE_MERGE
         qr._keyshard = self
         qr._step = self._jit
 
