@@ -7,6 +7,35 @@ Nothing here names a cell, a configuration or a metric: those are data
 modules found by name (`drivers/<driver>.py`, `layer_metrics/<name>.py`).
 From the program the benchmark takes `SiddhiManager`, `send_columns`, the
 query callback, `snapshot_status()` and `profile_report()["compile"]`.
+
+A configuration's `reference.py` says what the query callback is owed, in
+one of two forms, told apart by what the file defines:
+
+- kept form: `kept(cols)` marks the input rows that emit, one emission each,
+  in arrival order, and `Running(sizes, control=False)` with
+  `step(ts, cols, leaving, emit)` carries the state over the kept rows alone.
+  Emission number d is the d-th kept row, so what is due at any row is known
+  before a reference has run.
+- replay form: no `kept`; `Replay(sizes, control=False)` with
+  `feed(stream, ts, cols, emit) -> (n, lanes)`. After the window `feed` is
+  called once for every `send_columns` call the deployment made, in the order
+  they were made, from the first send of the fill, with the stream's name and
+  the timestamps and columns as a reference sees them (string columns as
+  indices into `gen.STRINGS`). `n` is how many emissions the query callback is
+  owed by the time that call returns; `lanes`, where `emit` is true, is one
+  array per name in the configuration's `outputs` plus `event_time`, each of
+  length `n`, in the order in which the callback has to receive them (with
+  `emit` false it may be None: a reference may count more cheaply than it
+  emits). For patterns, joins, tumbling windows and `@app:watermark`
+  reordering: queries stated on event time. An emission that a wall-clock
+  timer causes arrives between sends and is outside this contract.
+
+With `"streams": ["A", "B"]` in place of `"stream"`, `gen.make(seed, rows)`
+returns one pool per stream, `gen.split(lo, hi)` the rows `(a, b)` of each
+stream, in that stream's own count, that lie inside rows lo..hi-1 of the
+merged stream, and `gen.timestamps(a, b, stream)` (and `with_index(cols, a, b,
+ts, stream)`, where there is one) take the stream's name. One send of a driver
+is then one `send_columns` per stream, in the order of `streams`.
 """
 
 from __future__ import annotations
@@ -81,6 +110,9 @@ def load_cell(manifest_path: Path, workload: str, rehearse: bool) -> dict:
     cfg_entry = next(c for c in manifest["configs"] if c["name"] == w["config"])
     cfg_file = root / cfg_entry["file"]
     cfg = json.loads(cfg_file.read_text())
+    # readers and `<stream>` ask for one input stream: the first
+    cfg.setdefault("streams", [cfg.get("stream")])
+    cfg.setdefault("stream", cfg["streams"][0])
     bench_dir = root / manifest["paths"][0]
     traffic = json.loads(
         (bench_dir / "traffic" / f"{w['traffic']}.json").read_text())
@@ -151,38 +183,74 @@ def size_of(cell: dict, value):
     return cell["sizes"][value] if isinstance(value, str) else value
 
 
+def cyclic(pool: dict, n: int, lo: int, hi: int) -> dict:
+    """Rows lo..hi-1 of a pool of `n` rows replayed in cycles."""
+    a, b = lo % n, lo % n + (hi - lo)
+    if b <= n:
+        return {k: v[a:b] for k, v in pool.items()}
+    # the stretch wraps round the pool: piece it together
+    cuts = [(a, n)] + [(0, n)] * (b // n - 1) + [(0, b % n)]
+    return {k: np.concatenate([v[i:j] for i, j in cuts])
+            for k, v in pool.items()}
+
+
 class Stream:
     """The seeded input stream. A pool of rows is generated once from the
     seed and replayed in cycles; event time follows the global row index, so
     it never repeats. Every row of the stream is addressable, which is what
-    lets the reference be run on any stretch of it after the window."""
+    lets the reference be run on any stretch of it after the window.
 
-    def __init__(self, gen, reference, seed: int, pool_rows: int):
+    With several input streams (`names`) the pool is one per stream and a
+    stretch of the merged stream is cut into one part for each (`parts`).
+    Under a reference of the replay form, which rows emit is known only once
+    the reference has replayed the sends (`note_due`)."""
+
+    def __init__(self, gen, reference, seed: int, pool_rows: int,
+                 names: list | None = None):
         self.gen = gen
         self.reference = reference
+        self.names = names or [None]
         # a generator with a schedule replays whole cycles of it
         cycle = getattr(gen, "CYCLE_ROWS", 1)
         pool_rows = -(-pool_rows // cycle) * cycle
         self.pool = gen.make(seed, pool_rows)
         self.n = pool_rows
+        self.replayed = not hasattr(reference, "kept")
+        if self.replayed:
+            self.due_at = {0: 0}   # send boundary (stream row) -> emissions
+            self.emit_share = None
+            return
         keep = reference.kept(self.pool)
         self.cumk = np.concatenate([[0], np.cumsum(keep, dtype=np.int64)])
         self.kept_per_cycle = int(self.cumk[-1])
+        # the share of the rows sent that emit
+        self.emit_share = self.kept_per_cycle / self.n
 
     def columns(self, lo: int, hi: int, pool: dict | None = None):
         """(timestamps, columns) of stream rows lo..hi-1."""
-        pool = self.pool if pool is None else pool
-        a, b = lo % self.n, lo % self.n + (hi - lo)
-        if b <= self.n:
-            cols = {k: v[a:b] for k, v in pool.items()}
-        else:  # the stretch wraps round the pool: piece it together
-            cuts = [(a, self.n)] + [(0, self.n)] * (b // self.n - 1) + [
-                (0, b % self.n)]
-            cols = {k: np.concatenate([v[i:j] for i, j in cuts])
-                    for k, v in pool.items()}
+        cols = cyclic(self.pool if pool is None else pool, self.n, lo, hi)
         ts = self.gen.timestamps(lo, hi)
         with_index = getattr(self.gen, "with_index", None)
         return ts, (with_index(cols, lo, hi, ts) if with_index else cols)
+
+    def parts(self, lo: int, hi: int, pool: dict | None = None) -> list:
+        """Rows lo..hi-1 of the (merged) stream as the `send_columns` calls
+        that carry them: (stream name, timestamps, columns), one per input
+        stream in the order of `names`."""
+        if len(self.names) == 1:
+            return [(self.names[0], *self.columns(lo, hi, pool))]
+        pool = self.pool if pool is None else pool
+        with_index = getattr(self.gen, "with_index", None)
+        out = []
+        cut = self.gen.split(lo, hi)
+        for name in self.names:
+            a, b = cut[name]
+            rows = len(next(iter(pool[name].values())))
+            cols = cyclic(pool[name], rows, a, b)
+            ts = self.gen.timestamps(a, b, name)
+            out.append((name, ts, with_index(cols, a, b, ts, name)
+                        if with_index else cols))
+        return out
 
     def kept_columns(self, d_lo: int, d_hi: int):
         """(timestamps, columns) of emissions d_lo..d_hi-1: the stream rows
@@ -196,9 +264,27 @@ class Stream:
         return ts[keep], {k: v[keep] for k, v in cols.items()}
 
     def kept_before(self, i):
-        """How many of stream rows 0..i-1 produce an emission."""
+        """How many emissions stream rows 0..i-1 produce. Under the replay
+        form only the ends of sends can be asked for, once the reference has
+        replayed them."""
         i = np.asarray(i, dtype=np.int64)
+        if self.replayed:
+            try:
+                return np.asarray([self.due_at[int(r)] for r in i.ravel()],
+                                  dtype=np.int64).reshape(i.shape)
+            except KeyError as row:
+                raise LookupError(
+                    f"what is due at stream row {row} is not known: under a "
+                    f"reference of the replay form only the ends of sends "
+                    f"are, and only after `compare_samples`") from None
         return (i // self.n) * self.kept_per_cycle + self.cumk[i % self.n]
+
+    def note_due(self, ends, due) -> None:
+        """Replay form: the emissions owed once the send that ends at each
+        stream row of `ends` has returned."""
+        self.due_at.update(zip(map(int, ends), map(int, due)))
+        if len(ends) and ends[-1]:
+            self.emit_share = int(due[-1]) / int(ends[-1])
 
     def raw_of_kept(self, d: int) -> int:
         """Stream row that produces emission number d (0-based)."""
@@ -281,41 +367,52 @@ class Deployment:
             self.string_index[col] = {s: i for i, s in enumerate(names)}
         batch = cell["sizes"]["batch"]
         pool_rows = cell["traffic"]["pool_batches"] * batch
-        self.stream = Stream(self.gen, self.reference, seed, pool_rows)
+        names = cfg["streams"]
+        self.stream = Stream(self.gen, self.reference, seed, pool_rows, names)
+
+        def interned(pool: dict) -> dict:
+            return {k: (ids[k][v] if k in ids else v) for k, v in pool.items()}
+
         # what is sent carries interned ids; the reference sees indices
-        self.send_pool = {
-            k: (ids[k][v] if k in ids else v)
-            for k, v in self.stream.pool.items()
-        }
+        self.send_pool = (
+            interned(self.stream.pool) if len(names) == 1 else
+            {name: interned(pool) for name, pool in self.stream.pool.items()})
         self.recorder = recorder
         self.rt = self.mgr.create_siddhi_app_runtime(text)
         self.rt.add_callback(cfg["query"], self.recorder)
         self.rt.start()
-        self.handler = self.rt.get_input_handler(cfg["stream"])
+        self.handlers = {name: self.rt.get_input_handler(name)
+                         for name in names}
         self.cursor = 0           # next stream row to send
         self.sends: list[tuple] = []  # (t_start, t_end, lo, hi, ok)
+        # (t_start, t_end) of every `send_columns` call, in the order made:
+        # the parts of sends[0], then those of sends[1], ...
+        self.calls: list[tuple] = []
 
     def prepare(self, rows: int):
         """Columns of the next `rows` stream rows, ready to send."""
         lo, hi = self.cursor, self.cursor + rows
         self.cursor = hi
-        ts, cols = self.stream.columns(lo, hi, self.send_pool)
-        return lo, hi, ts, cols
+        return lo, hi, self.stream.parts(lo, hi, self.send_pool)
 
     def send(self, prepared) -> float:
-        """One `send_columns` call; returns the seconds the caller was
-        blocked. A send that raises is logged and counted as failed."""
-        lo, hi, ts, cols = prepared
+        """One send of a driver: one `send_columns` call per input stream;
+        returns the seconds the caller was blocked. A send that raises is
+        logged and counted as failed."""
+        lo, hi, parts = prepared
         ok = True
-        t0 = time.perf_counter()
-        try:
-            self.handler.send_columns(ts, cols)
-        except Exception:
-            ok = False
-            say("send raised:\n" + traceback.format_exc())
-        t1 = time.perf_counter()
-        self.sends.append((t0, t1, lo, hi, ok))
-        return t1 - t0
+        for name, ts, cols in parts:
+            t0 = time.perf_counter()
+            try:
+                self.handlers[name].send_columns(ts, cols)
+            except Exception:
+                ok = False
+                say("send raised:\n" + traceback.format_exc())
+            t1 = time.perf_counter()
+            self.calls.append((t0, t1))
+        first = self.calls[-len(parts)][0]
+        self.sends.append((first, t1, lo, hi, ok))
+        return t1 - first
 
     def status(self) -> dict:
         return self.rt.snapshot_status()
@@ -326,11 +423,18 @@ class Deployment:
 
     def fill(self) -> None:
         """Sends that fill the configuration's device state: at least one,
-        and as many as bring `fill_kept_rows` rows into it."""
+        and as many as bring `fill_kept_rows` rows into it. Under the replay
+        form, where no row is known to be kept before the reference has run,
+        the configuration states the fill in rows sent: `fill_rows`."""
         cfg = self.cell["config"]
         rows = cfg["fill_send_batches"] * self.cell["sizes"]["batch"]
-        need = size_of(self.cell, cfg["fill_kept_rows"])
         self.send(self.prepare(rows))
+        if self.stream.replayed:
+            need = size_of(self.cell, cfg["fill_rows"])
+            while self.cursor < need:
+                self.send(self.prepare(rows))
+            return
+        need = size_of(self.cell, cfg["fill_kept_rows"])
         while self.stream.kept_before(self.cursor) < need:
             self.send(self.prepare(rows))
 
@@ -369,12 +473,14 @@ def lookup(tree: dict, dotted: str):
 
 def check_paths(cell: dict, status: dict, expect: dict, what: str) -> list:
     """Dotted paths into `snapshot_status()` against expected values;
-    `<stream>` stands for the configuration's input stream. Returns the
-    failures as text."""
+    `<stream>` stands for the configuration's (first) input stream. A string
+    that names one of the configuration's sizes stands for that size, any
+    other string for itself. Returns the failures as text."""
     bad = []
     for path, want in expect.items():
         path = path.replace("<stream>", cell["config"]["stream"])
-        want = size_of(cell, want)
+        if isinstance(want, str):
+            want = cell["sizes"].get(want, want)
         try:
             got = lookup(status, path)
         except (KeyError, TypeError):
@@ -415,52 +521,145 @@ def lane_gap(got: np.ndarray, ref: np.ndarray, rule: dict) -> float:
 SWEEP_ROWS = 1 << 20  # emissions per step while the reference only moves on
 
 
-def compare_samples(dep: Deployment, samples: list, control=False):
-    """Carry the configuration's reference along the whole stream, from its
-    first row, and compare what the callback received in each kept sample
-    with what the reference emits for those rows: {number compared: (value,
-    limit)}. The reference sees the input stream alone. With `control`, the
-    reference in its lower precision is carried along too and stands in the
-    program's place: a second dict of the same numbers, its emissions over
-    the same rows against the reference's, is returned beside the first."""
-    cell, stream = dep.cell, dep.stream
-    rules = cell["config"]["compare"]
-    history = size_of(cell, cell["config"]["history_kept_rows"])
-    runs = [dep.reference.Running(cell["sizes"])]
-    if control:
-        runs.append(dep.reference.Running(cell["sizes"], control=True))
-    worst = [{lane: 0.0 for lane in rules} for _ in runs]
-    rows = at = 0
+class KeptEmissions:
+    """What a reference of the kept form owes the callback, through the
+    interface the replay form is served by (`ReplayEmissions`): emission
+    number d is the d-th kept row of the stream, so what is due after a send
+    is arithmetic, and the reference is carried over the kept rows alone."""
 
-    def advance(upto: int, emit: bool) -> list:
+    def __init__(self, dep: Deployment, control: bool):
+        cell = dep.cell
+        self.stream = dep.stream
+        self.history = size_of(cell, cell["config"]["history_kept_rows"])
+        self.runs = [dep.reference.Running(cell["sizes"])]
+        if control:
+            self.runs.append(dep.reference.Running(cell["sizes"], control=True))
+        self.at = 0
+
+    def _advance(self, upto: int, emit: bool) -> list:
+        stream, at, history = self.stream, self.at, self.history
         ts, cols = stream.kept_columns(at, upto)
         _, leaving = stream.kept_columns(max(at - history, 0),
                                          max(upto - history, 0))
-        return [run.step(ts, cols, leaving, emit) for run in runs]
+        self.at = upto
+        return [run.step(ts, cols, leaving, emit) for run in self.runs]
 
+    def lanes(self, d0: int, d1: int) -> list:
+        """Emissions d0..d1-1 as each run states them; asked for in rising
+        order."""
+        if not self.history:  # nothing is carried: no row before counts
+            self.at = d0
+        while self.at < d0:  # the rows between samples move the state alone
+            self._advance(min(self.at + SWEEP_ROWS, d0), emit=False)
+        return self._advance(d1, emit=True)
+
+    def finish(self) -> None:
+        """What is due after every send needs no further step."""
+
+
+class ReplayEmissions:
+    """What a reference of the replay form owes the callback: its `Replay`
+    is fed every `send_columns` call the deployment made, in order, in one
+    pass. A call emits where a compared sample was delivered during it (the
+    sample's callback ran between the call's start and its return); every
+    other call only counts."""
+
+    def __init__(self, dep: Deployment, control: bool):
+        cell, rec = dep.cell, dep.recorder
+        self.dep = dep
+        self.runs = [dep.reference.Replay(cell["sizes"])]
+        if control:
+            self.runs.append(dep.reference.Replay(cell["sizes"], control=True))
+        self.call_end = np.asarray([t1 for _, t1 in dep.calls])
+        # a sample is known by the rows delivered before it: find its clock
+        self.before = np.cumsum(rec.n) - np.asarray(rec.n)
+        self.fed = 0                 # send_columns calls fed so far
+        self.due = [0]               # the reference's emissions after each
+        self.pending = self._calls()
+        self.held = None             # (call, emissions before it, lanes per run)
+
+    def _calls(self):
+        for _, _, lo, hi, _ in self.dep.sends:
+            yield from self.dep.stream.parts(lo, hi)
+
+    def _feed(self, emit: bool):
+        name, ts, cols = next(self.pending)
+        out = [run.feed(name, ts, cols, emit) for run in self.runs]
+        self.held = (self.fed, self.due[-1], [lanes for _, lanes in out])
+        self.due.append(self.due[-1] + int(out[0][0]))
+        self.fed += 1
+
+    def lanes(self, d0: int, d1: int) -> list:
+        """Emissions d0..d1-1 as each run states them, or None for a run
+        that states no such emissions in the call that delivered them."""
+        j = int(np.searchsorted(self.before, d0))
+        call = min(int(np.searchsorted(self.call_end, self.dep.recorder.t[j])),
+                   len(self.call_end) - 1)
+        while self.fed < call:
+            self._feed(emit=False)
+        if self.fed == call:
+            self._feed(emit=True)
+        k, start, stated = self.held
+        out = []
+        for lanes in stated:
+            n = len(lanes["event_time"]) if k == call and lanes else -1
+            inside = 0 <= d0 - start and d1 - start <= n
+            out.append({name: lane[d0 - start:d1 - start]
+                        for name, lane in lanes.items()} if inside else None)
+        return out
+
+    def finish(self) -> None:
+        """Feed what is left, and tell the stream what is due after each
+        send: that of its last call."""
+        while self.fed < len(self.dep.calls):
+            self._feed(emit=False)
+        parts = len(self.dep.stream.names)
+        self.dep.stream.note_due([s[3] for s in self.dep.sends],
+                                 self.due[parts::parts])
+
+
+def compare_samples(dep: Deployment, samples: list, control=False):
+    """Carry the configuration's reference along the whole stream, from its
+    first row, and compare what the callback received in each kept sample
+    with the emissions the reference states at those places: count, order
+    and values, as {number compared: (value, limit)}. The reference sees the
+    input stream alone. With `control`, the reference in its lower precision
+    is carried along too and stands in the program's place: a second dict of
+    the same numbers, its emissions at the same places against the
+    reference's, is returned beside the first. Afterwards
+    `dep.stream.kept_before` answers at the end of every send."""
+    rules = dep.cell["config"]["compare"]
+    form = ReplayEmissions if dep.stream.replayed else KeptEmissions
+    stated = form(dep, control)
+    worst = [{lane: 0.0 for lane in rules} for _ in stated.runs]
+    rows = 0
     for d0, events in sorted(samples, key=lambda s: s[0]):
-        if not history:  # nothing is carried: no row before the sample counts
-            at = d0
-        while at < d0:  # the rows between samples move the state alone
-            upto = min(at + SWEEP_ROWS, d0)
-            advance(upto, emit=False)
-            at = upto
-        out = advance(d0 + len(events), emit=True)
-        at = d0 + len(events)
-        for w, got in zip(worst, [events_to_lanes(dep, events), *out[1:]]):
+        want, *others = stated.lanes(d0, d0 + len(events))
+        for w, got in zip(worst, [events_to_lanes(dep, events), *others]):
             for lane, rule in rules.items():
-                w[lane] = max(w[lane], lane_gap(got[lane], out[0][lane], rule))
+                gap = (lane_gap(got[lane], want[lane], rule)
+                       if got is not None and want is not None else
+                       float(len(events)) if rule["limit"] == 0 else
+                       float("inf"))
+                w[lane] = max(w[lane], gap)
         rows += len(events)
+    stated.finish()
     numbers = [{**{f"{lane}.gap": (w[lane], rules[lane]["limit"])
                    for lane in rules}, "rows_compared": (rows, None)}
                for w in worst]
     return tuple(numbers) if control else numbers[0]
 
 
-def order_faults(rec: Recorder, lo: int, hi: int) -> int:
+def order_faults(rec: Recorder, lo: int, hi: int, calls=()) -> int:
     """Callbacks lo..hi-1 whose event times run backwards, within a
-    callback or against the one before."""
+    callback or against the one before. With several input streams (`calls`:
+    every `send_columns` call's start and end) each call starts over in
+    event time, so a callback is held against the one before only where
+    both were delivered during one call."""
     first = np.asarray(rec.first[lo:hi])
     last = np.asarray(rec.last[lo:hi])
-    return int(np.count_nonzero(first > last)
-               + np.count_nonzero(first[1:] < last[:-1]))
+    back = first[1:] < last[:-1]
+    if len(calls):
+        of = np.searchsorted([t1 for _, t1 in calls], rec.t[lo:hi])
+        back &= of[1:] == of[:-1]
+    return int(np.count_nonzero(first > last) + np.count_nonzero(back))

@@ -17,7 +17,6 @@ def read(trace, spans, counters, cell):
     cost = harness.load_module(cost_file)
     if not hasattr(cost, "exchange_bytes_per_microbatch"):
         return None
-    stream = spans["stream"]
     need = cost.exchange_bytes_per_microbatch(
-        cell["sizes"], stream.kept_per_cycle / stream.n)
+        cell["sizes"], spans["stream"].emit_share)
     return 100.0 * need / cost.ICI_BYTES_PER_S / (ms / 1e3)

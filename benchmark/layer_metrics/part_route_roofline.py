@@ -13,7 +13,6 @@ def read(trace, spans, counters, cell):
     cost_file = cell["config_dir"] / "cost.py"
     if not ms or not cost_file.exists():
         return None
-    stream = spans["stream"]
     need = harness.load_module(cost_file).route_bytes(
-        cell["traffic"]["send_rows"], stream.kept_per_cycle / stream.n)
+        cell["traffic"]["send_rows"], spans["stream"].emit_share)
     return part_scopes.share_of_hbm_roofline(need, ms, counters)

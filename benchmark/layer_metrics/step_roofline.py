@@ -14,11 +14,10 @@ def read(trace, spans, counters, cell):
     depth = readers.chunk_batches(counters, cell)
     if per_chunk_ms is None or not depth or not cost_file.exists():
         return None
-    stream = spans["stream"]
     wire = counters["status"]["streams"][cell["config"]["stream"]][
         "pipeline"]["wire"]["encoded_B_per_ev"]
     need = harness.load_module(cost_file).bytes_per_microbatch(
-        cell["sizes"], wire, stream.kept_per_cycle / stream.n)
+        cell["sizes"], wire, spans["stream"].emit_share)
     kind = counters["device_kind"]
     least_s = need / readers.peaks(kind)["hbm_bytes_per_s"]
     return 100.0 * least_s / (per_chunk_ms / 1e3 / depth)

@@ -17,8 +17,7 @@ def read(trace, spans, counters, cell):
     cost = harness.load_module(cost_file)
     if not hasattr(cost, "window_bytes_per_microbatch"):
         return None
-    stream = spans["stream"]
     need = cost.window_bytes_per_microbatch(
-        cell["sizes"], stream.kept_per_cycle / stream.n)
+        cell["sizes"], spans["stream"].emit_share)
     peak = readers.peaks(counters["device_kind"])["hbm_bytes_per_s"]
     return 100.0 * need / peak / (ms / 1e3)

@@ -6,7 +6,8 @@ Set-up (import, deploy, state fill, warm-up on the cell's own traffic) is
 timed as `setup_s`; then the cell's driver offers its traffic for `--seconds`
 and what the query callback received is compared with the plain reference.
 The last line of stdout is the result: one JSON object with `correct`,
-`attempted`, `failed`, `metrics`, `device` and, traced, `breakdown`. With
+`attempted`, `failed`, `metrics`, `device`, traced `breakdown`, and last
+`compared` (every number compared, with its limit). With
 `--trace 0` the metrics are the cell's end-to-end metrics; with `--trace 1`
 the window is traced (and cut to the mix's `trace_seconds`) and the metrics
 are its per-layer ones.
@@ -83,6 +84,19 @@ class CompileLog(logging.Handler):
         msg = record.getMessage()
         if msg.startswith("Finished XLA compilation of "):
             self.names.append(msg.split(" ")[4])
+
+
+def layer_values(cell: dict, trace, spans: dict, counters: dict) -> dict:
+    """{per-layer metric: (value, unit)} of the cell's entries, each from its
+    reader; a reader that finds nothing to read leaves its metric out."""
+    values = {}
+    for m in cell["per_layer"]:
+        reader = harness.load_module(
+            harness.reader_file(cell["bench_dir"], m["name"]))
+        value = reader.read(trace, spans, counters, cell)
+        if value is not None:
+            values[m["name"]] = (float(value), m["unit"])
+    return values
 
 
 def main(argv=None, manifest: Path | None = None) -> int:
@@ -194,15 +208,19 @@ def main(argv=None, manifest: Path | None = None) -> int:
     say(driver.describe(dep, traffic, win))
     bad_path = bad_setup + harness.check_paths(
         cell, status, traffic["expect"], "path engaged")
+    # the reference first: under the replay form what was due is its to say
+    t_ref = time.perf_counter()
+    compared = harness.compare_samples(dep, rec.samples)
     due = int(dep.stream.kept_before(dep.cursor))
+    several = dep.calls if len(dep.stream.names) > 1 else ()
     numbers = {
         "delivered.missing": (abs(due - rec.delivered), 0),
-        "order.faults": (harness.order_faults(rec, c0, win["cb_hi"]), 0),
+        "order.faults": (
+            harness.order_faults(rec, c0, win["cb_hi"], several), 0),
         "expired.delivered": (rec.expired_seen, 0),
         "engine.errors": (log.errors, 0),
+        **compared,
     }
-    t_ref = time.perf_counter()
-    numbers.update(harness.compare_samples(dep, rec.samples))
     say(f"reference along the stream, {len(rec.samples)} callbacks compared, took "
         f"{time.perf_counter() - t_ref:.2f} s")
     correct = True
@@ -266,13 +284,8 @@ def main(argv=None, manifest: Path | None = None) -> int:
             "programs_built_in_window": len(built),
             "device_kind": device["kind"],
         }
-        for m in cell["per_layer"]:
-            reader = harness.load_module(
-                harness.reader_file(cell["bench_dir"], m["name"]))
-            value = reader.read(trace if has_device else None, spans,
-                                counters, cell)
-            if value is not None:
-                values[m["name"]] = (float(value), m["unit"])
+        values = layer_values(cell, trace if has_device else None, spans,
+                              counters)
     else:
         end_to_end["setup_s"] = setup_s
         for m in cell["end_to_end"]:
@@ -287,7 +300,16 @@ def main(argv=None, manifest: Path | None = None) -> int:
         result["metrics"] = {k: {"value": v, "unit": u}
                              for k, (v, u) in values.items()}
     dep.close()
+    # each number compared beside its limit: last in the result and on stderr
+    big = sys.float_info.max
+    result["compared"] = {
+        name: {"value": min(float(value), big), "limit": limit}
+        for name, (value, limit) in numbers.items()}
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in numbers.items():
+        print(f"compared {name} = {value!r} (limit {limit!r})",
+              file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

@@ -221,7 +221,8 @@ def test_a_trace_without_spans_or_scopes_reads_none_everywhere(tmp_path):
     (out / "t.xplane.pb").write_bytes(gzip.decompress(packed.read_bytes()))
     trace = tr.load(str(out / "t.xplane.pb"))
     cell = {"name": "old.cell", "bench_dir": tmp_path / "benchmark",
-            "config": {"stream": "S"}}
+            "config": {"stream": "S", "query": "q"}, "sizes": {},
+            "traffic": {}, "config_dir": tmp_path / "no-such-configuration"}
     ps = ps_mod.of(cell, trace)
     assert ps.threads == [] and ps.idle_by_span() == {}
     # the scopes' road is there all the same: operations with their `tf_op`
@@ -231,12 +232,19 @@ def test_a_trace_without_spans_or_scopes_reads_none_everywhere(tmp_path):
     spans = {"sends": np.zeros((12, 4))}
     counters = {"status": {"streams": {"S": {}}}}
     manifest = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    new = [m["name"] for m in manifest["per_layer"]
-           if (BENCH / "layer_metrics" / f"{harness.stem(m['name'])}.py").exists()
-           and "program_spans" in (
-               BENCH / "layer_metrics" / f"{harness.stem(m['name'])}.py"
-           ).read_text() or harness.stem(m["name"]) == "compile_events"]
-    assert len(new) == 29
+
+    def reads_spans(metric):
+        file = harness.reader_file(BENCH, metric)
+        return file.exists() and ("program_spans" in file.read_text()
+                                  or file.stem == "compile_events")
+
+    new = [m["name"] for m in manifest["per_layer"] if reads_spans(m["name"])]
+    # every reader file that reads spans, scopes or the compile ring is some
+    # entry's: the manifest decides how many entries share one
+    files = {p.name for p in (BENCH / "layer_metrics").glob("*.py")
+             if reads_spans(p.stem)}
+    assert {harness.reader_file(BENCH, m).name for m in new} == files
+    assert len(new) >= len(files) >= 20
     for metric in new:
         reader = harness.load_module(harness.reader_file(BENCH, metric))
         assert reader.read(trace, spans, counters, cell) is None, metric

@@ -1,7 +1,8 @@
 """`run.py --rehearse` end to end on the CPU at batch 512, for every cell
 of the manifest; the same run with the timed path broken underneath has to
-come out as not correct; and a new traffic mix and a new per-layer metric
-are added as files and entries alone, in a temporary copy."""
+come out as not correct; and a new traffic mix, a new per-layer metric and a
+configuration of the replay form with two input streams are added as files
+and entries alone, in a temporary copy."""
 
 import json
 import shutil
@@ -10,6 +11,7 @@ import pytest
 
 import run as bench_run
 from conftest import BENCH
+from fixtures import tree
 
 MANIFEST = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
@@ -28,7 +30,8 @@ def rehearse(capsys, workload, trace=0, seconds=1.5, manifest=None):
 def test_rehearsal_of_each_cell(capsys, workload):
     result, out = rehearse(capsys, workload)
     assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device", "rehearsal"}
+                           "device", "rehearsal", "compared"}
+    assert list(result)[-1] == "compared"
     assert result["correct"] is True, out
     assert result["attempted"] > 0 and result["failed"] == 0
     # a CPU run reports no number under a metric's name
@@ -95,7 +98,7 @@ def test_cells_and_metrics_are_added_as_data(capsys, tmp_path):
         if m["name"] == "send_p50_ms":
             m["workloads"].append("q1-plug.trickle8k")
         if m["name"] in ("events_per_s.filter", "chunk_device_ms.filter",
-                         "compiles_in_window.filter"):
+                         "compile_events.filter"):
             m["workloads"].append("filter.bulk4m")
     manifest["per_layer"].append({
         "name": "sends_in_window", "unit": "count", "better": "higher",
@@ -107,9 +110,41 @@ def test_cells_and_metrics_are_added_as_data(capsys, tmp_path):
     assert result["correct"] is True, out
     assert "rehearsal computed (not reported): ['sends_in_window']" in out
     for trace, computed in ((0, "['events_per_s.filter', 'setup_s']"),
-                            (1, "['compiles_in_window.filter']")):
+                            (1, "['compile_events.filter']")):
         result, out = rehearse(capsys, "filter.bulk4m", trace=trace,
                                manifest=tmp_path / "BENCHMARK.json")
         assert result["correct"] is True, out
         assert f"rehearsal computed (not reported): {computed}" in out
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
+def test_a_replay_configuration_with_two_streams_is_added_as_data(
+        capsys, tmp_path):
+    """What the next deployment brings: a configuration whose reference
+    replays the sends (`t-join`: two input streams, emissions per pair), its
+    traffic mix, a cell and a per-layer reader that reads what the replay
+    found out. `tree` adds them to a copy and checks that no file that was
+    there changed."""
+    manifest = json.loads(json.dumps(MANIFEST))
+    manifest["per_layer"].append({
+        "name": "emissions_per_row", "unit": "ratio", "better": "lower",
+        "source": "program_counter", "layer": "operators / kernels",
+        "moves": "events_per_s", "workloads": ["t-join.sends"]})
+    path = tree(tmp_path, manifest)
+    before = {p: p.read_bytes()
+              for p in (tmp_path / "benchmark").rglob("*") if p.is_file()}
+    (tmp_path / "benchmark" / "layer_metrics" / "emissions_per_row.py"
+     ).write_text(
+        "def read(trace, spans, counters, cell):\n"
+        "    assert cell['config']['streams'] == ['A', 'B']\n"
+        "    assert cell['config']['stream'] == 'A'\n"
+        "    return spans['stream'].emit_share\n")
+    result, out = rehearse(capsys, "t-join.sends", trace=1, seconds=1,
+                           manifest=path)
+    assert result["correct"] is True and result["failed"] == 0, out
+    assert "rehearsal computed (not reported): ['emissions_per_row']" in out
+    result, out = rehearse(capsys, "t-join.sends", trace=0, seconds=1,
+                           manifest=path)
+    assert result["correct"] is True, out
+    assert "rehearsal computed (not reported): ['events_per_s', 'setup_s']" in out
     assert all(p.read_bytes() == b for p, b in before.items())
