@@ -574,6 +574,7 @@ def _pattern_cost(
         TensorSpec("tok.slot", (T,), "int32"),
         TensorSpec("tok.start_ts", (T,), "int64"),
         TensorSpec("tok.entry_ts", (T,), "int64"),
+        TensorSpec("tok.seq", (T,), "int64"),
     ]
     n_slots = 0
 

@@ -8,11 +8,13 @@ LogicalPreStateProcessor.java:35, AbsentStreamPreStateProcessor.java:37-140).
 
 Here the whole NFA lives in one fixed-capacity **token table** on device: every
 partial match is a row holding (current slot, capture columns for every state
-ref, occurrence counts, timestamps). Processing a micro-batch is a `lax.scan`
-over event rows; each scan step runs a static, vectorized pass per NFA slot —
-eligibility mask -> compiled condition over the token table -> capture/advance
-scatter. `every` is modelled as *persistent* slots whose tokens fork into free
-rows instead of being consumed (reference semantics: `every` re-arms via
+ref, occurrence counts, timestamps). The general path processes a micro-batch
+as a `lax.scan` over event rows; each scan step runs a static, vectorized pass
+per NFA slot — eligibility mask -> compiled condition over the token table ->
+capture/advance scatter. Simple chains take one vectorized program per batch
+instead (`apply_batch_fast`; a count state first, `apply_batch_count`). `every`
+is modelled as *persistent* slots whose tokens fork into free rows instead of
+being consumed (reference semantics: `every` re-arms via
 nextEveryStatePreProcessor, StreamPostStateProcessor.java:100-120).
 
 Count states `<m:n>` follow the reference's shared-token model exactly
@@ -23,7 +25,28 @@ same event (descending slot order, matching
 PatternMultiProcessStreamReceiver's reversed eventSequence), a trailing count
 emits at exactly min and is consumed, and min-0 counts forward/emit at arrival.
 
+Tokens that one event completes are emitted in the order in which their
+FIRST events arrived, the order of the reference's pending list
+(StreamPreStateProcessor walks `pendingStateEventList` oldest first), on every
+path. The batch kernel (`apply_batch_fast`) keeps the table as a log: tokens
+are armed into the lanes behind `head` in arrival order and the live ones are
+moved to the front, in order, when the tail has no room left, so lane order IS
+arming order there; the count kernel and the per-event scan hand out free
+lanes and order completions by the token's `seq`, its arming number.
+
+A state whose condition ties the arriving row to an earlier capture by
+equality (`row.attr == eK.attr`, any number of attributes) finds its tokens by
+key (`_match_keyed`): tokens and rows are sorted together on the key, a token
+placed behind the row that brought it there, and each token meets the rows of
+its own key alone, one candidate a pass, the residual evaluated on those
+pairs; nothing of T x B elements exists. A state with no such conjunct keeps
+the dense [T, B] match matrix (`_match_matrix`). `match_kind` says which a
+program took: `keyed`, `matrix`, `count` or `scan`.
+
 Deliberate deviations from the reference interpreter (documented, test-covered):
+- with three states or more, the matches of one event come in the order of
+  their FIRST events, where the reference's last pending list holds them in
+  the order in which they advanced into it; with two states the two are one;
 - token/capture capacity is static (`@app:patternCapacity`, `@app:countCapacity`)
   with overflow surfaced via aux flags, where the reference grows lists unboundedly;
 - `every` over a count state arms a fresh virgin token when a token's count
@@ -31,8 +54,6 @@ Deliberate deviations from the reference interpreter (documented, test-covered):
   capture chains with the parent (StateEventCloner.copyStateEvent is shallow)
   and is never re-forwarded — a structural dead end no reference test covers —
   so the clean generation-chain semantics is used instead;
-- emission order among tokens completing on the SAME event is lane order, not
-  pending-list age order;
 - counts absorb past the capture capacity on both execution paths (the
   occurrence counter keeps counting while capture writes drop), so `<m:>`
   with m above `@app:countCapacity` still fires — only the first `cap`
@@ -87,9 +108,21 @@ from siddhi_tpu.query_api.execution import (
     StateStreamType,
     StreamStateElement,
 )
-from siddhi_tpu.ops.prefix import first_indices
+from siddhi_tpu.ops.group import permute_by, permute_in_groups
+from siddhi_tpu.ops.prefix import (
+    compact_front,
+    cummax,
+    first_indices,
+    segmented_carry,
+)
 from siddhi_tpu.ops.scatter import set_at as _set_at
-from siddhi_tpu.query_api.expression import Expression
+from siddhi_tpu.query_api.expression import (
+    And,
+    Compare,
+    CompareOp,
+    Expression,
+    Variable,
+)
 
 NO_TIMER = np.int64(np.iinfo(np.int64).max)
 
@@ -99,6 +132,35 @@ DEFAULT_COUNT_CAPACITY = 8
 # Test hook: force every pattern onto the per-event scan path (the batch
 # kernels' differential oracle). Read at step-build time.
 FORCE_SCAN = False
+
+# How a program finds each token's first matching row
+# (`snapshot_status()["queries"][q]["pattern"]["match"]`).
+MATCH_KEYED, MATCH_MATRIX, MATCH_COUNT, MATCH_SCAN = (
+    "keyed", "matrix", "count", "scan"
+)
+
+# attribute types whose `==` is an equality of bits, so that a sort on the
+# value finds the equal ones (floats are not: -0.0 == 0.0, NaN != NaN)
+_KEY_TYPES = (AttrType.INT, AttrType.LONG, AttrType.BOOL, AttrType.STRING)
+
+def _conjuncts(expr: Expression) -> list:
+    if isinstance(expr, And):
+        return _conjuncts(expr.left) + _conjuncts(expr.right)
+    return [expr]
+
+
+@dataclasses.dataclass
+class SlotPlan:
+    """A slot's condition cut into its conjuncts: those that read the
+    arriving row alone (`row`), equalities between an attribute of the row
+    and one of an earlier capture (`keys`: row attribute, the capture's ref
+    index and attribute, the type) and the rest, which reads both (`rest`,
+    with the VarKeys it reads)."""
+
+    row: list
+    keys: list
+    rest: list
+    rest_keys: frozenset
 
 
 def _min_within(slot_ms, global_ms):
@@ -259,6 +321,87 @@ def _flatten_state(
         raise SiddhiAppCreationError(f"unsupported state element {type(elem).__name__}")
 
 
+def _key_words(x: jnp.ndarray) -> list:
+    """An integral lane as the 32-bit words a sort compares: equal values
+    have equal words (the order among unequal ones does not matter)."""
+    if x.dtype == jnp.bool_:
+        return [x.astype(jnp.int32)]
+    if x.dtype.itemsize < 8:
+        return [x.astype(jnp.int32)]
+    return [
+        (x >> 32).astype(jnp.int32),
+        (x & np.int64(0xFFFFFFFF)).astype(jnp.uint32),
+    ]
+
+
+def _pair_lanes(tl: dict, rl: dict, T: int, B: int) -> list:
+    """Token-side [T] lanes and row-side [B] lanes as [T + B] lanes, a token
+    lane and a row lane of one dtype sharing one (a token element reads the
+    one, a row element the other): (lane, token key or None, row key or
+    None), so that a payload sort carries as few operands as it can."""
+    out = []
+    tk = sorted(tl, key=repr)
+    rk = sorted(rl, key=repr)
+    for dt in sorted({str(v.dtype) for v in (*tl.values(), *rl.values())}):
+        ts_ = [k for k in tk if str(tl[k].dtype) == dt]
+        rs_ = [k for k in rk if str(rl[k].dtype) == dt]
+        for i in range(max(len(ts_), len(rs_))):
+            t = ts_[i] if i < len(ts_) else None
+            r = rs_[i] if i < len(rs_) else None
+            out.append((
+                jnp.concatenate([
+                    tl[t] if t is not None else jnp.zeros((T,), dt),
+                    rl[r] if r is not None else jnp.zeros((B,), dt),
+                ]),
+                t, r,
+            ))
+    return out
+
+
+def tokens_from_legacy(tok: dict, like: dict) -> dict:
+    """A token table saved before its tokens carried their arming order
+    (before PR 43: any free lane took a new token), in the layout of `like`:
+    the live tokens move to the front, virgins first and the others in the
+    order of their first events (`start_ts`, then the lane they held: what
+    such a snapshot still knows of it), `seq` numbers them so and `head`
+    stands behind them; the counters start at zero. On the host, in numpy;
+    leading axes (a partition's [P]) pass through."""
+    active = np.asarray(tok["active"])
+    T = active.shape[-1]
+    lead = active.ndim - 1
+    lanes = np.broadcast_to(np.arange(T), active.shape)
+    start = np.asarray(tok["start_ts"])
+    order = np.lexsort((lanes, np.where(start < 0, -1, start), ~active), axis=-1)
+
+    def move(x):
+        x = np.asarray(x)
+        if x.ndim <= lead or x.shape[lead] != T:
+            return x
+        idx = order.reshape(order.shape + (1,) * (x.ndim - lead - 1))
+        return np.take_along_axis(x, idx, axis=lead)
+
+    out = jax.tree_util.tree_map(move, dict(tok))
+    n = active.sum(axis=-1)
+    out["seq"] = np.broadcast_to(np.arange(T, dtype=np.int64), active.shape).copy()
+    out["next_seq"] = n.astype(np.int64) + 1
+    out["head"] = n.astype(np.int32)
+    for k in ("armed", "expired", "completed", "refused", "max_row"):
+        out[k] = np.zeros(n.shape, like[k].dtype)
+    return out
+
+
+def _longest_run(keys_sorted: jnp.ndarray, n) -> jnp.ndarray:
+    """The longest run of equal values among the first `n` of a sorted
+    [M] lane, int32: the most matches one row completed."""
+    m = keys_sorted.shape[0]
+    idx = jnp.arange(m, dtype=jnp.int32)
+    opens = jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_), keys_sorted[1:] != keys_sorted[:-1]]
+    )
+    start = cummax(jnp.where(opens, idx, 0))
+    return jnp.max(jnp.where(idx < n, idx - start + 1, 0))
+
+
 class PatternProgram:
     """Compiled NFA: slot chain + per-atom conditions + token-table layout."""
 
@@ -316,6 +459,10 @@ class PatternProgram:
                 self._conds[(slot.index, atom.ref_idx)] = conds
                 self._cond_keys[(slot.index, atom.ref_idx)] = keys
 
+        self._plans = {
+            slot.index: self._plan_slot(slot) for slot in self.slots
+        }
+
         self.stream_ids = sorted({a.stream_id for a in self.refs})
         self.needs_scheduler = any(
             a.waiting_ms is not None for a in self.refs
@@ -334,6 +481,98 @@ class PatternProgram:
         # so next-slot pending membership is a contended, per-event win —
         # SequenceTestCase testQuery6/11). Patterns keep implicit count-skip.
         self._use_fwd = self.sequence and any(s.is_count for s in self.slots)
+
+    # ---- the keyed match's plan ------------------------------------------
+
+    def _plan_slot(self, slot: Slot) -> Optional[SlotPlan]:
+        """Cut the slot's condition into row-only, key-equality and other
+        conjuncts, or None where the batch kernel cannot take it apart (a
+        logical or count slot, an indexed capture read)."""
+        if len(slot.atoms) != 1 or slot.is_count or slot.is_absent:
+            return None
+        atom = slot.atoms[0]
+        if atom.cap != 1:
+            return None
+        by_ref = {a.ref: a for a in self.refs}
+        plan = SlotPlan([], [], [], frozenset())
+        rest_keys: set = set()
+        for f in atom.filters:
+            for e in _conjuncts(f):
+                s = self.scope.child()
+                s.default_ref = atom.ref
+                s.prefer_default = True
+                c = compile_expression(e, s)
+                keys = set(s.used_keys)
+                if any(
+                    k[1] not in (None, 0) or k[0] not in by_ref
+                    or k[2] == "__arrived__" for k in keys
+                ):
+                    return None
+                if all(k[0] == atom.ref for k in keys):
+                    plan.row.append(c)
+                    continue
+                pair = self._key_pair(e, s, atom, by_ref)
+                if pair is not None:
+                    plan.keys.append(pair)
+                    continue
+                plan.rest.append(c)
+                rest_keys |= keys
+        plan.rest_keys = frozenset(rest_keys)
+        return plan
+
+    def _key_pair(self, e, scope, atom: Atom, by_ref: dict):
+        """(row attribute, capture ref index, capture attribute, type) of a
+        conjunct `row.attr == eK.attr` (either way round) over one integral
+        type, eK an earlier single capture; else None."""
+        if not (
+            isinstance(e, Compare) and e.op is CompareOp.EQ
+            and isinstance(e.left, Variable) and isinstance(e.right, Variable)
+        ):
+            return None
+        (lk, lt), (rk, rt) = scope.resolve(e.left), scope.resolve(e.right)
+        if lt is not rt or lt not in _KEY_TYPES:
+            return None
+        if rk[0] == atom.ref:
+            lk, rk = rk, lk
+        other = by_ref.get(rk[0])
+        if (
+            lk[0] != atom.ref or other is None or other.ref_idx >= atom.ref_idx
+            or other.cap != 1 or TS_ATTR in (lk[2], rk[2])
+        ):
+            return None
+        return (lk[2], other.ref_idx, rk[2], lt)
+
+    def slot_keyed(self, p: int) -> bool:
+        """Whether the batch kernel matches slot p's tokens by key."""
+        plan = self._plans.get(p)
+        return (
+            plan is not None and bool(plan.keys) and not self.sequence
+            and not (p == 0 and self.slots[0].persistent)
+        )
+
+    def slot_row_only(self, p: int) -> bool:
+        """Whether slot p's whole condition reads the arriving row alone."""
+        plan = self._plans.get(p)
+        return plan is not None and not plan.keys and not plan.rest
+
+    @property
+    def match_kind(self) -> str:
+        """How the program built for this pattern finds a token's first
+        matching row: `keyed` when every state the batch kernel matches ties
+        the row to a capture by equality (an `every` first state filters rows
+        alone), `matrix` when some state keeps the dense [T, B] matrix,
+        `count` for the closed-form count kernel, `scan` for the per-event
+        scan."""
+        if FORCE_SCAN:
+            return MATCH_SCAN
+        if self.fast_path_ok:
+            arm = self.slots[0].persistent
+            ok = all(
+                self.slot_row_only(p) if (p == 0 and arm) else self.slot_keyed(p)
+                for p in range(len(self.slots))
+            )
+            return MATCH_KEYED if ok else MATCH_MATRIX
+        return MATCH_COUNT if self.count_fast_ok else MATCH_SCAN
 
     # ---- capture projection ---------------------------------------------
 
@@ -435,6 +674,21 @@ class PatternProgram:
             "start_ts": jnp.full((T,), -1, dtype=jnp.int64),
             "entry_ts": jnp.full((T,), now, dtype=jnp.int64).at[1:].set(0),
             "caps": caps,
+            # the token's arming number: completions of one event are
+            # emitted in its order (the count kernel and the scan; the batch
+            # kernel keeps the lanes themselves in that order, behind `head`)
+            "seq": jnp.zeros((T,), dtype=jnp.int64),
+            "next_seq": jnp.ones((), dtype=jnp.int64),
+            "head": jnp.ones((), dtype=jnp.int32),
+            # since deploy: tokens armed, tokens whose `within` ran out,
+            # and the most matches one row has completed
+            "armed": jnp.zeros((), dtype=jnp.int64),
+            "expired": jnp.zeros((), dtype=jnp.int64),
+            "max_row": jnp.zeros((), dtype=jnp.int32),
+            # kept by the runtime's step: matches emitted, and arms and
+            # emissions refused for want of a lane or of room
+            "completed": jnp.zeros((), dtype=jnp.int64),
+            "refused": jnp.zeros((), dtype=jnp.int64),
         }
         if self._use_fwd:
             # a min-0 count start state forwards its virgin immediately
@@ -604,8 +858,12 @@ class PatternProgram:
             dead = kills[0]
             for k in kills[1:]:
                 dead = dead | k
-            active = tok["active"] & ~(dead & valid)
-        tok = {**tok, "active": active}
+            dead = tok["active"] & dead & valid
+            active = tok["active"] & ~dead
+            tok = {
+                **tok, "active": active,
+                "expired": tok["expired"] + dead.sum(dtype=jnp.int64),
+            }
 
         touched = jnp.zeros((self.T,), dtype=jnp.bool_)
         last = len(self.slots) - 1
@@ -900,6 +1158,13 @@ class PatternProgram:
                     "start_ts": jnp.where(
                         match & (tok["start_ts"] < 0), ts, tok["start_ts"]
                     ),
+                    # a virgin that captures its first event in place is
+                    # armed by it (forks take their number in _alloc_lanes)
+                    "seq": jnp.where(
+                        match & (tok["start_ts"] < 0),
+                        tok["next_seq"], tok["seq"],
+                    ),
+                    "next_seq": tok["next_seq"] + 1,
                 }
 
                 if slot.logical is not None:
@@ -1078,10 +1343,17 @@ class PatternProgram:
             for o, n_ in zip(old["caps"], new["caps"])
         ]
         merged = {
+            # the scalars only ever count up: either side may be the newer
+            **{
+                k: jnp.maximum(old[k], new[k])
+                for k in ("next_seq", "head", "armed", "expired", "max_row",
+                          "completed", "refused")
+            },
             "active": sel(old["active"], new["active"]),
             "slot": sel(old["slot"], new["slot"]),
             "start_ts": sel(old["start_ts"], new["start_ts"]),
             "entry_ts": sel(old["entry_ts"], new["entry_ts"]),
+            "seq": sel(old["seq"], new["seq"]),
             "caps": caps,
         }
         if "fwd" in old:
@@ -1154,7 +1426,7 @@ class PatternProgram:
         Matches are strictly serial — EveryPatternTestCase testQuery5/7."""
         first, last = block
         T = self.T
-        dest, overflow = self._alloc_lanes(tok, mask, overflow)
+        dest, overflow, took = self._alloc_lanes(tok, mask, overflow)
         block_refs = {
             a.ref_idx for s in self.slots[first:last + 1] for a in s.atoms
         }
@@ -1200,6 +1472,7 @@ class PatternProgram:
         )
         dest_mask = jnp.zeros((T,), jnp.bool_).at[dest].set(True, mode="drop")
         res = {
+            **self._armed(tok, dest, took),
             "active": tok["active"].at[dest].set(True, mode="drop"),
             "slot": tok["slot"].at[dest].set(first, mode="drop"),
             "start_ts": tok["start_ts"].at[dest].set(start, mode="drop"),
@@ -1215,7 +1488,7 @@ class PatternProgram:
     def _arm_virgins(self, tok, mask, p: int, ts, overflow):
         """Scatter fresh virgin tokens (slot p, no captures) into free rows."""
         T = self.T
-        dest, overflow = self._alloc_lanes(tok, mask, overflow)
+        dest, overflow, took = self._alloc_lanes(tok, mask, overflow)
         caps = []
         for a in self.refs:
             c = tok["caps"][a.ref_idx]
@@ -1235,6 +1508,7 @@ class PatternProgram:
                 }
             )
         res = {
+            **self._armed(tok, dest, took),
             "active": tok["active"].at[dest].set(True, mode="drop"),
             "slot": tok["slot"].at[dest].set(p, mode="drop"),
             "start_ts": tok["start_ts"].at[dest].set(np.int64(-1), mode="drop"),
@@ -1266,14 +1540,29 @@ class PatternProgram:
         rank = jnp.cumsum(mask.astype(jnp.int32)) - 1
         ok = mask & (rank < nfree)
         dest = jnp.where(ok, order[jnp.clip(rank, 0, T - 1)], T)
-        return dest, overflow | jnp.any(mask & ~ok)
+        refused = (mask & ~ok).sum(dtype=jnp.int32)
+        return dest, overflow + refused, ok.sum(dtype=jnp.int64)
+
+    @staticmethod
+    def _armed(tok, dest, took):
+        """The table's scalars and `seq` lane once `took` tokens have been
+        armed into the lanes `dest` (row r's into dest[r], T where it got
+        none): each takes the next arming number, in row order."""
+        T = tok["seq"].shape[0]
+        rank = jnp.cumsum((dest < T).astype(jnp.int64)) - 1
+        return {
+            **tok,
+            "seq": _set_at(tok["seq"], dest, tok["next_seq"] + rank),
+            "next_seq": tok["next_seq"] + took,
+            "armed": tok["armed"] + took,
+        }
 
     def _fork(self, tok, adv_tok, mask, next_slot: int, ts, overflow):
         """Scatter advanced copies of `mask` rows into free rows
         (reference: every re-arm keeps the pre-state armed while the matched
         StateEvent moves on)."""
         T = self.T
-        dest, overflow = self._alloc_lanes(tok, mask, overflow)
+        dest, overflow, took = self._alloc_lanes(tok, mask, overflow)
 
         def scat(lane, adv_lane, fill=None):
             return lane.at[dest].set(adv_lane, mode="drop")
@@ -1288,6 +1577,7 @@ class PatternProgram:
         ]
         dest_mask = jnp.zeros((T,), dtype=jnp.bool_).at[dest].set(True, mode="drop")
         res = {
+            **self._armed(tok, dest, took),
             "active": tok["active"].at[dest].set(True, mode="drop"),
             "slot": tok["slot"].at[dest].set(
                 jnp.full((T,), next_slot, dtype=jnp.int32), mode="drop"
@@ -1310,13 +1600,13 @@ class PatternProgram:
     # ---- vectorized batch fast path --------------------------------------
     #
     # Simple chains (single-atom slots, no counts/absent/logical, `every`
-    # only as the arming slot) admit a fully vectorized batch kernel: per NFA
-    # state one [T, B] match matrix (tokens x rows), tokens advancing to
-    # their FIRST matching row — the dense "token-matrix x batch" form of
-    # SURVEY §3.3's north star. One device program per batch instead of a
-    # B-step scan; multi-hop within a batch falls out of the ascending state
-    # loop (a token advancing at state p on row j can only use rows > j at
-    # state p+1).
+    # only as the arming slot) admit a fully vectorized batch kernel
+    # (`apply_batch_fast`, further down): per NFA state every token advances
+    # to its FIRST matching row, found among the rows of its own key where
+    # the state's condition ties the row to a capture by equality
+    # (`_match_keyed`) and in a dense [T, B] match matrix where it does not
+    # (`_match_matrix`). One device program per batch instead of a B-step
+    # scan.
 
     @property
     def fast_path_ok(self) -> bool:
@@ -1507,6 +1797,7 @@ class PatternProgram:
             caps[atom1.ref_idx] = c1
         entry_row = jnp.where(advD, j, -1)
         tok = {
+            **tok,
             "active": tok["active"],
             "slot": jnp.where(advD, 2, tok["slot"]),
             "start_ts": start_ts,
@@ -1529,9 +1820,9 @@ class PatternProgram:
             g = jnp.arange(Gmax, dtype=jnp.int32)
             s_g = (m - ny) + g * m
             valid_g = tail_exists & (s_g <= k_total)
-            overflow = overflow | (
+            overflow = overflow + (
                 tail_exists & ((m - ny) + Gmax * m <= k_total)
-            )
+            ).astype(jnp.int32)
             # generation g advances at the first row b with Madv[b] and
             # midx_excl[b] >= s_g + m (room never blocks, see above). Same
             # suffix-min + sorted-searchsorted factoring as the per-token
@@ -1558,7 +1849,7 @@ class PatternProgram:
             free_idx = first_indices(free, Gmax)
             grank = (jnp.cumsum(valid_g.astype(jnp.int32)) - 1).astype(jnp.int32)
             okg = valid_g & (grank < nfree) & (free_idx[jnp.clip(grank, 0, Gmax - 1)] >= 0)
-            overflow = overflow | jnp.any(valid_g & ~okg)
+            overflow = overflow + (valid_g & ~okg).sum(dtype=jnp.int32)
             dst = jnp.where(okg, free_idx[jnp.clip(grank, 0, Gmax - 1)], T)
 
             src_g = s_g[:, None] + qpos[None, :]
@@ -1631,6 +1922,8 @@ class PatternProgram:
                 caps[ridx] = c
             g_start = jnp.where(Ag > 0, mts[jnp.clip(s_g, 0, B - 1)], np.int64(-1))
             tok = {
+                # a generation is armed by the match that opens it: in order
+                **self._armed(tok, dst, okg.sum(dtype=jnp.int64)),
                 "active": tok["active"].at[dst].set(True, mode="drop"),
                 "slot": tok["slot"].at[dst].set(
                     jnp.where(has_advg, 2, 0), mode="drop"
@@ -1676,25 +1969,30 @@ class PatternProgram:
             }
             caps[atom.ref_idx] = crp
             tok = {
-                "active": tok["active"],
+                **tok,
                 "slot": jnp.where(has, p + 1, tok["slot"]),
-                "start_ts": tok["start_ts"],
                 "entry_ts": jnp.where(has, batch_ts[jjc], tok["entry_ts"]),
                 "caps": caps,
             }
             entry_row = jnp.where(has, jj, entry_row)
 
-        # ---- completions (ordered by completion row, then lane) ----
+        # ---- completions, ordered by completion row, those of one row by
+        # the tokens' arming numbers ----
         done = tok["active"] & (tok["slot"] == S)
         cap = out["valid"].shape[0]
-        key = jnp.where(
-            done, entry_row.astype(jnp.int64) * T + toks, np.int64(1) << 60
+        row_key = jnp.where(done, entry_row, np.iinfo(np.int32).max)
+        rows_s, _, order = lax.sort(
+            (row_key, tok["seq"], toks), num_keys=2, is_stable=True
         )
-        order = jnp.argsort(key).astype(jnp.int32)
         d_sorted = done[order]
         rank = (jnp.cumsum(d_sorted.astype(jnp.int32)) - d_sorted).astype(jnp.int32)
         dest = jnp.where(d_sorted & (out_n + rank < cap), out_n + rank, cap)
-        overflow = overflow | (d_sorted & (out_n + rank >= cap)).any()
+        overflow = overflow + (d_sorted & (out_n + rank >= cap)).sum(
+            dtype=jnp.int32
+        )
+        tok = {**tok, "max_row": jnp.maximum(
+            tok["max_row"], _longest_run(rows_s, done.sum(dtype=jnp.int32))
+        )}
         src_t = order
         out = dict(out)
         emit_ts = jnp.where(
@@ -1751,6 +2049,319 @@ class PatternProgram:
         cols[(a.ref, None, "__arrived__")] = jnp.ones((1, 1), dtype=jnp.bool_)
         return Env(cols, now=now)
 
+    # ---- the batch kernel --------------------------------------------------
+    #
+    # One pass over a whole batch of one stream's rows: per NFA state each
+    # token advances to its FIRST matching row; several hops inside one batch
+    # fall out of the ascending state loop (a token that advanced at state p
+    # on row j meets only rows behind j at state p+1). The table is a log:
+    # `every` arms its tokens into the lanes behind `head` in row order, and
+    # when the tail has no room the live tokens move to the front, in order
+    # (`_make_room`); so lane order is arming order, which is the order the
+    # completions of one row are emitted in, and nothing is scattered.
+
+    @staticmethod
+    def _lanes_of(tok) -> dict:
+        """The table's per-token lanes as flat [T] arrays (the batch kernel
+        takes patterns of single captures: a [T, 1] lane is its column;
+        `seq` stays behind: lane order is arming order here)."""
+        flat = {k: tok[k] for k in ("slot", "start_ts", "entry_ts")}
+        for i, c in enumerate(tok["caps"]):
+            flat[f"n{i}"] = c["n"]
+            flat[f"ts{i}"] = c["ts"][:, 0]
+            for name, arr in c["cols"].items():
+                flat[f"c{i}.{name}"] = arr[:, 0]
+        return flat
+
+    @staticmethod
+    def _with_lanes(tok, flat: dict) -> dict:
+        caps = [
+            {
+                "n": flat[f"n{i}"],
+                "ts": flat[f"ts{i}"][:, None],
+                "cols": {
+                    name: flat[f"c{i}.{name}"][:, None] for name in c["cols"]
+                },
+            }
+            for i, c in enumerate(tok["caps"])
+        ]
+        return {
+            **tok,
+            **{k: flat[k] for k in ("slot", "start_ts", "entry_ts")},
+            "caps": caps,
+        }
+
+    def _make_room(self, tok, need):
+        """Room for `need` more tokens behind `head`: where the tail lacks
+        it, the live tokens move to the front in lane order (log2 T passes of
+        shifted selects, `compact_front`) and `head` falls to their number:
+        every few batches, as often as the dead lanes behind `head` and the
+        free ones ahead of it add up to a batch's arms."""
+        T = self.T
+
+        def compact(tok):
+            alive = tok["active"]
+            n = alive.sum(dtype=jnp.int32)
+            flat = compact_front(alive, self._lanes_of(tok))
+            return {
+                **self._with_lanes(tok, flat),
+                "active": jnp.arange(T, dtype=jnp.int32) < n,
+                "head": n,
+            }
+
+        return lax.cond(tok["head"] + need > T, compact, lambda t: t, tok)
+
+    @staticmethod
+    def _place(lane, block, at, n):
+        """`lane` with its places at..at+n-1 taken from the front of
+        `block`: a shifted read and a select, no scatter."""
+        size, have = lane.shape[0], block.shape[0]
+        blk = block[:size] if have >= size else jnp.concatenate(
+            [block, jnp.zeros((size - have,), block.dtype)]
+        )
+        moved = lax.dynamic_slice(
+            jnp.concatenate([jnp.zeros((size,), blk.dtype), blk]),
+            (size - at,), (size,),
+        )
+        idx = jnp.arange(size, dtype=jnp.int32)
+        return jnp.where(
+            (idx >= at) & (idx < at + n), moved.astype(lane.dtype), lane
+        )
+
+    def _arm(self, tok, fork, ev, batch_ts, rows, entry_row, overflow):
+        """`every`: each passing row arms a fresh token one state on, into
+        the lanes behind `head`, in row order."""
+        T = self.T
+        atom = self.slots[0].atoms[0]
+        _keep_cols, ts_used = self.capture_keep()
+        fork = fork & tok["active"][0] & (tok["slot"][0] == 0)
+        want = fork.sum(dtype=jnp.int32)
+        tok = self._make_room(tok, want)
+        head = tok["head"]
+        took = jnp.minimum(want, T - head)
+        overflow = overflow + (want - took)
+        cr = tok["caps"][atom.ref_idx]
+        front = compact_front(fork, {
+            "ts": batch_ts, "row": rows,
+            "cols": {n: ev[n].astype(a.dtype) for n, a in cr["cols"].items()},
+        })
+
+        def put(lane, block):
+            return self._place(lane, block, head, took)
+
+        lanes = jnp.arange(T, dtype=jnp.int32)
+        fresh = (lanes >= head) & (lanes < head + took)
+        caps = []
+        for i, c in enumerate(tok["caps"]):
+            if i != atom.ref_idx:
+                # a lane's last tenant may have left later captures behind
+                caps.append({**c, "n": jnp.where(fresh, 0, c["n"])})
+                continue
+            caps.append({
+                "n": jnp.where(fresh, 1, c["n"]),
+                "ts": put(c["ts"][:, 0], front["ts"])[:, None]
+                if ts_used[i] else c["ts"],
+                "cols": {
+                    n: put(a[:, 0], front["cols"][n])[:, None]
+                    for n, a in c["cols"].items()
+                },
+            })
+        tok = {
+            **tok,
+            "active": tok["active"] | fresh,
+            "slot": jnp.where(fresh, 1, tok["slot"]),
+            "start_ts": put(tok["start_ts"], front["ts"]),
+            "entry_ts": put(tok["entry_ts"], front["ts"]),
+            "caps": caps,
+            "head": head + took,
+            "armed": tok["armed"] + took.astype(jnp.int64),
+        }
+        return tok, put(entry_row, front["row"]), overflow
+
+    def _match_matrix(self, tok, p, ev, batch_ts, v, rows, entry_row, now):
+        """Slot p's tokens against the batch as a dense [T, B] matrix: (tok,
+        which tokens found a row, the row, its time, its captured values)."""
+        T, B = self.T, batch_ts.shape[0]
+        slot = self.slots[p]
+        atom = slot.atoms[0]
+        elig = tok["active"] & (tok["slot"] == p)
+        env = self._matrix_env(tok, ev, batch_ts, now, atom.ref_idx)
+        cond = jnp.ones((T, B), dtype=jnp.bool_)
+        for c in self._conds[(p, atom.ref_idx)]:
+            cond = cond & jnp.broadcast_to(c(env), (T, B))
+        M = elig[:, None] & v[None, :] & (rows[None, :] > entry_row[:, None]) & cond
+        win = _min_within(slot.within_ms, self.within_ms)
+        if win is not None:
+            started = tok["start_ts"] >= 0
+            M = M & ~(
+                started[:, None]
+                & (batch_ts[None, :] - tok["start_ts"][:, None] > win)
+            )
+        if self.sequence and not slot.persistent and p > 0:
+            # strict continuity: the match must be the FIRST valid row
+            # after the token's entry; a non-matching next row kills it
+            nxt_ok = v[None, :] & (rows[None, :] > entry_row[:, None])
+            has_next = nxt_ok.any(axis=1)
+            jnext = jnp.argmax(nxt_ok, axis=1).astype(jnp.int32)
+            M = M & (rows[None, :] == jnext[:, None])
+            die = elig & has_next & ~M.any(axis=1)
+            tok = {**tok, "active": tok["active"] & ~die}
+        if p == 0 and slot.persistent:
+            return tok, M.any(axis=0) & v, None, None, None
+        has = M.any(axis=1)
+        j = jnp.argmax(M, axis=1).astype(jnp.int32)  # first match row
+        jc = jnp.clip(j, 0, B - 1)
+        vals = {
+            name: ev[name][jc]
+            for name in tok["caps"][atom.ref_idx]["cols"]
+        }
+        return tok, has, j, batch_ts[jc], vals
+
+    def _match_keyed(self, tok, p, ev, batch_ts, v, rows, entry_row, now):
+        """Slot p's tokens against the rows of their own key: (tok, which
+        tokens found a row, the row, its time, its captured values).
+
+        Tokens and rows are sorted together on (key, place), a token placed
+        just behind the row that brought it to this slot (ahead of every row
+        when an earlier batch did), so that the rows a token may match are
+        the rows behind it in its run of equal keys. Pass k hands every token
+        the k-th of them (a segmented carry along the runs, read from the
+        end) and evaluates the rest of the condition on those pairs; a token
+        keeps the first row that passes. The passes end when no token that
+        still looks has a row left: as many as the rows one key has in the
+        batch. Sorts, carries and selects over T + B elements; no gather, and
+        nothing of T x B."""
+        T, B = self.T, batch_ts.shape[0]
+        N = T + B
+        slot = self.slots[p]
+        atom = slot.atoms[0]
+        plan = self._plans[p]
+        by_ref = {a.ref: a for a in self.refs}
+        rowok = v
+        renv = self._row_env(ev, batch_ts, now, atom)
+        for c in plan.row:
+            rowok = rowok & jnp.broadcast_to(c(renv), (B,))
+        elig = tok["active"] & (tok["slot"] == p)
+
+        # ---- one sort on the key's words and the place
+        words = []
+        for row_attr, ridx, cap_attr, t in plan.keys:
+            tk = tok["caps"][ridx]["cols"][cap_attr][:, 0]
+            rk = ev[row_attr].astype(tk.dtype)
+            if t is not AttrType.BOOL:
+                # a null operand makes any comparison false
+                nv = np.asarray(null_value(t), dtype=tk.dtype)
+                elig = elig & (tk != nv)
+                rowok = rowok & (rk != nv)
+            words += _key_words(jnp.concatenate([tk, rk]))
+        place = jnp.concatenate([2 * (entry_row + 1), 2 * rows + 1])
+        who = jnp.arange(N, dtype=jnp.int32)
+        *ks, _, who_s = lax.sort(
+            (*words, place, who), num_keys=len(words) + 1, is_stable=False
+        )
+        differs = ks[0][1:] != ks[0][:-1]
+        for w in ks[1:]:
+            differs = differs | (w[1:] != w[:-1])
+        # read from the end: a run's first element there is its last here
+        opens = jnp.concatenate([differs, jnp.ones((1,), jnp.bool_)])[::-1]
+        (inv,) = permute_by(who_s, who)
+
+        # ---- what a pair's test reads, token side and row side
+        win = _min_within(slot.within_ms, self.within_ms)
+        # a row's lanes ride once, whoever reads them: ("col", attribute),
+        # the event time "__ts" and the row's place in the batch "__row"
+        tl, rl, reads = {}, {"__ts": batch_ts, "__row": rows}, {}
+        for key in plan.rest_keys:
+            ref, _k, attr = key
+            if ref == atom.ref:
+                reads[key] = "__ts" if attr == TS_ATTR else ("col", attr)
+            else:
+                c = tok["caps"][by_ref[ref].ref_idx]
+                tl[key] = (
+                    c["ts"][:, 0] if attr == TS_ATTR else c["cols"][attr][:, 0]
+                )
+        captured = tok["caps"][atom.ref_idx]["cols"]
+        for _, attr in {*(r for r in reads.values() if r != "__ts"),
+                        *(("col", n) for n in captured)}:
+            rl[("col", attr)] = ev[attr]
+        if win is not None:
+            tl["__dl"] = jnp.where(
+                tok["start_ts"] >= 0, tok["start_ts"] + win,
+                np.iinfo(np.int64).max,
+            )
+        merged = _pair_lanes(tl, rl, T, B)
+        merged.append((
+            jnp.concatenate([elig, rowok]).astype(jnp.int32), "__on", "__on"
+        ))
+        ts_lanes, rs_lanes = {}, {}
+        for (_, tkey, rkey), lane in zip(
+            merged, permute_in_groups(inv, [lane for lane, _, _ in merged])
+        ):
+            lane = lane[::-1]
+            if tkey is not None:
+                ts_lanes[tkey] = lane
+            if rkey is not None:
+                rs_lanes[rkey] = lane
+        # flags ride as int32: a [N] lane of PRED that outlives its fusion is
+        # laid out by bits and read on the scalar path (ops/prefix.py)
+        is_row = (who_s >= T)[::-1]
+        on = ts_lanes.pop("__on") > 0
+        rs_lanes.pop("__on")
+        row_on = (is_row & on).astype(jnp.int32)
+        tok_on = (~is_row & on).astype(jnp.int32)
+        inner = (~opens).astype(jnp.int32)   # has its run's next element behind
+        restart = (row_on > 0) | opens
+        rest = plan.rest
+        names = sorted(rs_lanes, key=repr)
+        keep = ["__ts", "__row", *(("col", n) for n in captured)]
+
+        def looking(state):
+            _x, xv, _best, found = state
+            return (xv > 0).any() & ((tok_on > 0) & (found == 0)).any()
+
+        def shifted(lane):
+            return jnp.concatenate([jnp.zeros((1,), lane.dtype), lane[:-1]])
+
+        def one_pass(state):
+            x, xv, best, found = state
+            carried = segmented_carry((xv, *x), restart)
+            rv, r = carried[0], dict(zip(names, carried[1:]))
+            hit = (tok_on > 0) & (found == 0) & (rv > 0)
+            if win is not None:
+                hit = hit & (r["__ts"] <= ts_lanes["__dl"])
+            if rest:
+                cols = {k: lane for k, lane in ts_lanes.items() if k != "__dl"}
+                cols.update({k: r[src] for k, src in reads.items()})
+                env = Env(cols, now=now)
+                for c in rest:
+                    hit = hit & jnp.broadcast_to(c(env), (N,))
+            best = tuple(jnp.where(hit, r[n], b) for n, b in zip(keep, best))
+            # a row's next candidate: what the element behind it was handed
+            return (
+                tuple(shifted(r[n]) for n in names),
+                shifted(rv) * inner * row_on, best,
+                found | hit.astype(jnp.int32),
+            )
+
+        x0 = tuple(rs_lanes[n] for n in names)
+        _, _, best, found = lax.while_loop(
+            looking, one_pass,
+            (x0, row_on, tuple(jnp.zeros_like(rs_lanes[n]) for n in keep),
+             jnp.zeros((N,), jnp.int32)),
+        )
+        best = dict(zip(keep, best))
+
+        # ---- back to lane order
+        back = [("__found", found[::-1])] + [
+            (n, best[n][::-1]) for n in keep
+        ]
+        res = {
+            n: lane[:T] for (n, _), lane in zip(
+                back, permute_in_groups(who_s, [lane for _, lane in back]))
+        }
+        vals = {n: res[("col", n)] for n in captured}
+        return tok, res["__found"] > 0, res["__row"], res["__ts"], vals
+
     def apply_batch_fast(
         self, tok, batch_ts, batch_kind, batch_valid, stream_cols: dict,
         out, out_n, overflow, now,
@@ -1761,7 +2372,6 @@ class PatternProgram:
         S = len(self.slots)
         _keep_cols, _ts_used = self.capture_keep()
         rows = jnp.arange(B, dtype=jnp.int32)
-        toks = jnp.arange(T, dtype=jnp.int32)
         v = batch_valid & (batch_kind == KIND_CURRENT)
         entry_row = jnp.full((T,), -1, jnp.int32)  # batch-local hop cursor
 
@@ -1770,70 +2380,26 @@ class PatternProgram:
             if atom.stream_id not in stream_cols:
                 continue
             ev = stream_cols[atom.stream_id]
-            elig = tok["active"] & (tok["slot"] == p)
-            env = self._matrix_env(tok, ev, batch_ts, now, atom.ref_idx)
-            cond = jnp.ones((T, B), dtype=jnp.bool_)
-            for c in self._conds[(p, atom.ref_idx)]:
-                cond = cond & jnp.broadcast_to(c(env), (T, B))
-            M = elig[:, None] & v[None, :] & (rows[None, :] > entry_row[:, None]) & cond
-            win = _min_within(slot.within_ms, self.within_ms)
-            if win is not None:
-                started = tok["start_ts"] >= 0
-                M = M & ~(
-                    started[:, None]
-                    & (batch_ts[None, :] - tok["start_ts"][:, None] > win)
-                )
-            if self.sequence and not slot.persistent and p > 0:
-                # strict continuity: the match must be the FIRST valid row
-                # after the token's entry; a non-matching next row kills it
-                nxt_ok = v[None, :] & (rows[None, :] > entry_row[:, None])
-                has_next = nxt_ok.any(axis=1)
-                jnext = jnp.argmax(nxt_ok, axis=1).astype(jnp.int32)
-                M = M & (rows[None, :] == jnext[:, None])
-                die = elig & has_next & ~M.any(axis=1)
-                tok = {**tok, "active": tok["active"] & ~die}
-
             if p == 0 and slot.persistent:
-                # `every`: each matching row forks a fresh token one state on
-                fork = M.any(axis=0) & v  # [B]
-                frank = (jnp.cumsum(fork.astype(jnp.int32)) - fork).astype(jnp.int32)
-                free = ~tok["active"]
-                free_idx = first_indices(free, B)
-                dest = jnp.where(fork, free_idx[jnp.clip(frank, 0, B - 1)], -1)
-                okf = fork & (dest >= 0)
-                overflow = overflow | (fork & (dest < 0)).any()
-                dstc = jnp.where(okf, dest, T)  # T = dropped lane
-                active2 = tok["active"].at[dstc].set(True, mode="drop")
-                slot2 = tok["slot"].at[dstc].set(1, mode="drop")
-                # set_at / column-slice forms: raw 64-bit scatters serialize
-                # on TPU (ops/scatter.py) — these run once per batch at [B]
-                start2 = _set_at(tok["start_ts"], dstc, batch_ts)
-                entry2 = _set_at(tok["entry_ts"], dstc, batch_ts)
-                entry_row = entry_row.at[dstc].set(rows, mode="drop")
-                caps = [dict(c) for c in tok["caps"]]
-                cr = dict(caps[atom.ref_idx])
-                cr["n"] = cr["n"].at[dstc].set(1, mode="drop")
-                if _ts_used[atom.ref_idx]:
-                    cr["ts"] = cr["ts"].at[:, 0].set(
-                        _set_at(cr["ts"][:, 0], dstc, batch_ts)
+                with jax.named_scope("pattern.arm"):
+                    if self.slot_row_only(0):
+                        fork = v
+                        renv = self._row_env(ev, batch_ts, now, atom)
+                        for c in self._plans[0].row:
+                            fork = fork & jnp.broadcast_to(c(renv), (B,))
+                    else:
+                        tok, fork, _, _, _ = self._match_matrix(
+                            tok, p, ev, batch_ts, v, rows, entry_row, now
+                        )
+                    tok, entry_row, overflow = self._arm(
+                        tok, fork, ev, batch_ts, rows, entry_row, overflow
                     )
-                cr["cols"] = {
-                    name: arr.at[:, 0].set(
-                        _set_at(arr[:, 0], dstc, ev[name].astype(arr.dtype))
-                    )
-                    for name, arr in cr["cols"].items()
-                }
-                caps[atom.ref_idx] = cr
-                tok = {
-                    "active": active2, "slot": slot2, "start_ts": start2,
-                    "entry_ts": entry2, "caps": caps,
-                }
-            else:
-                has = M.any(axis=1)
-                j = jnp.argmax(M, axis=1).astype(jnp.int32)  # first match row
-                jc = jnp.clip(j, 0, B - 1)
-                adv = has
-                mts = batch_ts[jc]
+                continue
+            match = self._match_keyed if self.slot_keyed(p) else self._match_matrix
+            with jax.named_scope("pattern.match"):
+                tok, adv, j, mts, vals = match(
+                    tok, p, ev, batch_ts, v, rows, entry_row, now
+                )
                 caps = [dict(c) for c in tok["caps"]]
                 cr = dict(caps[atom.ref_idx])
                 cr["n"] = jnp.where(adv, 1, cr["n"])
@@ -1844,13 +2410,13 @@ class PatternProgram:
                     )
                 cr["cols"] = {
                     name: arr.at[:, 0].set(
-                        jnp.where(adv, ev[name][jc].astype(arr.dtype), arr[:, 0])
+                        jnp.where(adv, vals[name].astype(arr.dtype), arr[:, 0])
                     )
                     for name, arr in cr["cols"].items()
                 }
                 caps[atom.ref_idx] = cr
                 tok = {
-                    "active": tok["active"],
+                    **tok,
                     "slot": jnp.where(adv, p + 1, tok["slot"]),
                     "start_ts": jnp.where(
                         adv & (tok["start_ts"] < 0), mts, tok["start_ts"]
@@ -1860,55 +2426,85 @@ class PatternProgram:
                 }
                 entry_row = jnp.where(adv, j, entry_row)
 
-        # completions: tokens past the last slot emit, ordered by their
-        # completion row (then token index for same-row ties)
-        done = tok["active"] & (tok["slot"] == S)
-        cap = out["valid"].shape[0]
-        key = jnp.where(done, entry_row.astype(jnp.int64) * T + toks, np.int64(1) << 60)
-        order = jnp.argsort(key).astype(jnp.int32)  # done tokens first, row order
-        d_sorted = done[order]
-        rank = (jnp.cumsum(d_sorted.astype(jnp.int32)) - d_sorted).astype(jnp.int32)
-        dest = jnp.where(d_sorted & (out_n + rank < cap), out_n + rank, cap)
-        overflow = overflow | (d_sorted & (out_n + rank >= cap)).any()
-        src = order  # token index per sorted position
-        out = dict(out)
-        emit_ts = jnp.where(
-            entry_row[src] >= 0, batch_ts[jnp.clip(entry_row[src], 0, B - 1)], now
-        )
-        out["ts"] = _set_at(out["ts"], dest, emit_ts)
-        out["valid"] = out["valid"].at[dest].set(True, mode="drop")
-        for a in self.refs:
-            c = tok["caps"][a.ref_idx]
-            out[f"n{a.ref_idx}"] = out[f"n{a.ref_idx}"].at[dest].set(c["n"][src], mode="drop")
-            if f"ts{a.ref_idx}" in out:
-                out[f"ts{a.ref_idx}"] = _set_at(out[f"ts{a.ref_idx}"], dest, c["ts"][src])
-            for name in c["cols"]:
-                out[f"c{a.ref_idx}.{name}"] = _set_at(
-                    out[f"c{a.ref_idx}.{name}"], dest, c["cols"][name][src]
-                )
-        out_n = jnp.minimum(out_n + done.sum(dtype=jnp.int32), cap).astype(jnp.int32)
-        tok = {**tok, "active": tok["active"] & ~done}
+        with jax.named_scope("pattern.emit"):
+            tok, out, out_n, overflow = self._emit_done(
+                tok, entry_row, out, out_n, overflow
+            )
 
         # purge tokens whose within expired by the end of the batch (the scan
         # path kills them on the next arrival; purging bounds table growth)
-        last_ts = jnp.max(jnp.where(v, batch_ts, np.int64(0)))
-        win_by_slot = np.full((S + 1,), np.iinfo(np.int64).max, dtype=np.int64)
-        for p, slot in enumerate(self.slots):
-            w = _min_within(slot.within_ms, self.within_ms)
-            if w is not None:
-                win_by_slot[p] = w
-        # select-chain over the (tiny) slot count: keeps the per-slot window
-        # durations as scalar literals instead of a device-array const
-        slot_c = jnp.clip(tok["slot"], 0, S)
-        win_t = jnp.full(slot_c.shape, win_by_slot[S], dtype=jnp.int64)
-        for p in range(S):
-            win_t = jnp.where(slot_c == p, win_by_slot[p], win_t)
-        started = tok["start_ts"] >= 0
-        expired = started & (last_ts - tok["start_ts"] > win_t)
-        keep0 = jnp.arange(T) == 0  # the arming token never dies
-        is_armer = keep0 & np.asarray(self.slots[0].persistent)
-        tok = {**tok, "active": tok["active"] & ~(expired & ~is_armer)}
+        with jax.named_scope("pattern.purge"):
+            last_ts = jnp.max(jnp.where(v, batch_ts, np.int64(0)))
+            win_by_slot = np.full(
+                (S + 1,), np.iinfo(np.int64).max, dtype=np.int64
+            )
+            for p, slot in enumerate(self.slots):
+                w = _min_within(slot.within_ms, self.within_ms)
+                if w is not None:
+                    win_by_slot[p] = w
+            # select-chain over the (tiny) slot count: keeps the per-slot
+            # window durations as scalar literals, not a device-array const
+            slot_c = jnp.clip(tok["slot"], 0, S)
+            win_t = jnp.full(slot_c.shape, win_by_slot[S], dtype=jnp.int64)
+            for p in range(S):
+                win_t = jnp.where(slot_c == p, win_by_slot[p], win_t)
+            started = tok["start_ts"] >= 0
+            expired = (
+                tok["active"] & started & (last_ts - tok["start_ts"] > win_t)
+            )
+            tok = {
+                **tok,
+                "active": tok["active"] & ~expired,
+                "expired": tok["expired"] + expired.sum(dtype=jnp.int64),
+            }
         return tok, out, out_n, overflow
+
+    def _emit_done(self, tok, entry_row, out, out_n, overflow):
+        """Tokens past the last slot emit, ordered by their completion row;
+        those of one row in lane order, the order their first events arrived
+        in. The done tokens move to the front (`compact_front`), the first
+        `cap` of them are sorted on the row, stably, and laid behind the
+        emissions the step already holds."""
+        T = self.T
+        S = len(self.slots)
+        cap = out["valid"].shape[0]
+        done = tok["active"] & (tok["slot"] == S)
+        nd = done.sum(dtype=jnp.int32)
+        src = {"__row": entry_row, "ts": tok["entry_ts"]}
+        for i, c in enumerate(tok["caps"]):
+            if f"ts{i}" in out:
+                src[f"ts{i}"] = c["ts"][:, 0]
+            for name, arr in c["cols"].items():
+                src[f"c{i}.{name}"] = arr[:, 0]
+        front = compact_front(done, src)
+        E = min(T, cap)
+        idx = jnp.arange(E, dtype=jnp.int32)
+        key = jnp.where(idx < nd, front["__row"][:E], np.iinfo(np.int32).max)
+        key_s, order = lax.sort((key, idx), num_keys=1, is_stable=True)
+        (inv,) = permute_by(order, idx)
+        names = [k for k in front if k != "__row"]
+        took = jnp.minimum(jnp.minimum(nd, E), cap - out_n)
+        overflow = overflow + (nd - took)
+        out = dict(out)
+        for k, lane in zip(
+            names, permute_in_groups(inv, [front[k][:E] for k in names])
+        ):
+            flat = out[k] if out[k].ndim == 1 else out[k][:, 0]
+            flat = self._place(flat, lane, out_n, took)
+            out[k] = flat if out[k].ndim == 1 else flat[:, None]
+        at = jnp.arange(cap, dtype=jnp.int32)
+        fresh = (at >= out_n) & (at < out_n + took)
+        out["valid"] = out["valid"] | fresh
+        for i in range(len(self.refs)):
+            out[f"n{i}"] = jnp.where(fresh, 1, out[f"n{i}"])
+        tok = {
+            **tok,
+            "active": tok["active"] & ~done,
+            "max_row": jnp.maximum(
+                tok["max_row"], _longest_run(key_s, jnp.minimum(nd, E))
+            ),
+        }
+        return tok, out, out_n + took, overflow
 
     def init_out(self, cap: int):
         keep_cols, ts_used = self.capture_keep()
@@ -1932,11 +2528,18 @@ class PatternProgram:
 
     def _write_emits(self, out, out_n, overflow, emit, tok, ts):
         cap = out["valid"].shape[0]
-        rank = jnp.cumsum(emit.astype(jnp.int32)) - 1
+        # the emitting tokens in the order they were armed (the source walks
+        # its pending list oldest first), lane order among equals
+        order = jnp.argsort(
+            jnp.where(emit, tok["seq"], np.iinfo(np.int64).max)
+        )
+        rank = jnp.zeros((self.T,), jnp.int32).at[order].set(
+            jnp.arange(self.T, dtype=jnp.int32)
+        )
         dest_raw = out_n + rank
         ok = emit & (dest_raw < cap)
         dest = jnp.where(ok, dest_raw, cap)
-        overflow = overflow | jnp.any(emit & ~ok)
+        overflow = overflow + (emit & ~ok).sum(dtype=jnp.int32)
         out = dict(out)
         out["ts"] = out["ts"].at[dest].set(jnp.broadcast_to(ts, (self.T,)), mode="drop")
         out["valid"] = out["valid"].at[dest].set(True, mode="drop")

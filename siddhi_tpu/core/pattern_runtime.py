@@ -58,9 +58,13 @@ class PatternQueryRuntime(BaseQueryRuntime):
         # reference allows them there too; that lands with the NFA env rework)
         for t in (tables or {}).values():
             self.prog.scope.add_table(t)
-        # emission buffer scales with the token table: every pending token can
-        # emit on one event, so raising @app:patternCapacity raises this too
-        self.out_cap = max(batch_size, 64, token_capacity)
+        # the emission buffer holds what one micro-batch may emit: two
+        # matches for every row of it, or the whole table where that is
+        # smaller. It no longer grows with @app:patternCapacity (a table of
+        # 163,840 pending matches made a fused chunk pack 32 x 163,840 row
+        # places of which a twentieth were filled); an emission that finds no
+        # room raises the overflow flag and its ERROR, never dropped silently
+        self.out_cap = max(batch_size, 64, min(token_capacity, 2 * batch_size))
 
         # select * over a pattern exposes every ref's attributes in order
         # (duplicate names require explicit projection)
@@ -103,6 +107,7 @@ class PatternQueryRuntime(BaseQueryRuntime):
             for sid in self.prog.stream_ids
         }
         self._timer_step = jax.jit(self._make_step(None), donate_argnums=(0,))
+        self._census_jit = jax.jit(self._census)
 
     def arm_lineage(self, cfg) -> None:
         """Enable provenance recording (@app:lineage): force every ref's
@@ -144,8 +149,10 @@ class PatternQueryRuntime(BaseQueryRuntime):
         chunk = None
         if stream_id is not None and not pattern_mod.FORCE_SCAN:
             if prog.fast_path_ok:
-                # chunks no larger than half the token table, so a chunk's
-                # fork demand can always be met by lanes freed previously
+                # chunks no larger than half the token table, so that the
+                # lanes of the tokens one chunk completes are there for the
+                # next one's arms (the scan path recycles a lane per event);
+                # a table of two batches or more is stepped in one piece
                 kernel, chunk = prog.apply_batch_fast, max(1, prog.T // 2)
             elif prog.count_fast_ok:
                 # chunk = T*min_count keeps the no-spurious-overflow bound
@@ -206,7 +213,7 @@ class PatternQueryRuntime(BaseQueryRuntime):
                 }
                 (tok, out, _n, ovf), _ = lax.scan(
                     chunk_body,
-                    (state["tok"], out0, np.int32(0), np.bool_(False)),
+                    (state["tok"], out0, np.int32(0), np.int32(0)),
                     xs,
                 )
                 # fast-path patterns have no waiting atoms -> no timers
@@ -223,7 +230,7 @@ class PatternQueryRuntime(BaseQueryRuntime):
                 state["tok"],
                 out0,
                 np.int32(0),
-                np.bool_(False),
+                np.int32(0),
             )
             xs = {
                 "ts": batch.ts,
@@ -243,7 +250,7 @@ class PatternQueryRuntime(BaseQueryRuntime):
                     if stream_id is not None
                     else {}
                 )
-                tok, out, out_n, ovf = prog.apply_event(
+                tok, out, n_after, ovf = prog.apply_event(
                     tok,
                     row["ts"],
                     row["kind"],
@@ -254,7 +261,10 @@ class PatternQueryRuntime(BaseQueryRuntime):
                     ovf,
                     timer_seen=state["timer_ts"],
                 )
-                return (tok, out, out_n, ovf), None
+                tok = {**tok, "max_row": jnp.maximum(
+                    tok["max_row"], n_after - out_n
+                )}
+                return (tok, out, n_after, ovf), None
 
             (tok, out, _, ovf), _ = lax.scan(body, carry0, xs)
             timer_rows = batch.valid & (batch.kind == KIND_TIMER)
@@ -292,7 +302,14 @@ class PatternQueryRuntime(BaseQueryRuntime):
         if self.table_op is not None:
             tstates = self.table_op(tstates, out_batch, now, flow.aux)
         aux = dict(flow.aux)
-        aux["pattern_overflow"] = ovf
+        # `ovf` counts the arms refused for want of a lane and the emissions
+        # refused for want of room in this step
+        aux["pattern_overflow"] = ovf > 0
+        tok = {
+            **tok,
+            "completed": tok["completed"] + out["valid"].sum(dtype=jnp.int64),
+            "refused": tok["refused"] + ovf.astype(jnp.int64),
+        }
         aux["next_timer"] = prog.next_timer(tok, after=timer_ts)
         if self.lineage is not None:
             # provenance lanes: the emission buffer's per-ref capture
@@ -363,12 +380,21 @@ class PatternQueryRuntime(BaseQueryRuntime):
         return out, aux
 
     def describe_state(self) -> dict:
-        """NFA introspection: active state-machine instances per linearized
-        slot (the device token table's `active`/`slot` lanes pulled to host)
-        plus the earliest pending within/absent deadline."""
+        """NFA introspection. `pattern`: how the program matches (`match`),
+        the table's size and its counters, kept on the device by the step and
+        read here as scalars: `tokens` (partial matches alive now), `armed`,
+        `completed`, `expired`, `overflow` (arms refused for want of a lane
+        plus emissions refused for want of room) and `max_emits_per_row`
+        (the most matches one row has completed), all since deploy. Per
+        linearized slot the instances at it, counted on the device, and the
+        earliest pending absent deadline. No [T] lane comes to the host."""
         d = super().describe_state()
         prog = self.prog
         d["token_capacity"] = prog.T
+        d["pattern"] = {
+            "match": prog.match_kind, "token_capacity": prog.T,
+            "emit_capacity": self.out_cap,
+        }
         slots = []
         for s in prog.slots:
             slots.append({
@@ -378,29 +404,49 @@ class PatternQueryRuntime(BaseQueryRuntime):
             })
         if self.state is None:
             d["states"] = [dict(s, active=0) for s in slots]
+            d["pattern"].update(tokens=0, armed=0, completed=0, expired=0,
+                                overflow=0, max_emits_per_row=0)
             return d
         try:
             with self._receive_lock:
                 tok = self.state["tok"]
-                active = np.asarray(tok["active"])
-                slot = np.asarray(tok["slot"])
-                deadline = int(
-                    np.asarray(
-                        prog.next_timer(tok, after=self.state["timer_ts"])
-                    )
+                per_state, pending, deadline, counters = jax.device_get(
+                    self._census_jit(tok, self.state["timer_ts"])
                 )
         except Exception:
             # a concurrent donated-state dispatch (fused ingest) can delete
             # the buffers under us; introspection degrades, never raises
             d["states"] = [dict(s, active=None) for s in slots]
             return d
-        per_state = np.bincount(slot[active], minlength=len(slots))
         d["states"] = [
             dict(s, active=int(per_state[i])) for i, s in enumerate(slots)
         ]
-        d["active_instances"] = int(active.sum())
-        d["next_deadline_ms"] = deadline if deadline < int(NO_TIMER) else None
+        d["active_instances"] = int(per_state.sum())
+        d["next_deadline_ms"] = (
+            int(deadline) if int(deadline) < int(NO_TIMER) else None
+        )
+        d["pattern"].update(
+            tokens=int(pending),
+            **{k: int(v) for k, v in counters.items()},
+        )
         return d
+
+    def _census(self, tok, timer_ts):
+        """On the device: instances per slot, partial matches alive (tokens
+        that hold a first event), the earliest deadline, and the counters."""
+        prog = self.prog
+        active, slot = tok["active"], tok["slot"]
+        per_state = jnp.stack([
+            (active & (slot == i)).sum() for i in range(len(prog.slots))
+        ])
+        return (
+            per_state,
+            (active & (tok["start_ts"] >= 0)).sum(),
+            prog.next_timer(tok, after=timer_ts),
+            {"armed": tok["armed"], "completed": tok["completed"],
+             "expired": tok["expired"], "overflow": tok["refused"],
+             "max_emits_per_row": tok["max_row"]},
+        )
 
     def prime(self, now: int) -> dict:
         """Arm the initial token's clock (absent-at-start patterns need a timer

@@ -207,7 +207,9 @@ def _upgrade(tree, like):
     was saved before it took slots back (before PR 40) gets the stack of
     its unused slots (`ops/group.py` `table_from_legacy`); a key table that
     keeps a bucket index (since PR 41) gets it laid out from the saved `keys`
-    and `used`, whenever it was saved (`index_from_table`)."""
+    and `used`, whenever it was saved (`index_from_table`); a pattern's token
+    table that was saved before its tokens carried their arming order (before
+    PR 43) is laid out in it (`core/pattern.py` `tokens_from_legacy`)."""
     if isinstance(tree, dict) and isinstance(like, dict):
         if "used" in tree and "used" in like:
             from siddhi_tpu.ops.group import index_from_table, table_from_legacy
@@ -218,6 +220,10 @@ def _upgrade(tree, like):
                 tree = {**tree,
                         "index": index_from_table(tree["keys"], tree["used"])}
             return tree
+        if "caps" in tree and "next_seq" in like and "next_seq" not in tree:
+            from siddhi_tpu.core.pattern import tokens_from_legacy
+
+            return tokens_from_legacy(tree, like)
         if "head" in like and "head" not in tree and "seq" in tree:
             from siddhi_tpu.core.windows import ring_from_legacy
 

@@ -527,10 +527,14 @@ class BaseQueryRuntime:
             self._warned_pattern_overflow = True
             import logging
 
-            logging.getLogger(__name__).warning(
+            logging.getLogger(__name__).error(
                 "query '%s': pattern token table or emission buffer "
-                "overflowed; partial matches or emissions were dropped — "
-                "raise @app:patternCapacity(size='N') (sizes both)",
+                "overflowed; partial matches or emissions were dropped "
+                "(`overflow` of the query's `pattern` status counts them) "
+                "— raise @app:patternCapacity(size='N'): the table has to "
+                "hold the partial matches alive at once and the tokens one "
+                "micro-batch arms; the emission buffer holds two matches "
+                "for every row of a micro-batch (or the table, if smaller)",
                 self.query_id,
             )
         if (

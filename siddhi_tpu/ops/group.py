@@ -143,6 +143,31 @@ def permute_by(key: jnp.ndarray, *lanes: jnp.ndarray) -> tuple:
     return res[1:]
 
 
+# the most 32-bit words one payload sort carries beside its key (a 64-bit
+# lane rides as two): a sort's compile time grows steeply with its operands
+# (fourteen took minutes, PERF.md PR 25)
+SORT_WORDS = 3
+
+
+def permute_in_groups(key: jnp.ndarray, lanes: list) -> list:
+    """`permute_by(key, *lanes)`, at most `SORT_WORDS` words to a sort.
+    Each sort's key is shifted by its group's number: the order is the same,
+    but XLA folds sorts that share one key operand back into one sort of all
+    their lanes, the very sort the groups are there to avoid (one of
+    fourteen operands took 213 s of the pattern step's compile for a v5e, PR 43)."""
+    out, group, words = [], [], 0
+    for lane in [*lanes, None]:
+        w = 0 if lane is None else max(1, lane.dtype.itemsize // 4)
+        if group and (lane is None or words + w > SORT_WORDS):
+            shift = np.asarray(len(out), key.dtype)
+            out += permute_by(key + shift, *group)
+            group, words = [], 0
+        if lane is not None:
+            group.append(lane)
+            words += w
+    return out
+
+
 @dataclasses.dataclass
 class SortedGroups:
     """Sorted per-batch view: rows permuted by (active, reset-era, key, idx).

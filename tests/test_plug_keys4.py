@@ -145,7 +145,11 @@ def lowered_text(config: str) -> str:
 # lane as the table's count of rows. The key-sharded program has no window
 # ahead and the filter no group-by: both kept their hash. The same PR added
 # `nexmark-q5-hot-items`, the first table whose keys come and go.
+# PR 43 replaced none and added `debs14-load-rise-pattern`, the first chunk
+# program that holds a pattern's NFA step (core/pattern.py `apply_batch_fast`,
+# its tokens found by key): the four above hold no pattern.
 STANDING_PROGRAMS = {
+    "debs14-load-rise-pattern": "e6f145513d5b21943458c961435d5d28f9aa8f21415f9647832f6497f1e3e89d",
     "debs14-q1-plug": "b63ddfe821c0da2206bbfa1c0dde441375f003a43321677cd12b12fa4603dd16",
     "nexmark-q5-hot-items": "8deedbf12f5b611c70ef6c18f63178542c853dab6eaacbb9a67c4e35513b2836",
     "siddhi-simple-filter": "e57dc6766097200e83d5fedc19d003b611a6acb4532be6940bfab8eaf418132b",
@@ -200,6 +204,66 @@ def test_plug_programs_read_per_group_values_by_segment(config):
             assert left == [f"{batch}xui8"], left
         else:
             assert len(left) == 3 and f"{batch}xui8" in left, left
+
+
+def pair_tensors(stablehlo: str, sizes) -> list:
+    """The tensor types of a lowered program that have one element for every
+    pair of a token and a row of a micro-batch, or of two rows: T x B (the
+    match matrix `apply_batch_fast` built for every state before PR 43, T x C
+    for its chunk C = min(B, T // 2), which is B at these sizes) or B x B,
+    in any order and with further axes."""
+    import re
+
+    T, B = sizes["tokens"], sizes["batch"]
+    pairs = {T * B, B * B}
+    found = set()
+    for m in re.finditer(r"tensor<((?:\d+x)+)\w+>", stablehlo):
+        dims = [int(d) for d in m.group(1).rstrip("x").split("x")]
+        if any(a * b in pairs for i, a in enumerate(dims) for b in dims[i + 1:]):
+            found.add(m.group(0))
+    return sorted(found)
+
+
+def test_the_pattern_program_holds_nothing_of_tokens_by_rows():
+    """At `debs14-load-rise-pattern`'s own sizes (163,840 tokens, micro-batches
+    of 32,768 rows) the chunk program holds no tensor of T x B, T x C or
+    B x B elements: every state finds its tokens by key, the `every` state
+    filters rows alone, and the status says so. What is sorted is T + B
+    long; the longest lanes are the pack's."""
+    _, _, cfg = load("debs14-load-rise-pattern")
+    sizes = cfg["sizes"]
+    text, status = lowered("debs14-load-rise-pattern", rehearse=False)
+    assert status["pattern"]["match"] == "keyed"
+    assert status["pattern"]["token_capacity"] == sizes["tokens"]
+    # two matches a row of a micro-batch, not the table
+    assert status["pattern"]["emit_capacity"] == 2 * sizes["batch"]
+    assert pair_tensors(text, sizes) == []
+    merged = sizes["tokens"] + sizes["batch"]
+    assert f"tensor<{merged}xi32>" in text
+    assert [n for _, n in long_sorts(text.splitlines(), merged)] == []
+    # the scan finds the matrix where there is one: the same app with the
+    # key taken out of its second state
+    from siddhi_tpu import SiddhiManager
+    import jax
+
+    app = (CONFIGS / "debs14-load-rise-pattern" / "app.siddhi").read_text()
+    for attr in ("house_id", "household_id", "plug_id"):
+        app = app.replace(f"{attr} == e1.{attr}", f"{attr} <= e1.{attr}")
+    small = {**sizes, "tokens": 2048, "batch": 1024}
+    mgr = SiddhiManager()
+    rt = mgr.create_siddhi_app_runtime(app.format(**small))
+    try:
+        qr = rt.queries[cfg["query"]]
+        assert rt.snapshot_status()["queries"][cfg["query"]]["pattern"][
+            "match"] == "matrix"
+        dense = jax.jit(qr._make_step("Plug")).lower(
+            jax.eval_shape(lambda: qr._fresh(qr.init_state(0))), {},
+            rt.junctions["Plug"].schema.empty_batch(1024),
+            np.int64(0)).as_text()
+        assert "tensor<2048x1024xi1>" in pair_tensors(dense, small)
+    finally:
+        rt.shutdown()
+        mgr.shutdown()
 
 
 def table_slots(sizes) -> int:
