@@ -25,8 +25,9 @@ falls back to the per-batch path with identical semantics.
 Chunk stages (encode -> h2d -> dispatch -> drain) run double-buffered by
 default through core/pipeline.py: chunk N+1 is encoded into a pooled wire
 buffer and device_put while chunk N's donated-state dispatch is in flight,
-and deliver-mode readback+decode+callbacks run on a bounded background
-drain worker in chunk order. A re-entrant send (a callback or failure
+and deliver-mode decode+callbacks run on a bounded background drain worker
+in chunk order, which awaits the readback the sender started when it
+dispatched the chunk. A re-entrant send (a callback or failure
 handler that sends again from inside a send) runs the same chunk loop
 against the pipeline's inline side: fresh buffers, drained on the caller.
 """
@@ -53,6 +54,7 @@ from siddhi_tpu.observability.profiler import (
     CAUSE_DELIVER_SET,
     CAUSE_FULL_WIDTH,
     CAUSE_TAIL_K,
+    inherited_ids,
     stage,
 )
 from siddhi_tpu.testing import faults as _faults
@@ -124,6 +126,12 @@ def _as_bytes(word) -> jnp.ndarray:
     """[R, itemsize] u8: the bytes of a packed [R] word, row by row."""
     u8 = lax.bitcast_convert_type(word, jnp.uint8)
     return u8[:, None] if u8.ndim == 1 else u8
+
+
+def _bucket(rows: int, R: int) -> int:
+    """The power of two at or above `rows`, at most `R`: the sizes a read of
+    a packed buffer's rows comes in, so that few slice programs are built."""
+    return min(R, 1 << max(0, int(rows - 1).bit_length()))
 
 
 def _needs_scheduler(qr) -> bool:
@@ -209,6 +217,16 @@ class FusedJunctionIngest:
         # and the allocator is told here to keep the chunks' host buffers
         self.host_blocks = keep_host_blocks()
         self.decode_native_rows = 0
+        # per endpoint, the total its last drained chunk had: the prefix a
+        # chunk's first read asks for (the drain writes it, the sender reads)
+        self._drain_guess: dict = {}
+        # chunks whose first read was started at dispatch; those of them
+        # whose bytes lay on the host when the drain asked; chunks whose
+        # guess fell short of their total (a top-up read). Counts for the
+        # status, bumped without a lock like `chunks_dispatched`
+        self.readback_started = 0
+        self.readback_ready = 0
+        self.readback_topups = 0
         self._fused = None
         self._fused_deliver = None
         self._disabled = False
@@ -275,6 +293,14 @@ class FusedJunctionIngest:
             # builder has made
             "decode": "python" if event_builder() is None else "native",
             "decode_native_rows": self.decode_native_rows,
+            # a chunk's first read is started when the chunk is dispatched
+            # (`_start_reads`): chunks it was started for, those of them
+            # whose bytes lay on the host, dense, when the drain asked (the
+            # hit share), and chunks whose prefix fell short of their rows
+            # (a second, blocking read)
+            "readback_started": self.readback_started,
+            "readback_ready": self.readback_ready,
+            "readback_topups": self.readback_topups,
             # whether glibc's allocator keeps what the chunks' large host
             # buffers free (`native.keep_host_blocks`): kept / as_set / default
             "host_blocks": self.host_blocks,
@@ -1216,8 +1242,12 @@ class FusedJunctionIngest:
                 if deliver and packs is not None:
                     # hand the packs to the drain BEFORE staging the next
                     # chunk: nothing downstream can lose them, and the
-                    # worker's readback+decode overlaps the encode below
-                    pl.submit(packs, K, wf, chunk)
+                    # worker's decode overlaps the encode below. Their first
+                    # read is started here, behind the program just
+                    # dispatched, so that it runs while the drain is still
+                    # busy with the chunk before
+                    reads = self._start_reads(packs, K, chunk)
+                    pl.submit(packs, reads, K, wf, chunk)
                 elif wf is not None:
                     prof = self.junction.profiler
                     if prof is not None:
@@ -1339,12 +1369,76 @@ class FusedJunctionIngest:
                     out[k, :] = 0
             return out, counts, bases
 
+    def _start_reads(self, packs, K: int, chunk=None) -> dict:
+        """Start the first read of one dispatched chunk's packed outputs, on
+        the sender's thread, waiting for nothing: {endpoint: _start_read's
+        tuple} for the endpoints that have callbacks (a chunk nobody listens
+        to starts nothing). The slice is queued behind the chunk program on
+        the device, the copy behind the slice and the host's part behind
+        both on the pipeline's reader thread, so the read runs while the
+        drain is still building the `Event`s of the chunk before; `_drain`
+        awaits it. The `readback_start` stage."""
+        reads = {}
+        with stage("readback_start", chunk=chunk):
+            for i, pack in zip(self._deliver_idx, packs):
+                if not getattr(self.endpoints[i].qr, "query_callbacks", None):
+                    continue
+                try:
+                    reads[i] = self._start_read(i, pack, K)
+                except Exception:
+                    # not this thread's failure to report: the drain starts
+                    # the read again and meets what went wrong with the
+                    # chunk where it always has, at its own read
+                    continue
+        if reads:
+            self.readback_started += 1
+        return reads
+
+    def _start_read(self, i: int, pack, K: int):
+        """Queue, without waiting, endpoint `i`'s first read of a chunk's
+        packed buffer: ONE round trip in the steady state, because the
+        buffer's header rows carry the per-iteration counts and the prefix
+        is sized from the total of the endpoint's last drained chunk (all
+        `R` rows when none is known). Returns (buf, hdr_rows, guess, head):
+        the buffer on its device, its header rows, the rows asked for
+        behind them and the Future of the prefix as dense bytes on the
+        host."""
+        _layout, row_bytes = self._deliver_layout[i]
+        hdr_rows = -(-4 * K // row_bytes)
+        buf = pack["buf"]
+        if self._mesh_place is not None:
+            # replicated over the mesh: read (and slice) one copy
+            buf = buf.addressable_data(0)
+        R = buf.shape[0] - hdr_rows
+        guess = _bucket(self._drain_guess.get(i, R), R)
+        head = buf[: hdr_rows + guess]
+        head.copy_to_host_async()
+        # the reader's span carries the `send` and `chunk` of the span this
+        # is called under (`readback_start`, or the drain's own)
+        return buf, hdr_rows, guess, self.pipeline.read_ahead(
+            self._finish_read, head, inherited_ids()
+        )
+
+    @staticmethod
+    def _finish_read(head, ids):
+        """The host's half of a read, on the pipeline's reader thread: wait
+        for the bytes, which arrive in the device's order of dimensions
+        (the packed buffer lies there with its rows as the minor
+        dimension), and lay them out as dense rows, which the `.view(dtype)`
+        reinterprets of the decode require. Both hold no interpreter lock,
+        so they run beside the drain's decode. The `readback_copy` stage."""
+        with stage("readback_copy", **ids):
+            return np.ascontiguousarray(head)
+
     def _drain(
-        self, packs, K: int, wf=None, ids=None, t_submit=0, tracker=None
+        self, packs, reads, K: int, wf=None, ids=None, t_submit=0,
+        tracker=None,
     ) -> None:
-        """Deliver one chunk's packed outputs to query callbacks: one counts
-        readback + one sliced transfer per endpoint-with-callbacks, then a
-        vectorized host decode, preserving per-micro-batch callback grouping
+        """Deliver one chunk's packed outputs to query callbacks: per
+        endpoint-with-callbacks the read that `_start_reads` began when the
+        chunk was dispatched (`reads`) is awaited, topped up where its
+        prefix fell short of the header's counts, then a vectorized host
+        decode, preserving per-micro-batch callback grouping
         (reference: QueryCallback.receive per chunk,
         query/output/callback/QueryCallback.java:52-105). `K` is the chunk's
         batch count (variable: short tails ride smaller-K programs).
@@ -1353,64 +1447,52 @@ class FusedJunctionIngest:
         `ids` are the sender's `send` and `chunk`, and `t_submit`
         (perf_counter_ns at the hand-off) gives the time the chunk waited,
         which no span can cross threads to show.
-        Inside it: `readback_wait` (the FIRST blocking readback, dominated
-        by waiting for the program; the waterfall's `device`), `readback`
-        (top-up transfers), then `decode` and `callback` in
-        deliver_endpoint (the waterfall's `deliver`); closes the chunk's
-        waterfall record."""
-        import jax
-
-        if not hasattr(self, "_drain_guess"):
-            self._drain_guess = {}
+        Inside it: `readback_wait` (the FIRST await of a chunk: the program,
+        where it still runs, then the bytes; the waterfall's `device`),
+        `readback` (further endpoints' awaits and top-up transfers), then
+        `decode` and `callback` in deliver_endpoint (the waterfall's
+        `deliver`); closes the chunk's waterfall record. A chunk program
+        that failed raises here, at the await."""
         queued = time.perf_counter_ns() - t_submit if t_submit else 0
         if wf is not None and queued:
             wf.stage("queue", queued)
         sync = self.junction.device_stats
         sync = sync and sync.sync_stall
         first_get = True
+        topped_up = False
         with stage("drain", tracker, queued_us=queued // 1000, **(ids or {})):
+            if reads and all(r[3].done() for r in reads.values()):
+                self.readback_ready += 1
             # packs align with the endpoints the program was built to deliver
             for i, pack in zip(self._deliver_idx, packs):
                 qr = self.endpoints[i].qr
                 if not getattr(qr, "query_callbacks", None):
                     continue
-                layout, row_bytes = self._deliver_layout[i]
-                hdr_rows = -(-4 * K // row_bytes)
-                buf = pack["buf"]
-                if self._mesh_place is not None:
-                    # replicated over the mesh: read (and slice) one copy
-                    buf = buf.addressable_data(0)
+                # started at dispatch; only now for an endpoint that had no
+                # callback then, or whose start failed
+                read = reads.get(i) or self._start_read(i, pack, K)
+                buf, hdr_rows, guess, head = read
                 R = buf.shape[0] - hdr_rows
-
-                def bucket(x: int) -> int:
-                    return min(R, 1 << max(0, int(x - 1).bit_length()))
-
-                # ONE round trip in the steady state: the buffer's header
-                # rows carry the per-iteration counts, and the prefix is
-                # sized from the previous chunk's total; top up only when
-                # the guess undershoots (workload rates are stable)
-                guess = bucket(self._drain_guess.get(i, R))
-                # ascontiguousarray: this backend's device_get can hand back
-                # a strided view of the device-layout buffer for some slice
-                # sizes, and the .view(dtype) reinterprets below require
-                # dense bytes
                 with stage(
                     "readback_wait" if first_get else "readback", sync,
                     wf=wf, wf_name="device" if first_get else "readback",
                 ):
-                    head = np.ascontiguousarray(
-                        jax.device_get(buf[: hdr_rows + guess])
-                    )
+                    head = head.result()
                 first_get = False
                 cnts = head[:hdr_rows].reshape(-1)[: 4 * K].view(np.int32)
                 total = int(cnts.sum())
                 self._drain_guess[i] = max(total, 1)
                 if total == 0:
                     continue
-                L = bucket(total)
+                L = _bucket(total, R)
                 if L <= guess:
                     host = head[hdr_rows:]
                 else:
+                    # the guess undershot (workload rates are stable, so
+                    # this is rare): a second, blocking read of the rest
+                    if not topped_up:
+                        topped_up = True
+                        self.readback_topups += 1
                     with stage("readback", sync, wf=wf):
                         tail = np.ascontiguousarray(
                             jax.device_get(

@@ -20,7 +20,14 @@ This module keeps those stages concurrently busy (the Hazelcast Jet
 3. a bounded background drain worker syncs each chunk's packed output
    buffer, decodes it, and runs query-callback delivery in chunk order,
    with backpressure (at most `depth` undrained chunks in flight) so state
-   donation stays safe and device memory for packed outputs is bounded.
+   donation stays safe and device memory for packed outputs is bounded;
+4. the read of a chunk's packed output is STARTED by the sender when the
+   chunk is handed to the drain (`submit`'s `reads`: a slice queued behind
+   the chunk program, its copy to the host behind the slice, and on the
+   reader thread, `read_ahead`, the wait for the bytes and their relayout
+   into dense rows, which hold no interpreter lock) and only AWAITED by
+   whoever drains the chunk, so the read of chunk N+1 runs while the
+   worker is still decoding chunk N.
 
 Ordering and failure semantics:
 
@@ -52,6 +59,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
+from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Optional
 
 import numpy as np
@@ -143,7 +151,7 @@ class IngestPipeline:
     def __init__(self, junction, depth: int = DEFAULT_DEPTH, drain_fn=None):
         self.junction = junction
         self.depth = max(1, int(depth))
-        # fn(packs, K, wf, ids, t_submit, tracker): the ingest's _drain
+        # fn(packs, reads, K, wf, ids, t_submit, tracker): the ingest's _drain
         self.drain_fn = drain_fn
         self.stats = None  # PipelineStats | None, set by the owner
         # where ship() puts the wire: None = the default device; the owner
@@ -156,6 +164,12 @@ class IngestPipeline:
         self._error: Optional[BaseException] = None
         self._q: Optional[queue.Queue] = None
         self._thread: Optional[threading.Thread] = None
+        # one thread that finishes the reads the sender starts (read_ahead);
+        # it starts with the first of them
+        self._reader = ThreadPoolExecutor(
+            max_workers=1,
+            thread_name_prefix=f"siddhi-readback-{junction.schema.stream_id}",
+        )
         self._closed = False
 
     # ---- wire buffer pool ------------------------------------------------
@@ -287,11 +301,20 @@ class IngestPipeline:
             and threading.current_thread() is self._thread
         )
 
-    def submit(self, packs, K: int, wf=None, chunk=None) -> None:
-        """Queue one chunk's packed outputs for ordered delivery (`wf`:
-        the chunk's stage waterfall, closed by the drain; `chunk`: its id
-        in the stage spans). Blocks while `depth` chunks are already in
-        flight (backpressure): the `submit_wait` stage."""
+    def read_ahead(self, fn, *args) -> Future:
+        """Run `fn(*args)` on the reader thread: the host's half of a read
+        the sender has queued on the device (waiting for the bytes, laying
+        them out), so that it is done, or under way, when the drain asks.
+        Reads finish in the order they were started; a failure is kept in
+        the Future and raised where its result is asked for, at the drain."""
+        return self._reader.submit(fn, *args)
+
+    def submit(self, packs, reads, K: int, wf=None, chunk=None) -> None:
+        """Queue one chunk's packed outputs for ordered delivery, with the
+        reads of them the sender has started (`reads`, awaited by the
+        drain; `wf`: the chunk's stage waterfall, closed by the drain;
+        `chunk`: its id in the stage spans). Blocks while `depth` chunks
+        are already in flight (backpressure): the `submit_wait` stage."""
         if self._thread is None:
             self._start_thread()
         with self._cv:
@@ -301,7 +324,7 @@ class IngestPipeline:
                         self._cv.wait()
             self._inflight += 1
         ids = {**inherited_ids(), "chunk": chunk}
-        self._q.put((packs, K, wf, ids, time.perf_counter_ns()))
+        self._q.put((packs, reads, K, wf, ids, time.perf_counter_ns()))
 
     def pending_error(self) -> bool:
         """True once an unguarded drain failure is stashed for barrier():
@@ -347,7 +370,7 @@ class IngestPipeline:
                     self._inflight -= 1
                     self._cv.notify_all()
 
-    def _drain_one(self, packs, K: int, wf, ids, t_submit) -> None:
+    def _drain_one(self, packs, reads, K: int, wf, ids, t_submit) -> None:
         # fault-injection site `drain_worker` (testing/faults.py): the
         # pipelined analog of the @async drain-worker site — an injected
         # fault rides the same guarded/unguarded routing a poisoned
@@ -357,7 +380,7 @@ class IngestPipeline:
                 "drain_worker", self.junction.schema.stream_id
             )
         ps = self.stats
-        self.drain_fn(packs, K, wf, ids, t_submit, ps and ps.drain)
+        self.drain_fn(packs, reads, K, wf, ids, t_submit, ps and ps.drain)
 
     def _junction_owns(self, exc: Exception, where: str) -> bool:
         """Hand a delivery failure to a guarded junction's failure
@@ -383,8 +406,10 @@ class IngestPipeline:
         return _InlineDrain(self)
 
     def close(self) -> None:
-        """Flush nothing (callers barrier first); stop the drain worker."""
+        """Flush nothing (callers barrier first); stop the drain worker
+        and the reader."""
         self._closed = True
+        self._reader.shutdown(wait=False)
         with self._cv:
             self._cv.notify_all()
         t = self._thread
@@ -417,12 +442,13 @@ class _InlineDrain:
     def retire(self, slot: _WireSlot, completion) -> None:
         """Nothing to gate: the buffer is never written again."""
 
-    def submit(self, packs, K: int, wf=None, chunk=None) -> None:
+    def submit(self, packs, reads, K: int, wf=None, chunk=None) -> None:
         """Park this chunk and drain the PREVIOUS one now that this chunk's
-        device work is launched: the host decode overlaps device compute,
-        and callbacks still fire in order before the send returns."""
+        device work is launched: the host decode overlaps device compute
+        and this chunk's read, and callbacks still fire in order before the
+        send returns."""
         prev, self._parked = self._parked, (
-            packs, K, wf, {"chunk": chunk}, time.perf_counter_ns(),
+            packs, reads, K, wf, {"chunk": chunk}, time.perf_counter_ns(),
         )
         if prev is not None:
             self._drain(prev)

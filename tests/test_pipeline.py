@@ -394,9 +394,9 @@ def test_reentrant_send_drains_one_chunk_late_off_the_pool():
         log.append(("dispatch", kw["chunk"]))
         return dispatch(*a, **kw)
 
-    def spy_drain(packs, K, wf, ids, *rest):
+    def spy_drain(packs, reads, K, wf, ids, *rest):
         log.append(("drain", ids["chunk"]))
-        return drain(packs, K, wf, ids, *rest)
+        return drain(packs, reads, K, wf, ids, *rest)
 
     fi._dispatch_chunk, fi._drain = spy_dispatch, spy_drain
     slots = {}
@@ -515,6 +515,215 @@ def test_reentrant_send_from_failure_handler_on_sender_thread():
     assert got == [1000.0 + i for i in range(64 * 4)]
     fi = _fused(rt)
     assert fi.events_fused == 64 * 4 and fi.pipeline.in_flight() == 0
+    rt.shutdown()
+    mgr.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# a chunk's first read: started at dispatch, awaited by the drain
+# ---------------------------------------------------------------------------
+
+CHUNK_HEAD = "@app:ingestChunk(size='4')\n" + HEAD  # a chunk is 256 rows
+GATE_BODY = (
+    "@info(name='q') from S[price >= 50] select symbol, price, volume "
+    "insert into Out;"
+)
+
+
+def _gated_feed(n, passing, seed=42):
+    """n rows of which the first `passing` of every 64 pass GATE_BODY's
+    filter: every chunk of a send delivers the same number of rows."""
+    ts, cols = _feed(n, seed)
+    cols["price"] = np.where(
+        np.arange(n) % 64 < passing, 50.0 + np.arange(n), 1.0
+    ).astype(np.float32)
+    return ts, cols
+
+
+def _run_gated(head, sends):
+    got = []
+    mgr, rt = _boot(
+        head + GATE_BODY,
+        callback=lambda ts, ins, rem: got.append(
+            (ts, [tuple(e.data) for e in ins])
+        ),
+    )
+    for n, passing in sends:
+        rt.get_input_handler("S").send_columns(*_gated_feed(n, passing))
+    fi = rt.junctions["S"].fused_ingest
+    status = rt.snapshot_status()["streams"]["S"].get("pipeline")
+    chunks = fi.chunks_dispatched if fi is not None else 0
+    rt.shutdown()
+    mgr.shutdown()
+    return got, status, chunks
+
+
+@pytest.mark.parametrize(
+    "sends, topups",
+    [
+        # first chunks after deploy: no total is known, all rows are asked for
+        pytest.param([(256 * 3, 40)], (0, 0), id="unknown"),
+        # the last drained chunk had this chunk's total: one read
+        pytest.param([(256 * 3, 40), (256 * 3, 40)], (0, 0), id="exact"),
+        # it had far fewer rows: the prefix is short and the rest is read
+        # behind it (how many of the second send's chunks were started
+        # before the first of them was drained is the threads' business)
+        pytest.param([(256 * 3, 2), (256 * 3, 64)], (1, 3), id="short"),
+    ],
+)
+def test_read_started_at_dispatch_delivers_what_per_batch_does(sends, topups):
+    """The sender starts each chunk's first read when it hands the chunk to
+    the drain; whatever the prefix it asked for, the callback sees the rows
+    of the per-batch path, in its order and grouping."""
+    fused, status, chunks = _run_gated(CHUNK_HEAD, sends)
+    per_batch, _status, _chunks = _run_gated(
+        "@app:fuse(disable='true')\n" + CHUNK_HEAD, sends
+    )
+    assert fused == per_batch
+    assert sum(len(rows) for _ts, rows in fused) == sum(
+        n // 64 * passing for n, passing in sends
+    )
+    assert chunks == 3 * len(sends)
+    assert status["readback_started"] == chunks
+    assert 0 <= status["readback_ready"] <= chunks
+    assert topups[0] <= status["readback_topups"] <= topups[1]
+
+
+def test_readback_counters_read_what_happened():
+    """`readback_started` counts the chunks whose read the sender started,
+    `readback_ready` those whose bytes were on the host when the drain
+    asked: behind a slow callback every chunk but a send's first. An
+    endpoint nobody listens to starts no read."""
+    import time
+
+    mgr, rt = _boot(
+        CHUNK_HEAD + GATE_BODY + "\n@info(name='q2') from S[price >= 50] "
+        "select symbol insert into Out2;",
+        callback=lambda ts, ins, rem: time.sleep(0.05),
+    )
+    fi = _fused(rt)
+    started = []
+    start_read = fi._start_read
+
+    def spy(i, pack, K):
+        started.append(fi.endpoints[i].qr.query_id)
+        return start_read(i, pack, K)
+
+    fi._start_read = spy
+    rt.get_input_handler("S").send_columns(*_gated_feed(256 * 3, 8))
+    status = rt.snapshot_status()["streams"]["S"]["pipeline"]
+    assert fi.chunks_dispatched == 3 and started == ["q"] * 3
+    assert status["readback_started"] == 3
+    assert status["readback_ready"] in (2, 3)
+    assert status["readback_topups"] == 0
+    rt.shutdown()
+    mgr.shutdown()
+
+
+def test_reentrant_send_starts_its_reads_too():
+    """The inline side parks a chunk with the read the caller started for
+    it: same drain, same counters, delivery in order."""
+    n_out, n_in = 64 * 8, 64 * 8
+    mgr, rt, cb = _boot_reentrant(CHUNK_HEAD + PASS_BODY, n_in)
+    rt.get_input_handler("S").send_columns(*_outer_feed(n_out))
+    assert cb.order == (
+        list(range(64))
+        + [1000.0 + i for i in range(n_in)]
+        + list(range(64, n_out))
+    )
+    fi = _fused(rt)
+    assert fi.chunks_dispatched == 4  # two of the outer send, two inside
+    assert fi.readback_started == 4 and fi.readback_topups == 0
+    rt.shutdown()
+    mgr.shutdown()
+
+
+def test_reads_ahead_under_a_short_switch_interval():
+    """Sender, reader and drain worker hand chunks to one another across
+    many sends with the interpreter switching threads every few
+    microseconds: every row arrives once, in order, and every chunk's read
+    was started once and awaited."""
+    import sys
+
+    got = []
+    mgr, rt = _boot(
+        CHUNK_HEAD + PASS_BODY,
+        callback=lambda ts, ins, rem: got.extend(e.data[1] for e in ins),
+    )
+    h = rt.get_input_handler("S")
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for _ in range(25):
+            h.send_columns(*_outer_feed(256 * 3))
+    finally:
+        sys.setswitchinterval(interval)
+    fi = _fused(rt)
+    assert got == list(range(256 * 3)) * 25
+    assert fi.chunks_dispatched == fi.readback_started == 75
+    assert fi.readback_topups == 0 and fi.pipeline.in_flight() == 0
+    rt.shutdown()
+    mgr.shutdown()
+
+
+class _FailedBuffer:
+    """A packed buffer whose chunk program failed on the device: the
+    failure shows when its bytes are asked for (`at="await"`) or, on a
+    backend that knows by then, when the slice is queued (`"enqueue"`)."""
+
+    def __init__(self, shape, at):
+        self.shape = shape
+        self.at = at
+
+    def __getitem__(self, rows):
+        if self.at == "enqueue":
+            raise RuntimeError("chunk program failed")
+        return self
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, *a, **kw):
+        raise RuntimeError("chunk program failed")
+
+
+@pytest.mark.parametrize("policy", ["handler", "none"])
+@pytest.mark.parametrize("at", ["await", "enqueue"])
+def test_failed_chunk_program_surfaces_at_the_drain(at, policy):
+    """Starting a chunk's read on the sender's thread moves no failure
+    there: a program that failed is met by the drain, which hands it to the
+    junction's handler or, with none, to the barrier that ends the send."""
+    got = []
+    mgr, rt = _boot(
+        CHUNK_HEAD + PASS_BODY,
+        callback=lambda ts, ins, rem: got.extend(e.data[1] for e in ins),
+    )
+    seen = []
+    if policy == "handler":
+        rt.set_exception_handler(seen.append)
+    fi = _fused(rt)
+    dispatch = fi._dispatch_chunk
+
+    def failing_second_chunk(*a, **kw):
+        packs, completion = dispatch(*a, **kw)
+        if fi.chunks_dispatched == 2:
+            packs = [
+                {**p, "buf": _FailedBuffer(p["buf"].shape, at)} for p in packs
+            ]
+        return packs, completion
+
+    fi._dispatch_chunk = failing_second_chunk
+    h = rt.get_input_handler("S")
+    if policy == "handler":
+        h.send_columns(*_outer_feed(256 * 3))  # must not raise
+        assert [str(e) for e in seen] == ["chunk program failed"]
+        # the chunks before and behind it are delivered whole
+        assert got == list(range(256)) + list(range(512, 768))
+    else:
+        with pytest.raises(RuntimeError, match="chunk program failed"):
+            h.send_columns(*_outer_feed(256 * 3))
+        assert got[:256] == list(range(256))
+    assert fi.pipeline.in_flight() == 0
     rt.shutdown()
     mgr.shutdown()
 

@@ -39,6 +39,7 @@ select k, avg(v) as a group by k insert into Out;
 FUSED_SENDER = {
     "siddhi:encode", "siddhi:h2d", "siddhi:lock_wait", "siddhi:dispatch",
     "siddhi:slot_wait", "siddhi:submit_wait", "siddhi:barrier",
+    "siddhi:readback_start",
 }
 DRAIN_CHILDREN = {
     "siddhi:readback_wait", "siddhi:readback", "siddhi:decode",
@@ -147,6 +148,30 @@ def test_fused_path_spans(tmp_path):
         for ev in by_name[name]:
             (parent,) = [d for d in drains if d["chunk"] == ev["chunk"]]
             assert _inside(ev, parent), ev
+    # every chunk's read is started once by the sender, between its dispatch
+    # and the next chunk's encode, finished once on the reader thread and
+    # awaited once by its drain
+    for name in ("siddhi:readback_start", "siddhi:readback_copy",
+                 "siddhi:readback_wait"):
+        assert sorted(e["chunk"] for e in by_name[name]) == sorted(
+            d["chunk"] for d in drains
+        ), name
+    for copy in by_name["siddhi:readback_copy"]:
+        (wait,) = [
+            e for e in by_name["siddhi:readback_wait"]
+            if e["chunk"] == copy["chunk"]
+        ]
+        assert copy["send"] == send["send"] and copy["t1"] <= wait["t1"]
+    for start in by_name["siddhi:readback_start"]:
+        (dispatch,) = [
+            e for e in by_name["siddhi:dispatch"]
+            if e["chunk"] == start["chunk"]
+        ]
+        (wait,) = [
+            e for e in by_name["siddhi:readback_wait"]
+            if e["chunk"] == start["chunk"]
+        ]
+        assert dispatch["t1"] <= start["t0"] and start["t1"] <= wait["t1"]
     calls = by_name["siddhi:callback"]
     assert sum(c["rows"] for c in calls) == len(got) - sum(
         1 for e in got if e[0] < 10_000
