@@ -34,6 +34,7 @@ against the pipeline's inline side: fresh buffers, drained on the caller.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import threading
 import time
@@ -132,6 +133,43 @@ def _bucket(rows: int, R: int) -> int:
     """The power of two at or above `rows`, at most `R`: the sizes a read of
     a packed buffer's rows comes in, so that few slice programs are built."""
     return min(R, 1 << max(0, int(rows - 1).bit_length()))
+
+
+@functools.lru_cache(maxsize=None)
+def _prefix_program(n: int, W: int):
+    """The small program every read of a packed `[rows, W]` u8 buffer goes
+    through: `n` rows from a row offset, laid out row-major on the device as
+    one vector of `n * W` bytes. The buffer lies there with its rows as the
+    minor dimension, and a slice of it read as it lies reaches the host in
+    that order, to be transposed there byte by byte (some 25 ms for a
+    chunk's 16.8 MB); the vector arrives dense. One program per (n, W) and
+    buffer shape: `_bucket` keeps the `n` few."""
+
+    def readback_prefix(buf, start):
+        return lax.dynamic_slice_in_dim(buf, start, n, 0).reshape(-1)
+
+    return jax.jit(readback_prefix)
+
+
+def start_dense_read(buf, start: int, n: int):
+    """Queue, without waiting, the read of rows `start : start + n` of a
+    packed buffer: the prefix program behind whatever wrote the buffer, the
+    copy to the host behind the program. Returns the vector on its device,
+    for `finish_dense_read`."""
+    vec = _prefix_program(n, buf.shape[1])(buf, np.int32(start))
+    vec.copy_to_host_async()
+    return vec
+
+
+def finish_dense_read(vec, n: int) -> np.ndarray:
+    """Wait for a started read's bytes and see them as the `[n, W]` u8 rows
+    the decode's `.view(dtype)` lanes are cut from: a view, no copy."""
+    return np.asarray(vec).reshape(n, -1)
+
+
+def read_dense(buf, start: int, n: int) -> np.ndarray:
+    """Rows `start : start + n` of a packed buffer, read now."""
+    return finish_dense_read(start_dense_read(buf, start, n), n)
 
 
 def _needs_scheduler(qr) -> bool:
@@ -301,6 +339,10 @@ class FusedJunctionIngest:
             "readback_started": self.readback_started,
             "readback_ready": self.readback_ready,
             "readback_topups": self.readback_topups,
+            # how a read's bytes reach the host: laid out row-major on the
+            # device by the program that cuts them (`_prefix_program`), so
+            # that the host only views them
+            "readback_layout": "dense",
             # whether glibc's allocator keeps what the chunks' large host
             # buffers free (`native.keep_host_blocks`): kept / as_set / default
             "host_blocks": self.host_blocks,
@@ -1373,8 +1415,8 @@ class FusedJunctionIngest:
         """Start the first read of one dispatched chunk's packed outputs, on
         the sender's thread, waiting for nothing: {endpoint: _start_read's
         tuple} for the endpoints that have callbacks (a chunk nobody listens
-        to starts nothing). The slice is queued behind the chunk program on
-        the device, the copy behind the slice and the host's part behind
+        to starts nothing). The prefix program is queued behind the chunk
+        program on the device, the copy behind it and the host's part behind
         both on the pipeline's reader thread, so the read runs while the
         drain is still building the `Event`s of the chunk before; `_drain`
         awaits it. The `readback_start` stage."""
@@ -1401,34 +1443,31 @@ class FusedJunctionIngest:
         is sized from the total of the endpoint's last drained chunk (all
         `R` rows when none is known). Returns (buf, hdr_rows, guess, head):
         the buffer on its device, its header rows, the rows asked for
-        behind them and the Future of the prefix as dense bytes on the
+        behind them and the Future of the prefix as dense rows on the
         host."""
         _layout, row_bytes = self._deliver_layout[i]
         hdr_rows = -(-4 * K // row_bytes)
         buf = pack["buf"]
         if self._mesh_place is not None:
-            # replicated over the mesh: read (and slice) one copy
+            # replicated over the mesh: read one copy, on its device
             buf = buf.addressable_data(0)
         R = buf.shape[0] - hdr_rows
         guess = _bucket(self._drain_guess.get(i, R), R)
-        head = buf[: hdr_rows + guess]
-        head.copy_to_host_async()
+        vec = start_dense_read(buf, 0, hdr_rows + guess)
         # the reader's span carries the `send` and `chunk` of the span this
         # is called under (`readback_start`, or the drain's own)
         return buf, hdr_rows, guess, self.pipeline.read_ahead(
-            self._finish_read, head, inherited_ids()
+            self._finish_read, vec, hdr_rows + guess, inherited_ids()
         )
 
     @staticmethod
-    def _finish_read(head, ids):
+    def _finish_read(vec, n, ids):
         """The host's half of a read, on the pipeline's reader thread: wait
-        for the bytes, which arrive in the device's order of dimensions
-        (the packed buffer lies there with its rows as the minor
-        dimension), and lay them out as dense rows, which the `.view(dtype)`
-        reinterprets of the decode require. Both hold no interpreter lock,
-        so they run beside the drain's decode. The `readback_copy` stage."""
+        for the bytes, which the prefix program laid out row-major on the
+        device, and see them as rows. The wait holds no interpreter lock, so
+        it runs beside the drain's decode. The `readback_copy` stage."""
         with stage("readback_copy", **ids):
-            return np.ascontiguousarray(head)
+            return finish_dense_read(vec, n)
 
     def _drain(
         self, packs, reads, K: int, wf=None, ids=None, t_submit=0,
@@ -1494,11 +1533,7 @@ class FusedJunctionIngest:
                         topped_up = True
                         self.readback_topups += 1
                     with stage("readback", sync, wf=wf):
-                        tail = np.ascontiguousarray(
-                            jax.device_get(
-                                buf[hdr_rows + guess : hdr_rows + L]
-                            )
-                        )
+                        tail = read_dense(buf, hdr_rows + guess, L - guess)
                     host = np.concatenate([head[hdr_rows:], tail])
                 self.deliver_endpoint(i, host, cnts, total, wf)
         prof = self.junction.profiler

@@ -373,7 +373,7 @@ class BatchShardRouter:
         segment comes from device k % D's next undelivered iteration, so
         the interleaved row stream (and the per-segment callback grouping)
         is byte-identical to the single-device drain."""
-        import jax
+        from siddhi_tpu.core.ingest import _bucket, read_dense
 
         for pos, i in enumerate(fi._deliver_idx):
             qr = fi.endpoints[i].qr
@@ -391,21 +391,16 @@ class BatchShardRouter:
                         cnt_parts.append(np.zeros((nb,), np.int32))
                         continue  # alignment with its assigned batches
                     hdr_rows = -(-4 * K // row_bytes)
-                    # header first, then exactly the filled row prefix —
-                    # never the whole [K*cap] buffer
-                    hdr = np.ascontiguousarray(
-                        jax.device_get(packs[pos]["buf"][:hdr_rows])
-                    )
+                    buf = packs[pos]["buf"]
+                    # header first, then the filled row prefix by the sizes
+                    # the fused drain reads in, never the whole [K*cap]
+                    # buffer: the one way to read a packed buffer
+                    hdr = read_dense(buf, 0, hdr_rows)
                     cnts = hdr.reshape(-1)[: 4 * K].view(np.int32)
                     total = int(cnts.sum())
                     if total:
-                        parts.append(np.ascontiguousarray(
-                            jax.device_get(
-                                packs[pos]["buf"][
-                                    hdr_rows : hdr_rows + total
-                                ]
-                            )
-                        ))
+                        L = _bucket(total, buf.shape[0] - hdr_rows)
+                        parts.append(read_dense(buf, hdr_rows, L)[:total])
                     # padding iterations (j >= nb) carry count 0 and no rows
                     cnt_parts.append(np.asarray(cnts[:nb], np.int32))
                 dev_rows.append(

@@ -540,17 +540,42 @@ def _gated_feed(n, passing, seed=42):
     return ts, cols
 
 
-def _run_gated(head, sends):
+def _watch_hosts(fi):
+    """The header-stripped arrays `_drain` hands `deliver_endpoint` from
+    now on, as a list that fills."""
+    hosts = []
+    deliver = fi.deliver_endpoint
+
+    def spy(i, host, *a):
+        hosts.append(host)
+        return deliver(i, host, *a)
+
+    fi.deliver_endpoint = spy
+    return hosts
+
+
+def _run_gated(head, sends, body=GATE_BODY, seen=None):
+    """The callback's rows, the stream's pipeline status and the chunks
+    dispatched; into `seen`, where given: `hosts`, the arrays the drain
+    handed on, and `compiles`, the process's compile count after each
+    send."""
     got = []
     mgr, rt = _boot(
-        head + GATE_BODY,
+        head + body,
         callback=lambda ts, ins, rem: got.append(
             (ts, [tuple(e.data) for e in ins])
         ),
     )
+    fi = rt.junctions["S"].fused_ingest
+    if seen is not None:
+        seen["hosts"] = _watch_hosts(fi) if fi is not None else []
+        seen["compiles"] = []
     for n, passing in sends:
         rt.get_input_handler("S").send_columns(*_gated_feed(n, passing))
-    fi = rt.junctions["S"].fused_ingest
+        if seen is not None:
+            seen["compiles"].append(
+                rt.snapshot_status()["compile_events"]["compiles"]
+            )
     status = rt.snapshot_status()["streams"]["S"].get("pipeline")
     chunks = fi.chunks_dispatched if fi is not None else 0
     rt.shutdown()
@@ -668,14 +693,15 @@ def test_reads_ahead_under_a_short_switch_interval():
 
 class _FailedBuffer:
     """A packed buffer whose chunk program failed on the device: the
-    failure shows when its bytes are asked for (`at="await"`) or, on a
-    backend that knows by then, when the slice is queued (`"enqueue"`)."""
+    failure shows when the bytes of the prefix cut from it are asked for
+    (`at="await"`) or, on a backend that knows by then, when the prefix
+    program is queued (`"enqueue"`). Stands for its own prefix too."""
 
     def __init__(self, shape, at):
         self.shape = shape
         self.at = at
 
-    def __getitem__(self, rows):
+    def prefix(self, start):
         if self.at == "enqueue":
             raise RuntimeError("chunk program failed")
         return self
@@ -687,9 +713,28 @@ class _FailedBuffer:
         raise RuntimeError("chunk program failed")
 
 
+@pytest.fixture
+def failing_prefix(monkeypatch):
+    """The engine's prefix program, with a `_FailedBuffer` behaving as a
+    buffer would whose chunk program failed."""
+    from siddhi_tpu.core import ingest
+
+    program = ingest._prefix_program
+
+    def prefix_program(n, W):
+        real = program(n, W)
+        return lambda buf, start: (
+            buf.prefix(start)
+            if isinstance(buf, _FailedBuffer)
+            else real(buf, start)
+        )
+
+    monkeypatch.setattr(ingest, "_prefix_program", prefix_program)
+
+
 @pytest.mark.parametrize("policy", ["handler", "none"])
 @pytest.mark.parametrize("at", ["await", "enqueue"])
-def test_failed_chunk_program_surfaces_at_the_drain(at, policy):
+def test_failed_chunk_program_surfaces_at_the_drain(at, policy, failing_prefix):
     """Starting a chunk's read on the sender's thread moves no failure
     there: a program that failed is met by the drain, which hands it to the
     junction's handler or, with none, to the barrier that ends the send."""
@@ -726,6 +771,130 @@ def test_failed_chunk_program_surfaces_at_the_drain(at, policy):
     assert fi.pipeline.in_flight() == 0
     rt.shutdown()
     mgr.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# a read's bytes: laid out dense on the device, viewed on the host
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("hdr", [0, 5], ids=["no_header", "header"])
+@pytest.mark.parametrize("W", [28, 32, 25])
+def test_dense_prefix_is_the_contiguous_slice(W, hdr):
+    """What the prefix program hands the host is, byte for byte, what
+    `np.ascontiguousarray(buf[:n])` was: for rows of 28 and 32 bytes and a
+    width that is no multiple of 4 (a bool lane), every size a read comes
+    in up to the buffer's `R` rows, with and without header rows, and from
+    the offset of a top-up behind every shorter prefix. The host's array is
+    a view, not a copy."""
+    import jax.numpy as jnp
+
+    from siddhi_tpu.core.ingest import _bucket, read_dense
+
+    R = 256
+    rows = np.random.default_rng(W + hdr).integers(
+        0, 256, size=(hdr + R, W), dtype=np.uint8
+    )
+    buf = jnp.asarray(rows)
+    sizes = sorted({_bucket(n, R) for n in range(1, R + 1)})
+    assert sizes == [1 << k for k in range(9)]
+    for n in sizes:
+        got = read_dense(buf, 0, hdr + n)
+        want = np.ascontiguousarray(np.asarray(buf[: hdr + n]))
+        assert got.dtype == np.uint8 and got.shape == (hdr + n, W)
+        assert got.tobytes() == want.tobytes() == rows[: hdr + n].tobytes()
+        assert got.flags.c_contiguous
+        assert not got.flags.owndata and got.base is not None
+        for guess in sizes[: sizes.index(n)]:
+            tail = read_dense(buf, hdr + guess, n - guess)
+            assert tail.tobytes() == rows[hdr + guess : hdr + n].tobytes()
+
+
+# the delivered row: ts and volume 8 bytes each, symbol and price 4, and then
+WIDTHS = {
+    28: "price * 2 as twice",  # a float: 4 more
+    32: "volume + 1 as more",  # a long: 8 more
+    25: "price >= 75 as hot",  # a bool: 1 more, no multiple of 4
+}
+
+
+@pytest.mark.parametrize("W", sorted(WIDTHS))
+def test_dense_read_delivers_rows_of_any_width(W):
+    """Through the engine: first chunks (all rows asked for), a steady
+    prefix, a prefix that undershoots (the top-up goes through the same
+    program) and one that overshoots, for each row width: the per-batch
+    path's rows, from arrays that are dense without a copy, and no program
+    built past the first chunk of a size."""
+    body = (
+        "@info(name='q') from S[price >= 50] select symbol, price, volume, "
+        f"{WIDTHS[W]} insert into Out;"
+    )
+    sends = [(256 * 3, 40), (256 * 3, 40), (256 * 3, 2), (256 * 3, 64)] + [
+        (256 * 3, 64)
+    ] * 3
+    seen = {}
+    fused, status, chunks = _run_gated(CHUNK_HEAD, sends, body, seen)
+    per_batch, _status, _chunks = _run_gated(
+        "@app:fuse(disable='true')\n" + CHUNK_HEAD, sends, body
+    )
+    assert fused == per_batch
+    assert sum(len(rows) for _ts, rows in fused) == sum(
+        n // 64 * passing for n, passing in sends
+    )
+    assert status["readback_layout"] == "dense"
+    assert status["readback_started"] == chunks == 3 * len(sends)
+    assert 1 <= status["readback_topups"] <= 3
+    hosts = seen["hosts"]
+    assert len(hosts) == chunks
+    for host in hosts:
+        assert host.dtype == np.uint8 and host.shape[1] == W
+        assert host.flags.c_contiguous
+    # a read that needed no top-up is a view of the bytes that arrived
+    views = [h for h in hosts if not h.flags.owndata]
+    assert len(views) >= len(hosts) - status["readback_topups"]
+    assert all(h.base is not None for h in views)
+    # the last three sends read the sizes the one before them read
+    assert len(set(seen["compiles"][-3:])) == 1
+
+
+def test_dense_read_on_the_keys_mesh(monkeypatch):
+    """On the virtual keys mesh the packed buffer is replicated: one copy
+    is read, through the prefix program on that copy's device, and the
+    callback sees what it sees with no mesh."""
+    ql = (
+        "@app:ingestChunk(size='4')\n@app:batch(size='64')\n{HEAD}"
+        "define stream S (symbol string, price float, volume long);\n"
+        "@info(name='q') from S select symbol, sum(volume) as sv, "
+        "count() as c group by symbol insert into Out;"
+    )
+
+    def run(head, shard):
+        monkeypatch.setenv("SIDDHI_TPU_SHARD", shard)
+        got = []
+        mgr, rt = _boot(
+            ql.replace("{HEAD}", head),
+            callback=lambda ts, ins, rem: got.append(
+                (ts, [tuple(e.data) for e in ins])
+            ),
+        )
+        hosts = _watch_hosts(_fused(rt))
+        for _ in range(3):
+            rt.get_input_handler("S").send_columns(*_feed(256 * 3))
+        status = rt.snapshot_status()
+        rt.shutdown()
+        mgr.shutdown()
+        return got, hosts, status
+
+    sharded, hosts, status = run("@app:shard(devices='4', axis='keys')\n", "4")
+    plain, _hosts, _status = run("", "0")
+    pipe = status["streams"]["S"]["pipeline"]
+    assert pipe["mesh_devices"] == 4
+    assert status["shard"]["keyshard"]["q"]["path"] == "fused"
+    assert pipe["readback_layout"] == "dense"
+    assert pipe["readback_started"] == 9 and pipe["readback_topups"] == 0
+    assert sharded == plain and len(sharded) == 36
+    assert len(hosts) == 9
+    assert all(h.flags.c_contiguous and not h.flags.owndata for h in hosts)
 
 
 # ---------------------------------------------------------------------------

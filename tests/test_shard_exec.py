@@ -185,6 +185,31 @@ class TestBatchRouter:
         assert len(sharded["q"]) > 500  # the filter actually selected rows
         assert len(sharded["q2"]) == 4096
 
+    def test_merged_drain_reads_through_the_dense_prefix(self, monkeypatch):
+        """The merged drain has no read of its own: every device's header
+        and rows come through the fused drain's prefix program, on that
+        device, in the sizes the fused drain reads in."""
+        from siddhi_tpu.core import ingest
+
+        reads = []
+        start = ingest.start_dense_read
+
+        def spy(buf, at, n):
+            reads.append((next(iter(buf.devices())).id, at, n))
+            return start(buf, at, n)
+
+        monkeypatch.setattr(ingest, "start_dense_read", spy)
+        monkeypatch.setenv("SIDDHI_TPU_SHARD", "8")
+        sharded, _s, router_state, _ = _run_stateless("", n=4096)
+        assert router_state is not None
+        assert {d for d, _at, _n in reads} == set(range(8))
+        headers = [r for r in reads if r[1] == 0]
+        rows = [r for r in reads if r[1] > 0]
+        # per device, chunk and endpoint one header; rows in powers of two
+        assert len(headers) >= 16 and len(rows) == len(headers)
+        assert all(n & (n - 1) == 0 for _d, _at, n in rows)
+        assert len(sharded["q2"]) == 4096
+
     def test_multi_chunk_per_device_stays_byte_identical(self, monkeypatch):
         """More than two chunks per device in one send: every chunk's wire
         is staged before any dispatch, so staging must never reuse a buffer
