@@ -123,7 +123,7 @@ def _check_fuse_annotation(app: SiddhiApp, diags: list[Diagnostic]) -> None:
 
 
 def _check_shard_annotation(app: SiddhiApp, diags: list[Diagnostic]) -> None:
-    """Validate `@app:shard(devices='N', axis='part|batch|auto')` — the
+    """Validate `@app:shard(devices='N', axis='part|keys|auto')` — the
     first-class sharded-execution mode. One SA129 per malformed element,
     using the SAME rule set the runtime resolver raises on
     (parallel/shard.py iter_shard_annotation_problems), so the two can

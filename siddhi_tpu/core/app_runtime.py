@@ -1927,11 +1927,12 @@ class SiddhiAppRuntime:
         plan-driven GROUP engines first (the FusionPlan's fusable subset
         runs as one chunk program, blocked queries ride the residual
         per-batch path, shared-window candidates reference one ring), then
-        the legacy all-or-nothing engine for junctions where every
-        subscriber registered a FuseEndpoint. Called by start() and by the
-        churn splice (core/churn.py) after the wiring grows/shrinks — the
-        fusion groups re-form around the new query set. Batch shard
-        routers re-arm on the rebuilt engines."""
+        the all-or-nothing engine for junctions where every subscriber
+        registered a FuseEndpoint (a plan group needs two queries, so this
+        is the engine of every one-query junction). Called by start() and
+        by the churn splice (core/churn.py) after the wiring grows/shrinks —
+        the fusion groups re-form around the new query set. Key-sharded
+        state re-arms on the rebuilt engines."""
         from siddhi_tpu.core.ingest import FusedJunctionIngest
         from siddhi_tpu.core.pipeline import resolve_pipeline_annotation
 
@@ -1998,11 +1999,10 @@ class SiddhiAppRuntime:
                 )
         if self._shard is not None:
             self._shard.rearm_keyshard()
-            self._shard.rearm_routers()
         # re-pair the calibration ledger against the AST that just formed
         # these engines: churn splices and fused re-formations re-price
         # automatically while cumulative mispriced counters survive (the
-        # rearm_routers precedent — rebuild-owned re-arming)
+        # rearm_keyshard precedent — rebuild-owned re-arming)
         if self._calibration is not None:
             self._calibration.pair()
 
@@ -2055,9 +2055,9 @@ class SiddhiAppRuntime:
         if self._fuse_enabled:
             self._build_fused_ingest()
         # first-class sharded execution (parallel/shard.py): place
-        # partitioned [P] state on the device mesh and arm batch-axis
-        # routers on junctions whose fused endpoints are all stateless —
-        # resolved from @app:shard / SIDDHI_TPU_SHARD at creation
+        # partitioned [P] state (and, under axis='keys', group-by and join
+        # state) on the device mesh — resolved from @app:shard /
+        # SIDDHI_TPU_SHARD at creation
         shard_devices, shard_axis = self._shard_conf
         if shard_devices >= 2:
             from siddhi_tpu.parallel.shard import ShardRuntime

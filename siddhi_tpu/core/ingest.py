@@ -289,11 +289,6 @@ class FusedJunctionIngest:
         # invalidates BOTH programs, and each must attribute its own
         # rebuild compile (tail-variant hints are computed per call)
         self._cause_hints: dict = {}
-        # batch-axis sharded execution (parallel/shard.py): armed by the
-        # app's ShardRuntime ONLY when every endpoint is provably stateless
-        # — micro-batches round-robin across devices, outputs merged back
-        # in batch order. None = one attribute check per send.
-        self.shard_router = None
         # key-sharded members (parallel/keyshard.py, @app:shard axis='keys'):
         # set by _build when an endpoint's state lives on the keys mesh —
         # (per-endpoint state shardings, replicated, the sharded members'
@@ -309,9 +304,6 @@ class FusedJunctionIngest:
             getattr(ep.qr, "lineage", None) is not None
             for ep in self.endpoints
         )
-        # sharded sends park observations here keyed by global batch index
-        # so the recorder replays them in original batch order
-        self._lin_pending = None
         ps = getattr(junction, "pipeline_stats", None)
         if ps is not None:
             ps.depth = self.pipeline_depth
@@ -353,8 +345,6 @@ class FusedJunctionIngest:
         gr = self.group_report()
         if gr is not None:
             d["fusedgroup"] = gr
-        if self.shard_router is not None:
-            d["shard"] = self.shard_router.describe_state()
         ps = getattr(self.junction, "pipeline_stats", None)
         if ps is not None:
             d["occupancy"] = round(ps.occupancy(), 3)
@@ -845,89 +835,60 @@ class FusedJunctionIngest:
     def _send_engaged(
         self, prog, encode, deliver, dset, ts_arr, cols, n, B, now
     ) -> bool:
-        """The engaged send: program and codec are chosen, pick who walks the
-        chunks (the shard router or the chunk loop, on the pipeline's worker
-        side or, re-entrant, its inline side) and commit the side records."""
-
-        # flight recorder: the fused path never materializes an EventBatch
-        # host-side, so record straight from the (host, physical) columns —
-        # but only once a send path COMMITS (returns True): a False return
-        # re-sends the same events through the per-batch path, whose
-        # publish_batch would record them a second time
-        def record_flight(ok: bool) -> bool:
-            fl = self.junction.flight
-            if ok and fl is not None:
-                fl.record_columns(ts_arr, cols, n)
-            bb = self.junction.blackbox
-            if ok and bb is not None:
-                # black-box ring: same once-per-commit contract
-                bb.record_columns(ts_arr, cols, n)
-            la = self.junction.lineage
-            if ok and la is not None:
-                # lineage stamp: the fused commit is this send's one
-                # publish — same once-per-commit contract as the flight
-                # ring (a False return re-sends per batch, whose
-                # publish_batch stamps instead)
-                la.record_columns(ts_arr, cols, n)
-            if not ok:
-                return False
-            if self.residual:
-                # fused chunks committed (group callbacks delivered at the
-                # barrier above); now the blocked consumers get the same
-                # events per batch, preserving their unfused semantics
-                self._residual_dispatch(ts_arr, cols, n, now)
-            return True
-
-        # observability hooks: device-budget trackers on the junction plus
-        # per-endpoint latency trackers (recording CHUNK dispatch wall time —
-        # in fused mode the chunk is the unit of processing). All None/empty
-        # when statistics are off: the loops below pay one truthiness check.
-        ds = self.junction.device_stats
-        tracked = [
-            ep.latency_tracker
-            for ep in self.endpoints
-            if ep.latency_tracker is not None
-        ]
-        tr = self.junction.tracer
-        stream_span = f"stream.{self.junction.schema.stream_id}"
-
-        # batch-axis sharded execution (parallel/shard.py): round-robin the
-        # call's micro-batches across devices and merge outputs in batch
-        # order. None = not sharded; a None RESULT = the router declined
-        # (too few batches / narrow-wire misfit) and the single-device
-        # paths below own the call.
-        if self.shard_router is not None:
-            sent = self.shard_router.try_send(
-                self, prog, encode, deliver, ts_arr, cols, n, B, now,
-                ds, tracked, tr, stream_span,
-            )
-            if sent is not None:
-                return record_flight(sent)
-
-        def run(side) -> bool:
-            return record_flight(self._send_chunks(
-                prog, encode, deliver, dset, ts_arr, cols, n, B, now,
-                ds, tracked, tr, stream_span, side,
-            ))
-
+        """The engaged send: program and codec are chosen, pick the side of
+        the pipeline the chunk loop runs against."""
         pl = self._pipeline()
-        if (
-            pl.is_drain_thread()
-            or self._sender is threading.current_thread()
-        ):
+        me = threading.current_thread()
+        if pl.is_drain_thread() or self._sender is me:
             # re-entrant: a query callback that sends again from the drain
             # worker must not wait on the pipeline it is draining; neither
             # must the thread that already holds the send lock (a failure
             # handler run on the sending thread). The pooled wire slots and
             # the drain queue belong to the outer send, so this one runs
             # the loop against the pipeline's inline side.
-            return run(pl.inline())
+            return self._send_committed(
+                prog, encode, deliver, dset, ts_arr, cols, n, B, now,
+                pl.inline(),
+            )
         with self._send_lock:
-            self._sender = threading.current_thread()
+            self._sender = me
             try:
-                return run(pl)
+                return self._send_committed(
+                    prog, encode, deliver, dset, ts_arr, cols, n, B, now, pl
+                )
             finally:
                 self._sender = None
+
+    def _send_committed(
+        self, prog, encode, deliver, dset, ts_arr, cols, n, B, now, side
+    ) -> bool:
+        """The chunk loop against `side` of the pipeline and, once it has
+        committed, the side records of the send. A method of its own and
+        not the tail of `_send_engaged`: the chunk program is first called,
+        and so lowered, under these frames, and with one frame fewer every
+        process's first send ran longer (13 s of `q1-plug.bulk`'s set-up on
+        the chip; PERF.md, PR 46)."""
+        if not self._send_chunks(
+            prog, encode, deliver, dset, ts_arr, cols, n, B, now, side
+        ):
+            # nothing committed: the caller re-sends the same events through
+            # the per-batch path, whose publish_batch records them —
+            # recording here too would record them twice
+            return False
+        # the fused path never materializes an EventBatch host-side, so the
+        # flight recorder, the black-box ring and the lineage stamp record
+        # straight from the (host, physical) columns, once per committed
+        # send: the fused commit is this send's one publish
+        j = self.junction
+        for ring in (j.flight, j.blackbox, j.lineage):
+            if ring is not None:
+                ring.record_columns(ts_arr, cols, n)
+        if self.residual:
+            # fused chunks committed (group callbacks delivered at the chunk
+            # loop's barrier); now the blocked consumers get the same events
+            # per batch, preserving their unfused semantics
+            self._residual_dispatch(ts_arr, cols, n, now)
+        return True
 
     def _pipeline(self):
         pl = self.pipeline
@@ -977,8 +938,8 @@ class FusedJunctionIngest:
         return prog, encode
 
     def _dispatch_chunk(
-        self, prog, wire, counts, bases, now, ds, tracked, tr, stream_span,
-        ps=None, wf=None, deliver=False, lin_ks=None, chunk=None,
+        self, prog, wire, counts, bases, now,
+        ps=None, wf=None, deliver=False, chunk=None,
     ):
         """One donated-state dispatch under the app lock: collect states,
         run the program, write back, publish stats, surface aux flags.
@@ -988,6 +949,11 @@ class FusedJunctionIngest:
         IngestPipeline.retire. On a dispatch failure owned by the
         junction's exception handler returns (None, None) and the caller
         skips to the next chunk, like per-batch send_columns would."""
+        # observability hooks: the junction's device-budget tracker and
+        # tracer. None when statistics are off: one check each per chunk.
+        j = self.junction
+        ds = j.device_stats
+        tr = j.tracer
         lock = self.app._process_lock
         with stage("lock_wait", chunk=chunk):
             lock.acquire()
@@ -1009,14 +975,21 @@ class FusedJunctionIngest:
                 for ks in self._mesh_place[2]:
                     ks.path = "fused"
             span = (
-                tr.start_span(stream_span, int(counts.sum()))
+                tr.start_span(
+                    f"stream.{j.schema.stream_id}", int(counts.sum())
+                )
                 if tr is not None
                 else None
             )
             # the chunk is the unit of processing here, so the endpoints'
             # latency trackers record the chunk's dispatch wall time
             clock = stage(
-                "dispatch", ds and ds.step, ps and ps.dispatch, *tracked,
+                "dispatch", ds and ds.step, ps and ps.dispatch,
+                *(
+                    ep.latency_tracker
+                    for ep in self.endpoints
+                    if ep.latency_tracker is not None
+                ),
                 wf=wf, chunk=chunk,
             )
             try:
@@ -1092,10 +1065,8 @@ class FusedJunctionIngest:
                 ep.qr._warn_aux(flags)
         if self._lin_any:
             # provenance readback (one d2h when lineage is on): feed each
-            # armed endpoint's recorder per micro-batch, in chunk order —
-            # or park with the global batch index when the shard router
-            # dispatches chunks round-robin (see _lin_begin_send)
-            self._lin_observe_chunk(lin_stack, counts, now, lin_ks)
+            # armed endpoint's recorder per micro-batch, in chunk order
+            self._lin_observe_chunk(lin_stack, counts, now)
         # completion: ONLY leaves that are never donated to a later dispatch
         # (aux flags, output packs, table states). The query states are
         # donated at the NEXT dispatch's submit — which deletes the array
@@ -1108,11 +1079,9 @@ class FusedJunctionIngest:
 
     # ---- lineage observation (observability/lineage.py) ------------------
 
-    def _lin_observe_chunk(self, lin_stack, counts, now, lin_ks=None) -> None:
+    def _lin_observe_chunk(self, lin_stack, counts, now) -> None:
         """Feed each armed endpoint's recorder the chunk's stacked `__lin.*`
-        lanes, one micro-batch at a time. With `lin_ks` (the sharded
-        router's global batch indices for this chunk) observations are
-        parked for the in-order replay at _lin_end_send()."""
+        lanes, one micro-batch at a time."""
         import numpy as _np
 
         K = int(counts.shape[0])
@@ -1127,37 +1096,14 @@ class FusedJunctionIngest:
                 if int(counts[k]) == 0:
                     continue  # padding iteration: no valid rows
                 lanes = {kk: v[k] for kk, v in host.items()}
-                if lin_ks is not None and self._lin_pending is not None:
-                    self._lin_pending.append(
-                        (int(lin_ks[k]), i, lin, lanes, now, tag)
+                try:
+                    lin.observe(lanes, now, tag)
+                except Exception:  # provenance must never break dispatch
+                    import logging
+
+                    logging.getLogger(__name__).debug(
+                        "fused lineage observe failed", exc_info=True
                     )
-                else:
-                    self._lin_observe_one(lin, lanes, now, tag)
-
-    @staticmethod
-    def _lin_observe_one(lin, lanes, now, tag) -> None:
-        try:
-            lin.observe(lanes, now, tag)
-        except Exception:  # provenance must never break dispatch
-            import logging
-
-            logging.getLogger(__name__).debug(
-                "fused lineage observe failed", exc_info=True
-            )
-
-    def _lin_begin_send(self) -> None:
-        if self._lin_any:
-            self._lin_pending = []
-
-    def _lin_end_send(self) -> None:
-        pend, self._lin_pending = self._lin_pending, None
-        if pend:
-            # original batch order, then endpoint order — exactly the
-            # single-device chunk loop's observation order
-            for _k, _i, lin, lanes, now, tag in sorted(
-                pend, key=lambda x: (x[0], x[1])
-            ):
-                self._lin_observe_one(lin, lanes, now, tag)
 
     # ---- cross-query state sharing (plan share sets) ---------------------
 
@@ -1250,8 +1196,7 @@ class FusedJunctionIngest:
             j.dispatch_subset(decode(buf, np.int32(m)), now, self.residual)
 
     def _send_chunks(
-        self, prog, encode, deliver, dset, ts_arr, cols, n, B, now,
-        ds, tracked, tr, stream_span, pl,
+        self, prog, encode, deliver, dset, ts_arr, cols, n, B, now, pl
     ) -> bool:
         """THE chunk loop of a fused send, written against the pipeline's
         verbs (core/pipeline.py). `pl` decides where a chunk's wire buffer
@@ -1276,8 +1221,8 @@ class FusedJunctionIngest:
                 dev_wire, counts, bases, K, slot, wf, chunk = staged
                 staged = None
                 packs, completion = self._dispatch_chunk(
-                    prog, dev_wire, counts, bases, now, ds, tracked, tr,
-                    stream_span, ps, wf=wf, deliver=deliver, chunk=chunk,
+                    prog, dev_wire, counts, bases, now,
+                    ps, wf=wf, deliver=deliver, chunk=chunk,
                 )
                 pl.retire(slot, completion)
                 dispatched = True
@@ -1545,13 +1490,9 @@ class FusedJunctionIngest:
         per micro-batch segment. `host` is the header-stripped byte buffer
         (rows at the front, `row_bytes` wide per `_deliver_layout[i]`),
         `cnts` the deliverable-row count per micro-batch IN DELIVERY ORDER,
-        `total` their sum. Shared by `_drain` (one chunk's buffer) and the
-        batch shard router's merged drain (segments interleaved back into
-        global batch order, parallel/shard.py) — one delivery code path, so
-        callback grouping/ordering semantics cannot drift between them.
-        Rows are decoded one micro-batch at a time, just before that
-        micro-batch's callbacks, and dropped right after them: the host
-        never holds more than one segment's `Event`s, so their memory is
+        `total` their sum. Rows are decoded one micro-batch at a time, just
+        before that micro-batch's callbacks, and dropped right after them:
+        the host never holds more than one segment's `Event`s, so their memory is
         reused from segment to segment instead of being mapped and unmapped
         once per chunk (half a million rows are some 140 MB of small objects,
         and the page faults they cost grow fewer as the process ages, so a

@@ -224,11 +224,7 @@ class IngestPipeline:
         """Start the async host->device transfer of the slot's buffer and
         return the device array; detects per shipment whether the backend
         aliased the host buffer (see _WireSlot) and gates the slot
-        accordingly. (The batch shard router — parallel/shard.py — does
-        NOT ride these pooled slots: it stages every chunk of a send
-        before dispatching any, so a slot could be re-acquired before its
-        first occupant shipped; it uses a fresh buffer per chunk and a
-        plain pinned device_put instead.)"""
+        accordingly."""
         import jax
 
         if self.wire_sharding is None:

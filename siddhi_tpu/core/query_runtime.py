@@ -886,26 +886,6 @@ class QueryRuntime(BaseQueryRuntime):
 
     # ---- device program --------------------------------------------------
 
-    @property
-    def stateless_chain(self) -> bool:
-        """True when this query carries NO cross-batch state — no window, no
-        aggregator, no group-by slot table, no table reads/writes — and no
-        host-side ordering state (rate limiter): its output for a micro-batch
-        depends only on that micro-batch. The batch shard router
-        (parallel/shard.py) relies on this to route micro-batches of one
-        send to different devices and merge the outputs back in batch order
-        with byte-identical results."""
-        sel = self.selector
-        return (
-            self.chain.window is None
-            and not sel.aggregators
-            and sel.group is None
-            and self.rate_limiter is None
-            and self.table_op is None
-            and not self.tables
-            and not getattr(self, "join_findables", None)
-        )
-
     def init_state(self):
         return {"chain": self.chain.init_state(), "sel": self.selector.init_state()}
 

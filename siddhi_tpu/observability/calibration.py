@@ -22,7 +22,7 @@ flags mispricings with stable reason codes:
                                    Share", PAPERS.md: sharing gone stale)
 
 Pairing happens at `start()` and re-pairs on every churn splice / fused
-rebuild (the `rearm_routers` precedent) — predictions are rebuilt from the
+rebuild (the `rearm_keyshard` precedent) — predictions are rebuilt from the
 *current* AST, while cumulative mispriced counters survive re-pairing.
 With `@app:statistics` absent no ledger exists at all: the zero-overhead
 contract is one `is None` check.

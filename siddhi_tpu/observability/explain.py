@@ -269,11 +269,6 @@ def build_plan(app, runtime=None) -> dict:
                         gr = fi.group_report()
                         if gr is not None:
                             counters["fusedgroup"] = gr
-                        # batch-axis sharded execution (parallel/shard.py):
-                        # per-device dispatch/event counts on the stream node
-                        sr = getattr(fi, "shard_router", None)
-                        if sr is not None:
-                            counters["shard"] = sr.describe_state()
                         # compact wire encodings (core/wire.py): per-column
                         # encoder choices + encoded-vs-logical bytes/event,
                         # once the first engaged send chose them
@@ -526,13 +521,7 @@ def _fmt_counters(c: Optional[dict]) -> str:
         )
     if "shard" in c:
         s = c["shard"]
-        if "per_device_dispatches" in s:  # stream node: batch router counts
-            parts.append(
-                f"shard[devices={s.get('devices')}] "
-                f"dispatches={s.get('per_device_dispatches')} "
-                f"events={s.get('per_device_events')}"
-            )
-        elif s.get("sharded"):  # query node: partition-axis mesh placement
+        if s.get("sharded"):  # query node: partition-axis mesh placement
             parts.append(
                 f"shard[devices={s.get('devices')} axis={s.get('axis')} "
                 f"local_slots={s.get('local_slots')}]"

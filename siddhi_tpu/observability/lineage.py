@@ -16,7 +16,7 @@ Opt-in with `@app:lineage(capacity='N', mode='full|sample')`. Three layers:
    slice-copy writes, zero per-event allocation) that assigns each valid
    CURRENT event a monotonically increasing per-stream sequence id and
    keeps the last `capacity` events decodable on demand. Seq ids survive
-   fusion, pipelining and the sharded router because every delivery path
+   fusion, pipelining and sharding because every delivery path
    in this engine is order-preserving per stream (the byte-parity CI
    contract): a consumer's k-th CURRENT row IS the junction's seq k.
 
@@ -38,9 +38,8 @@ Opt-in with `@app:lineage(capacity='N', mode='full|sample')`. Three layers:
    * aggregations: per time-bucket contributing seq ranges and counts.
 
    In fused mode the `__lin.*` lanes bypass the chunk program's boolean
-   aux reduction and are stacked across the K micro-batches; the sharded
-   router's chunks are re-ordered back to global batch order before the
-   recorder consumes them.
+   aux reduction and are stacked across the K micro-batches; the
+   recorder consumes them in chunk order.
 
 3. **Serving** — `runtime.lineage(stream_or_query, index)` walks the
    recorded graph backward (multi-hop through insert-into chains) to the

@@ -130,9 +130,10 @@ class StatisticsManager:
         # pipelined fused ingest: component -> PipelineStats (stage
         # histograms ride device_time; occupancy/depth are gauges here)
         self.pipeline: dict[str, PipelineStats] = {}
-        # sharded execution (parallel/shard.py): component -> router-like
-        # object with describe_state() -> per-device dispatch/event counts
-        # + occupancy; rendered as the siddhi_shard_* Prometheus families
+        # key-sharded queries (parallel/keyshard.py): component -> the
+        # query's KeyShardedGroupExec, whose describe_state() -> per-device
+        # keys, occupancy and skew feeds the siddhi_keyshard_* Prometheus
+        # families
         self.shard: dict[str, object] = {}
         # event-time robustness (core/watermark.py): () -> the watermark
         # runtime's describe_state() — per-stream watermarks/lag, late-event
@@ -220,11 +221,11 @@ class StatisticsManager:
             p = self.pipeline[component] = PipelineStats(self, component)
         return p
 
-    def register_shard(self, component: str, router) -> None:
-        """Attach a shard router (parallel/shard.py BatchShardRouter) whose
-        describe_state() feeds the report's `shard` section and the
-        siddhi_shard_* Prometheus families."""
-        self.shard[component] = router
+    def register_shard(self, component: str, ex) -> None:
+        """Attach a key-sharded query's executor (parallel/keyshard.py
+        KeyShardedGroupExec) whose describe_state() feeds the report's
+        `shard` section and the siddhi_keyshard_* Prometheus families."""
+        self.shard[component] = ex
 
     def register_watermark(self, fn) -> None:
         """Attach the @app:watermark runtime's describe_state supplier; it

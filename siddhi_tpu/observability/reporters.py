@@ -156,17 +156,6 @@ _FAMILIES = {
         "gauge",
         "Configured max in-flight chunks of the pipelined fused ingest "
         "(0 = pipeline disabled)"),
-    "siddhi_shard_device_dispatches_total": (
-        "counter",
-        "Fused chunk dispatches per mesh device of a batch-sharded "
-        "junction (parallel/shard.py; device label: mesh position)"),
-    "siddhi_shard_device_events_total": (
-        "counter",
-        "Events routed to each mesh device of a batch-sharded junction"),
-    "siddhi_shard_device_occupancy": (
-        "gauge",
-        "Per-device share of a batch-sharded junction's events, "
-        "normalized so 1.0 = a perfectly even split across the mesh"),
     "siddhi_keyshard_device_keys": (
         "gauge",
         "Group keys owned by each mesh device of a key-sharded query "
@@ -317,27 +306,9 @@ def render_prometheus(reports: list[dict]) -> str:
                 f"siddhi_h2d_mb_s{_labels(app=app, component=n)}"
                 f" {ent.get('h2d_mb_s_1m', 0)}"
             )
+        # key-sharded queries (parallel/keyshard.py)
         for n, ent in rep.get("shard", {}).items():
-            occ = ent.get("occupancy", [])
-            for d, v in enumerate(ent.get("per_device_dispatches", [])):
-                body["siddhi_shard_device_dispatches_total"].append(
-                    "siddhi_shard_device_dispatches_total"
-                    f"{_labels(app=app, component=n, device=str(d))} {v}"
-                )
-            for d, v in enumerate(ent.get("per_device_events", [])):
-                body["siddhi_shard_device_events_total"].append(
-                    "siddhi_shard_device_events_total"
-                    f"{_labels(app=app, component=n, device=str(d))} {v}"
-                )
-                if d < len(occ):
-                    body["siddhi_shard_device_occupancy"].append(
-                        "siddhi_shard_device_occupancy"
-                        f"{_labels(app=app, component=n, device=str(d))}"
-                        f" {occ[d]}"
-                    )
-            # key-sharded query entries (parallel/keyshard.py) carry
-            # per_device_keys instead of dispatch counters
-            kocc = ent.get("occupancy", []) if "per_device_keys" in ent else []
+            kocc = ent.get("occupancy", [])
             for d, v in enumerate(ent.get("per_device_keys", [])):
                 body["siddhi_keyshard_device_keys"].append(
                     "siddhi_keyshard_device_keys"
@@ -349,7 +320,7 @@ def render_prometheus(reports: list[dict]) -> str:
                         f"{_labels(app=app, component=n, device=str(d))}"
                         f" {kocc[d]}"
                     )
-            if "skew" in ent and "per_device_keys" in ent:
+            if "skew" in ent:
                 body["siddhi_keyshard_skew"].append(
                     f"siddhi_keyshard_skew{_labels(app=app, component=n)}"
                     f" {ent['skew']}"

@@ -3,7 +3,6 @@
 `@app:shard` runtime mode (parallel/shard.py)."""
 
 from siddhi_tpu.parallel.shard import (  # noqa: F401
-    BatchShardRouter,
     ShardRuntime,
     resolve_shard_annotation,
     shard_env_override,

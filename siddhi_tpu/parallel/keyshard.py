@@ -1,8 +1,8 @@
 """Key-sharded stateful scale-out: group-by aggregation and join state on
 the `@app:shard` mesh (axis='keys').
 
-PR 10 sharded partitioned `[P]` state and stateless batch routing; every
-non-partitioned group-by aggregation and join window still lived on one
+`axis='part'` shards partitioned `[P]` state; without this module every
+non-partitioned group-by aggregation and join window lives on one
 device. This module hashes group keys to mesh devices so each device owns a
 DISJOINT key range of the aggregation table:
 
@@ -92,9 +92,8 @@ def owner_of(keys, n_devices: int):
 def keyed_shardable(qr) -> tuple[bool, Optional[str]]:
     """(eligible, reason-when-not) for key-sharding one query runtime.
 
-    The contract mirrors `shardable_stateless` (parallel/shard.py) but
-    allows exactly ONE kind of cross-batch state: the group-by slot table
-    plus its aggregator lanes. A windowless grouped query's per-group
+    Exactly ONE kind of cross-batch state is allowed: the group-by slot
+    table plus its aggregator lanes. A windowless grouped query's per-group
     values depend only on that group's rows, and a group's rows always
     hash to one device — so per-device selectors advancing disjoint key
     ranges reproduce the unsharded output at every owned row position."""

@@ -476,11 +476,14 @@ class TestSelfMonitor:
         rows = []
         rt.add_callback("mon", lambda ts, ins, rem: rows.extend(ins or []))
         rt.start()
+        want = {"stream.S", "stream.Out"}
         t0 = time.time()
-        while not rows and time.time() - t0 < 10:
+        while (
+            not {e.data[0] for e in list(rows)} >= want
+            and time.time() - t0 < 10
+        ):
             time.sleep(0.02)
-        assert rows
-        assert {e.data[0] for e in rows} >= {"stream.S", "stream.Out"}
+        assert {e.data[0] for e in rows} >= want
         assert rt.snapshot_status()["selfmon"]["ticks"] >= 1
         mgr.shutdown()
 

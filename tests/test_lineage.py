@@ -7,7 +7,7 @@ Covers the acceptance contract of the lineage layer:
   emission, a pattern/sequence match, a join match, and a group-by
   aggregation bucket;
 * identical lineage records under whole-graph fusion on/off and the
-  8-device batch-shard router on/off;
+  8-device mesh on/off;
 * emissions byte-identical with lineage on vs off;
 * zero overhead when off (no arenas, no recorders, no `__lin.*` lanes in
   the traced step — the profiler/tracing gating contract);
@@ -572,8 +572,8 @@ class TestParity:
         assert rec1 == rec0
 
     def test_records_identical_shard_8_vs_0(self, monkeypatch):
-        # stateless query: the batch-shard router's round-robin dispatch
-        # must replay lineage observations in original batch order
+        # stateless query: under the mesh its junction keeps the fused chunk
+        # loop, whose chunk order is the recorder's order
         app = (
             "@app:lineage(capacity='4096')\n"
             "define stream S (v long);\n"
@@ -608,19 +608,14 @@ class TestParity:
                 for i_ in range(lin.out_count)
                 for r in [rt.lineage("f", i_)]
             ]
-            routed = (
-                rt.junctions["S"].fused_ingest is not None
-                and rt.junctions["S"].fused_ingest.shard_router is not None
-            )
             out = [(e.timestamp, tuple(e.data)) for e in got]
             mgr.shutdown()
-            return out, recs, routed
+            return out, recs
 
         monkeypatch.setenv("SIDDHI_TPU_SHARD", "8")
-        out8, rec8, routed8 = drive()
+        out8, rec8 = drive()
         monkeypatch.setenv("SIDDHI_TPU_SHARD", "0")
-        out0, rec0, routed0 = drive()
-        assert routed8 and not routed0
+        out0, rec0 = drive()
         assert out8 == out0
         assert rec8 == rec0
 
