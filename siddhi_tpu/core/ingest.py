@@ -129,6 +129,12 @@ def _as_bytes(word) -> jnp.ndarray:
     return u8[:, None] if u8.ndim == 1 else u8
 
 
+# the least a read of a packed buffer asks for (rows, some 30 KB): under
+# that a read costs its round trip whatever it carries, and a floor keeps
+# the sizes read, a program each, few (`_first_read_rows`, `_note_total`)
+_LEAST_READ_ROWS = 1024
+
+
 def _bucket(rows: int, R: int) -> int:
     """The power of two at or above `rows`, at most `R`: the sizes a read of
     a packed buffer's rows comes in, so that few slice programs are built."""
@@ -244,6 +250,9 @@ class FusedJunctionIngest:
         self._aliased = False
         # achieved-dispatch accounting (vs the plan's n*K -> 1 prediction)
         self.chunks_dispatched = 0
+        # of those, by the depth K of the program variant that ran them
+        # (`_chunk_K`: full chunks at self.K, a send's short tail at less)
+        self.chunks_by_depth: dict = {}
         # the `chunk` id of the stage spans (observability/profiler.py):
         # what ties a chunk's drain-worker spans to its sender's
         self._chunk_ids = itertools.count(1)
@@ -255,8 +264,11 @@ class FusedJunctionIngest:
         # and the allocator is told here to keep the chunks' host buffers
         self.host_blocks = keep_host_blocks()
         self.decode_native_rows = 0
-        # per endpoint, the total its last drained chunk had: the prefix a
-        # chunk's first read asks for (the drain writes it, the sender reads)
+        # per endpoint and chunk depth: (the total its last drained chunk of
+        # that depth had, the slack its reads have come to need), which size
+        # a chunk's first read (`_first_read_rows`; the drain writes, the
+        # sender reads). By depth, because a send's short tail holds a few
+        # hundred rows where a full chunk holds half a million
         self._drain_guess: dict = {}
         # chunks whose first read was started at dispatch; those of them
         # whose bytes lay on the host when the drain asked; chunks whose
@@ -313,6 +325,11 @@ class FusedJunctionIngest:
         flight (see observability/introspect.py)."""
         d: dict = {
             "chunk_batches": self.K,
+            # chunk executions since deploy by the depth of the variant
+            # that ran them: {K: n}; a key below `chunk_batches` is a tail
+            "chunks_by_depth": {
+                str(k): n for k, n in sorted(self.chunks_by_depth.items())
+            },
             "enabled": not self._disabled,
             "pipeline_enabled": True,
             "depth": self.pipeline_depth,
@@ -1055,7 +1072,9 @@ class FusedJunctionIngest:
         finally:
             lock.release()
         self.chunks_dispatched += 1
-        self.batches_fused += int(counts.shape[0])
+        K = int(counts.shape[0])
+        self.chunks_by_depth[K] = self.chunks_by_depth.get(K, 0) + 1
+        self.batches_fused += K
         self.events_fused += int(counts.sum())
         if self.junction.on_publish_stats is not None:
             self.junction.on_publish_stats(int(counts.sum()))
@@ -1397,13 +1416,41 @@ class FusedJunctionIngest:
             # replicated over the mesh: read one copy, on its device
             buf = buf.addressable_data(0)
         R = buf.shape[0] - hdr_rows
-        guess = _bucket(self._drain_guess.get(i, R), R)
+        guess = self._first_read_rows(i, K, R)
         vec = start_dense_read(buf, 0, hdr_rows + guess)
         # the reader's span carries the `send` and `chunk` of the span this
         # is called under (`readback_start`, or the drain's own)
         return buf, hdr_rows, guess, self.pipeline.read_ahead(
             self._finish_read, vec, hdr_rows + guess, inherited_ids()
         )
+
+    def _first_read_rows(self, i: int, K: int, R: int) -> int:
+        """Rows a chunk's first read asks for: the bucket of the total the
+        endpoint's last drained chunk of this depth had (all `R` when none
+        is known), never under `_LEAST_READ_ROWS`. Once a read of this depth
+        has fallen short, `slack` rows more than the bucket of that total
+        less `slack`: a total that hovers round a power of two (half of a
+        chunk's rows, say) then neither falls short by a few rows nor asks
+        for twice as many. The floor holds under the slack too: a total that
+        hovers round the slack itself (a tail's thousand rows round a slack
+        of 1,024) read `slack + 16`, `+ 32`, `+ 64` ... rows, a program
+        each, built while a send waited."""
+        total, slack = self._drain_guess.get((i, K), (R, 0))
+        return min(R, _bucket(max(total - slack, _LEAST_READ_ROWS), R) + slack)
+
+    def _note_total(self, i: int, K: int, total: int, guess: int, R: int) -> int:
+        """A drained chunk's total, kept for the next read of its depth.
+        Returns the rows a second read has to fetch behind the `guess` the
+        first one asked for, 0 where the first held them all: what is
+        missing in its own bucket, never under `_LEAST_READ_ROWS`; the reads
+        of this depth ask for that much more from now on (`slack`)."""
+        slack = self._drain_guess.get((i, K), (0, 0))[1]
+        more = 0
+        if total > guess:
+            more = _bucket(max(total - guess, _LEAST_READ_ROWS), R - guess)
+            slack = max(slack, more)
+        self._drain_guess[(i, K)] = (max(total, 1), slack)
+        return more
 
     @staticmethod
     def _finish_read(vec, n, ids):
@@ -1465,20 +1512,22 @@ class FusedJunctionIngest:
                 first_get = False
                 cnts = head[:hdr_rows].reshape(-1)[: 4 * K].view(np.int32)
                 total = int(cnts.sum())
-                self._drain_guess[i] = max(total, 1)
+                more = self._note_total(i, K, total, guess, R)
                 if total == 0:
                     continue
-                L = _bucket(total, R)
-                if L <= guess:
+                if not more:
                     host = head[hdr_rows:]
                 else:
-                    # the guess undershot (workload rates are stable, so
-                    # this is rare): a second, blocking read of the rest
+                    # the guess undershot: a second, blocking read of what
+                    # is missing. It waits for whatever the sender has
+                    # queued on the device meanwhile (the send's next chunk:
+                    # 80 ms at a deployment's size), which is why the reads
+                    # of this depth ask for that much more from now on
                     if not topped_up:
                         topped_up = True
                         self.readback_topups += 1
                     with stage("readback", sync, wf=wf):
-                        tail = read_dense(buf, hdr_rows + guess, L - guess)
+                        tail = read_dense(buf, hdr_rows + guess, more)
                     host = np.concatenate([head[hdr_rows:], tail])
                 self.deliver_endpoint(i, host, cnts, total, wf)
         prof = self.junction.profiler

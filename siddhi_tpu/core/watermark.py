@@ -13,12 +13,21 @@ The annotation installs three cooperating pieces:
   rows at or below it are released in one stably-sorted columnar send, so
   the fused / pipelined / sharded send paths downstream all see ordered
   input. Rows older than the watermark at arrival are LATE and never reach
-  the junction; they are metered and handled by `late.policy`.
+  the junction; they are metered and handled by `late.policy`. The
+  watermark moves ONCE PER CALL: a call's rows are judged by the watermark
+  the calls before left, so how many rows are late depends on how the
+  caller cuts its sends. The stage runs on the caller's thread, ahead of
+  the inner send (span `siddhi:reorder`, `siddhi:late` inside it; counters
+  `offers`, `offered`, `released`, `buffered`, `late_total`, with
+  `offered == released + buffered + late_total`).
 
 * A WATERMARK CLOCK. Each source stream tracks its own watermark; the
   app-level watermark is the minimum over non-idle sources (classic
   min-propagation; a source that has been quiet for `idle.timeout` is
-  flushed and excluded so it cannot stall the app). The clock drives an
+  flushed and excluded so it cannot stall the app; quiet is measured from
+  the RETURN of the stream's last call, never from its start: a stream is
+  not quiet while its call runs, however long a first send compiles;
+  `idle.timeout='0'` turns the heartbeat off). The clock drives an
   EventTimeScheduler, so window flushes, pattern within/absent deadlines
   and aggregation bucket closes fire on WATERMARK ADVANCE, not raw
   arrival. Insert-into targets inherit min-over-inputs watermarks
@@ -54,6 +63,8 @@ import time as _time
 from typing import Callable, Optional
 
 import numpy as np
+
+from siddhi_tpu.observability.profiler import stage
 
 WATERMARK_ENV = "SIDDHI_TPU_WATERMARK"
 
@@ -219,14 +230,28 @@ class LatenessHistogram:
         self._lock = threading.Lock()
 
     def record(self, ms: int) -> None:
-        ms = int(ms)
-        idx = min(max(ms, 0).bit_length(), self._NBUCKETS - 1)
+        self.record_many(np.asarray([ms], dtype=np.int64))
+
+    def record_many(self, ms) -> None:
+        """A call's late rows at once: the same buckets, sums and quantiles
+        as one `record` per row."""
+        ms = np.asarray(ms, dtype=np.int64)
+        if ms.size == 0:
+            return
+        # bit_length of max(ms, 0): frexp's exponent, exact below 2**53 and
+        # clipped to the last bucket long before
+        idx = np.minimum(
+            np.frexp(np.maximum(ms, 0).astype(np.float64))[1],
+            self._NBUCKETS - 1,
+        )
+        per_bucket = np.bincount(idx, minlength=self._NBUCKETS).tolist()
+        total, mx = int(ms.sum()), int(ms.max())
         with self._lock:
-            self._counts[idx] += 1
-            self._sum += ms
-            self._count += 1
-            if ms > self._max:
-                self._max = ms
+            self._counts = [a + b for a, b in zip(self._counts, per_bucket)]
+            self._sum += total
+            self._count += int(ms.size)
+            if mx > self._max:
+                self._max = mx
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -263,7 +288,13 @@ class ReorderTracker:
     `max event time - bound`, and releases everything at or below it in a
     single stably-sorted columnar `deliver()` call. The stable sort makes
     the released sequence a pure function of the row multiset and the
-    watermark trajectory — the disorder-parity gate's foundation."""
+    watermark trajectory — the disorder-parity gate's foundation.
+
+    What is held between calls is one run of rows sorted by event time
+    (`_held_ts`, `_held_cols`: arrays of their own, a few seconds of
+    stream). A call's columns are read once: the sort runs on the event
+    times alone, late rows never enter it, and each column is gathered
+    through the order straight into what is released and what stays."""
 
     def __init__(
         self,
@@ -277,13 +308,16 @@ class ReorderTracker:
         self._deliver = deliver
         self._on_late = on_late
         self._lock = threading.RLock()
-        self._chunks: list = []     # [(ts array, {name: col array})]
+        self._held_ts = np.empty(0, dtype=np.int64)
+        self._held_cols: dict = {}
         self.max_ts: Optional[int] = None
         self.wm: Optional[int] = None
         self.buffered = 0
         self.peak_buffered = 0
         self.released = 0
         self.late_total = 0
+        self.offers = 0             # calls that brought rows
+        self.offered = 0            # rows they brought
         self.idle = False
         self.last_event_monotonic: Optional[float] = None
 
@@ -295,31 +329,96 @@ class ReorderTracker:
         with self._lock:
             self.idle = False
             self.last_event_monotonic = _time.monotonic()
-            if self.wm is not None:
-                late = ts < self.wm
-                if late.any():
-                    lateness = (self.wm - ts[late]).astype(np.int64)
-                    self.late_total += int(late.sum())
-                    self._on_late(
-                        ts[late], {k: v[late] for k, v in cols.items()},
-                        lateness,
+            try:
+                with stage(
+                    "reorder", stream=self.stream, rows=int(ts.size),
+                ) as span:
+                    late_before = self.late_total
+                    out = self._take(ts, cols)
+                    span.set(
+                        released=0 if out is None else int(out[0].size),
+                        held=self.buffered,
+                        late=self.late_total - late_before,
                     )
-                    keep = ~late
-                    ts = ts[keep]
-                    cols = {k: v[keep] for k, v in cols.items()}
-                    if ts.size == 0:
-                        return
-            self._chunks.append((ts, cols))
-            self.buffered += int(ts.size)
-            if self.buffered > self.peak_buffered:
-                self.peak_buffered = self.buffered
-            m = int(ts.max())
-            if self.max_ts is None or m > self.max_ts:
-                self.max_ts = m
-            new_wm = self.max_ts - self.bound
-            if self.wm is None or new_wm > self.wm:
-                self.wm = new_wm
-            self._release_locked()
+                if out is not None:
+                    self._deliver(*out)
+            finally:
+                # the stream is not quiet while its call runs: the idle
+                # heartbeat, which waited for this lock, measures from here
+                self.last_event_monotonic = _time.monotonic()
+
+    def _take(self, ts, cols):
+        """Count a call's rows, split off the late ones, hold the rest,
+        advance the watermark; returns what it lets through."""
+        self.offers += 1
+        self.offered += int(ts.size)
+        fresh = None  # the call's rows that are not late (None: all)
+        if self.wm is not None:
+            late = ts < self.wm
+            n_late = int(np.count_nonzero(late))
+            if n_late:
+                where = np.flatnonzero(late)
+                late_ts = ts[where]
+                self.late_total += n_late
+                self._on_late(
+                    late_ts, {k: v[where] for k, v in cols.items()},
+                    self.wm - late_ts,
+                )
+                if n_late == ts.size:
+                    return None
+                fresh = np.flatnonzero(~late)
+                ts = ts[fresh]
+        self.buffered += int(ts.size)
+        if self.buffered > self.peak_buffered:
+            self.peak_buffered = self.buffered
+        m = int(ts.max())
+        if self.max_ts is None or m > self.max_ts:
+            self.max_ts = m
+        new_wm = self.max_ts - self.bound
+        if self.wm is None or new_wm > self.wm:
+            self.wm = new_wm
+        return self._cut(ts, cols, fresh)
+
+    def _cut(self, ts, cols, fresh):
+        """Join a call's rows (`cols[fresh]`, event times `ts`) to what is
+        held, in event-time order, rows of one time in arrival order (the
+        held ones came first), and cut at the watermark: the head is
+        returned, the rest is held."""
+        n_held = int(self._held_ts.size)
+        both = np.concatenate([self._held_ts, ts]) if n_held else ts
+        order = np.argsort(both, kind="stable")
+        both = both[order]
+        n = int(np.searchsorted(both, self.wm, side="right"))
+        # where each sorted row lies: a held row at `order`, a row of the
+        # call at `order - n_held` of its non-late rows
+        from_held = np.flatnonzero(order < n_held)
+        src = order - n_held
+        src[from_held] = 0
+        if fresh is not None:
+            src = fresh[src]
+        held_src = order[from_held]
+
+        def gather(v, held_v):
+            out = np.take(v, src)  # faster than v[src] at a call's size
+            if from_held.size:
+                out[from_held] = held_v[held_src]
+            return out
+
+        sorted_cols = {
+            k: gather(v, self._held_cols.get(k)) for k, v in cols.items()
+        }
+        if n == both.size:
+            self._held_ts = both[:0]
+            self._held_cols = {}
+        else:
+            # copies: a view would keep the whole call alive until the next
+            self._held_ts = both[n:].copy()
+            self._held_cols = {k: v[n:].copy() for k, v in sorted_cols.items()}
+        if n == 0:
+            return None
+        self.buffered -= n
+        self.released += n
+        return both[:n], {k: v[:n] for k, v in sorted_cols.items()}
 
     def flush(self) -> None:
         """Idle timeout / drain: advance the watermark to the newest event
@@ -330,37 +429,14 @@ class ReorderTracker:
                 self.wm is None or self.max_ts > self.wm
             ):
                 self.wm = self.max_ts
-            self._release_locked()
+            n = int(self._held_ts.size)
+            if n:
+                ts, cols = self._held_ts, self._held_cols
+                self._held_ts, self._held_cols = ts[:0], {}
+                self.buffered -= n
+                self.released += n
+                self._deliver(ts, cols)
             self.idle = True
-
-    def _release_locked(self) -> None:
-        if not self._chunks or self.wm is None:
-            return
-        if len(self._chunks) == 1:
-            ts, cols = self._chunks[0]
-        else:
-            ts = np.concatenate([c[0] for c in self._chunks])
-            names = list(self._chunks[0][1])
-            cols = {
-                k: np.concatenate([c[1][k] for c in self._chunks])
-                for k in names
-            }
-        order = np.argsort(ts, kind="stable")
-        ts = ts[order]
-        cols = {k: v[order] for k, v in cols.items()}
-        n = int(np.searchsorted(ts, self.wm, side="right"))
-        if n == 0:
-            self._chunks = [(ts, cols)]  # keep pre-sorted
-            return
-        rel_ts = ts[:n]
-        rel_cols = {k: v[:n] for k, v in cols.items()}
-        if n < ts.size:
-            self._chunks = [(ts[n:], {k: v[n:] for k, v in cols.items()})]
-        else:
-            self._chunks = []
-        self.buffered -= n
-        self.released += n
-        self._deliver(rel_ts, rel_cols)
 
     def describe(self) -> dict:
         with self._lock:
@@ -376,6 +452,8 @@ class ReorderTracker:
                 "peak_buffered": self.peak_buffered,
                 "released": self.released,
                 "late_total": self.late_total,
+                "offers": self.offers,
+                "offered": self.offered,
                 "idle": self.idle,
             }
 
@@ -456,9 +534,11 @@ class WatermarkRuntime:
     # -- late policies -------------------------------------------------------
 
     def _handle_late(self, stream_id, ts, cols, lateness) -> None:
-        hist = self.lateness[stream_id]
-        for v in lateness:
-            hist.record(int(v))
+        with stage("late", stream=stream_id, rows=int(len(ts))):
+            self._apply_late_policy(stream_id, ts, cols, lateness)
+
+    def _apply_late_policy(self, stream_id, ts, cols, lateness) -> None:
+        self.lateness[stream_id].record_many(lateness)
         meters = self.meters[stream_id]
         policy = self.cfg.late_policy
         if policy == "drop":

@@ -583,6 +583,16 @@ def _run_gated(head, sends, body=GATE_BODY, seen=None):
     return got, status, chunks
 
 
+@pytest.fixture
+def reads_of_any_size(monkeypatch):
+    """These chunks' buffers hold 256 rows, fewer than the least a read asks
+    for at a deployment's size: without that floor a short guess is short
+    here too."""
+    import siddhi_tpu.core.ingest as ingest
+
+    monkeypatch.setattr(ingest, "_LEAST_READ_ROWS", 1)
+
+
 @pytest.mark.parametrize(
     "sends, topups",
     [
@@ -596,7 +606,8 @@ def _run_gated(head, sends, body=GATE_BODY, seen=None):
         pytest.param([(256 * 3, 2), (256 * 3, 64)], (1, 3), id="short"),
     ],
 )
-def test_read_started_at_dispatch_delivers_what_per_batch_does(sends, topups):
+def test_read_started_at_dispatch_delivers_what_per_batch_does(
+        sends, topups, reads_of_any_size):
     """The sender starts each chunk's first read when it hands the chunk to
     the drain; whatever the prefix it asked for, the callback sees the rows
     of the per-batch path, in its order and grouping."""
@@ -819,7 +830,7 @@ WIDTHS = {
 
 
 @pytest.mark.parametrize("W", sorted(WIDTHS))
-def test_dense_read_delivers_rows_of_any_width(W):
+def test_dense_read_delivers_rows_of_any_width(W, reads_of_any_size):
     """Through the engine: first chunks (all rows asked for), a steady
     prefix, a prefix that undershoots (the top-up goes through the same
     program) and one that overshoots, for each row width: the per-batch

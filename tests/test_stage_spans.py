@@ -106,7 +106,13 @@ def _inside(child, parent) -> bool:
     return parent["t0"] <= child["t0"] and child["t1"] <= parent["t1"]
 
 
-def test_fused_path_spans(tmp_path):
+def test_fused_path_spans(tmp_path, monkeypatch):
+    # these chunks hold fewer rows than the least a read asks for at a
+    # deployment's size: without that floor the small guess below is short
+    # here too, and the second read's span (`readback`) is there to see
+    import siddhi_tpu.core.ingest as ingest
+
+    monkeypatch.setattr(ingest, "_LEAST_READ_ROWS", 1)
     # a callback slower than a chunk's dispatch backs the drain up, so the
     # sender meets the bounded queue (submit_wait) and the barrier
     mgr, rt, got = _deploy(slow_callback_s=0.004)
